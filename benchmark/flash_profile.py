@@ -35,8 +35,8 @@ def _time_chained(fn, args, flops, program=None):
 
     The body DEPENDS on the scan carry (q is perturbed by a zero that
     XLA cannot prove zero-valued at trace time), so the kernel cannot
-    be hoisted out of the loop; K=32 amortizes the ~50–90 ms relay
-    d2h fetch to ~2 ms which the null variant subtracts.
+    be hoisted out of the loop; K=32 amortizes the d2h fetch, which
+    the null variant subtracts.
 
     With telemetry enabled and a `program` name, the chained program's
     cost/memory analysis and best measured wall land in the

@@ -27,27 +27,35 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import jax
 import jax.numpy as jnp
 
-V, D, DFF, L, H = 32000, 1024, 4096, 12, 16
+SIZE = V, D, DFF, L, H = 32000, 1024, 4096, 12, 16
 STEPS, WARMUP = 10, 2
 
 
-def measure(T: int, B: int, dropout: float = 0.1):
+def build(T: int, B: int, dropout: float = 0.1, *, capture_hlo=False,
+          size=SIZE):
+    """The long-context LM, its Trainer and one fixed random batch,
+    through the public Gluon loop.  Returns a namespace with ``net``,
+    ``trainer`` and ``step()`` (one record/backward/step, returns the
+    loss).  `chip_smoke.py` drives the same builder."""
+    import types
+
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import autograd
-    from incubator_mxnet_tpu.callback import device_peak_flops
     from incubator_mxnet_tpu.gluon import Trainer
     from incubator_mxnet_tpu.gluon.block import HybridBlock
     from incubator_mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
     from incubator_mxnet_tpu.models.transformer import TransformerLM
     from incubator_mxnet_tpu.ndarray.ndarray import NDArray
 
+    v, d, dff, n_layers, heads = size
     mx.random.seed(0)
-    net = TransformerLM(vocab=V, units=D, hidden_size=DFF, num_layers=L,
-                        num_heads=H, max_len=T, dropout=dropout)
+    net = TransformerLM(vocab=v, units=d, hidden_size=dff,
+                        num_layers=n_layers, num_heads=heads, max_len=T,
+                        dropout=dropout)
     net.initialize()
     # materialize deferred shapes with a SHORT sequence: the params are
     # still f32 here, and an f32 flash kernel at T=8192 exceeds VMEM
-    net(NDArray(jnp.ones((B, 128), jnp.int32)))
+    net(NDArray(jnp.ones((B, min(T, 128)), jnp.int32)))
     net.cast("bfloat16")
 
     class LMWithLoss(HybridBlock):
@@ -68,9 +76,10 @@ def measure(T: int, B: int, dropout: float = 0.1):
     trainer = Trainer(model.collect_params(), "sgd",
                       {"learning_rate": 1e-3, "momentum": 0.9,
                        "multi_precision": True}, keep_grads=False)
+    trainer._capture_hlo = capture_hlo
     kx, ky = jax.random.split(jax.random.PRNGKey(0))
-    tokens = NDArray(jax.random.randint(kx, (B, T), 0, V, dtype=jnp.int32))
-    labels = NDArray(jax.random.randint(ky, (B, T), 0, V, dtype=jnp.int32))
+    tokens = NDArray(jax.random.randint(kx, (B, T), 0, v, dtype=jnp.int32))
+    labels = NDArray(jax.random.randint(ky, (B, T), 0, v, dtype=jnp.int32))
 
     def step():
         with autograd.record():
@@ -78,6 +87,15 @@ def measure(T: int, B: int, dropout: float = 0.1):
         loss.backward()
         trainer.step(1)
         return loss
+
+    return types.SimpleNamespace(net=net, trainer=trainer, step=step)
+
+
+def measure(T: int, B: int, dropout: float = 0.1):
+    from incubator_mxnet_tpu.callback import device_peak_flops
+
+    built = build(T, B, dropout)
+    net, step = built.net, built.step
 
     for _ in range(WARMUP):
         loss = step()

@@ -20,8 +20,8 @@ exactly as a real frontend would see.  Per-request timestamps come from
 the engine itself (Request.t_submit / t_first / t_done), so TTFT
 includes queueing delay and TPOT is pure decode cadence.
 
-Emits ONE BENCH-style JSON row (the repo convention, see bench.py /
-BENCH_r06.json): {"metric", "value", "unit", "detail"} where value is
+Emits ONE BENCH-style JSON row (the repo convention, see bench.py):
+{"metric", "value", "unit", "detail"} where value is
 GOODPUT UNDER SLO — decoded tok/s of requests that completed AND met
 both latency targets (``--ttft-slo-ms``, ``--tpot-slo-ms``; shed,
 evicted and SLO-violating work all count as zero, the number a

@@ -64,7 +64,4 @@ JAX_PLATFORMS=cpu python ci/resume_smoke.py
 echo "serving smoke: overloaded Poisson run — sheds, drains, 0 recompiles"
 JAX_PLATFORMS=cpu python ci/serving_smoke.py
 
-echo "baseline sync: BASELINE.md matches the committed BENCH round(s)"
-python tools/gen_baseline.py --check
-
 echo "lint gates: OK"

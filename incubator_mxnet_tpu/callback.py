@@ -67,64 +67,52 @@ class Speedometer:
                 f"Speed: {speed:.2f} samples/sec")
 
 
-_BF16_PEAKS = [  # chip-kind substring -> bf16 peak FLOP/s (canonical
-    ("v6e", 918e12), ("v6", 918e12),     # table — bench.py imports it)
+# Published per-chip peaks (Google Cloud TPU documentation, one page per
+# generation), keyed by a substring of jax's ``device_kind``.  The ONE
+# peaks table: bench.py, chip_smoke.py and telemetry.perf read it.
+_BF16_PEAKS = [  # bf16 peak FLOP/s
+    ("v6e", 918e12), ("v6", 918e12),
     ("v5p", 459e12),
     ("v5e", 197e12), ("v5 lite", 197e12), ("v5litepod", 197e12),
     ("v4", 275e12), ("v3", 123e12), ("v2", 45e12),
 ]
 
-_HBM_PEAKS = [  # chip-kind substring -> peak HBM bandwidth, bytes/s
-    ("v6e", 1640e9), ("v6", 1640e9),     # (telemetry.perf roofline
-    ("v5p", 2765e9),                     # denominator — same substring
-    ("v5e", 819e9), ("v5 lite", 819e9),  # matching as _BF16_PEAKS)
-    ("v5litepod", 819e9),
+_HBM_PEAKS = [  # peak HBM bandwidth, bytes/s
+    ("v6e", 1640e9), ("v6", 1640e9),
+    ("v5p", 2765e9),
+    ("v5e", 819e9), ("v5 lite", 819e9), ("v5litepod", 819e9),
     ("v4", 1228e9), ("v3", 900e9), ("v2", 700e9),
 ]
 
 
-def device_peak_hbm_bytes_per_s(device=None) -> float:
-    """Peak HBM bandwidth (bytes/s) for the (first) local accelerator.
-
-    The memory-side roofline denominator (telemetry/perf.py); an
-    unknown accelerator falls back to a nominal 100 GB/s — like
-    `device_peak_flops` the fallback keeps CPU smoke configurations
-    silent (bandwidth-bound fractions there are not meaningful).
-    """
+def _device_peak(table, what, device):
     import jax
 
     dev = device or jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu").lower()
-    for sub, peak in _HBM_PEAKS:
+    kind = getattr(dev, "device_kind", "").lower()
+    for sub, peak in table:
         if sub in kind:
             return peak
-    return 100e9  # nominal (CPU smoke / unknown chip)
+    raise ValueError(
+        f"no {what} peak known for device kind {dev.device_kind!r} "
+        f"(platform {dev.platform!r}): a utilization against a guessed "
+        f"peak is meaningless — add the chip to the table in callback.py")
+
+
+def device_peak_hbm_bytes_per_s(device=None) -> float:
+    """Peak HBM bandwidth (bytes/s) of the (first) local accelerator —
+    the memory-side roofline denominator (telemetry/perf.py).  Raises
+    `ValueError` for a device the table does not know, the CPU
+    included."""
+    return _device_peak(_HBM_PEAKS, "HBM bandwidth", device)
 
 
 def device_peak_flops(device=None) -> float:
-    """bf16 peak for the (first) local accelerator.
-
-    An UNKNOWN accelerator warns loudly and returns a nominal 1 TFLOP/s
-    — a silent wrong denominator would fabricate absurd MFU numbers on
-    exactly the benchmarks this meter exists for (VERDICT r2 Weak #9).
-    CPU stays silent (smoke-test configurations, MFU not meaningful).
-    """
-    import jax
-
-    dev = device or jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu").lower()
-    for sub, peak in _BF16_PEAKS:
-        if sub in kind:
-            return peak
-    if getattr(dev, "platform", "cpu") != "cpu" and "cpu" not in kind:
-        import warnings
-
-        warnings.warn(
-            f"device_peak_flops: unknown accelerator kind '{kind}' — "
-            f"using a nominal 1 TFLOP/s peak; MFU numbers will be "
-            f"meaningless. Add the chip to callback._BF16_PEAKS.",
-            stacklevel=2)
-    return 1e12  # nominal (CPU smoke / unknown chip after warning)
+    """bf16 peak FLOP/s of the (first) local accelerator.  Raises
+    `ValueError` for a device the table does not know, the CPU
+    included: a silent wrong denominator would fabricate MFU numbers on
+    exactly the benchmarks this figure exists for."""
+    return _device_peak(_BF16_PEAKS, "bf16 FLOP/s", device)
 
 
 class MFUMeter(Speedometer):

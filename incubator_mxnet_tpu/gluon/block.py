@@ -31,6 +31,7 @@ from .. import random as _random
 from ..base import MXNetError
 from ..engine import LazyRef
 from ..ndarray.ndarray import NDArray, raw, wrap
+from ..ops import mosaic
 from .parameter import DeferredInitializationError, Parameter, ParameterDict
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "nn_block_scope", "functionalize"]
@@ -606,6 +607,9 @@ class HybridBlock(Block):
         self._cached_param_order: Optional[List[Parameter]] = None
         self._aval_cache: "OrderedDict" = OrderedDict()
         self._cache_version = 0  # bumped on every _build_cache (Trainer key)
+        # the mesh `parallel.shard_params` placed the parameters on: the
+        # forward is traced with it in context (ops/mosaic.py)
+        self._mesh = None
         # _ChainedOp compositions by key
         self._chain_cache: "OrderedDict" = OrderedDict()
 
@@ -693,8 +697,9 @@ class HybridBlock(Block):
             # + a python counter: zero eager RNG dispatches per step.
             full = jax.tree_util.tree_unflatten(arg_tree, list(input_raws))
             key = jax.random.fold_in(rng_key, rng_ctr)
-            return apply_fn(train_raws, aux_raws, key, *full,
-                            training=training)
+            with mosaic.mesh_context(self._mesh):
+                return apply_fn(train_raws, aux_raws, key, *full,
+                                training=training)
 
         (self._cached_fn, self._cached_grad, self._cached_fwd_record,
          self._cached_bwd_record) = _program_jits(raw_fn)

@@ -72,7 +72,7 @@ def _sharded_loss(emb, w_shard, labels, *, axis_name, scale, m2, m3):
 def arcface_loss_sharded(emb, weight, labels, mesh: Mesh, scale=64.0,
                          margin_m2=0.5, margin_m3=0.0, axis_name: str = "model"):
     """Top-level: weight (C, D) sharded on classes over `axis_name`."""
-    from ..parallel.compat import shard_map
+    from jax import shard_map
 
     fn = shard_map(
         functools.partial(_sharded_loss, axis_name=axis_name, scale=scale,

@@ -213,8 +213,7 @@ class TransformerLM(HybridBlock):
         # models (max_len > _PE_TABLE_MAX) compute pe IN-PROGRAM
         # instead: the closed-over table would otherwise embed an
         # O(max_len*units) fp32 CONSTANT into every compiled program —
-        # at max_len=65536 that is 256 MB of HLO literal, which this
-        # sandbox's compile relay rejects outright (HTTP 413) and any
+        # at max_len=65536 that is 256 MB of HLO literal, which every
         # deployment pays in program size; sin/cos over the slice is
         # VPU noise under jit.
         self._pe = positional_encoding(max_len, units) \

@@ -62,7 +62,7 @@ ceil = _unary("ceil", jnp.ceil)
 round = _unary("round", jnp.round)
 rint = _unary("rint", jnp.rint)
 trunc = _unary("trunc", jnp.trunc)
-fix = _unary("fix", jnp.fix)
+fix = _unary("fix", jnp.trunc)
 negative = _unary("negative", jnp.negative)
 sigmoid = _unary("sigmoid", jax.nn.sigmoid)
 hard_sigmoid = _unary("hard_sigmoid", lambda x: jnp.clip(0.2 * x + 0.5, 0.0, 1.0))

@@ -15,6 +15,8 @@ Design (per /opt/skills/guides/pallas_guide.md):
   same way so ragged Tk works.
 - `interpret=True` on CPU so the same kernel runs in the test suite
   (SURVEY.md §4: CPU is the reference implementation).
+- batch and heads are independent: in a program traced over a mesh the
+  kernels run per shard of (batch, heads) (`ops/mosaic.py`).
 
 `attention_reference` is the jnp oracle used by the numeric tests.
 """
@@ -26,6 +28,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from . import mosaic
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "attention_reference", "attention_small_t"]
@@ -505,6 +510,14 @@ def attention_bthd(q, k, v, scale: Optional[float] = None):
     return out.astype(q.dtype)
 
 
+def _per_shard(core, *arrays, **static):
+    """Run a kernel core ((B, H, ...) arrays in and out) per shard of
+    batch and heads."""
+    spec = P(*mosaic.split(arrays[0].shape[:2]))
+    return mosaic.per_shard(functools.partial(core, **static), spec, spec)(
+        *arrays)
+
+
 def _use_pallas(platform, tq, tk, force_reference: bool):
     if force_reference:
         return False
@@ -536,7 +549,8 @@ def _dispatch_fwd(q, k, v, causal, scale, block_q, block_k,
         interp = platform == "cpu"
         bq = min(block_q, 64) if interp else block_q
         bk = min(block_k, 64) if interp else block_k
-        return _flash_core(q, k, v, causal, scale, bq, bk, interp)
+        return _per_shard(_flash_core, q, k, v, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, interpret=interp)
     if not force_reference and _use_small_t(platform, q.shape[2],
                                             k.shape[2], q.dtype):
         # sub-crossover fused path (lse=None → exact reference backward)
@@ -598,8 +612,9 @@ def _flash_bwd(causal, scale, block_q, block_k, force_reference, res, do):
     bq = min(block_q, 64) if interp else max(block_q, 512)
     bk = min(block_k, 64) if interp else max(block_k, 512)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    return _flash_bwd_core(q, k, v, do, lse, delta, causal, scale, bq, bk,
-                           interp)
+    return _per_shard(_flash_bwd_core, q, k, v, do, lse, delta,
+                      causal=causal, scale=scale, block_q=bq, block_k=bk,
+                      interpret=interp)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -639,7 +654,8 @@ def _flash_lse(q, k, v, causal, scale, block_q, block_k, force_reference):
         interp = platform == "cpu"
         bq = min(block_q, 64) if interp else block_q
         bk = min(block_k, 64) if interp else block_k
-        return _flash_core(q, k, v, causal, scale, bq, bk, interp)
+        return _per_shard(_flash_core, q, k, v, causal=causal, scale=scale,
+                          block_q=bq, block_k=bk, interpret=interp)
     # reference path: ONE score computation yields both out and lse
     return _reference_attention_lse(q, k, v, causal, scale)
 
@@ -662,8 +678,9 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, force_reference, res, cots):
         interp = platform == "cpu"
         bq = min(block_q, 64) if interp else max(block_q, 512)
         bk = min(block_k, 64) if interp else max(block_k, 512)
-        return _flash_bwd_core(q, k, v, do, lse, delta, causal, scale, bq, bk,
-                               interp)
+        return _per_shard(_flash_bwd_core, q, k, v, do, lse, delta,
+                          causal=causal, scale=scale, block_q=bq, block_k=bk,
+                          interpret=interp)
     return _flash_bwd_reference(q, k, v, do, causal, scale, delta=delta)
 
 

@@ -8,10 +8,10 @@ module is the kernel-speed replacement (ISSUE 15, the vLLM
 PagedAttention recipe on TPU):
 
 * ``paged_attention`` with ``impl="pallas"`` — a Pallas kernel with
-  grid ``(lane, head, block)``: the KV walk is the innermost grid axis
-  and the index map reads each page DIRECTLY from the pool via the
-  lane's block-table row (scalar-prefetched, the TPU paged-attention
-  idiom) — no dense gather, nothing ``(B, H, max_seq_len)``-shaped is
+  grid ``(lane, block)``, every head of a page in one step: the KV walk
+  is the innermost grid axis and the index map reads each page DIRECTLY
+  from the pool via the lane's block-table row (scalar-prefetched, the
+  TPU paged-attention idiom) — no dense gather, nothing ``(B, H, max_seq_len)``-shaped is
   ever materialized.  Online-softmax state (m, l, acc) lives in VMEM
   scratch exactly like `flash_attention._fa_kernel_streamed`, and dead
   blocks (``block > pos // block_size``) skip their math the same way
@@ -30,7 +30,11 @@ roughly doubles resident sequences per HBM byte.
 The pallas and dense impls agree to fp32 roundoff (online vs full-width
 softmax re-associate the same sums), NOT bitwise — dispatch therefore
 never mixes impls within one engine: tokens are reproducible per
-(engine config), which is what the eviction contract needs.
+(engine config), which is what the eviction contract needs.  On the TPU
+that holds when both are traced under matmul precision "highest"
+(2e-6 on a v5e); at the default precision the MXU rounds each impl's
+fp32 softmax weights to bf16, and they agree to that rounding — within
+2^-8 of the largest |v|, below the bf16 rounding of the output itself.
 """
 from __future__ import annotations
 
@@ -40,6 +44,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from . import mosaic
 
 __all__ = ["paged_attention", "paged_attention_dense", "default_impl"]
 
@@ -88,9 +95,17 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
 
 def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   bs, kv_quant):
-    """One grid step = one (lane, head, page).  The page arrived via
-    the block-table index map; this body does the online-softmax
-    update, `pl.when`-skipping pages past the lane's length bound."""
+    """One grid step = one (lane, page), all heads at once.  The page
+    arrived via the block-table index map; this body does the
+    online-softmax update, `pl.when`-skipping pages past the lane's
+    length bound.
+
+    Blocks span the whole head axis so their trailing two dims equal
+    the array's (Mosaic's block-shape rule), and both dots are plain
+    2-D MXU matmuls: the page is flattened to ``(H*bs, D)`` and every
+    head scores against every head's slots, then slots of OTHER heads
+    are masked like future positions — ``exp`` underflows them to
+    exactly 0.0, so each head's row reduces over its own page only."""
     from jax.experimental import pallas as pl
 
     if kv_quant:
@@ -98,8 +113,8 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
     t = pos_ref[b]
 
     @pl.when(j == 0)
@@ -115,94 +130,80 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # by emit time.
     @pl.when(j <= t // bs)
     def _update():
-        d = q_ref.shape[-1]
-        q = q_ref[0, 0, :].astype(jnp.float32)          # (D,)
+        h, d = q_ref.shape[-2:]
+        q = q_ref[0].astype(jnp.float32)                # (H, D)
         if kv_quant:
-            k = _dequant(k_ref[0, 0], sk_ref[0, 0])     # (bs, D) f32
-            v = _dequant(v_ref[0, 0], sv_ref[0, 0])
+            k = _dequant(k_ref[0], sk_ref[0])           # (H, bs, D) f32
+            v = _dequant(v_ref[0], sv_ref[0])
         else:
-            k = k_ref[0, 0].astype(jnp.float32)
-            v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(k, q, preferred_element_type=jnp.float32) \
-            / math.sqrt(d)                              # (bs,)
-        kpos = j * bs + jax.lax.iota(jnp.int32, bs)
-        s = jnp.where(kpos <= t, s, jnp.finfo(jnp.float32).min)
-        m_prev, l_prev = m_ref[0, 0], l_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
+            k = k_ref[0].astype(jnp.float32)
+            v = v_ref[0].astype(jnp.float32)
+        k = k.reshape(h * bs, d)
+        v = v.reshape(h * bs, d)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) \
+            / math.sqrt(d)                              # (H, H*bs)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # column c holds slot c - row*bs of head c // bs
+        slot = col - row * bs
+        own = jnp.logical_and(slot >= 0, slot < bs)
+        s = jnp.where(jnp.logical_and(own, j * bs + slot <= t), s,
+                      jnp.finfo(jnp.float32).min)
+        m_prev, l_prev = m_ref[...], l_ref[...]         # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
-        acc_ref[0, :] = acc_ref[0, :] * alpha \
+        acc_ref[...] = acc_ref[...] * alpha \
             + jnp.dot(p, v, preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
-        l_ref[0, 0] = alpha * l_prev + jnp.sum(p)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
     @pl.when(j == nb - 1)
     def _emit():
-        o_ref[0, 0, :] = (acc_ref[0, :] / l_ref[0, 0]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_core(q, pool_k, pool_v, tables, pos, interpret):
+def _paged_call(q, pools, tables, pos, interpret):
+    """Shared pallas_call: ``pools`` is (pool_k, pool_v) or, for int8
+    pages, (pool_k, pool_v, scale_k, scale_v)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    bs = pool_k.shape[2]
+    bs = pools[0].shape[2]
     nbps = tables.shape[1]
-    kernel = functools.partial(_paged_kernel, bs=bs, kv_quant=False)
+    kv_quant = len(pools) == 4
+    kernel = functools.partial(_paged_kernel, bs=bs, kv_quant=kv_quant)
+    lane = pl.BlockSpec((1, H, D), lambda b, j, t, p: (b, 0, 0))
+    page = pl.BlockSpec((1, H, bs, D), lambda b, j, t, p: (t[b, j], 0, 0, 0))
+    page_scale = pl.BlockSpec((1, H, bs), lambda b, j, t, p: (t[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, nbps),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, j, t, p: (b, h, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j, t, p: (b, h, 0)),
-        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)],
+        grid=(B, nbps),
+        in_specs=[lane, page, page] + [page_scale] * (2 * kv_quant),
+        out_specs=lane,
+        scratch_shapes=[pltpu.VMEM((H, D), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32)],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(tables, pos, q, pool_k, pool_v)
+    )(tables, pos, q, *pools)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_core(q, pool_k, pool_v, tables, pos, interpret):
+    return _paged_call(q, (pool_k, pool_v), tables, pos, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_core_q8(q, pool_k, pool_v, scale_k, scale_v, tables, pos,
                    interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    bs = pool_k.shape[2]
-    nbps = tables.shape[1]
-    kernel = functools.partial(_paged_kernel, bs=bs, kv_quant=True)
-    page = pl.BlockSpec((1, 1, bs, D),
-                        lambda b, h, j, t, p: (t[b, j], h, 0, 0))
-    page_scale = pl.BlockSpec((1, 1, bs),
-                              lambda b, h, j, t, p: (t[b, j], h, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, nbps),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, j, t, p: (b, h, 0)),
-            page, page, page_scale, page_scale,
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j, t, p: (b, h, 0)),
-        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
-    )(tables, pos, q, pool_k, pool_v, scale_k, scale_v)
+    return _paged_call(q, (pool_k, pool_v, scale_k, scale_v), tables, pos,
+                       interpret)
 
 
 def paged_attention(q, pool_k, pool_v, tables, pos, *,
@@ -227,7 +228,13 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
         raise ValueError(f"paged_attention impl {impl!r} (pallas|dense)")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    if scale_k is not None:
-        return _paged_core_q8(q, pool_k, pool_v, scale_k, scale_v,
-                              tables, pos, interpret)
-    return _paged_core(q, pool_k, pool_v, tables, pos, interpret)
+    # lanes and heads are independent: per shard of both under a mesh
+    # (ops/mosaic.py); every shard walks the whole pool of its heads
+    lanes, heads = mosaic.split(q.shape[:2])
+    lane, pool = P(lanes, heads), P(None, heads)
+    pools = (pool_k, pool_v) if scale_k is None \
+        else (pool_k, pool_v, scale_k, scale_v)
+    core = functools.partial(_paged_core if scale_k is None
+                             else _paged_core_q8, interpret=interpret)
+    in_specs = (lane,) + (pool,) * len(pools) + (P(lanes), P(lanes))
+    return mosaic.per_shard(core, in_specs, lane)(q, *pools, tables, pos)

@@ -220,7 +220,7 @@ def make_train_step(mesh: Mesh, cfg: HybridConfig):
     step(params, x, y) -> (new_params, loss). Params must be placed with
     `shard_params_to_mesh`; x,y are (B, T) int32 global arrays with
     B % (data·microbatches) == 0 and T % seq == 0."""
-    from .compat import shard_map
+    from jax import shard_map
 
     specs = param_specs(cfg)
     if cfg.n_stages != mesh.shape["pipe"]:

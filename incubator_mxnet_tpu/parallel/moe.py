@@ -96,7 +96,7 @@ def moe_layer_sharded(x, router_w, expert_ws, mesh: Mesh,
                       axis_name: str = "expert"):
     """Top-level: x (B, T, D) replicated batch; expert weights sharded
     on their leading (expert) dim."""
-    from .compat import shard_map
+    from jax import shard_map
 
     B, T, D = x.shape
     xf = x.reshape(B * T, D)

@@ -51,14 +51,8 @@ __all__ = ["pipeline_forward", "pipeline_apply", "pipeline_train_1f1b"]
 
 
 def _vma_of(z) -> set:
-    """Varying-manual-axes of ``z`` under shard_map, or the empty set on
-    jax versions without ``jax.typeof``/vma tracking (< 0.6 — there the
-    check_rep system owns replication discipline and no explicit pcast
-    is needed, see parallel/compat.py)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return set()
-    return set(getattr(typeof(z), "vma", ()))
+    """Varying-manual-axes of ``z`` under shard_map."""
+    return set(jax.typeof(z).vma)
 
 
 def _record_schedule(schedule: str, n_stages: int, n_micro: int) -> None:
@@ -167,7 +161,7 @@ def pipeline_apply(stage_fn: Callable, all_stage_params, x, mesh: Mesh,
     size (module docstring).  For PP×TP TRAINING use
     `pipeline_train_1f1b`.
     """
-    from .compat import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     if B % num_microbatches:
@@ -250,11 +244,6 @@ def _1f1b_device(stage_fn, loss_fn, params, xm, targets, axis_name,
         return _vma_of(z)
 
     def cast_to(z, target):
-        # no vma system (jax < 0.6): the legacy check_rep machinery
-        # tracks replication itself — explicit pcasts neither exist nor
-        # are needed for correct psum transposition there
-        if not hasattr(lax, "pcast"):
-            return z
         need = tuple(a for a in sorted(set(target) - _vma(z)))
         return lax.pcast(z, need, to="varying") if need else z
 
@@ -432,7 +421,7 @@ def pipeline_train_1f1b(stage_fn: Callable, loss_fn: Callable,
     pipeline microbatch-by-microbatch (or pre-shard x along a data axis
     composed with pipe) if that bites.
     """
-    from .compat import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     M = num_microbatches

@@ -152,7 +152,7 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, causal: bool = False,
     replicate across the data axis inside the attention region).  The
     ring collectives only span `axis_name`, so the data axis rides
     along for free."""
-    from .compat import shard_map
+    from jax import shard_map
 
     b = data_axis if data_axis and data_axis in mesh.axis_names \
         and mesh.shape[data_axis] > 1 else None

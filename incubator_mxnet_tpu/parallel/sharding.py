@@ -256,6 +256,11 @@ def shard_params(block, mesh: Mesh, rules=None, dp_axis: Optional[str] = None,
         log.info("shard_params: expert=%d — all_to_all dispatch enabled "
                  "on %d MoE block(s)", mesh.shape["expert"],
                  report.expert_parallel)
+    if hasattr(block, "_invalidate_cached_program"):
+        # the forward is re-traced with the mesh in context, so that its
+        # Pallas kernels are emitted per shard (ops/mosaic.py)
+        block._mesh = mesh
+        block._invalidate_cached_program()
     log.info(report.summary())
     return report
 

@@ -50,7 +50,7 @@ def ulysses_attention(q, k, v, axis_name: str = "seq", causal: bool = False,
 def ulysses_attention_sharded(q, k, v, mesh: Mesh, causal: bool = False,
                               scale: Optional[float] = None, axis_name: str = "seq",
                               attn_fn: Optional[Callable] = None):
-    from .compat import shard_map
+    from jax import shard_map
 
     spec = P(None, None, axis_name, None)
     fn = shard_map(

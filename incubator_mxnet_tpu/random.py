@@ -109,8 +109,7 @@ def step_key():
     The base key array is STABLE across calls (no device dispatch per
     step); the python counter advances and is folded into the key
     inside the jitted program — fresh randomness per step with zero
-    eager RNG ops (the r1 bench's per-step `split` cost ~3ms/step of
-    relay dispatch).
+    eager RNG ops.
 
     Provider-aware (r5 fix): when a TraceKeyProvider is active we are
     INSIDE another cached program's trace (a hybridized child called

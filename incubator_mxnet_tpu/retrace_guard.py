@@ -86,6 +86,15 @@ class RetraceError(MXNetError):
     """A watched callable recompiled more often than its budget allows."""
 
 
+def _bare_name(logged: str) -> str:
+    """pxla logs the callable wrapped in its transform, ``jit(step)`` or
+    ``pmap(step)``; sinks and PROGRAM_NAMES key on the bare ``step``."""
+    for wrapper in ("jit(", "pmap("):
+        if logged.startswith(wrapper) and logged.endswith(")"):
+            return logged[len(wrapper):-1]
+    return logged
+
+
 class _CompileLogHandler(logging.Handler):
     """Logging handler forwarding compile events to monitor sinks."""
 
@@ -98,7 +107,7 @@ class _CompileLogHandler(logging.Handler):
             if (isinstance(record.msg, str)
                     and record.msg.startswith(_COMPILE_MSG_PREFIX)
                     and record.args):
-                self._monitor._dispatch(str(record.args[0]))
+                self._monitor._dispatch(_bare_name(str(record.args[0])))
         except Exception:
             # never let accounting break the compile it observes
             pass

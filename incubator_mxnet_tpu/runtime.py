@@ -113,75 +113,23 @@ def set_nan_guard(enabled: bool = True):
 
 
 # --------------------------------------------------------------------- #
-# XLA latency-hiding scheduler / async-collective enablement
-# (ISSUE 5 tentpole: the bucketed ZeRO exchange only overlaps if the
-# compiler is allowed to float collectives over the backward matmuls)
+# persistent compilation cache
 # --------------------------------------------------------------------- #
-# Per-platform XLA flags.  TPU: the latency-hiding scheduler plus the
-# async-collective fusion passes that split reduce-scatter/all-gather
-# into start/done pairs so independent compute schedules between them.
-# CPU (where the virtual-device parity/dryrun suites run) has no async
-# collectives — its memory-minimizing list scheduler already interleaves
-# the bucketed collectives into the backward schedule, so no flags.
-_OVERLAP_XLA_FLAGS = {
-    "tpu": (
-        "--xla_tpu_enable_latency_hiding_scheduler=true",
-        "--xla_tpu_enable_async_collective_fusion=true",
-        "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    ),
-    "gpu": ("--xla_gpu_enable_latency_hiding_scheduler=true",),
-    "cpu": (),
-}
-
-
-def collective_overlap_flags(platform: str = None) -> tuple:
-    """The XLA flags that let collectives overlap compute on
-    ``platform`` (inferred from the environment when None — never by
-    initializing a backend)."""
-    return _OVERLAP_XLA_FLAGS.get(platform or _infer_platform(), ())
-
-
-def _infer_platform() -> str:
-    """Best-effort platform guess WITHOUT touching the jax backend
-    (initializing it would make flag changes too late by definition)."""
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at ``<checkout>/.jax_cache``
+    unless ``JAX_COMPILATION_CACHE_DIR`` already places it from outside
+    (then nothing is set in code).  The path is part of the cache key's
+    surroundings, so it is fixed and normalised — never temporary, pid-
+    or time-derived.  Returns the directory in use."""
     import os
 
-    plats = os.environ.get("JAX_PLATFORMS", "").lower()
-    if "cpu" in plats.split(","):
-        return "cpu"
-    if "tpu" in plats or any(k.startswith("TPU_") for k in os.environ):
-        return "tpu"
-    return "cpu"
+    import jax
 
-
-def _backend_initialized() -> bool:
-    import sys
-
-    xb = sys.modules.get("jax._src.xla_bridge")
-    return bool(getattr(xb, "_backends", None))
-
-
-def enable_collective_overlap(platform: str = None) -> list:
-    """Append the platform's overlap flags to ``XLA_FLAGS`` (deduped).
-
-    Must run BEFORE the first jax computation initializes the backend —
-    call it at program start (bench.py does), or export the flags in the
-    launcher.  Returns the list of flags actually added: empty when the
-    platform needs none, every flag is already present, the backend is
-    already live (too late — a warning is NOT raised because the Trainer
-    invokes this opportunistically per build), or ``MXTPU_OVERLAP_FLAGS=0``
-    kills the feature.
-    """
-    import os
-
-    if os.environ.get("MXTPU_OVERLAP_FLAGS", "").strip() == "0":
-        return []
-    flags = collective_overlap_flags(platform)
-    if not flags or _backend_initialized():
-        return []
-    cur = os.environ.get("XLA_FLAGS", "")
-    have = set(cur.split())
-    add = [f for f in flags if f not in have]
-    if add:
-        os.environ["XLA_FLAGS"] = (cur + " " + " ".join(add)).strip()
-    return add
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -117,26 +117,26 @@ def test_amp_wraps_contrib_ops():
     assert nd.contrib.interleaved_matmul_selfatt_qk is orig
 
 
-def test_device_peak_flops_warns_on_unknown_accel():
-    import warnings
-
-    from incubator_mxnet_tpu.callback import device_peak_flops
+def test_device_peak_flops_raises_on_unknown_device():
+    from incubator_mxnet_tpu.callback import (device_peak_flops,
+                                              device_peak_hbm_bytes_per_s)
 
     class FakeDev:
         device_kind = "QuantumAccel 9000"
         platform = "quantum"
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        peak = device_peak_flops(FakeDev())
-    assert peak == 1e12
-    assert any("unknown accelerator" in str(x.message) for x in w)
-
     class FakeCPU:
         device_kind = "cpu"
         platform = "cpu"
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        device_peak_flops(FakeCPU())
-    assert not w  # CPU stays silent
+    class FakeV5e:
+        device_kind = "TPU v5 lite"
+        platform = "tpu"
+
+    for dev in (FakeDev(), FakeCPU()):  # no nominal figure, no warning
+        with pytest.raises(ValueError, match="peak known"):
+            device_peak_flops(dev)
+        with pytest.raises(ValueError, match="peak known"):
+            device_peak_hbm_bytes_per_s(dev)
+    assert device_peak_flops(FakeV5e()) == 197e12
+    assert device_peak_hbm_bytes_per_s(FakeV5e()) == 819e9

@@ -1,0 +1,249 @@
+"""Ask the TPU v5e compiler, without a chip, whether it accepts every
+Pallas kernel on the two paths `chip_smoke.py` drives — at the smoke's
+real widths (on-chip-measurement guide §2, rehearsal 3).
+
+Interpret mode cannot see what Mosaic refuses (block shapes that break
+the (8, 128) rule, unsupported layout changes, VMEM overflow); these
+compiles can, and cost no chip time.  Nothing here runs: a pass means
+"compiles for v5e", never "measured".
+
+The same is asked for the four chips of one host: a Mosaic kernel cannot
+be partitioned automatically, so in a program traced over a mesh
+(`ops/mosaic.py`) every kernel has to arrive at the compiler inside a
+`shard_map`.
+
+The topology is described inside a module-scoped fixture so that only
+the xdist worker that owns this file loads the TPU library; everything
+built from it (shardings, meshes, shapes) is built in the test.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from incubator_mxnet_tpu.ops import mosaic
+
+# ops/__init__ re-exports functions under the module names
+_flash = importlib.import_module("incubator_mxnet_tpu.ops.flash_attention")
+_paged = importlib.import_module("incubator_mxnet_tpu.ops.paged_attention")
+_xent = importlib.import_module("incubator_mxnet_tpu.ops.xent_kernel")
+_dropout = importlib.import_module("incubator_mxnet_tpu.ops.dropout_kernel")
+
+bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e:2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises when it is absent
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable is written to the persistent cache
+    # but cannot be read back without a chip: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e[0])
+
+
+# serve phase widths (H=16, D=64, block 16, 512 positions), batch 32
+_B, _H, _D, _BS, _NBPS = 32, 16, 64, 16, 32
+_NB = _B * _NBPS + 1
+_PAGE = (_NB, _H, _BS, _D)
+
+
+def _paged_bf16(S):
+    return _paged._paged_core.lower(
+        S((_B, _H, _D), bf16), S(_PAGE, bf16), S(_PAGE, bf16),
+        S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
+
+
+def _paged_int8(S):
+    return _paged._paged_core_q8.lower(
+        S((_B, _H, _D), bf16), S(_PAGE, i8), S(_PAGE, i8),
+        S(_PAGE[:3], f32), S(_PAGE[:3], f32),
+        S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
+
+
+def _flash_fwd(T, bk):
+    def lower(S):
+        x = S((2, 16, T, 64), bf16)
+        return _flash._flash_core.lower(x, x, x, True, 0.125, 512, bk, False)
+    return lower
+
+
+def _flash_bwd(S):
+    x = S((2, 16, 2048, 64), bf16)
+    row = S((2, 16, 2048), f32)
+    return _flash._flash_bwd_core.lower(x, x, x, x, row, row, True, 0.125,
+                                        512, 512, False)
+
+
+def _xent_fwd(V):
+    return lambda S: jax.jit(_xent._pallas_fwd, static_argnums=(1, 2)).lower(
+        S((4096, V), bf16), False, False)
+
+
+def _xent_bwd(V):
+    return lambda S: jax.jit(_xent._pallas_bwd, static_argnums=(4,)).lower(
+        S((4096, V), bf16), S((4096,), i32), S((4096,), f32),
+        S((4096,), f32), False)
+
+
+def _dropout_mask(cols):
+    def lower(S):
+        br, bc = _dropout._tile_geometry(4096, cols, 2)   # bf16 activations
+        fn = jax.jit(_dropout._kernel2d, static_argnums=(0, 4, 5, 6, 7, 8))
+        return fn.lower((4096, cols), S((1,), i32), S((), i32), S((), i32),
+                        0.1, br, bc, cols // bc, False)
+    return lower
+
+
+# (lowering, number of Mosaic kernels the program must contain)
+_KERNELS = {
+    "paged_bf16": (_paged_bf16, 1),
+    "paged_int8": (_paged_int8, 1),
+    # T=2048: K/V stay VMEM-resident; T=16384: streamed over the grid
+    "flash_fwd_resident_T2048": (_flash_fwd(2048, 512), 1),
+    "flash_fwd_streamed_T16384": (_flash_fwd(16384, 1024), 1),
+    "flash_bwd_dkdv_dq_T2048": (_flash_bwd, 2),
+    "xent_fwd_V30522": (_xent_fwd(30522), 1),
+    "xent_bwd_V30522": (_xent_bwd(30522), 1),
+    "xent_fwd_V32000": (_xent_fwd(32000), 1),
+    "xent_bwd_V32000": (_xent_bwd(32000), 1),
+    "dropout_mask_4096x1024": (_dropout_mask(1024), 1),
+    "dropout_mask_4096x4096": (_dropout_mask(4096), 1),
+}
+
+
+@pytest.mark.parametrize("name", list(_KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    lower, n_kernels = _KERNELS[name]
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = lower(S).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= n_kernels, name
+
+
+# --- the same kernels in a program traced over the 2x2 mesh ------------- #
+def _mesh_xent(x, labels):
+    def loss(x):
+        return jnp.mean(_xent.fused_sparse_xent(x, labels))
+    return jax.value_and_grad(loss)(x)
+
+
+def _mesh_flash(q):
+    def loss(q):
+        return jnp.sum(_flash.flash_attention(q, q, q, causal=True)
+                       .astype(f32))
+    return jax.value_and_grad(loss)(q)
+
+
+def _mesh_dropout(x, seed):
+    return _dropout.fused_dropout(x, seed, 0.1)
+
+
+def _mesh_paged(q, pool, tables, pos):
+    return _paged.paged_attention(q, pool, pool, tables, pos, impl="pallas")
+
+
+# (function, (shape, dtype, spec) per argument, Mosaic kernels it must hold)
+_MESH_PROGRAMS = {
+    # BERT-large MLM logits, vocab-sharded as the TP rules leave them
+    "xent_fwd_bwd_V30522": (_mesh_xent, [((4096, 30522), bf16,
+                                          P("data", "model")),
+                                         ((4096,), i32, P("data"))], 2),
+    "dropout_mask_4096x4096": (_mesh_dropout, [((4096, 4096), bf16,
+                                                P("data", "model")),
+                                               ((1,), i32, P())], 1),
+    "flash_fwd_bwd_T2048": (_mesh_flash, [((8, 16, 2048, 64), bf16,
+                                           P("data", "model"))], 3),
+    "paged_bf16": (_mesh_paged, [((_B, _H, _D), bf16, P()),
+                                 (_PAGE, bf16, P()),
+                                 ((_B, _NBPS), i32, P()),
+                                 ((_B,), i32, P())], 1),
+}
+
+
+@pytest.mark.parametrize("name", list(_MESH_PROGRAMS))
+def test_kernel_compiles_per_shard_for_four_v5e(v5e, monkeypatch, name):
+    fn, args, n_kernels = _MESH_PROGRAMS[name]
+    mesh = Mesh(onp.array(v5e).reshape(2, 2), ("data", "model"))
+    # the kernel gates ask jax for the backend and would take their CPU
+    # branch here: steer them, in the test only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def traced(*a):
+        with mosaic.mesh_context(mesh):
+            return fn(*a)
+
+    avals = [jax.ShapeDtypeStruct(shape, dtype,
+                                  sharding=NamedSharding(mesh, spec))
+             for shape, dtype, spec in args]
+    hlo = jax.jit(traced).lower(*avals).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= n_kernels, name
+    assert "CustomSPMDPartitioning" not in hlo
+
+
+def test_kernels_compile_inside_a_callers_manual_region(v5e, monkeypatch):
+    """The ZeRO-1 explicit tier's shape of things: the step runs inside
+    the Trainer's own `shard_map` over `data`, no mesh is put in context,
+    and each shard's dropout and cross-entropy kernels are called as they
+    are."""
+    mesh = Mesh(onp.array(v5e), ("data",))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def body(x, labels, seed):
+        def loss(x):
+            x = _dropout.fused_dropout(x, seed, 0.1)
+            return jnp.mean(_xent.fused_sparse_xent(x, labels))
+        return jax.lax.pmean(jax.grad(loss)(x), "data")
+
+    step = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data"), P()),
+                         out_specs=P("data"), check_vma=False)
+    avals = [jax.ShapeDtypeStruct(shape, dtype,
+                                  sharding=NamedSharding(mesh, spec))
+             for shape, dtype, spec in [((4096, 30522), bf16, P("data")),
+                                        ((4096,), i32, P("data")),
+                                        ((1,), i32, P())]]
+    hlo = jax.jit(step).lower(*avals).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3      # mask, xent fwd, xent bwd
+
+
+def test_int8_dot_moves_fewer_bytes_than_float(one_chip):
+    """The ordering the int8 decode programs rely on: the TPU program of
+    an int8-weight mixed dot is charged fewer bytes than the bf16 dot of
+    the same shape.  Asked of the v5e compiler, not of XLA:CPU — the CPU
+    backend upcasts the int8 operand into a temporary and charges that
+    too, which says nothing about the accelerator program."""
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def bytes_accessed(wdtype):
+        x = jax.ShapeDtypeStruct((8, 256), bf16, sharding=one_chip)
+        w = jax.ShapeDtypeStruct((256, 256), wdtype, sharding=one_chip)
+        cost = jax.jit(dot).lower(x, w).compile().cost_analysis()
+        return cost["bytes accessed"]
+
+    assert bytes_accessed(i8) < bytes_accessed(bf16)

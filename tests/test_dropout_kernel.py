@@ -81,65 +81,44 @@ def test_nd_dropout_routes_and_backprops():
     onp.testing.assert_array_equal(yv != 0, g != 0)
 
 
-def test_partition_rule_keeps_row_sharding():
-    """Pin that the partition rule does NOT fall back to replication
-    for ordinary activation shapes on power-of-two row shardings — the
-    r4 review found the first tile geometry silently replicated."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def test_mask_keeps_row_sharding(mesh8):
+    """Pin that the mask is NOT drawn replicated for ordinary activation
+    shapes on power-of-two row shardings — the r4 review found the first
+    tile geometry silently replicated."""
+    from incubator_mxnet_tpu.ops import dropout_kernel as dk, mosaic
 
-    from incubator_mxnet_tpu.ops import dropout_kernel as dk
-    from incubator_mxnet_tpu.parallel import create_mesh
-
-    mesh = create_mesh(data=8)
     # 4800 = 2^5*3*5^2: br must come from divisors of R/8 (600), not R,
-    # or the rule silently replicates (the r4 review's counterexample)
-    for R, Cl in [(4096, 1024), (64, 256), (128, 384), (512, 1024),
-                  (4800, 512), (33280, 1024)]:
-        br, bc = dk._tile_geometry(R, Cl if Cl % 128 == 0 else Cl + (-Cl) % 128,
-                                   4)
-        x_info = jax.ShapeDtypeStruct(
-            (R, Cl), jnp.float32,
-            sharding=NamedSharding(mesh, P("data", None)))
-        s_info = jax.ShapeDtypeStruct(
-            (1,), jnp.int32, sharding=NamedSharding(mesh, P(None)))
-        ncb = (Cl + (-Cl) % 128) // bc
-        _, _, out_sh, arg_shs = dk._dp2d_partition(
-            0.4, br, bc, ncb, mesh, (x_info, s_info), x_info)
-        assert out_sh.spec[0] == "data", (R, Cl, br, out_sh.spec)
-        assert arg_shs[0].spec[0] == "data", (R, Cl, br)
+    # or the rows silently stay whole (the r4 review's counterexample)
+    with mosaic.mesh_context(mesh8):
+        for R, Cl in [(4096, 1024), (64, 256), (128, 384), (512, 1024),
+                      (4800, 512), (33280, 1024)]:
+            Clp = Cl + (-Cl) % 128
+            br, bc = dk._tile_geometry(R, Clp, 4)
+            assert mosaic.split((R, Clp), (br, bc)) == ("data", None), \
+                (R, Cl, br, bc)
 
 
-def test_partition_rule_keeps_col_sharding():
-    """Model-dim (tensor-parallel) shardings must stay sharded too —
-    forcing column replication would all-gather every dropout call on
-    TP meshes (r4 review finding)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def test_mask_keeps_col_sharding(mesh42):
+    """Model-dim (tensor-parallel) activations get a column-sharded mask
+    too — a row-only mask would be resharded at every dropout call on TP
+    meshes (r4 review finding)."""
+    from incubator_mxnet_tpu.ops import dropout_kernel as dk, mosaic
 
-    from incubator_mxnet_tpu.ops import dropout_kernel as dk
-    from incubator_mxnet_tpu.parallel import create_mesh
-
-    mesh = create_mesh(data=4, model=2)
     # (128, 384) CANNOT col-shard 2-way (192 per shard has no 128-lane
-    # tile) — the rule must fall back to col replication there, sharded
-    # rows intact
-    for R, Cl, want in [(4096, 1024, P("data", "model")),
-                        (256, 512, P("data", "model")),
-                        (128, 384, P("data", None))]:
-        br, bc = dk._tile_geometry(R, Cl, 4)
-        x_info = jax.ShapeDtypeStruct(
-            (R, Cl), jnp.float32,
-            sharding=NamedSharding(mesh, P("data", "model")))
-        s_info = jax.ShapeDtypeStruct(
-            (1,), jnp.int32, sharding=NamedSharding(mesh, P(None)))
-        _, _, out_sh, arg_shs = dk._dp2d_partition(
-            0.4, br, bc, Cl // bc, mesh, (x_info, s_info), x_info)
-        assert out_sh.spec == want, (R, Cl, br, bc, out_sh.spec)
+    # tile) — the second axis must then split the rows further, the
+    # columns stay whole
+    with mosaic.mesh_context(mesh42):
+        for R, Cl, want in [(4096, 1024, ("data", "model")),
+                            (256, 512, ("data", "model")),
+                            (128, 384, (("data", "model"), None))]:
+            br, bc = dk._tile_geometry(R, Cl, 4)
+            assert mosaic.split((R, Cl), (br, bc)) == want, (R, Cl, br, bc)
 
 
 def test_partitioned_matches_unpartitioned_bitexact():
-    """The GSPMD property: ANY row sharding regenerates the identical
-    global mask (the tile grid is fixed by the GLOBAL shape), so the
-    sharded op equals the single-device op bit-for-bit."""
+    """With no mesh in context the reference is plain jnp, which GSPMD
+    partitions by itself: ANY sharding of x gives the single-device op
+    bit-for-bit."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from incubator_mxnet_tpu.parallel import create_mesh
@@ -152,6 +131,36 @@ def test_partitioned_matches_unpartitioned_bitexact():
         for spec in [P("data", None), P(None, "data"), P(None, None)]:
             xs = jax.device_put(x, NamedSharding(mesh, spec))
             y = jax.jit(lambda x: fused_dropout(x, SEED, 0.4))(xs)
+            onp.testing.assert_array_equal(onp.asarray(y), ref,
+                                           err_msg=f"{shape} {spec}")
+
+
+@pytest.mark.parametrize("axes", [{"data": 8}, {"data": 4, "model": 2},
+                                  {"data": 2, "model": 2}])
+def test_shards_draw_the_global_mask_bitexact(axes):
+    """The mesh property: in a program traced over a mesh every shard
+    draws ITS tiles of the global mask (the tile grid is fixed by the
+    GLOBAL shape), whatever sharding x arrives with."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from incubator_mxnet_tpu.ops import mosaic
+    from incubator_mxnet_tpu.parallel import create_mesh
+
+    mesh = create_mesh(**axes)
+
+    def under_mesh(x):
+        with mosaic.mesh_context(mesh):
+            return fused_dropout(x, SEED, 0.4)
+
+    for shape in [(64, 1024), (4096, 1024), (8, 16, 384), (5, 77)]:
+        x = jnp.ones(shape, jnp.float32)
+        ref = onp.asarray(jax.jit(lambda x: fused_dropout(x, SEED, 0.4))(x))
+        assert "shard_map" in str(jax.make_jaxpr(under_mesh)(x))
+        specs = [P()] if shape == (5, 77) else \
+            [P("data"), P(*(None,) * (len(shape) - 1), "data"), P()]
+        for spec in specs:
+            xs = jax.device_put(x, NamedSharding(mesh, spec))
+            y = jax.jit(under_mesh)(xs)
             onp.testing.assert_array_equal(onp.asarray(y), ref,
                                            err_msg=f"{shape} {spec}")
 

@@ -196,10 +196,10 @@ def test_split_and_load_mesh_mode():
 
 
 def test_gluon_bert_tp_dp_with_dropout_composes():
-    """Dropout-enabled BERT must still train sharded (the threefry path
-    engages under GSPMD — the Pallas PRNG kernel is gated to
-    single-device processes).  Same seed → same mask on both runs, so
-    full parity holds even with dropout on."""
+    """Dropout-enabled BERT must still train sharded: the Trainer traces
+    its step with the mesh in context, so every shard draws its own
+    tiles of the global mask (ops/mosaic.py).  Same seed → same mask on
+    both runs, so full parity holds even with dropout on."""
     def build():
         mx.random.seed(0)
         net = bert.BERTForPretraining(vocab_size=V, units=D, hidden_size=DFF,
@@ -219,7 +219,10 @@ def test_gluon_bert_tp_dp_with_dropout_composes():
     shard_params(net1, mesh)
     tr1 = Trainer(model1.collect_params(), "sgd", {"learning_rate": 0.1},
                   mesh=mesh)
+    tr1._capture_hlo = True
     losses1 = _train(model1, tr1, 2, mesh=mesh)
+    # the masks are drawn inside a shard_map of the step program
+    assert "sdy.manual_computation" in tr1.last_step_stablehlo
     onp.testing.assert_allclose(losses0, losses1, rtol=3e-4, atol=3e-5)
     for n, a in _params_host(net0).items():
         onp.testing.assert_allclose(a, _params_host(net1)[n], rtol=2e-3,

@@ -1,0 +1,221 @@
+"""Pallas kernels in programs traced over a mesh (`ops/mosaic.py`).
+
+A Mosaic kernel cannot be partitioned automatically, so every kernel of
+the package is emitted per shard wherever a mesh is in the trace
+context, by one rule on every backend.  On the CPU the kernels run in
+interpret mode (or as their jnp references), so these tests pin the
+rule itself and that a kernel run per shard gives what one device
+gives; `tests/test_chip_compile.py` asks the TPU compiler.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+from incubator_mxnet_tpu.ops import mosaic
+
+
+@pytest.mark.parametrize("ctx,manual,want", [
+    (False, None, {}),                              # no mesh: a plain call
+    (True, None, {"data": 4, "model": 2}),          # the traced mesh, all of it
+    (True, ("data", "model"), {}),                  # fully manual already
+    (False, ("data", "model"), {}),                 # a caller's own shard_map
+    (True, ("data",), {"model": 2}),                # partly manual: the rest
+])
+def test_per_shard_reads_the_trace_context(mesh42, ctx, manual, want):
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from incubator_mxnet_tpu.ops import mosaic
+
+    seen = []
+
+    def body(x):
+        seen.append(mosaic.auto_axes())
+        wrapped = mosaic.per_shard(lambda v: v * 2, P(), P())
+        return wrapped(x) if want else x * 2
+
+    fn = body if manual is None else shard_map(
+        body, mesh=mesh42, in_specs=P("data"), out_specs=P("data"),
+        axis_names=set(manual))
+
+    def traced(x):
+        with mosaic.mesh_context(mesh42 if ctx else None):
+            return fn(x)
+
+    x = jax.device_put(jnp.ones((8, 4)), NamedSharding(mesh42, P("data")))
+    out = jax.jit(traced)(x)
+    assert seen == [want]
+    onp.testing.assert_array_equal(onp.asarray(out), 2.0)
+
+
+def test_split_deals_axes_over_dims(mesh42):
+    from incubator_mxnet_tpu.ops import mosaic
+
+    assert mosaic.split((8, 16)) == (None, None)        # no mesh, no split
+    with mosaic.mesh_context(mesh42):
+        assert mosaic.split((8, 16)) == ("data", "model")
+        assert mosaic.split((4096,), (8,)) == (("data", "model"),)
+        assert mosaic.split((8, 3)) == (("data", "model"), None)
+        assert mosaic.split((6, 3)) == ("model", None)  # 4 divides neither
+        assert mosaic.split((16,), (8,)) == ("model",)  # 16/4 breaks the quantum
+        assert mosaic.n_shards(("data", "model")) == 8
+        assert mosaic.n_shards(None) == 1
+
+
+def test_mesh_context_outermost_wins(mesh42, mesh8):
+    from incubator_mxnet_tpu.parallel import create_mesh
+
+    with mosaic.mesh_context(None):
+        assert mosaic.auto_axes() == {}
+    with mosaic.mesh_context(create_mesh(jax.devices()[:1], data=1)):
+        assert mosaic.auto_axes() == {}                # one device: no mesh
+    with mosaic.mesh_context(mesh42):
+        with mosaic.mesh_context(mesh8):               # a block inside a step
+            assert mosaic.auto_axes() == {"data": 4, "model": 2}
+    assert mosaic.auto_axes() == {}
+
+
+def _flash(q, pool, tables, pos, labels):
+    from incubator_mxnet_tpu.ops.flash_attention import flash_attention
+
+    def loss(q):
+        return flash_attention(q, q, q, causal=True).sum()
+    return jax.grad(loss)(q)
+
+
+def _paged(q, pool, tables, pos, labels):
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention
+
+    return paged_attention(q[:, :, 0], pool, pool * 0.5, tables, pos,
+                           impl="pallas")
+
+
+def _paged_int8(q, pool, tables, pos, labels):
+    from incubator_mxnet_tpu.contrib.quantization import quantize_kv
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention
+
+    k8, sk = quantize_kv(pool)
+    return paged_attention(q[:, :, 0], k8, k8, tables, pos, scale_k=sk,
+                           scale_v=sk, impl="pallas")
+
+
+def _xent(smoothing):
+    def run(q, pool, tables, pos, labels):
+        from incubator_mxnet_tpu.ops import xent_kernel as xk
+
+        logits = q.reshape(64, -1)                          # (64, 256)
+        loss, lse = xk.run_interpret(logits, labels, smoothing)
+        return loss, xk.run_interpret_bwd(logits, labels, lse, loss,
+                                          smoothing)
+    return run
+
+
+@pytest.mark.parametrize("kernel", [_flash, _paged, _paged_int8, _xent(0.0),
+                                    _xent(0.1)],
+                         ids=["flash_fwd_bwd", "paged", "paged_int8", "xent",
+                              "xent_smoothed"])
+def test_kernel_per_shard_matches_one_device(kernel):
+    """The kernel under the 2x2 mesh's `shard_map` (interpret mode here)
+    against the same kernel called as it is."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from incubator_mxnet_tpu.parallel import create_mesh
+
+    mesh = create_mesh(jax.devices()[:4], data=2, model=2)
+    B, H, T, D, bs, nbps = 4, 4, 64, 16, 4, 4
+    k = jax.random.PRNGKey(0)
+    args = (jax.random.normal(k, (B, H, T, D), jnp.float32),
+            jax.random.normal(k, (B * nbps + 1, H, bs, D), jnp.float32),
+            jnp.arange(1, B * nbps + 1, dtype=jnp.int32).reshape(B, nbps),
+            jnp.array([0, 5, 9, 15], jnp.int32),
+            jnp.arange(64, dtype=jnp.int32))
+
+    def under_mesh(*a):
+        with mosaic.mesh_context(mesh):
+            return kernel(*a)
+
+    assert "shard_map" in str(jax.make_jaxpr(under_mesh)(*args))
+    assert "shard_map" not in str(jax.make_jaxpr(kernel)(*args))
+    want = jax.jit(kernel)(*args)
+    on_mesh = [jax.device_put(a, NamedSharding(mesh, P())) for a in args]
+    on_mesh[0] = jax.device_put(args[0], NamedSharding(mesh, P("data", "model")))
+    got = jax.jit(under_mesh)(*on_mesh)
+    for w, g in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        onp.testing.assert_allclose(onp.asarray(g), onp.asarray(w),
+                                    rtol=1e-6, atol=1e-6)
+
+
+def test_shard_params_block_traces_its_forward_under_the_mesh():
+    """A block that `shard_params` placed knows its mesh: its own
+    forward (inference, no Trainer) emits the flash kernel per shard and
+    answers as the unsharded block does."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models.transformer import TransformerLM
+    from incubator_mxnet_tpu.ndarray.ndarray import NDArray
+    from incubator_mxnet_tpu.parallel import create_mesh
+    from incubator_mxnet_tpu.parallel.sharding import shard_params
+
+    mx.random.seed(0)
+    net = TransformerLM(vocab=64, units=32, hidden_size=64, num_layers=1,
+                        num_heads=4, max_len=64, dropout=0.0)
+    net.initialize()
+    net.hybridize()
+    tokens = NDArray(jnp.arange(4 * 64, dtype=jnp.int32).reshape(4, 64) % 64)
+    want = net(tokens).asnumpy()
+
+    split = []
+    real = mosaic.per_shard
+    mesh = create_mesh(jax.devices()[:4], data=2, model=2)
+    shard_params(net, mesh, warn=False)
+    assert net._mesh is mesh
+    try:
+        mosaic.per_shard = lambda *a: split.append(mosaic.auto_axes()) \
+            or real(*a)
+        got = net(tokens).asnumpy()
+    finally:
+        mosaic.per_shard = real
+    assert split and all(s == {"data": 2, "model": 2} for s in split), split
+    onp.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_trainer_mesh_that_nothing_is_placed_on_leaves_a_one_device_step():
+    """`Trainer(mesh=)` with unsharded params, no optimizer state and a
+    batch the data axis does not divide runs a one-device program: it
+    must not be traced under the mesh (a `shard_map` in a one-device
+    program cannot be lowered)."""
+    import warnings
+
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import autograd
+    from incubator_mxnet_tpu.gluon import Trainer, nn
+    from incubator_mxnet_tpu.gluon.block import HybridBlock
+    from incubator_mxnet_tpu.ndarray.ndarray import NDArray
+    from incubator_mxnet_tpu.parallel import create_mesh
+
+    class Net(HybridBlock):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            self.dense = nn.Dense(128, in_units=128, flatten=False)
+            self.drop = nn.Dropout(0.5)
+
+        def forward(self, x):
+            return self.drop(self.dense(x)).sum()
+
+    mx.random.seed(0)
+    net = Net()
+    net.initialize()
+    net.hybridize()
+    trainer = Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1},
+                      mesh=create_mesh(jax.devices()[:4], data=4))
+    trainer._capture_hlo = True
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")      # "6 is not divisible by 4"
+        with autograd.record():
+            loss = net(NDArray(jnp.ones((6, 128))))
+        loss.backward()
+        trainer.step(1)
+    assert trainer._fullstep_ctx is not None
+    assert onp.isfinite(loss.asnumpy())
+    assert "manual_computation" not in trainer.last_step_stablehlo

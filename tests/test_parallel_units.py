@@ -132,7 +132,7 @@ def test_moe_dispatch_conservation():
 
 
 def test_collectives_psum_across_mesh():
-    from incubator_mxnet_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = par.create_mesh(data=8)
@@ -276,13 +276,6 @@ def test_pipeline_1f1b_matches_oracle():
                                 rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.xfail(
-    not hasattr(jax, "typeof"),
-    reason="needs the jax >= 0.6 vma system (jax.typeof/lax.pcast) to "
-           "rewrite the psum transpose through an in-stage TP collective; "
-           "under the legacy check_rep discipline the backward psum is not "
-           "re-associated and grads come out axis_size('model')x too large",
-    strict=True)
 def test_pipeline_1f1b_composes_with_tp_collectives():
     """PP×TP: the stage contains a psum over 'model' INSIDE the 1F1B
     branches — the uniform-branch argument (predicates depend only on
@@ -312,7 +305,7 @@ def test_pipeline_1f1b_composes_with_tp_collectives():
     def loss_fn(y, t):
         return jnp.mean((y - t) ** 2)
 
-    from incubator_mxnet_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def run(W1, W2):
@@ -369,7 +362,7 @@ def test_pipeline_gpipe_skip_inactive_with_tp_collective():
     from jax import lax
     from jax.sharding import PartitionSpec as P
     from incubator_mxnet_tpu.parallel import create_mesh, pipeline as pp
-    from incubator_mxnet_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     n, tp, M, mb, d = 2, 2, 2, 2, 4
     mesh = create_mesh(jax.devices()[:n * tp], pipe=n, model=tp)

@@ -1,7 +1,9 @@
 """telemetry.perf: roofline/MFU program attribution and device-memory
 watermarks (ISSUE 8 tentpole) — capture from real compiled programs,
-achieved-rate gauges, the decode int8-vs-float byte ordering, per-device
-shard attribution, and the background watermark poller."""
+achieved-rate gauges, per-device shard attribution, and the background
+watermark poller.  (The int8-vs-bf16 dot byte ordering is a claim about
+the TPU program and is asked of the v5e compiler in
+test_chip_compile.py.)"""
 import math
 import time
 
@@ -100,23 +102,6 @@ def test_roofline_table_rows_are_name_sorted(tel):
     for r in rows:
         assert set(r) >= {"program", "flops", "hbm_bytes", "intensity",
                           "bound_by", "mfu", "hbm_gbps", "roofline_fraction"}
-
-
-def test_int8_dot_moves_fewer_bytes_than_float(tel):
-    """The acceptance ordering the decode programs rely on, pinned on
-    bare dots: an int8-weight mixed dot's cost analysis must charge
-    fewer bytes than the f32 dot of the same shape."""
-    def dot(a, b):
-        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    x = jnp.ones((8, 256), jnp.bfloat16)
-    wf = jnp.ones((256, 256), jnp.bfloat16)
-    w8 = jnp.ones((256, 256), jnp.int8)
-
-    pf = perf.capture("dot_bf16", jax.jit(dot), x, wf)
-    pi = perf.capture("dot_int8", jax.jit(dot), x, w8)
-    assert pi.bytes_accessed < pf.bytes_accessed
 
 
 # --------------------------------------------------------------------- #

@@ -171,6 +171,34 @@ def test_naive_engine_nan_guard():
             float(jnp2.sum(bad))
 
 
+@pytest.mark.parametrize("placed", ["/some/dir", None])
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch, placed):
+    import os
+
+    import jax
+
+    from incubator_mxnet_tpu import runtime
+
+    before = jax.config.jax_compilation_cache_dir
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = runtime.use_compile_cache()
+        if placed:  # nothing is set in code
+            assert got == placed
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert got == os.path.normpath(got) and os.path.isabs(got)
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_inception_v3_in_zoo():
     from incubator_mxnet_tpu.gluon.model_zoo import vision
     from incubator_mxnet_tpu.ndarray.ndarray import NDArray

@@ -1,22 +1,19 @@
 """Backward-overlapped bucketed gradient sync (ISSUE 5): the
 parallel/overlap.py partitioner + pack/unpack kernels, the HLO schedule
 analyzer, the Trainer's bucketed explicit-tier path (parity vs the
-monolithic exchange), the sticky fallback, and the runtime XLA-flag
-hook.  Runs on the 8-virtual-CPU mesh from conftest."""
-import os
-
+monolithic exchange) and the sticky fallback.  Runs on the
+8-virtual-CPU mesh from conftest."""
 import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 import incubator_mxnet_tpu as mx
-from incubator_mxnet_tpu import autograd, gluon, runtime
+from incubator_mxnet_tpu import autograd, gluon
 from incubator_mxnet_tpu.gluon import nn
 from incubator_mxnet_tpu.parallel import overlap as ov
-from incubator_mxnet_tpu.parallel.compat import shard_map
 
 D = 8
 
@@ -96,9 +93,9 @@ def test_pack_unpack_parity_bit_exact(mesh8):
         return segs, ov.unpack_gathered(flat, b.chunks, D)
 
     f1 = jax.jit(shard_map(per_param, mesh=mesh8, in_specs=(P(),),
-                           out_specs=P("data"), check_rep=False))
+                           out_specs=P("data"), check_vma=False))
     f2 = jax.jit(shard_map(bucketed, mesh=mesh8, in_specs=(P(),),
-                           out_specs=(P("data"), P()), check_rep=False))
+                           out_specs=(P("data"), P()), check_vma=False))
     want = f1(gs)
     segs, backs = f2(gs)
     for a, b in zip(want, segs):
@@ -107,7 +104,7 @@ def test_pack_unpack_parity_bit_exact(mesh8):
         assert onp.array_equal(onp.asarray(a), onp.asarray(b))
     psum = jax.jit(shard_map(lambda g: lax.psum(g, "data"), mesh=mesh8,
                              in_specs=(P(),), out_specs=P(),
-                             check_rep=False))
+                             check_vma=False))
     for j in range(3):
         onp.testing.assert_allclose(onp.asarray(backs[j]),
                                     onp.asarray(psum(gs[j])))
@@ -312,36 +309,3 @@ def test_trainer_env_knob_disables(mesh8, monkeypatch):
     _, _, tr = _train(mesh8)  # zero_overlap unset -> env decides
     assert tr._fullstep_ctx["zero_buckets"] is None
     assert not tr._zero_overlap_broken  # disabled, not broken
-
-
-# ---------------------------------------------------------------------------
-# runtime XLA-flag hook
-# ---------------------------------------------------------------------------
-
-def test_overlap_flags_per_platform():
-    assert runtime.collective_overlap_flags("tpu")
-    assert all(f.startswith("--xla_") for f in
-               runtime.collective_overlap_flags("tpu"))
-    # CPU's list scheduler already interleaves; and unknown flags are
-    # fatal to XLA, so the CPU set must stay empty
-    assert runtime.collective_overlap_flags("cpu") == ()
-
-
-def test_enable_collective_overlap_guards(monkeypatch):
-    # live backend (these tests hold one): must refuse to touch env
-    before = os.environ.get("XLA_FLAGS")
-    assert runtime.enable_collective_overlap("tpu") == []
-    assert os.environ.get("XLA_FLAGS") == before
-    # pre-init path: flags land in XLA_FLAGS exactly once
-    monkeypatch.setattr(runtime, "_backend_initialized", lambda: False)
-    monkeypatch.setenv("XLA_FLAGS", "--existing=1")
-    added = runtime.enable_collective_overlap("tpu")
-    assert added == list(runtime.collective_overlap_flags("tpu"))
-    for f in added:
-        assert f in os.environ["XLA_FLAGS"]
-    assert runtime.enable_collective_overlap("tpu") == []  # deduped
-    # kill switch
-    monkeypatch.setenv("MXTPU_OVERLAP_FLAGS", "0")
-    monkeypatch.setenv("XLA_FLAGS", "")
-    assert runtime.enable_collective_overlap("tpu") == []
-    assert os.environ["XLA_FLAGS"] == ""

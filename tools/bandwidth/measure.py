@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 def measure(sizes_mb, n_devices=None, runs=5):
     import jax
     import jax.numpy as jnp
-    from incubator_mxnet_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     import incubator_mxnet_tpu.parallel as par

@@ -159,9 +159,8 @@ JIT_WRAPPERS = {
 
 # wrappers that additionally BIND mesh axis names for the wrapped
 # function (collectives inside may name them).  `shard_map` is matched
-# by resolved-name tail as well so project-local compat shims
-# (parallel/compat.py) count — that is the cross-module propagation
-# per-file linting could never see.
+# by resolved-name tail as well so project-local wrappers count — that
+# is the cross-module propagation per-file linting could never see.
 SHARD_WRAPPER_TAILS = {"shard_map", "pmap", "smap"}
 AXIS_BINDING_WRAPPERS = {
     "jax.experimental.shard_map.shard_map", "jax.shard_map",
@@ -450,8 +449,8 @@ class Project:
     def _call_arg_names(call: ast.Call) -> List[str]:
         names = [a.id for a in call.args if isinstance(a, ast.Name)]
         # *args forwarding counts: `_shard_map(*args, **kwargs)` passes
-        # the vararg tuple through — without this, a compat shim like
-        # parallel/compat.shard_map breaks wrapper propagation and every
+        # the vararg tuple through — without this, a forwarding wrapper
+        # around shard_map breaks wrapper propagation and every
         # shard_map body behind it silently drops out of trace scope
         names += [a.value.id for a in call.args
                   if isinstance(a, ast.Starred) and isinstance(a.value, ast.Name)]
