@@ -247,6 +247,7 @@ def _kernel2d(shape, seed, row_blk_off, col_blk_off, rate, br, bc, ncb_g,
         out_specs=pl.BlockSpec((kr * br, kc * bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(shape, jnp.uint8),
         interpret=interpret,
+        name="dropout_mask",
     )(seeds)
 
 

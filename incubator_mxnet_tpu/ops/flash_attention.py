@@ -227,6 +227,7 @@ def _flash_core(q, k, v, causal, scale, block_q, block_k, interpret):
             ],
             out_shape=out_shape,
             interpret=interpret,
+            name="flash_fwd",
         )(qf, kf, vf)
     else:
         # beyond it: stream KV via the innermost grid axis
@@ -252,6 +253,7 @@ def _flash_core(q, k, v, causal, scale, block_q, block_k, interpret):
                 pltpu.VMEM((bq, D), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_fwd_streamed",
         )(qf, kf, vf)
     return (out[:, :Tq, :].reshape(B, H, Tq, D),
             lse[:, 0, :Tq].reshape(B, H, Tq))
@@ -415,6 +417,7 @@ def _flash_bwd_core(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, Tk_p, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dqk = functools.partial(_fa_dq_kernel, scale=scale, causal=causal,
@@ -433,6 +436,7 @@ def _flash_bwd_core(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_p, D), jnp.float32),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     return (dq[:, :Tq, :].reshape(B, H, Tq, D).astype(q.dtype),

@@ -191,6 +191,7 @@ def _paged_call(q, pools, tables, pos, interpret):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_attention_q8" if kv_quant else "paged_attention",
     )(tables, pos, q, *pools)
 
 

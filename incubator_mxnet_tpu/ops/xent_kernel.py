@@ -168,6 +168,7 @@ def _pallas_fwd(x2, interpret, want_sum):
         out_shape=(row,) * n_out if want_sum else row,
         scratch_shapes=[scratch] * (n_out + 1),
         interpret=interpret,
+        name="xent_fwd",
     )(x2)
     return tuple(r[:, 0] for r in (res if want_sum else (res,)))
 
@@ -190,6 +191,7 @@ def _pallas_bwd(x2, labels, lse, g, interpret, eps=0.0):
         out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
         interpret=interpret,
+        name="xent_bwd",
     )(x2, labels.astype(jnp.int32).reshape(N, 1), lse.reshape(N, 1),
       g.astype(jnp.float32).reshape(N, 1))
 

@@ -613,11 +613,13 @@ class ServingEngine:
         # SIGTERM/crash bundles carry the in-flight table + trace ring
         telemetry.flight_recorder.register_section(
             self._name, self._flight_section)
-        # per-step stall-attribution ledger (ISSUE 17): always
+        # per-iteration stall-attribution ledger (ISSUE 17, 28): always
         # constructed and fed by the scheduler loop — disabling
         # (MXTPU_SERVING_PROFILER=0 / set_enabled(False)) leaves one
-        # flag read per note.  Registered process-wide so /profilez and
-        # /stallz see every engine's lane.
+        # flag read per phase.  Registered process-wide so /profilez
+        # and /stallz see every live engine's lane; its records stay in
+        # the process's ring (telemetry.profiler.iterations) after
+        # close().
         self._prof = telemetry.profiler.register(
             telemetry.profiler.EngineProfiler(self._name))
         telemetry.profiler.install_gc_hooks()
@@ -831,6 +833,7 @@ class ServingEngine:
                 "speculate": self._spec_section(),
                 "slo": self._slo.snapshot(now),
                 "stalls": self._prof.recent_stalls(8),
+                "recent_steps": self._prof.recent_steps(8),
                 "recent_traces": telemetry.requestlog.recent(32)}
 
     @property
@@ -1155,9 +1158,9 @@ class ServingEngine:
                 self._work.notify_all()
 
     def _loop(self) -> None:
-        # every phase of the iteration feeds the stall ledger: lock
-        # acquisition, reap+admission bookkeeping, idle polls — so the
-        # per-step causes sum to the step's wall time (profiler.py).
+        # every phase of the iteration runs inside `prof.phase(cause)`:
+        # lock acquisition, reap+admission bookkeeping, idle polls — so
+        # the per-step causes sum to the step's wall time (profiler.py).
         # Iteration shape (ISSUE 20): reap → admit everything that fits
         # (lanes + blocks claimed, prefix blocks bound) → run at most
         # ONE prefill chunk → run ONE decode step over live lanes.
@@ -1165,10 +1168,8 @@ class ServingEngine:
         # resident sequence's tpot spike to one chunk of compute.
         prof = self._prof
         while True:
-            t_lk = time.perf_counter()
-            with self._work:
-                t_bk = time.perf_counter()
-                prof.note("lock_wait", t_bk - t_lk)
+            with prof.phase("lock_wait"), self._work, \
+                    prof.phase("bookkeeping"):
                 if self._stop.is_set():
                     return
                 now = time.monotonic()
@@ -1183,12 +1184,10 @@ class ServingEngine:
                         self._pos.copy(), self._active.copy(),
                         self._keys.copy()) if live else None
                 hook = self._fault_hook
-                prof.note("bookkeeping", time.perf_counter() - t_bk)
                 if staged is None and not live:
                     if not self._queue:
-                        t_w = time.perf_counter()
-                        self._work.wait(self._poll)
-                        prof.note("wait", time.perf_counter() - t_w)
+                        with prof.phase("wait"):
+                            self._work.wait(self._poll)
                     continue
             if staged is not None:
                 self._run_chunk(staged, hook)
@@ -1334,87 +1333,83 @@ class ServingEngine:
         req = job.req
         # weight gather/requantize, timed apart from the device call so
         # a requantize after a weight swap shows up as its own cause
-        t_g = time.perf_counter()
-        params = self._live_params()
-        t_h = time.perf_counter()
-        prof.note("gather_params", t_h - t_g)
-        if hook is not None:
-            hook("prefill")                 # fault seam: once per chunk
+        with prof.phase("gather_params"):
+            params = self._live_params()
         final = start + n >= job.P
-        t0 = time.perf_counter()
-        (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-         first) = G._timed_decode(
-            f"serving_prefill_chunk_{self._label}",
-            f"serving_{self._label}", n,
-            self._programs.prefill_chunk, self._pool_k, self._pool_v,
-            self._scale_k, self._scale_v, job.row, toks,
-            np.int32(start), np.int32(job.P), job.key, params)
-        if self._spec:
-            # populate the DRAFT pool with the same chunk too — the
-            # draft's first proposal attends to the full prompt.  Same
-            # table row; lands under the prefill_chunk cause.
-            dparams = self._programs.draft_params(self._msl)
-            (self._dpool_k, self._dpool_v) = G._timed_decode(
-                f"serving_draft_prefill_chunk_{self._label}",
-                f"serving_{self._label}", n,
-                self._programs.draft_prefill_chunk,
-                self._dpool_k, self._dpool_v, job.row, toks,
-                np.int32(start), np.int32(job.P), dparams)
-        # only the final chunk's first-token pick is consumed — don't
-        # force a host sync per intermediate chunk
-        tok = int(np.asarray(first)) if final else None
-        dt = time.perf_counter() - t0
-        prof.note("prefill_chunk", time.perf_counter() - t_h)
+        with prof.phase("prefill_chunk"):
+            if hook is not None:
+                hook("prefill")             # fault seam: once per chunk
+            t0 = time.perf_counter()
+            with prof.phase("dispatch"):
+                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
+                 first) = G._timed_decode(
+                    f"serving_prefill_chunk_{self._label}",
+                    f"serving_{self._label}", n,
+                    self._programs.prefill_chunk, self._pool_k,
+                    self._pool_v, self._scale_k, self._scale_v, job.row,
+                    toks, np.int32(start), np.int32(job.P), job.key,
+                    params)
+                if self._spec:
+                    # populate the DRAFT pool with the same chunk too —
+                    # the draft's first proposal attends to the full
+                    # prompt.  Same table row.
+                    dparams = self._programs.draft_params(self._msl)
+                    (self._dpool_k, self._dpool_v) = G._timed_decode(
+                        f"serving_draft_prefill_chunk_{self._label}",
+                        f"serving_{self._label}", n,
+                        self._programs.draft_prefill_chunk,
+                        self._dpool_k, self._dpool_v, job.row, toks,
+                        np.int32(start), np.int32(job.P), dparams)
+            t_handed = time.monotonic()     # the chunk's stamp: no sync
+            # only the final chunk's first-token pick is consumed —
+            # don't force a host sync per intermediate chunk
+            tok = int(np.asarray(first)) if final else None
+            dt = time.perf_counter() - t0
         now = time.monotonic()
-        t_lk = time.perf_counter()
-        with self._work:
-            t_bk = time.perf_counter()
-            prof.note("lock_wait", t_bk - t_lk)
-            try:
-                job.t_work += dt
-                slot = self._slots[job.lane]
-                if slot is None or slot.req is not req:
-                    self._drop_job_locked(job)
-                    return                  # evicted while chunking
-                job.next_pos = start + n
-                self._note_chunk_queue_locked()
-                if not final:
-                    return
+        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
+            job.t_work += dt
+            slot = self._slots[job.lane]
+            if slot is None or slot.req is not req:
                 self._drop_job_locked(job)
-                # EWMA over the request's WHOLE prefill (all chunks):
-                # the SLO shed estimate stays comparable to r12's
-                self._prefill_ewma = job.t_work \
-                    if self._prefill_ewma is None \
-                    else 0.8 * self._prefill_ewma + 0.2 * job.t_work
-                req.status = "running"
-                req.trace.event("prefill", t=now,
-                                dur_s=round(job.t_work, 6), token=tok,
-                                cached_tokens=job.cached_len)
-                req._deliver(tok, now)
-                self._stats["admitted"] += 1
-                # publish the prompt's full blocks into the prefix
-                # cache now their content is final (COW: nothing
-                # writes positions < P past this point)
-                self._pool.register(job.prompt, job.row)
-                if telemetry.enabled():
-                    telemetry.counter("serving_admitted_total").inc()
-                    telemetry.histogram(
-                        "serving_ttft_seconds",
-                        labels={"path": self._path}) \
-                        .observe(now - req.t_submit)
-                    telemetry.gauge("serving_kv_blocks_in_use") \
-                        .set(self._pool.num_allocated)
-                if tok == self._eos \
-                        or len(req.tokens) >= req.max_new_tokens:
-                    self._retire_locked(job.lane)
-                    return
-                self._tables[job.lane, :] = job.row
-                self._toks[job.lane] = tok
-                self._pos[job.lane] = job.P
-                self._active[job.lane] = True
-                self._keys[job.lane, :] = job.key
-            finally:
-                prof.note("bookkeeping", time.perf_counter() - t_bk)
+                return                      # evicted while chunking
+            job.next_pos = start + n
+            prof.chunk(req.rid, start, n, t_handed)
+            self._note_chunk_queue_locked()
+            if not final:
+                return
+            self._drop_job_locked(job)
+            # EWMA over the request's WHOLE prefill (all chunks):
+            # the SLO shed estimate stays comparable to r12's
+            self._prefill_ewma = job.t_work \
+                if self._prefill_ewma is None \
+                else 0.8 * self._prefill_ewma + 0.2 * job.t_work
+            req.status = "running"
+            req.trace.event("prefill", t=now,
+                            dur_s=round(job.t_work, 6), token=tok,
+                            cached_tokens=job.cached_len)
+            req._deliver(tok, now)
+            self._stats["admitted"] += 1
+            # publish the prompt's full blocks into the prefix
+            # cache now their content is final (COW: nothing
+            # writes positions < P past this point)
+            self._pool.register(job.prompt, job.row)
+            if telemetry.enabled():
+                telemetry.counter("serving_admitted_total").inc()
+                telemetry.histogram(
+                    "serving_ttft_seconds",
+                    labels={"path": self._path}) \
+                    .observe(now - req.t_submit)
+                telemetry.gauge("serving_kv_blocks_in_use") \
+                    .set(self._pool.num_allocated)
+            if tok == self._eos \
+                    or len(req.tokens) >= req.max_new_tokens:
+                self._retire_locked(job.lane)
+                return
+            self._tables[job.lane, :] = job.row
+            self._toks[job.lane] = tok
+            self._pos[job.lane] = job.P
+            self._active[job.lane] = True
+            self._keys[job.lane, :] = job.key
 
     def _drop_job_locked(self, job: _PrefillJob) -> None:
         try:
@@ -1437,6 +1432,22 @@ class ServingEngine:
             telemetry.gauge("serving_prefill_chunk_queue_depth") \
                 .set(self._pending_chunks_locked())
 
+    def _pool_use_locked(self) -> dict:
+        """The lanes' hold on the pool for the ledger's record: blocks
+        reserved, and positions whose K/V the pool holds (`_pos` of a
+        decoding lane, `next_pos` of one still prefilling), both summed
+        over the occupied lanes."""
+        slots = self._slots
+        return {
+            "blocks_reserved": sum(len(s.blocks) for s in slots
+                                   if s is not None),
+            "blocks_total": self._num_blocks - 1,
+            "block_size": self._bs,
+            "positions_written": int(self._pos[self._active].sum()) + sum(
+                j.next_pos for j in self._prefill_jobs
+                if slots[j.lane] is not None
+                and slots[j.lane].req is j.req)}
+
     def _retire_locked(self, lane: int) -> None:
         req = self._slots[lane].req
         self._release_lane_locked(lane)
@@ -1451,31 +1462,28 @@ class ServingEngine:
         submit()/cancel() never block on compute (a fault hook's
         injected sleep included)."""
         prof = self._prof
-        t_g = time.perf_counter()
-        params = self._live_params()
-        t_h = time.perf_counter()
-        prof.note("gather_params", t_h - t_g)
-        if hook is not None:
-            hook("step")                    # fault seam: counts as device
+        with prof.phase("gather_params"):
+            params = self._live_params()
         tables, toks, pos, active, keys = snap
-        t0 = time.perf_counter()
-        (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-         nxt) = G._timed_decode(
-            f"serving_step_{self._label}", f"serving_{self._label}",
-            len(live), self._programs.step, self._pool_k, self._pool_v,
-            self._scale_k, self._scale_v, tables, toks, pos, active, keys,
-            params)
-        nxt = np.asarray(nxt)               # sync: tokens are consumed now
-        dt = time.perf_counter() - t0
         # the ledger's device_step cause includes the fault hook (an
         # injected stall IS device time to the requests waiting on it);
         # the tpot histogram keeps the pure device call, as before
-        prof.note("device_step", time.perf_counter() - t_h)
+        with prof.phase("device_step"):
+            if hook is not None:
+                hook("step")                # fault seam: counts as device
+            t0 = time.perf_counter()
+            with prof.phase("dispatch"):
+                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
+                 nxt) = G._timed_decode(
+                    f"serving_step_{self._label}",
+                    f"serving_{self._label}", len(live),
+                    self._programs.step, self._pool_k, self._pool_v,
+                    self._scale_k, self._scale_v, tables, toks, pos,
+                    active, keys, params)
+            nxt = np.asarray(nxt)           # sync: tokens are consumed now
+            dt = time.perf_counter() - t0
         now = time.monotonic()
-        t_lk = time.perf_counter()
-        with self._work:
-            t_bk = time.perf_counter()
-            prof.note("lock_wait", t_bk - t_lk)
+        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
             self._stats["steps"] += 1
             step_no = self._stats["steps"]
             mark = _TRACE_EVERY > 0 and step_no % _TRACE_EVERY == 0
@@ -1502,12 +1510,12 @@ class ServingEngine:
                 telemetry.gauge("serving_batch_occupancy") \
                     .set(len(live))
             queue_depth = len(self._queue)
-            prof.note("bookkeeping", time.perf_counter() - t_bk)
+            pool_use = self._pool_use_locked()
         # close the ledger OUTSIDE the engine lock (it takes its own
         # leaf lock + histogram locks; never nested under self._work)
         prof.end_step(rids=[req.rid for _, req in live],
                       occupancy=len(live), queue_depth=queue_depth,
-                      step=step_no)
+                      step=step_no, **pool_use)
         if telemetry.enabled() and step_no % 8 == 0:
             # keep lock_witness_edges_total / lock_contention_seconds
             # scrapeable mid-run, not only after an end-of-run snapshot
@@ -1533,39 +1541,40 @@ class ServingEngine:
         """
         prof = self._prof
         k = self._spec_k
-        t_g = time.perf_counter()
-        params = self._live_params()
-        dparams = self._programs.draft_params(self._msl)
-        t_h = time.perf_counter()
-        prof.note("gather_params", t_h - t_g)
+        with prof.phase("gather_params"):
+            params = self._live_params()
+            dparams = self._programs.draft_params(self._msl)
         tables, toks, pos, active, keys = snap
-        if hook is not None:
-            hook("draft")                   # fault seam: draft stream
-        (self._dpool_k, self._dpool_v, d_toks, d_probs) = G._timed_decode(
-            f"serving_draft_step_{self._label}", f"serving_{self._label}",
-            len(live) * k, self._programs.draft_step,
-            self._dpool_k, self._dpool_v, tables, toks, pos, active,
-            keys, dparams)
-        t1 = time.perf_counter()
-        prof.note("draft_step", t1 - t_h)
-        if hook is not None:
-            hook("step")                    # fault seam: target stream
-        t0 = time.perf_counter()
-        (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-         out, alen) = G._timed_decode(
-            f"serving_spec_verify_{self._label}", f"serving_{self._label}",
-            len(live), self._programs.spec_verify,
-            self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-            tables, toks, pos, active, keys, d_toks, d_probs, params)
-        out = np.asarray(out)               # sync: tokens consumed now
-        alen = np.asarray(alen)
-        dt = time.perf_counter() - t0
-        prof.note("verify_step", time.perf_counter() - t1)
+        t_h = time.perf_counter()
+        with prof.phase("draft_step"):
+            if hook is not None:
+                hook("draft")               # fault seam: draft stream
+            with prof.phase("dispatch"):
+                (self._dpool_k, self._dpool_v, d_toks,
+                 d_probs) = G._timed_decode(
+                    f"serving_draft_step_{self._label}",
+                    f"serving_{self._label}", len(live) * k,
+                    self._programs.draft_step, self._dpool_k,
+                    self._dpool_v, tables, toks, pos, active, keys,
+                    dparams)
+        dt_draft = time.perf_counter() - t_h
+        with prof.phase("verify_step"):
+            if hook is not None:
+                hook("step")                # fault seam: target stream
+            t0 = time.perf_counter()
+            with prof.phase("dispatch"):
+                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
+                 out, alen) = G._timed_decode(
+                    f"serving_spec_verify_{self._label}",
+                    f"serving_{self._label}", len(live),
+                    self._programs.spec_verify, self._pool_k,
+                    self._pool_v, self._scale_k, self._scale_v, tables,
+                    toks, pos, active, keys, d_toks, d_probs, params)
+            out = np.asarray(out)           # sync: tokens consumed now
+            alen = np.asarray(alen)
+            dt = time.perf_counter() - t0
         now = time.monotonic()
-        t_lk = time.perf_counter()
-        with self._work:
-            t_bk = time.perf_counter()
-            prof.note("lock_wait", t_bk - t_lk)
+        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
             self._stats["steps"] += 1
             self._stats["spec_steps"] += 1
             step_no = self._stats["steps"]
@@ -1631,7 +1640,7 @@ class ServingEngine:
             if telemetry.enabled():
                 # per-token time: the iteration's device time over the
                 # mean tokens a lane actually got out of it
-                per_tok = (dt + (t1 - t_h)) \
+                per_tok = (dt + dt_draft) \
                     / max(1.0, delivered_total / max(1, len(live)))
                 telemetry.histogram("serving_tpot_seconds",
                                     labels={"path": self._path}) \
@@ -1639,10 +1648,10 @@ class ServingEngine:
                 telemetry.gauge("serving_batch_occupancy") \
                     .set(len(live))
             queue_depth = len(self._queue)
-            prof.note("bookkeeping", time.perf_counter() - t_bk)
+            pool_use = self._pool_use_locked()
         prof.end_step(rids=[req.rid for _, req in live],
                       occupancy=len(live), queue_depth=queue_depth,
-                      step=step_no)
+                      step=step_no, **pool_use)
         if telemetry.enabled() and step_no % 8 == 0:
             telemetry.profiler.snapshot_lock_witness()
 

@@ -143,6 +143,45 @@ def _top_k_logits(logits, temperature, top_k):
     return lg
 
 
+def _layers(params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+            wblk, off, attend):
+    """The decoder stack of every serving program: per layer qkv, the
+    K/V write into the lanes' pages at ``(wblk, off)`` (quantized first
+    on an int8 pool), attention over the pool — ``attend(q, pk, pv, sk,
+    sv)`` is the one thing the programs differ in — projection and FFN.
+    Each part runs under the `jax.named_scope` a device trace shows it
+    by: ``layer<i>/kv_write``, ``layer<i>/paged_attn``,
+    ``layer<i>/ffn``.  Returns ``(h, new_k, new_v, new_sk, new_sv)``,
+    the scale tuples empty on a float pool."""
+    new_k, new_v, new_sk, new_sv = [], [], [], []
+    for li, (lp, act) in enumerate(zip(params["layers"], acts)):
+        with jax.named_scope(f"layer{li}"):
+            x = G._ln(h, *lp["ln1"])
+            q, k, v = G._qkv_heads(G._dense(x, *lp["qkv"]), H)
+            # write-then-read, the _cached_self_attn order: a position
+            # is valid by the time the mask admits it
+            with jax.named_scope("kv_write"):
+                if kv8:
+                    k, ks = quantize_kv(k)  # s8 values / f32 scales
+                    v, vs = quantize_kv(v)
+                    sk = scale_k[li].at[wblk, :, off].set(ks)
+                    sv = scale_v[li].at[wblk, :, off].set(vs)
+                    new_sk.append(sk)
+                    new_sv.append(sv)
+                else:
+                    sk = sv = None
+                pk = pool_k[li].at[wblk, :, off].set(k)
+                pv = pool_v[li].at[wblk, :, off].set(v)
+            with jax.named_scope("paged_attn"):
+                a = attend(q, pk, pv, sk, sv)
+            h = h + G._dense(a.reshape(h.shape), *lp["proj"])
+            with jax.named_scope("ffn"):
+                h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
+            new_k.append(pk)
+            new_v.append(pv)
+    return h, tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv)
+
+
 def _token_forward(params, acts, H, bs, kv8, attn_impl,
                    pool_k, pool_v, scale_k, scale_v,
                    tables, toks, pos, active, guard_msl=None):
@@ -160,7 +199,6 @@ def _token_forward(params, acts, H, bs, kv8, attn_impl,
     and keeps its original, unguarded ops byte-for-byte.
     """
     dt = params["embed"].dtype
-    B = toks.shape[0]
     C = params["embed"].shape[1]
     if guard_msl is None:
         pos_c = pos
@@ -171,40 +209,23 @@ def _token_forward(params, acts, H, bs, kv8, attn_impl,
         blk_idx = jnp.clip(pos_c // bs, 0, tables.shape[1] - 1)
         ok = active & (pos < guard_msl)
     off = pos_c % bs
-    h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-         + params["pe"][pos_c].astype(dt))                  # (B, C)
+    with jax.named_scope("embed"):
+        h = (params["embed"][toks].astype(dt) * math.sqrt(C)
+             + params["pe"][pos_c].astype(dt))              # (B, C)
     # the block this step writes: the lane's table entry for its
     # current position — inactive (or guarded-out) lanes are pointed
     # at scratch
     wblk = jnp.take_along_axis(tables, blk_idx[:, None], axis=1)[:, 0]
     wblk = jnp.where(ok, wblk, jnp.int32(0))
-    new_k, new_v, new_sk, new_sv = [], [], [], []
-    for li, (lp, act) in enumerate(zip(params["layers"], acts)):
-        x = G._ln(h, *lp["ln1"])
-        q, k, v = G._qkv_heads(G._dense(x, *lp["qkv"]), H)  # (B, H, D)
-        # write-then-read, the _cached_self_attn order: position
-        # `pos` is valid by the time the mask admits it
-        if kv8:
-            k, ks = quantize_kv(k)        # (B, H, D) s8 / (B, H) f32
-            v, vs = quantize_kv(v)
-            sk = scale_k[li].at[wblk, :, off].set(ks)
-            sv = scale_v[li].at[wblk, :, off].set(vs)
-            new_sk.append(sk)
-            new_sv.append(sv)
-        else:
-            sk = sv = None
-        pk = pool_k[li].at[wblk, :, off].set(k)
-        pv = pool_v[li].at[wblk, :, off].set(v)
-        a = paged_attention(q, pk, pv, tables, pos,
-                            scale_k=sk, scale_v=sv,
-                            impl=attn_impl)           # (B, H, D)
-        h = h + G._dense(a.reshape(B, C), *lp["proj"])
-        h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
-        new_k.append(pk)
-        new_v.append(pv)
-    logits = G._logits_of(params, h)                        # (B, V)
-    return (tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv),
-            logits)
+    h, new_k, new_v, new_sk, new_sv = _layers(
+        params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+        wblk, off,
+        lambda q, pk, pv, sk, sv: paged_attention(
+            q, pk, pv, tables, pos, scale_k=sk, scale_v=sv,
+            impl=attn_impl))                                # (B, H, D)
+    with jax.named_scope("head"):
+        logits = G._logits_of(params, h)                    # (B, V)
+    return new_k, new_v, new_sk, new_sv, logits
 
 
 def _build_step(H, acts, block_size, blocks_per_seq, temperature, top_k,
@@ -237,7 +258,8 @@ def _build_step(H, acts, block_size, blocks_per_seq, temperature, top_k,
         new_k, new_v, new_sk, new_sv, logits = _token_forward(
             params, acts, H, bs, kv8, attn_impl,
             pool_k, pool_v, scale_k, scale_v, tables, toks, pos, active)
-        nxt = jax.vmap(pick)(logits, pos, keys)
+        with jax.named_scope("pick"):
+            nxt = jax.vmap(pick)(logits, pos, keys)
         return new_k, new_v, new_sk, new_sv, nxt
 
     serving_step.__name__ = name
@@ -283,39 +305,25 @@ def _build_prefill_chunk(H, acts, block_size, blocks_per_seq, chunk,
         posw = start + jnp.arange(CH, dtype=jnp.int32)         # (CH,)
         ok = posw < valid_len
         posc = jnp.clip(posw, 0, msl - 1)
-        h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-             + params["pe"][posc].astype(dt))                  # (CH, C)
+        with jax.named_scope("embed"):
+            h = (params["embed"][toks].astype(dt) * math.sqrt(C)
+                 + params["pe"][posc].astype(dt))              # (CH, C)
         blk_idx = jnp.clip(posc // bs, 0, nbps - 1)
         off = posc % bs
         wblk = jnp.where(ok, table_row[blk_idx], jnp.int32(0))
         tables = jnp.broadcast_to(table_row[None, :], (CH, nbps))
-        new_k, new_v, new_sk, new_sv = [], [], [], []
-        for li, (lp, act) in enumerate(zip(params["layers"], acts)):
-            x = G._ln(h, *lp["ln1"])
-            q, kw, vw = G._qkv_heads(G._dense(x, *lp["qkv"]), H)
-            if kv8:
-                kw, ks = quantize_kv(kw)   # (CH,H,D) s8 / (CH,H) f32
-                vw, vs = quantize_kv(vw)
-                sk = scale_k[li].at[wblk, :, off].set(ks)
-                sv = scale_v[li].at[wblk, :, off].set(vs)
-                new_sk.append(sk)
-                new_sv.append(sv)
-            else:
-                sk = sv = None
-            pk = pool_k[li].at[wblk, :, off].set(kw)
-            pv = pool_v[li].at[wblk, :, off].set(vw)
-            a = paged_attention(q, pk, pv, tables, posc,
-                                scale_k=sk, scale_v=sv,
-                                impl=attn_impl)                # (CH,H,D)
-            h = h + G._dense(a.reshape(CH, C), *lp["proj"])
-            h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
-            new_k.append(pk)
-            new_v.append(pv)
-        logits = G._logits_of(params, h)                       # (CH, V)
-        li_idx = jnp.clip(valid_len - 1 - start, 0, CH - 1)
-        first = pick(logits[li_idx], valid_len - 1, key)
-        return (tuple(new_k), tuple(new_v), tuple(new_sk),
-                tuple(new_sv), first)
+        h, new_k, new_v, new_sk, new_sv = _layers(
+            params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+            wblk, off,
+            lambda q, pk, pv, sk, sv: paged_attention(
+                q, pk, pv, tables, posc, scale_k=sk, scale_v=sv,
+                impl=attn_impl))                               # (CH,H,D)
+        with jax.named_scope("head"):
+            logits = G._logits_of(params, h)                   # (CH, V)
+        with jax.named_scope("pick"):
+            li_idx = jnp.clip(valid_len - 1 - start, 0, CH - 1)
+            first = pick(logits[li_idx], valid_len - 1, key)
+        return new_k, new_v, new_sk, new_sv, first
 
     serving_prefill_chunk.__name__ = name
     return serving_prefill_chunk
@@ -350,16 +358,17 @@ def _build_draft_step(H, acts, block_size, k, temperature, top_k,
                 params, acts, H, bs, False, attn_impl,
                 pk, pv, (), (), tables, cur, pos + j, active,
                 guard_msl=msl)
-            if greedy:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                d_probs.append(jnp.zeros_like(logits[..., :1]))
-            else:
-                lg = _top_k_logits(logits, temperature, top_k)
-                nxt = jax.vmap(
-                    lambda l, t, key: jax.random.categorical(
-                        jax.random.fold_in(key, t), l, axis=-1)
-                )(lg, pos + j, keys).astype(jnp.int32)
-                d_probs.append(jax.nn.softmax(lg, axis=-1))
+            with jax.named_scope("pick"):
+                if greedy:
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    d_probs.append(jnp.zeros_like(logits[..., :1]))
+                else:
+                    lg = _top_k_logits(logits, temperature, top_k)
+                    nxt = jax.vmap(
+                        lambda l, t, key: jax.random.categorical(
+                            jax.random.fold_in(key, t), l, axis=-1)
+                    )(lg, pos + j, keys).astype(jnp.int32)
+                    d_probs.append(jax.nn.softmax(lg, axis=-1))
             d_toks.append(nxt)
             cur = nxt
         return (pk, pv, jnp.stack(d_toks, axis=1),
@@ -389,25 +398,18 @@ def _build_draft_prefill_chunk(H, acts, block_size, blocks_per_seq,
         posw = start + jnp.arange(CH, dtype=jnp.int32)
         ok = posw < valid_len
         posc = jnp.clip(posw, 0, msl - 1)
-        h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-             + params["pe"][posc].astype(dt))                  # (CH, C)
+        with jax.named_scope("embed"):
+            h = (params["embed"][toks].astype(dt) * math.sqrt(C)
+                 + params["pe"][posc].astype(dt))              # (CH, C)
         blk_idx = jnp.clip(posc // bs, 0, nbps - 1)
         off = posc % bs
         wblk = jnp.where(ok, table_row[blk_idx], jnp.int32(0))
         tables = jnp.broadcast_to(table_row[None, :], (CH, nbps))
-        new_k, new_v = [], []
-        for li, (lp, act) in enumerate(zip(params["layers"], acts)):
-            x = G._ln(h, *lp["ln1"])
-            q, kw, vw = G._qkv_heads(G._dense(x, *lp["qkv"]), H)
-            pk = pool_k[li].at[wblk, :, off].set(kw)
-            pv = pool_v[li].at[wblk, :, off].set(vw)
-            a = paged_attention(q, pk, pv, tables, posc,
-                                impl=attn_impl)
-            h = h + G._dense(a.reshape(CH, C), *lp["proj"])
-            h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
-            new_k.append(pk)
-            new_v.append(pv)
-        return tuple(new_k), tuple(new_v)
+        _, new_k, new_v, _, _ = _layers(
+            params, acts, H, False, h, pool_k, pool_v, (), (), wblk, off,
+            lambda q, pk, pv, sk, sv: paged_attention(
+                q, pk, pv, tables, posc, impl=attn_impl))
+        return new_k, new_v
 
     serving_draft_prefill_chunk.__name__ = name
     return serving_draft_prefill_chunk
@@ -459,83 +461,67 @@ def _build_spec_verify(H, acts, block_size, k, temperature, top_k,
                             toks, pos, active, keys, draft_toks,
                             draft_probs, params):
         dt = params["embed"].dtype
-        B = toks.shape[0]
         C = params["embed"].shape[1]
         win = jnp.concatenate([toks[:, None], draft_toks], axis=1)
         posw = (pos[:, None]
                 + jnp.arange(T, dtype=jnp.int32)[None, :])     # (B, T)
         posc = jnp.clip(posw, 0, msl - 1)
-        h = (params["embed"][win].astype(dt) * math.sqrt(C)
-             + params["pe"][posc].astype(dt))                  # (B, T, C)
+        with jax.named_scope("embed"):
+            h = (params["embed"][win].astype(dt) * math.sqrt(C)
+                 + params["pe"][posc].astype(dt))              # (B, T, C)
         blk_idx = jnp.clip(posc // bs, 0, tables.shape[1] - 1)
         off = posc % bs
         wblk = jnp.take_along_axis(tables, blk_idx, axis=1)    # (B, T)
         wblk = jnp.where(active[:, None] & (posw < msl), wblk,
                          jnp.int32(0))
-        new_k, new_v, new_sk, new_sv = [], [], [], []
-        for li, (lp, act) in enumerate(zip(params["layers"], acts)):
-            x = G._ln(h, *lp["ln1"])
-            q, kw, vw = G._qkv_heads(G._dense(x, *lp["qkv"]), H)
-            if kv8:
-                kw, ks = quantize_kv(kw)   # (B,T,H,D) s8 / (B,T,H) f32
-                vw, vs = quantize_kv(vw)
-                sk = scale_k[li].at[wblk, :, off].set(ks)
-                sv = scale_v[li].at[wblk, :, off].set(vs)
-                new_sk.append(sk)
-                new_sv.append(sv)
+        h, new_k, new_v, new_sk, new_sv = _layers(
+            params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+            wblk, off,
+            lambda q, pk, pv, sk, sv: jnp.stack(
+                [paged_attention(q[:, j], pk, pv, tables, pos + j,
+                                 scale_k=sk, scale_v=sv, impl=attn_impl)
+                 for j in range(T)], axis=1))                  # (B,T,H,D)
+        with jax.named_scope("head"):
+            logits = G._logits_of(params, h)                   # (B,T,V)
+
+        with jax.named_scope("pick"):
+            if greedy:
+                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                match = (draft_toks == out[:, :k]).astype(jnp.int32)
+                alen = jnp.cumprod(match, axis=1).sum(axis=1)
             else:
-                sk = sv = None
-            pk = pool_k[li].at[wblk, :, off].set(kw)
-            pv = pool_v[li].at[wblk, :, off].set(vw)
-            att = [paged_attention(q[:, j], pk, pv, tables, pos + j,
-                                   scale_k=sk, scale_v=sv,
-                                   impl=attn_impl)
-                   for j in range(T)]
-            a = jnp.stack(att, axis=1)                         # (B,T,H,D)
-            h = h + G._dense(a.reshape(B, T, C), *lp["proj"])
-            h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
-            new_k.append(pk)
-            new_v.append(pv)
-        logits = G._logits_of(params, h)                       # (B,T,V)
+                lg = _top_k_logits(logits, temperature, top_k)
+                p = jax.nn.softmax(lg, axis=-1)                    # (B,T,V)
 
-        if greedy:
-            out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            match = (draft_toks == out[:, :k]).astype(jnp.int32)
-            alen = jnp.cumprod(match, axis=1).sum(axis=1)
-        else:
-            lg = _top_k_logits(logits, temperature, top_k)
-            p = jax.nn.softmax(lg, axis=-1)                    # (B,T,V)
+                def lane(lg_l, p_l, q_l, d_l, t0, key):
+                    ts = t0 + jnp.arange(k, dtype=jnp.int32)
+                    us = jax.vmap(lambda t: jax.random.uniform(
+                        jax.random.fold_in(
+                            jax.random.fold_in(key, _ACCEPT_SALT), t)))(ts)
+                    pd = jnp.take_along_axis(p_l[:k], d_l[:, None], 1)[:, 0]
+                    qd = jnp.take_along_axis(q_l, d_l[:, None], 1)[:, 0]
+                    acc = (us * jnp.maximum(qd, 1e-38) < pd).astype(jnp.int32)
+                    alen_l = jnp.cumprod(acc).sum()
+                    # first rejected position (clamped when all accepted —
+                    # then `last` selects the bonus instead)
+                    ri = jnp.minimum(alen_l, k - 1)
+                    resid = jnp.maximum(p_l[ri] - q_l[ri], 0.0)
+                    corr = jax.random.categorical(
+                        jax.random.fold_in(
+                            jax.random.fold_in(key, _RESID_SALT), t0 + ri),
+                        jnp.log(resid + 1e-38)).astype(jnp.int32)
+                    bonus = jax.random.categorical(
+                        jax.random.fold_in(key, t0 + k),
+                        lg_l[k]).astype(jnp.int32)
+                    last = jnp.where(alen_l == k, bonus, corr)
+                    d_pad = jnp.concatenate(
+                        [d_l, jnp.zeros((1,), jnp.int32)])
+                    out_l = jnp.where(jnp.arange(T) < alen_l, d_pad, last)
+                    return out_l, alen_l
 
-            def lane(lg_l, p_l, q_l, d_l, t0, key):
-                ts = t0 + jnp.arange(k, dtype=jnp.int32)
-                us = jax.vmap(lambda t: jax.random.uniform(
-                    jax.random.fold_in(
-                        jax.random.fold_in(key, _ACCEPT_SALT), t)))(ts)
-                pd = jnp.take_along_axis(p_l[:k], d_l[:, None], 1)[:, 0]
-                qd = jnp.take_along_axis(q_l, d_l[:, None], 1)[:, 0]
-                acc = (us * jnp.maximum(qd, 1e-38) < pd).astype(jnp.int32)
-                alen_l = jnp.cumprod(acc).sum()
-                # first rejected position (clamped when all accepted —
-                # then `last` selects the bonus instead)
-                ri = jnp.minimum(alen_l, k - 1)
-                resid = jnp.maximum(p_l[ri] - q_l[ri], 0.0)
-                corr = jax.random.categorical(
-                    jax.random.fold_in(
-                        jax.random.fold_in(key, _RESID_SALT), t0 + ri),
-                    jnp.log(resid + 1e-38)).astype(jnp.int32)
-                bonus = jax.random.categorical(
-                    jax.random.fold_in(key, t0 + k),
-                    lg_l[k]).astype(jnp.int32)
-                last = jnp.where(alen_l == k, bonus, corr)
-                d_pad = jnp.concatenate(
-                    [d_l, jnp.zeros((1,), jnp.int32)])
-                out_l = jnp.where(jnp.arange(T) < alen_l, d_pad, last)
-                return out_l, alen_l
-
-            out, alen = jax.vmap(lane)(lg, p, draft_probs, draft_toks,
-                                       pos, keys)
-        return (tuple(new_k), tuple(new_v), tuple(new_sk),
-                tuple(new_sv), out, alen.astype(jnp.int32))
+                out, alen = jax.vmap(lane)(lg, p, draft_probs, draft_toks,
+                                           pos, keys)
+        return new_k, new_v, new_sk, new_sv, out, alen.astype(jnp.int32)
 
     serving_spec_verify.__name__ = name
     return serving_spec_verify

@@ -1,32 +1,51 @@
-"""Unified timeline profiler + decode-stall attribution (ISSUE 17).
+"""Unified timeline profiler + decode-stall attribution (ISSUE 17, 28).
 
 Two halves, mirroring the reference MXNet's ``src/profiler/``
 operator/phase-scoped timeline for this repo's serving stack:
 
-**Per-step stall ledger.**  `EngineProfiler` is an always-on, bounded
+**Per-iteration stall ledger.**  `EngineProfiler` is an always-on
 host-side ledger the serving scheduler feeds: every scheduler-loop
-phase notes its wall time under a named cause, and at each decode-step
-commit `end_step()` closes one ledger decomposing the step's wall time
-(measured from the previous step's commit, so prefill interleave, lock
-waits and idle polls between steps are attributed, not lost) into:
+phase runs inside ``with prof.phase(cause):``, which notes the phase's
+own wall time under its cause AND holds a
+``jax.profiler.TraceAnnotation("serving.<cause>", it=<step>)`` open, so
+that under any ``jax.profiler`` trace the scheduler thread's phases
+stand in the host plane on the clock of the device's operations.  At
+each decode-step commit `end_step()` closes one `Iteration` record
+decomposing the wall time since the previous commit (so prefill
+interleave, lock waits and idle polls between steps are attributed,
+not lost) into:
 
-    device_step     decode device call (fault-hook injection included)
-    prefill         interleaved prefill device calls (legacy cause;
-                    chunked prefill notes prefill_chunk)
-    prefill_chunk   interleaved fixed-width prefill-chunk device calls
+    device_step     decode step: fault hook + the blocking token fetch
+    draft_step      speculative draft call (fault hook included)
+    verify_step     speculative verify: fault hook + the blocking fetch
+    prefill_chunk   prefill chunk: fault hook + the final chunk's fetch
+    dispatch        host time inside the program calls until they
+                    return (nested in the four causes above)
     gather_params   weight gather / requantize for the program call
     lock_wait       scheduler blocked acquiring the engine lock
-    bookkeeping     reap + admission reservation + commit sections
+    bookkeeping     reap + admission + chunk staging + lane snapshot
+    commit          the locked section after a step or a chunk
     wait            idle condition-wait polls (no live lanes)
     gc              GC pauses on the scheduler thread (``gc.callbacks``)
     host_other      unattributed residue
 
-The invariant is that the causes sum to the step wall time: phases are
-disjoint intervals by construction, ``host_other`` is the exact
-remainder, and ``gc`` is carved out of that remainder (a pause inside a
-timed phase is already inside that phase's interval — carving keeps the
-sum exact instead of double-counting).  Violations beyond tolerance are
-counted (``invariant_violations``) and gated in ci/serving_smoke.py.
+The invariant is that the causes sum to the iteration's wall time: a
+phase is charged its own time only (what phases nested in it took is
+theirs), ``host_other`` is the exact remainder, and ``gc`` is carved
+out of that remainder (a pause inside a timed phase is already inside
+that phase's interval — carving keeps the sum exact instead of
+double-counting).  Violations beyond tolerance are counted
+(``invariant_violations``) and gated in ci/serving_smoke.py.
+
+Records go into ONE process-wide bounded ring (`StepRing`, 32,768
+records) that outlives the engines: `iterations(since, until)` cuts it
+to a window of ``time.monotonic()`` — the clock of `Request.t_tokens` —
+and says whether the ring still held the window's start.  A record also
+carries the lanes' pool use (`blocks_reserved`, `positions_written`)
+and a stamp for every prefill chunk committed since the record before
+it.  `recent_steps()`, the merged trace's scheduler lane and
+``/profilez`` are served from the same ring.
+
 Causes export as ``serving_step_stall_seconds{cause=}`` histograms when
 telemetry is enabled; a hiccup detector flags steps slower than
 k × rolling-p50 and records a full-detail stall record (per-cause
@@ -37,19 +56,21 @@ ring served by ``/stallz`` and bundled by the flight recorder.
 engine: ``ServingEngine.capture_profile()``) assembles ONE
 chrome-trace/Perfetto JSON with named pid/tid lanes from the streams
 that today export separately: requestlog lifecycle spans (one lane per
-rid), tracer spans (per real thread), engine scheduler phases (one
-synthetic lane per engine), program timings from `telemetry.perf`,
-GC pauses and lock-witness contention events — so a single trace shows
-a request's admit→prefill→decode marks aligned against the engine loop
-that served it.  All streams share the CLOCK_MONOTONIC family
+rid), tracer spans (per real thread), engine scheduler iterations (one
+synthetic lane per engine: each iteration's causes laid end to end
+inside its interval — their true places are in a ``jax.profiler``
+trace), program timings from `telemetry.perf`, GC pauses and
+lock-witness contention events — so a single trace shows a request's
+admit→prefill→decode marks aligned against the engine loop that served
+it.  All streams share the CLOCK_MONOTONIC family
 (``time.perf_counter`` / ``time.monotonic`` on the platforms we run
 on), so events interleave on one axis.  `validate_chrome_trace` is the
 conformance checker both `tests/` and the CI smoke load traces with.
 
 Knobs (environment):
 
-* ``MXTPU_SERVING_PROFILER=0``   kill switch — ledger records nothing
-  (the <5 µs/step disabled path the overhead test pins);
+* ``MXTPU_SERVING_PROFILER=0``   kill switch — ledger records nothing,
+  no span opens (the <5 µs/step disabled path the overhead test pins);
 * ``MXTPU_PROFILER_HICCUP_K=K``  hiccup threshold multiplier over the
   rolling p50 (default 3.0);
 * ``MXTPU_STALLZ_RING=N``        hiccup ring size (default 64).
@@ -57,48 +78,61 @@ Knobs (environment):
 THE NO-HOST-SYNC RULE applies: everything here reads host clocks,
 already-host ints, or bounded deques — never device data.
 
-Thread-safety: the ledger's accumulation dict and event deque are
-touched only by the scheduler thread (`note`/`end_step`); published
-aggregates (totals, hiccup ring, recent ledgers) are guarded by one
-leaf lock held only for copies — never while acquiring another lock,
-so the runtime lock witness records no new ordering edges through it.
+Thread-safety: the ledger's accumulation state is touched only by the
+scheduler thread (`phase`/`note`/`chunk`/`end_step`); published
+aggregates (totals, hiccup ring) and the step ring are each guarded by
+one leaf lock held only for copies — never while acquiring another
+lock, so the runtime lock witness records no new ordering edges
+through them.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
+import operator
 import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import registry as _registry_mod
 
-__all__ = ["EngineProfiler", "register", "unregister", "profilers",
+_tracing = TraceAnnotation.is_enabled      # is a jax.profiler trace running
+
+__all__ = ["EngineProfiler", "Iteration", "StepRing", "iterations",
+           "register", "unregister", "profilers",
            "stallz", "merged_chrome_trace", "capture",
            "validate_chrome_trace", "install_gc_hooks",
            "uninstall_gc_hooks", "gc_hooks_installed", "gc_events",
            "gc_pause_seconds", "snapshot_lock_witness",
-           "DEFAULT_HICCUP_K", "DEFAULT_STALL_RING",
+           "DEFAULT_HICCUP_K", "DEFAULT_STALL_RING", "DEFAULT_STEP_RING",
            "CAUSES", "MAX_CAPTURE_S"]
 
 DEFAULT_HICCUP_K = float(os.environ.get("MXTPU_PROFILER_HICCUP_K", "3.0")
                          or 3.0)
 DEFAULT_STALL_RING = int(os.environ.get("MXTPU_STALLZ_RING", "64") or 64)
-# ledger causes (the serving_step_stall_seconds{cause=} label set);
-# draft_step/verify_step are the speculative-decoding iteration's two
-# device phases (ISSUE 19) — a speculative engine notes those instead
-# of device_step
-CAUSES = ("device_step", "draft_step", "verify_step", "prefill",
-          "prefill_chunk", "gather_params", "lock_wait", "bookkeeping",
-          "wait", "gc", "host_other")
+# iteration records the process keeps: a 54 s benchmark run at a 5 ms
+# step is 11,000; about 800 bytes a record
+DEFAULT_STEP_RING = 32768
+# ledger causes (the serving_step_stall_seconds{cause=} label set, and
+# the order of `Iteration.causes`); draft_step/verify_step are the
+# speculative-decoding iteration's two device phases (ISSUE 19) — a
+# speculative engine notes those instead of device_step
+CAUSES = ("device_step", "draft_step", "verify_step", "prefill_chunk",
+          "dispatch", "gather_params", "lock_wait", "bookkeeping",
+          "commit", "wait", "gc", "host_other")
+_INDEX = {c: i for i, c in enumerate(CAUSES)}
+_LOCK_WAIT, _GC, _HOST_OTHER = (_INDEX[c] for c in
+                                ("lock_wait", "gc", "host_other"))
 # /profilez sleeps on an HTTP handler thread — bound it
 MAX_CAPTURE_S = 30.0
-# phase events shorter than this don't land in the trace deque (a 2 µs
-# bookkeeping note per idle poll would drown the lane)
+# causes shorter than this get no slice in the merged trace's scheduler
+# lane (a 2 µs lock_wait per iteration would drown it)
 _EVENT_MIN_S = 20e-6
-_EVENT_BUF = 8192
 # steps a hiccup judgment needs in the rolling window before firing
 _MIN_SAMPLES = 8
 # and an absolute floor so microsecond jitter on an idle engine never
@@ -196,23 +230,174 @@ def gc_events(since: Optional[float] = None) -> List[dict]:
 
 
 # --------------------------------------------------------------------- #
+# the ring of iteration records (process-wide; outlives the engines)
+# --------------------------------------------------------------------- #
+class Iteration:
+    """One scheduler iteration of one engine: the previous decode-step
+    commit (``t0``) to this one (``t1``), both ``time.monotonic()``.
+
+    ``causes`` holds seconds in the order of `CAUSES` and sums to
+    ``t1 - t0``.  ``blocks_reserved`` and ``positions_written`` are sums
+    over the occupied lanes at the commit (a block shared through the
+    prefix cache counts once for every lane that holds it, in both, so
+    ``positions_written <= blocks_reserved * block_size`` always);
+    ``blocks_total`` is the pool's allocatable blocks, of
+    ``block_size`` positions each.  ``chunks`` is
+    ``(rid, start, n, t)`` for every prefill chunk committed since the
+    record before: ``n`` prompt tokens from position ``start``, ``t``
+    the stamp taken when the chunk's program call RETURNED — the host
+    had handed it over; only a prompt's final chunk is ever fetched.
+    """
+
+    __slots__ = ("engine", "step", "t0", "t1", "causes", "occupancy",
+                 "queue_depth", "blocks_reserved", "blocks_total",
+                 "block_size", "positions_written", "chunks")
+
+    def __init__(self, engine, step, t0, t1, causes, occupancy=0,
+                 queue_depth=0, blocks_reserved=0, blocks_total=0,
+                 block_size=0, positions_written=0, chunks=()):
+        self.engine = engine
+        self.step = step
+        self.t0 = t0
+        self.t1 = t1
+        self.causes = causes
+        self.occupancy = occupancy
+        self.queue_depth = queue_depth
+        self.blocks_reserved = blocks_reserved
+        self.blocks_total = blocks_total
+        self.block_size = block_size
+        self.positions_written = positions_written
+        self.chunks = chunks
+
+    def as_dict(self) -> dict:
+        d = {k: getattr(self, k) for k in self.__slots__}
+        d["causes"] = dict(zip(CAUSES, self.causes))
+        d["chunks"] = [list(c) for c in self.chunks]
+        d["wall_s"] = self.t1 - self.t0
+        return d
+
+
+class StepRing:
+    """Bounded ring of `Iteration` records, oldest dropped first.  One
+    per process (`iterations()` reads it); tests make their own."""
+
+    def __init__(self, cap: int = DEFAULT_STEP_RING):
+        self._ring: deque = deque(maxlen=max(1, int(cap)))
+        self._lock = threading.Lock()       # leaf: append and copy only
+
+    @property
+    def cap(self) -> int:
+        return self._ring.maxlen
+
+    def push(self, rec: Iteration) -> None:
+        with self._lock:
+            self._ring.append(rec)
+
+    def window(self, since: Optional[float] = None,
+               until: Optional[float] = None,
+               engine: Optional[str] = None
+               ) -> Tuple[List[Iteration], bool]:
+        """``(records, held)``: the records (of one engine, or of all)
+        whose ``t1`` lies in ``[since, until)``, oldest first, and
+        whether the ring still held ``since`` — False once it has
+        dropped records and its oldest one began after ``since`` (or
+        when it is empty): the window's start may be missing then."""
+        with self._lock:
+            recs = list(self._ring)
+        held = bool(recs) and (len(recs) < self.cap or (
+            since is not None and recs[0].t0 <= since))
+        return [r for r in recs
+                if (engine is None or r.engine == engine)
+                and (since is None or r.t1 >= since)
+                and (until is None or r.t1 < until)], held
+
+
+_steps = StepRing()
+
+
+def iterations(since: Optional[float] = None,
+               until: Optional[float] = None,
+               engine: Optional[str] = None
+               ) -> Tuple[List[Iteration], bool]:
+    """`StepRing.window` of the process's ring: every engine's
+    iterations, still there after ``engine.close()``."""
+    return _steps.window(since, until, engine)
+
+
+# --------------------------------------------------------------------- #
 # per-engine stall ledger
 # --------------------------------------------------------------------- #
-class EngineProfiler:
-    """Bounded per-step stall-attribution ledger for one engine.
+class _Phase:
+    """One cause's reusable ``with`` block (scheduler thread only; a
+    cause never nests in itself).  Notes its own time — what phases
+    nested in it took is theirs — and holds the profiler-clock span
+    open.  A phase that starts inside an open ``lock_wait`` ends it:
+    ``with prof.phase("lock_wait"), lock, prof.phase("commit"):`` reads
+    as it runs."""
 
-    The scheduler thread is the only caller of `note()`/`end_step()`
-    (accumulation needs no lock); HTTP/flight readers go through
-    `stallz()`/`stall_table()`/`chrome_events()`, which copy under one
-    leaf lock.  ``clock`` and ``gc_seconds`` are injectable for the
-    attribution-math tests.
+    __slots__ = ("_prof", "_idx", "_name", "_span", "_t0", "_inner",
+                 "_parent")
+
+    def __init__(self, prof: "EngineProfiler", cause: str):
+        self._prof = prof
+        self._idx = _INDEX[cause]
+        self._name = "serving." + cause
+        self._t0 = None
+
+    def __enter__(self):
+        p = self._prof
+        par = p._open
+        if par is not None and par._idx == _LOCK_WAIT:
+            par.__exit__()
+            par = p._open
+        self._parent = par
+        p._open = self
+        self._inner = 0.0
+        # jax's own check (44 ns), the one a TraceMe makes of itself:
+        # building the span costs 0.5 us, which only a running trace
+        # gets anything for
+        self._span = TraceAnnotation(self._name, it=p._it).__enter__() \
+            if _tracing() else None
+        self._t0 = p._clock()
+        return self
+
+    def __exit__(self, *exc):
+        t0 = self._t0
+        if t0 is None:                      # ended by the phase it led to
+            return False
+        p = self._prof
+        dur = p._clock() - t0
+        self._t0 = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        p._open = par = self._parent
+        if par is not None:
+            par._inner += dur
+        p._acc[self._idx] += dur - self._inner
+        return False
+
+
+_NO_PHASE = contextlib.nullcontext()      # what phase() is with the ledger off
+
+
+class EngineProfiler:
+    """Per-iteration stall-attribution ledger for one engine.
+
+    The scheduler thread is the only caller of `phase()`/`note()`/
+    `chunk()`/`end_step()` (accumulation needs no lock); HTTP/flight
+    readers go through `stallz()`/`stall_table()`/`recent_steps()`,
+    which copy under leaf locks.  ``clock`` (default
+    ``time.monotonic``, the clock of the requests' token stamps),
+    ``gc_seconds`` and ``steps`` (the ring records go to; default the
+    process's) are injectable for the tests.
     """
 
     def __init__(self, name: str, *, hiccup_k: Optional[float] = None,
                  ring: Optional[int] = None, window: int = 128,
-                 clock: Callable[[], float] = time.perf_counter,
+                 clock: Callable[[], float] = time.monotonic,
                  gc_seconds: Optional[Callable[[], float]] = None,
-                 enabled: Optional[bool] = None):
+                 enabled: Optional[bool] = None,
+                 steps: Optional[StepRing] = None):
         self.name = name
         self._clock = clock
         self._gc_seconds = gc_seconds if gc_seconds is not None \
@@ -221,7 +406,14 @@ class EngineProfiler:
             os.environ.get("MXTPU_SERVING_PROFILER", "1") != "0"
         self.hiccup_k = float(hiccup_k if hiccup_k is not None
                               else DEFAULT_HICCUP_K)
-        self._causes: Dict[str, float] = {}      # scheduler thread only
+        self._ring = steps if steps is not None else _steps
+        # scheduler thread only: the iteration in progress (seconds
+        # per cause, in the order of CAUSES)
+        self._acc = [0.0] * len(CAUSES)
+        self._chunks: List[tuple] = []
+        self._phases = {c: _Phase(self, c) for c in CAUSES}
+        self._open: Optional[_Phase] = None
+        self._it = 1                         # the step it will commit as
         self._step_t0 = self._clock()
         self._last_gc = self._gc_seconds()
         self._walls: deque = deque(maxlen=max(8, int(window)))
@@ -230,16 +422,14 @@ class EngineProfiler:
         self.steps = 0
         self.hiccups_total = 0
         self.invariant_violations = 0
-        self._events: deque = deque(maxlen=_EVENT_BUF)  # (name,cat,t0,dur)
         # published aggregates: copies only under this leaf lock, never
         # another lock while holding it (lock-witness discipline)
         self._pub = threading.Lock()
-        self._totals: Dict[str, float] = {}
+        self._totals = (0.0,) * len(CAUSES)  # replaced whole: no lock
         self._total_wall = 0.0
         self._hiccups: deque = deque(
             maxlen=max(1, int(ring if ring is not None
                               else DEFAULT_STALL_RING)))
-        self._recent: deque = deque(maxlen=64)   # last-N step ledgers
 
     # -- hot path (scheduler thread) ----------------------------------- #
     @property
@@ -252,40 +442,49 @@ class EngineProfiler:
         disabled era to the next step."""
         on = bool(on)
         if on and not self._enabled:
-            self._causes = {}
+            self._acc = [0.0] * len(CAUSES)
+            self._chunks = []
             self._step_t0 = self._clock()
             self._last_gc = self._gc_seconds()
         self._enabled = on
 
+    def phase(self, cause: str):
+        """``with prof.phase(cause):`` — time the block under ``cause``
+        and show it as the span ``serving.<cause>`` (argument ``it``:
+        the step this iteration will commit as) in any running
+        ``jax.profiler`` trace.  One flag read when the ledger is off."""
+        return self._phases[cause] if self._enabled else _NO_PHASE
+
     def note(self, cause: str, dur: float) -> None:
-        """Accumulate ``dur`` seconds under ``cause`` for the step in
-        progress.  One dict update when enabled; one flag read when not
-        (the <5 µs disabled-path budget)."""
-        if not self._enabled:
-            return
-        c = self._causes
-        c[cause] = c.get(cause, 0.0) + dur
-        if _registry_mod._enabled and dur >= _EVENT_MIN_S:
-            # deque append is atomic under the GIL; readers copy
-            self._events.append(
-                (cause, "scheduler", self._clock() - dur, dur))
+        """Accumulate ``dur`` seconds under ``cause`` for the iteration
+        in progress.  One list update when enabled; one flag read when
+        not (the <5 µs disabled-path budget)."""
+        if self._enabled:
+            self._acc[_INDEX[cause]] += dur
+
+    def chunk(self, rid: int, start: int, n: int, t: float) -> None:
+        """Stamp one committed prefill chunk (see `Iteration.chunks`)."""
+        if self._enabled:
+            self._chunks.append((rid, start, n, t))
 
     def end_step(self, *, rids=(), occupancy: int = 0,
-                 queue_depth: int = 0, step: int = 0) -> Optional[dict]:
-        """Close the ledger at a decode-step commit: compute the wall
-        since the previous commit, carve gc + residue, feed histograms,
-        judge the hiccup threshold.  Returns the stall record when the
-        step was flagged, else None."""
+                 queue_depth: int = 0, step: int = 0,
+                 blocks_reserved: int = 0, blocks_total: int = 0,
+                 block_size: int = 0,
+                 positions_written: int = 0) -> Optional[dict]:
+        """Close the iteration at a decode-step commit: compute the
+        wall since the previous commit, carve gc + residue, push the
+        record, feed histograms, judge the hiccup threshold.  Returns
+        the stall record when the step was flagged, else None."""
         if not self._enabled:
             return None
         now = self._clock()
-        wall = now - self._step_t0
-        self._step_t0 = now
-        causes, self._causes = self._causes, {}
-        attributed = 0.0
-        for v in causes.values():
-            attributed += v
-        residue = wall - attributed
+        t0, self._step_t0 = self._step_t0, now
+        wall = now - t0
+        acc, self._acc = self._acc, [0.0] * len(CAUSES)
+        chunks, self._chunks = self._chunks, []
+        self._it = step + 1
+        residue = wall - sum(acc)
         cur_gc = self._gc_seconds()
         gc_dt = cur_gc - self._last_gc
         self._last_gc = cur_gc
@@ -293,17 +492,25 @@ class EngineProfiler:
         # interval; only the part that fell in unattributed time can be
         # carved without breaking the sum-to-wall invariant
         gc_cause = min(gc_dt, residue) if gc_dt > 0 and residue > 0 else 0.0
-        causes["gc"] = causes.get("gc", 0.0) + gc_cause
-        causes["host_other"] = max(0.0, residue - gc_cause)
+        acc[_GC] += gc_cause
+        acc[_HOST_OTHER] = max(0.0, residue - gc_cause)
         self.steps += 1
-        total = sum(causes.values())
-        if wall > 0 and abs(total - wall) > 0.05 * wall + 1e-6:
+        # the sum is the wall by construction unless phases were
+        # charged more than the wall (overlapping notes)
+        if -residue > 0.05 * wall + 1e-6:
             self.invariant_violations += 1
+        rec = Iteration(self.name, step, t0, now, tuple(acc), occupancy,
+                        queue_depth, blocks_reserved, blocks_total,
+                        block_size, positions_written, tuple(chunks))
+        self._ring.push(rec)
+        self._totals = tuple(map(operator.add, self._totals, acc))
+        self._total_wall += wall
         if _registry_mod._enabled:
             reg = _reg()
-            for cause, s in causes.items():
-                reg.histogram("serving_step_stall_seconds",
-                              {"cause": cause}).observe(s)
+            for cause, s in zip(CAUSES, acc):
+                if s or cause in ("gc", "host_other"):
+                    reg.histogram("serving_step_stall_seconds",
+                                  {"cause": cause}).observe(s)
         # rolling p50 over the wall window, recomputed every 16 steps
         # (every step while the window is still small)
         walls = self._walls
@@ -314,46 +521,31 @@ class EngineProfiler:
             self._p50 = sorted(walls)[n // 2]
             self._p50_at = self.steps
         p50 = self._p50
-        rec = {"step": int(step), "t_end": now, "wall_s": wall,
-               "causes": {k: round(v, 6) for k, v in causes.items()},
-               "occupancy": int(occupancy),
-               "queue_depth": int(queue_depth)}
-        hic = None
-        if (n >= _MIN_SAMPLES and p50 is not None and p50 > 0
+        if not (n >= _MIN_SAMPLES and p50 is not None and p50 > 0
                 and wall > self.hiccup_k * p50
                 and wall > _MIN_HICCUP_WALL_S):
-            dominant = max(causes, key=causes.get)
-            hic = dict(rec, dominant=dominant, p50_s=round(p50, 6),
-                       ratio=round(wall / p50, 2),
-                       rids=[int(r) for r in rids])
-            self.hiccups_total += 1
-            if _registry_mod._enabled:
-                _reg().counter("serving_step_hiccups_total",
-                               {"engine": self.name}).inc()
-                self._events.append(
-                    ("hiccup", "stall", now - wall, wall))
+            return None
+        hic = dict(rec.as_dict(), dominant=CAUSES[acc.index(max(acc))],
+                   p50_s=round(p50, 6), ratio=round(wall / p50, 2),
+                   rids=[int(r) for r in rids])
+        self.hiccups_total += 1
+        if _registry_mod._enabled:
+            _reg().counter("serving_step_hiccups_total",
+                           {"engine": self.name}).inc()
         with self._pub:
-            t = self._totals
-            for cause, s in causes.items():
-                t[cause] = t.get(cause, 0.0) + s
-            self._total_wall += wall
-            self._recent.append(rec)
-            if hic is not None:
-                self._hiccups.append(hic)
+            self._hiccups.append(hic)
         return hic
 
     # -- readers (any thread) ------------------------------------------ #
     def stall_table(self) -> List[dict]:
         """Aggregate attribution rows, biggest cause first:
         ``{"cause", "total_s", "share", "per_step_ms"}``."""
-        with self._pub:
-            totals = dict(self._totals)
-            wall = self._total_wall
+        totals, wall = self._totals, self._total_wall
         steps = max(1, self.steps)
         rows = [{"cause": c, "total_s": round(s, 6),
                  "share": round(s / wall, 4) if wall > 0 else 0.0,
                  "per_step_ms": round(s / steps * 1e3, 4)}
-                for c, s in totals.items()]
+                for c, s in zip(CAUSES, totals) if s]
         rows.sort(key=lambda r: -r["total_s"])
         return rows
 
@@ -364,10 +556,11 @@ class EngineProfiler:
         return out if n is None else out[-int(n):]
 
     def recent_steps(self, n: Optional[int] = None) -> List[dict]:
-        """Recent per-step ledgers (bounded ring), oldest first."""
-        with self._pub:
-            out = [dict(r) for r in self._recent]
-        return out if n is None else out[-int(n):]
+        """This engine's iteration records still in the ring, as
+        dicts, oldest first (all by default)."""
+        recs = self._ring.window(engine=self.name)[0]
+        return [r.as_dict() for r in (recs if n is None
+                                      else recs[-int(n):])]
 
     def stallz(self) -> dict:
         """The per-engine ``/stallz`` payload: config, invariant
@@ -388,11 +581,21 @@ class EngineProfiler:
                 "hiccups": hiccups}
 
     def chrome_events(self, since: Optional[float] = None) -> List[tuple]:
-        """Phase-event tuples ``(name, cat, t0, dur)`` for the merged
-        trace, optionally only those ending at/after ``since``."""
-        out = _snap_deque(self._events)
-        if since is not None:
-            out = [e for e in out if e[2] + e[3] >= since]
+        """Slices ``(name, cat, t0, dur)`` for the merged trace's
+        scheduler lane, from the ring: each iteration's causes laid end
+        to end from its ``t0`` in `CAUSES` order (durations exact,
+        places not), and the flagged iterations as ``hiccup`` slices;
+        optionally only iterations ending at/after ``since``."""
+        out = []
+        for r in self._ring.window(since, engine=self.name)[0]:
+            t = r.t0
+            for cause, s in zip(CAUSES, r.causes):
+                if s >= _EVENT_MIN_S:
+                    out.append((cause, "scheduler", t, s))
+                t += s
+        out += [("hiccup", "stall", h["t0"], h["wall_s"])
+                for h in self.recent_stalls()
+                if since is None or h["t1"] >= since]
         return out
 
 

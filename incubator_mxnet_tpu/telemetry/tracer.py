@@ -12,10 +12,11 @@ Bridging (the "one timeline" tentpole requirement):
   finished span is mirrored into its chrome-trace event stream via
   `profiler.record_host_event`, so `profiler.dump()` interleaves
   telemetry spans with the profiler's own Task/Frame scopes;
-* while a device trace is active (`profiler.state() == "running"`),
-  span enter/exit also wraps a `jax.profiler.TraceAnnotation`, so the
-  host span appears inside the XLA TensorBoard timeline next to the
-  device ops it dispatched.
+* span enter/exit also wraps a `jax.profiler.TraceAnnotation`
+  (under a microsecond while no trace runs), so under ANY
+  `jax.profiler` trace — this repo's `profiler`, the benchmark's
+  tracer, a bare `start_trace` — the host span stands in the trace's
+  host plane next to the device ops it dispatched.
 
 Disabled path: `span()` returns a shared no-op context manager — one
 module-flag read, no allocation, no clock read.
@@ -28,6 +29,8 @@ import threading
 import time
 from collections import deque
 from typing import Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import registry as _registry
 
@@ -94,19 +97,10 @@ class _Span:
             return self
         self._active = True
         _stack().append(self.name)
-        # bridge into an active XLA device trace so host spans land in
-        # the TensorBoard timeline (only while the profiler runs — the
-        # TraceAnnotation costs a C++ call we don't pay otherwise)
-        from .. import profiler
-
-        if profiler.state() == "running":
-            try:
-                import jax
-
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
+        # bridge into whatever jax.profiler trace is running, whoever
+        # started it, so host spans land beside the device's operations
+        self._jax_ctx = TraceAnnotation(self.name)
+        self._jax_ctx.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -115,9 +109,8 @@ class _Span:
             return False
         self._active = False
         t1 = time.perf_counter()
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(exc_type, exc, tb)
-            self._jax_ctx = None
+        self._jax_ctx.__exit__(exc_type, exc, tb)
+        self._jax_ctx = None
         st = _stack()
         depth = len(st) - 1
         if st and st[-1] == self.name:
