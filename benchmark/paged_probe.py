@@ -1,19 +1,28 @@
-"""On-chip probe of the paged-attention kernel alone, at the serve
-phase's widths (B=32 lanes, H=16, D=64, block 16, 512 positions).
+"""On-chip probe of the paged-attention kernel alone, at the served
+cell's widths (B=32 lanes, H=16, D=64, block 16, 64 blocks a sequence,
+a pool of 1025 blocks laid out ``(num_blocks, block_size, H*D)``).
 
-    python benchmark/paged_probe.py          # needs one TPU chip
+    python benchmark/paged_probe.py [seed]   # needs one TPU chip
 
-Prints two JSON lines, each naming the device:
+Prints three JSON lines, each naming the device:
 
-* ``numerics`` — max |x − truth| on random bf16 data, where truth is the
-  dense gather on fp32 copies at matmul precision "highest": the kernel
-  traced at "highest" (its math), the kernel at the default precision on
-  fp32 copies and on the bf16 pool itself (what the engine runs), the
-  dense gather at the default precision (what the kernel replaced), and
-  the kernel over an int8 pool against the dequantized truth.
+* ``numerics`` — max |x − truth| on random bf16 data with every page
+  live, where truth is the dense gather on fp32 copies at matmul
+  precision "highest": the kernel traced at "highest" (its math), the
+  kernel at the default precision on fp32 copies and on the bf16 pool
+  itself (what the engine runs), the dense gather at the default
+  precision (what the kernel replaced), and the kernel over an int8 pool
+  against the dequantized truth.
 * ``time`` — median milliseconds of one call over ``REPS`` calls, host
-  clock around `block_until_ready`, for the kernel and the dense gather
-  on the bf16 pool, with the bytes of the pages the lanes attend to.
+  clock around `block_until_ready`, every page live, for the kernel and
+  the dense gather on the bf16 pool, with the bytes of the pages the
+  lanes attend to.
+* ``time_in_a_program`` — milliseconds a call when ``CALLS`` calls feed
+  one another inside one program, as a served program's 24 layers do,
+  with lanes placed like the cell's (prompt and output lengths
+  log-uniform over the ranges of ``perf/traffic/serve-batch.json``, a
+  lane somewhere along its output, blocks reserved for the whole
+  request): what `decode_step_ms` holds of the kernel.
 
 A reading of one run, not a benchmark: no cell, no gate.
 """
@@ -33,8 +42,10 @@ from incubator_mxnet_tpu.contrib.quantization import quantize_kv
 from incubator_mxnet_tpu.ops.paged_attention import (paged_attention,
                                                      paged_attention_dense)
 
-B, H, D, BS, NBPS = 32, 16, 64, 16, 32
-REPS = 50
+B, H, D, BS, NBPS, NB = 32, 16, 64, 16, 64, 1025
+REPS, CALLS = 50, 24
+TRAFFIC = os.path.join(os.path.dirname(__file__), "..", "perf", "traffic",
+                       "serve-batch.json")
 
 
 def _device():
@@ -57,17 +68,37 @@ def _ms(fn, *args):
     return statistics.median(times)
 
 
+def _cell_lanes(rs):
+    """Block tables and positions as the cell's traffic leaves them."""
+    with open(TRAFFIC) as f:
+        mix = json.load(f)
+    prompt, output = (
+        onp.exp(rs.uniform(onp.log(mix[key]["lo"]), onp.log(mix[key]["hi"]),
+                           B)).astype(int)
+        for key in ("prompt_len", "output_len"))
+    pos = prompt + (output * rs.uniform(0, 1, B)).astype(int)
+    reserved = onp.minimum(-(-(prompt + output) // BS), NBPS)
+    ids = rs.permutation(NB - 1) + 1
+    ends = onp.cumsum(reserved)
+    tables = onp.zeros((B, NBPS), onp.int32)   # the rest: the scratch block
+    for lane, (lo, hi) in enumerate(zip(ends - reserved, ends)):
+        tables[lane, :hi - lo] = ids[lo:hi]
+    return (jnp.asarray(tables), jnp.asarray(pos.clip(0, NBPS * BS - 1),
+                                             jnp.int32))
+
+
 def main():
     if jax.devices()[0].platform != "tpu":
         sys.exit("paged_probe.py needs a TPU")
-    nb = B * NBPS + 1
+    rs = onp.random.RandomState(int(sys.argv[1]) if len(sys.argv) > 1 else 0)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-    pk = jax.random.normal(kk, (nb, H, BS, D), jnp.bfloat16)
-    pv = jax.random.normal(kv, (nb, H, BS, D), jnp.bfloat16)
-    tables = jnp.asarray(onp.random.RandomState(0).permutation(nb - 1)
-                         .reshape(B, NBPS) + 1, jnp.int32)
-    pos = jnp.full((B,), NBPS * BS - 1, jnp.int32)   # every page is live
+    pk = jax.random.normal(kk, (NB, BS, H * D), jnp.bfloat16)
+    pv = jax.random.normal(kv, (NB, BS, H * D), jnp.bfloat16)
+    # every page live: half a lane's blocks its own, walked twice
+    own = rs.permutation(NB - 1)[:B * NBPS // 2].reshape(B, NBPS // 2) + 1
+    tables = jnp.asarray(onp.concatenate([own, own], axis=1), jnp.int32)
+    pos = jnp.full((B,), NBPS * BS - 1, jnp.int32)
     q32, pk32, pv32 = (x.astype(jnp.float32) for x in (q, pk, pv))
 
     def kernel(*a, **kw):
@@ -76,8 +107,10 @@ def main():
     with jax.default_matmul_precision("highest"):
         truth = paged_attention_dense(q32, pk32, pv32, tables, pos)
         at_highest = _err(kernel(q32, pk32, pv32), truth)
-    k8, sk = quantize_kv(pk32)
-    v8, sv = quantize_kv(pv32)
+    heads = (NB, BS, H, D)               # one scale a (slot, head)
+    k8, sk = quantize_kv(pk32.reshape(heads))
+    v8, sv = quantize_kv(pv32.reshape(heads))
+    k8, v8 = k8.reshape(pk.shape), v8.reshape(pv.shape)
     with jax.default_matmul_precision("highest"):
         truth8 = paged_attention_dense(q32, k8, v8, tables, pos, sk, sv)
     print(json.dumps({"probe": "numerics", "device": _device(),
@@ -100,6 +133,22 @@ def main():
                                                   pos),
                       "page_bytes_attended": 2 * B * NBPS * H * BS * D * 2}),
           flush=True)
+
+    cell_tables, cell_pos = _cell_lanes(rs)
+
+    @jax.jit
+    def program(q, pk, pv):
+        for _ in range(CALLS):
+            q = paged_attention(q, pk, pv, cell_tables, cell_pos,
+                                impl="pallas")
+        return q
+
+    print(json.dumps({"probe": "time_in_a_program", "device": _device(),
+                      "reps": REPS, "calls_a_program": CALLS,
+                      "pages_live": int((cell_pos // BS + 1).sum()),
+                      "pages_walked": B * NBPS,
+                      "ms_a_call_kernel_bf16": _ms(program, q, pk, pv)
+                      / CALLS}), flush=True)
 
 
 if __name__ == "__main__":

@@ -185,7 +185,7 @@ def phase_flash(clock, T=2048, B=8, steps=2, kernels=True, size=None):
           hbm_in_use_gb=_hbm_in_use_gb(), **clock.split())
 
 
-def _paged_vs_dense(engine, seed=0):
+def _paged_vs_dense(engine, num_heads, seed=0):
     """max |pallas - dense| of single-query attention over the engine's
     LIVE KV pool (layer 0), through block tables drawn from the blocks
     the run wrote, at ragged positions.  Float pools are compared on
@@ -202,9 +202,9 @@ def _paged_vs_dense(engine, seed=0):
         paged_attention, paged_attention_dense)
 
     pk, pv = engine._pool_k[0], engine._pool_v[0]
-    nb, H, bs, D = pk.shape
-    written = onp.flatnonzero(onp.asarray(
-        jnp.any(pk != 0, axis=(1, 2, 3))))
+    nb, bs, HD = pk.shape                  # a position a row of H*D
+    H, D = num_heads, HD // num_heads
+    written = onp.flatnonzero(onp.asarray(jnp.any(pk != 0, axis=(1, 2))))
     assert written.size >= 4, "the run left no KV pages behind"
     B, nbps = engine._B, engine._nbps
     rng = onp.random.RandomState(seed)
@@ -215,7 +215,8 @@ def _paged_vs_dense(engine, seed=0):
                           jnp.bfloat16).astype(jnp.float32)
     if engine.kv_dtype == "int8":
         scales = (engine._scale_k[0], engine._scale_v[0])
-        v = pv[written].astype(jnp.float32) * scales[1][written][..., None]
+        v = pv[written].astype(jnp.float32).reshape(-1, bs, H, D) \
+            * scales[1][written][..., None]
         # dequantized K (8-bit integer × fp32 scale) is not bf16-exact
         # either: the scores are rounded as well as the weights
         rel = 4 * PAGED_REL_DEFAULT
@@ -277,7 +278,7 @@ def _greedy_parity(net, prompt, got):
     return out
 
 
-def _serve_once(net, requests, vocab, **engine_kw):
+def _serve_once(net, requests, vocab, num_heads, **engine_kw):
     """Submit ``requests`` = [(prompt_len, max_new_tokens)] to a fresh
     engine, run its normal loop until all finish; returns (engine
     checks, the first request's prompt and tokens)."""
@@ -303,7 +304,7 @@ def _serve_once(net, requests, vocab, **engine_kw):
                   "requests": len(requests),
                   "tokens": sum(len(o) for o in outs),
                   "decode_steps": engine.stats()["steps"],
-                  **_paged_vs_dense(engine)}
+                  **_paged_vs_dense(engine, num_heads)}
     return checks, prompts[0], outs[0]
 
 
@@ -333,7 +334,7 @@ def phase_serve(clock, size=None, max_batch=16, max_seq_len=512,
                 (150, 20), (200, 28)]
     requests = [(p, n) for p, n in requests if p + n <= max_seq_len]
     engine_kw = {"max_batch": max_batch, "max_seq_len": max_seq_len}
-    checks, prompt, got = _serve_once(net, requests, V, **engine_kw)
+    checks, prompt, got = _serve_once(net, requests, V, H, **engine_kw)
     if kernels:
         assert checks["attn_impl"] == "pallas", checks
     checks.update(_greedy_parity(net, prompt, got))
@@ -342,8 +343,8 @@ def phase_serve(clock, size=None, max_batch=16, max_seq_len=512,
           hbm_in_use_gb=_hbm_in_use_gb(), **clock.split())
 
     clock.start()
-    checks, _, _ = _serve_once(net, requests[:3], V, kv_dtype="int8",
-                               **engine_kw)
+    checks, _, _ = _serve_once(net, requests[:3], V, H,
+                               kv_dtype="int8", **engine_kw)
     if kernels:
         assert checks["attn_impl"] == "pallas", checks
     assert checks["kv_dtype"] == "int8"

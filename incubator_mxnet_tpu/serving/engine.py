@@ -83,6 +83,7 @@ import numpy as np
 
 from .. import telemetry
 from ..models import generation as G
+from ..ops.paged_attention import pool_shapes
 from .kv_pool import SCRATCH_BLOCK, BlockPool
 from .programs import PagedPrograms
 
@@ -486,29 +487,27 @@ class ServingEngine:
         params = self._programs.gather_params(self._msl)
         G._record_decode_weight_bytes(params, self._programs._qc)
 
-        # device pool: per-layer (num_blocks, H, bs, D); the engine
+        # device pool: per-layer (num_blocks, bs, H*D), a position a
+        # row and a head a run of D lanes — the one shape the K/V
+        # write, the paged kernel and the donated buffer all take as it
+        # lies, row-major and unpadded (docs/serving.md).  The engine
         # holds the ONLY reference and replaces it after every donated
         # call (the buffers really are deleted on XLA:CPU too).  With
         # kv_dtype="int8" the pages are s8 and fp32 scale pools
-        # (num_blocks, H, bs) ride alongside — also donated.
+        # (num_blocks, bs, H) ride alongside — also donated.
         emb = params["embed"]
         H = net._layers[0].attn._num_heads
-        D = net._units // H
         dt = jnp.int8 if self._kv_dtype == "int8" else emb.dtype
         L = len(net._layers)
-        self._pool_k = tuple(
-            jnp.zeros((self._num_blocks, H, self._bs, D), dt)
-            for _ in range(L))
-        self._pool_v = tuple(
-            jnp.zeros((self._num_blocks, H, self._bs, D), dt)
-            for _ in range(L))
+        page, scales = pool_shapes(self._num_blocks, self._bs, H,
+                                   net._units // H)
+        self._pool_k = tuple(jnp.zeros(page, dt) for _ in range(L))
+        self._pool_v = tuple(jnp.zeros(page, dt) for _ in range(L))
         if self._kv_dtype == "int8":
             self._scale_k = tuple(
-                jnp.ones((self._num_blocks, H, self._bs), jnp.float32)
-                for _ in range(L))
+                jnp.ones(scales, jnp.float32) for _ in range(L))
             self._scale_v = tuple(
-                jnp.ones((self._num_blocks, H, self._bs), jnp.float32)
-                for _ in range(L))
+                jnp.ones(scales, jnp.float32) for _ in range(L))
         else:
             self._scale_k = self._scale_v = ()
         # speculative draft KV pool: per-draft-layer arrays in the
@@ -519,15 +518,14 @@ class ServingEngine:
         if self._spec:
             dnet = self._programs.draft_net
             dparams = self._programs.draft_params(self._msl)
-            dH = dnet._layers[0].attn._num_heads
-            dD = dnet._units // dH
             ddt = dparams["embed"].dtype
+            dH = dnet._layers[0].attn._num_heads
+            dpage, _ = pool_shapes(self._num_blocks, self._bs, dH,
+                                   dnet._units // dH)
             self._dpool_k = tuple(
-                jnp.zeros((self._num_blocks, dH, self._bs, dD), ddt)
-                for _ in range(len(dnet._layers)))
+                jnp.zeros(dpage, ddt) for _ in range(len(dnet._layers)))
             self._dpool_v = tuple(
-                jnp.zeros((self._num_blocks, dH, self._bs, dD), ddt)
-                for _ in range(len(dnet._layers)))
+                jnp.zeros(dpage, ddt) for _ in range(len(dnet._layers)))
         # pool byte footprint is STATIC (donation replaces arrays, never
         # shapes) — freeze it here so ops-side readers never touch the
         # live pool tuples the scheduler thread is rewriting.  Draft

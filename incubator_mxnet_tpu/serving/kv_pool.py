@@ -1,7 +1,9 @@
 """Paged KV-cache block accounting for the serving engine.
 
 The pool itself is a pair of per-layer device arrays of shape
-``(num_blocks, H, block_size, D)`` owned by the engine; THIS module is
+``(num_blocks, block_size, H*D)`` owned by the engine — a position a
+row of its page, the one layout the K/V write, the paged kernel and the
+donated buffer share (docs/serving.md, "The pool's layout"); THIS module is
 only the host-side allocator that decides which block ids a sequence
 may write.  Splitting the accounting from the arrays keeps the device
 side static-shaped (admitting or evicting a sequence never changes an

@@ -38,7 +38,12 @@ Both donate the pool arrays and their scale pools
 (``donate_argnums=(0, 1, 2, 3)``): the K/V pool
 is a ring the engine threads through every call, and an un-donated
 pool would copy the whole cache per token.  Donation coverage is
-CI-pinned via `.hlolint_contracts.json` (serving_* entries).
+CI-pinned via `.hlolint_contracts.json` (serving_* entries).  A pool
+array is ``(num_blocks, block_size, H*D)``: the one shape the donated
+buffer, the K/V write (`ops.paged_attention.write_rows`) and the paged
+kernel's page block all take as it lies, so the compiled program holds
+no copy of it (`tests/test_chip_compile.py` pins that for a described
+v5e; docs/serving.md, "The pool's layout").
 
 Numerics: the step attention dispatches through
 `ops.paged_attention` — on CPU (and whenever ``attn_impl="dense"``)
@@ -74,7 +79,8 @@ import jax.numpy as jnp
 from .. import telemetry
 from ..contrib.quantization import quantize_kv
 from ..models import generation as G
-from ..ops.paged_attention import default_impl, paged_attention
+from ..ops.paged_attention import (default_impl, paged_attention,
+                                   write_rows)
 
 __all__ = ["PagedPrograms"]
 
@@ -164,14 +170,14 @@ def _layers(params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
                 if kv8:
                     k, ks = quantize_kv(k)  # s8 values / f32 scales
                     v, vs = quantize_kv(v)
-                    sk = scale_k[li].at[wblk, :, off].set(ks)
-                    sv = scale_v[li].at[wblk, :, off].set(vs)
+                    sk = write_rows(scale_k[li], wblk, off, ks)
+                    sv = write_rows(scale_v[li], wblk, off, vs)
                     new_sk.append(sk)
                     new_sv.append(sv)
                 else:
                     sk = sv = None
-                pk = pool_k[li].at[wblk, :, off].set(k)
-                pv = pool_v[li].at[wblk, :, off].set(v)
+                pk = write_rows(pool_k[li], wblk, off, k)
+                pv = write_rows(pool_v[li], wblk, off, v)
             with jax.named_scope("paged_attn"):
                 a = attend(q, pk, pv, sk, sv)
             h = h + G._dense(a.reshape(h.shape), *lp["proj"])
@@ -233,9 +239,10 @@ def _build_step(H, acts, block_size, blocks_per_seq, temperature, top_k,
     """The batched one-token decode program over the paged pool.
 
     Arguments (all traced):
-      pool_k/pool_v    per-layer tuples, each (num_blocks, H, bs, D) —
-                       s8 when ``kv_dtype="int8"``, model dtype else
-      scale_k/scale_v  per-layer fp32 scale pools (num_blocks, H, bs)
+      pool_k/pool_v    per-layer tuples, each (num_blocks, bs, H*D): a
+                       position a row, a head a run of D lanes — s8
+                       when ``kv_dtype="int8"``, model dtype else
+      scale_k/scale_v  per-layer fp32 scale pools (num_blocks, bs, H)
                        for the int8 pool; EMPTY tuples on the float path
       tables           (B, blocks_per_seq) int32 block ids per lane
       toks             (B,) int32 — token emitted by the previous step

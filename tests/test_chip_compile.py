@@ -17,6 +17,7 @@ the xdist worker that owns this file loads the TPU library; everything
 built from it (shardings, meshes, shapes) is built in the test.
 """
 import importlib
+import math
 import os
 import re
 
@@ -68,7 +69,8 @@ def one_chip(v5e):
 # serve phase widths (H=16, D=64, block 16, 512 positions), batch 32
 _B, _H, _D, _BS, _NBPS = 32, 16, 64, 16, 32
 _NB = _B * _NBPS + 1
-_PAGE = (_NB, _H, _BS, _D)
+_PAGE = (_NB, _BS, _H * _D)     # a position a row, a head a run of D
+_SCALE = (_NB, _BS, _H)
 
 
 def _paged_bf16(S):
@@ -80,7 +82,7 @@ def _paged_bf16(S):
 def _paged_int8(S):
     return _paged._paged_core_q8.lower(
         S((_B, _H, _D), bf16), S(_PAGE, i8), S(_PAGE, i8),
-        S(_PAGE[:3], f32), S(_PAGE[:3], f32),
+        S(_SCALE, f32), S(_SCALE, f32),
         S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
 
 
@@ -163,6 +165,101 @@ def test_kernel_keeps_its_name_for_v5e(one_chip, name):
         calls = re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call",
                            hlo)
         assert calls, (name, kernel)
+
+
+# --- the served programs: one layout for the KV pool -------------------- #
+# the benchmark cell's engine (gpt2-medium.serve-batch) at two layers
+_CELL_B, _CELL_NBPS, _CELL_NB, _CELL_CHUNK, _CELL_L = 32, 64, 1025, 32, 2
+_CELL_PAGE = (_CELL_NB, _BS, _H * _D)
+_CELL_SCALE = (_CELL_NB, _BS, _H)
+
+
+@pytest.fixture(scope="module")
+def cell_weights():
+    """(shapes of the weight pytree, activations) of a two-layer decoder
+    at the cell's widths, as `PagedPrograms` hands them to its programs."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models import generation as G
+    from incubator_mxnet_tpu.models.transformer import TransformerLM
+    from incubator_mxnet_tpu.ndarray.ndarray import NDArray
+
+    mx.random.seed(0)
+    units = _H * _D
+    net = TransformerLM(vocab=512, units=units, hidden_size=4 * units,
+                        num_layers=_CELL_L, num_heads=_H,
+                        max_len=_CELL_NBPS * _BS, dropout=0.0)
+    net.initialize()
+    net(NDArray(jnp.ones((1, 4), i32)))
+    net.cast("bfloat16")
+    params = G._gather_params(net, _CELL_NBPS * _BS)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
+    return shapes, tuple(lyr.ffn._act for lyr in net._layers)
+
+
+def _served_program(program, kv_dtype, acts):
+    """(the real program body, its arguments' shapes after the pools)."""
+    from incubator_mxnet_tpu.serving import programs as SP
+
+    if program == "serving_step":
+        fn = SP._build_step(_H, acts, _BS, _CELL_NBPS, 0.0, 0, kv_dtype,
+                            "pallas", program)
+        rest = [((_CELL_B, _CELL_NBPS), i32), ((_CELL_B,), i32),
+                ((_CELL_B,), i32), ((_CELL_B,), jnp.bool_),
+                ((_CELL_B, 2), jnp.uint32)]
+    else:
+        fn = SP._build_prefill_chunk(_H, acts, _BS, _CELL_NBPS, _CELL_CHUNK,
+                                     0.0, 0, kv_dtype, "pallas", program)
+        rest = [((_CELL_NBPS,), i32), ((_CELL_CHUNK,), i32), ((), i32),
+                ((), i32), ((2,), jnp.uint32)]
+    return fn, rest
+
+
+def _copies_of(hlo, dtype, shape):
+    dims = ",".join(map(str, shape))
+    return re.findall(rf"= {dtype}\[{dims}\]\S* copy\(", hlo)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "kv8"])
+@pytest.mark.parametrize("program", ["serving_step", "serving_prefill_chunk"])
+def test_served_program_leaves_the_pool_where_it_lies(
+        one_chip, monkeypatch, cell_weights, program, kv_dtype):
+    """The K/V write, the paged kernel and the donated buffer agree on
+    one layout of the pool (docs/serving.md, "The pool's layout"): the
+    compiled program copies no K/V pool array, holds no pool-sized
+    temporary, and returns every pool in its argument's buffer.  Over
+    the ``(num_blocks, H, bs, D)`` pool the same compile held three
+    copies of every pool array (12 here, 144 in the 24-layer cell) and
+    temporaries of twice the pool."""
+    shapes, acts = cell_weights
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kv8 = kv_dtype == "int8"
+    pool = (S(_CELL_PAGE, i8 if kv8 else bf16),) * _CELL_L
+    scale = (S(_CELL_SCALE, f32),) * _CELL_L if kv8 else ()
+    fn, rest = _served_program(program, kv_dtype, acts)
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), shapes)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3)).lower(
+        pool, pool, scale, scale, *(S(*sd) for sd in rest), params).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == _CELL_L      # the real kernel
+    # (a) no copy of a K/V pool array
+    assert not _copies_of(hlo, "s8" if kv8 else "bf16", _CELL_PAGE)
+    # the fp32 scale pools keep one copy in and one out: with 16 heads in
+    # its last place the device's own layout of that array makes
+    # `num_blocks` minor, and a Mosaic call takes row-major alone (the
+    # array is a sixteenth of its page pool)
+    assert len(_copies_of(hlo, "f32", _CELL_SCALE)) <= 2 * len(scale) * 2
+    # (b) no pool-sized temporary
+    page_bytes = math.prod(_CELL_PAGE) * (1 if kv8 else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < page_bytes
+    # (c) every pool output aliases its argument
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    for i in range(2 * len(pool) + 2 * len(scale)):
+        assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
 
 
 # --- the same kernels in a program traced over the 2x2 mesh ------------- #

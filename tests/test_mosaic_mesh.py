@@ -95,7 +95,9 @@ def _paged_int8(q, pool, tables, pos, labels):
     from incubator_mxnet_tpu.contrib.quantization import quantize_kv
     from incubator_mxnet_tpu.ops.paged_attention import paged_attention
 
-    k8, sk = quantize_kv(pool)
+    H, D = q.shape[1], q.shape[3]     # a page row is H runs of D
+    k8, sk = quantize_kv(pool.reshape(pool.shape[:2] + (H, D)))
+    k8 = k8.reshape(pool.shape)
     return paged_attention(q[:, :, 0], k8, k8, tables, pos, scale_k=sk,
                            scale_v=sk, impl="pallas")
 
@@ -126,7 +128,7 @@ def test_kernel_per_shard_matches_one_device(kernel):
     B, H, T, D, bs, nbps = 4, 4, 64, 16, 4, 4
     k = jax.random.PRNGKey(0)
     args = (jax.random.normal(k, (B, H, T, D), jnp.float32),
-            jax.random.normal(k, (B * nbps + 1, H, bs, D), jnp.float32),
+            jax.random.normal(k, (B * nbps + 1, bs, H * D), jnp.float32),
             jnp.arange(1, B * nbps + 1, dtype=jnp.int32).reshape(B, nbps),
             jnp.array([0, 5, 9, 15], jnp.int32),
             jnp.arange(64, dtype=jnp.int32))

@@ -5,10 +5,14 @@ attention.
 The load-bearing contracts:
 
 * **Kernel/dense parity** — the online-softmax Pallas kernel (grid over
-  (lane, head), KV pages read straight from the pool) agrees with the
+  (lane, page), KV pages read straight from the pool) agrees with the
   dense-gather reference to fp32 roundoff for ragged per-lane lengths
   and permuted block tables, in f32 and bf16, with and without int8
   pages.
+* **One layout** — the pool is ``(num_blocks, block_size, H*D)``; the
+  serving programs' write, the kernel and the dense recipe read a
+  position as the same bytes, and the dense recipe's output is bit for
+  bit what it was over the ``(num_blocks, H, block_size, D)`` pool.
 * **Path isolation** — an engine runs ONE attention impl for its whole
   life; within the forced-pallas path eviction bit-identity holds
   exactly, and across paths greedy tokens agree (dispatch never mixes
@@ -57,25 +61,60 @@ def _rand_pool(key, shape, dtype):
     return jax.random.normal(key, shape).astype(dtype)
 
 
+def _pages(x):
+    """Keys or values ``(num_blocks, H, bs, D)``, or their scales
+    ``(num_blocks, H, bs)``, laid out as the pool holds them: a position
+    a row, ``(num_blocks, bs, H*D)`` or ``(num_blocks, bs, H)``."""
+    x = jnp.swapaxes(x, 1, 2)
+    return x.reshape(x.shape[:2] + (-1,))
+
+
 def _paged_case(seed, B=3, heads=2, D=16, bs=8, nbps=4, dtype=jnp.float32):
-    """Random pool + permuted tables + ragged per-lane positions."""
+    """Random K/V by (block, head, slot) + permuted tables + ragged
+    per-lane positions."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     nblocks = B * nbps + 3  # spare blocks hold garbage the walk must skip
-    pool_k = _rand_pool(keys[0], (nblocks, heads, bs, D), dtype)
-    pool_v = _rand_pool(keys[1], (nblocks, heads, bs, D), dtype)
+    k = _rand_pool(keys[0], (nblocks, heads, bs, D), dtype)
+    v = _rand_pool(keys[1], (nblocks, heads, bs, D), dtype)
     q = _rand_pool(keys[2], (B, heads, D), dtype)
     tables = jax.random.permutation(keys[3],
                                     jnp.arange(B * nbps, dtype=jnp.int32))
     tables = tables.reshape(B, nbps)
     # ragged: lane 0 one token, lane 1 mid-block, lane 2 pool-full
     pos = jnp.array([0, bs + 3, bs * nbps - 1][:B], jnp.int32)
-    return q, pool_k, pool_v, tables, pos
+    return q, k, v, tables, pos
+
+
+def _former_dense(q, k, v, tables, pos, scale_k=None, scale_v=None):
+    """`paged_attention_dense` as it stood while the pool was
+    ``(num_blocks, H, bs, D)``, kept here word for word: what the CPU
+    engines' eviction and greedy-parity contracts were pinned on."""
+    B, nbps = tables.shape
+    H, bs, D = k.shape[1], k.shape[2], k.shape[3]
+    W = nbps * bs
+    if scale_k is not None:
+        gk = k[tables].astype(jnp.float32) * scale_k[tables][..., None]
+        gv = v[tables].astype(jnp.float32) * scale_v[tables][..., None]
+        gk = gk.transpose(0, 2, 1, 3, 4).reshape(B, H, W, D)
+        gv = gv.transpose(0, 2, 1, 3, 4).reshape(B, H, W, D)
+    else:
+        gk = k[tables].transpose(0, 2, 1, 3, 4).reshape(B, H, W, D)
+        gv = v[tables].transpose(0, 2, 1, 3, 4).reshape(B, H, W, D)
+    s = jnp.einsum("bhd,bhkd->bhk", q, gk,
+                   preferred_element_type=jnp.float32) / math.sqrt(D)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(kpos <= pos[:, None, None], s,
+                  jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhk,bhkd->bhd", p, gv,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 2e-2)])
 def test_pallas_kernel_matches_dense_ragged(dtype, tol):
-    q, pk, pv, tables, pos = _paged_case(0, dtype=dtype)
+    q, k, v, tables, pos = _paged_case(0, dtype=dtype)
+    pk, pv = _pages(k), _pages(v)
     dense = paged_attention(q, pk, pv, tables, pos, impl="dense")
     pallas = paged_attention(q, pk, pv, tables, pos, impl="pallas",
                              interpret=True)
@@ -85,24 +124,130 @@ def test_pallas_kernel_matches_dense_ragged(dtype, tol):
 
 
 def test_pallas_kernel_matches_dense_int8_pages():
-    q, pk, pv, tables, pos = _paged_case(1)
-    qk, sk = quantize_kv(pk)
-    qv, sv = quantize_kv(pv)
-    dense = paged_attention(q, qk, qv, tables, pos,
-                            scale_k=sk, scale_v=sv, impl="dense")
-    pallas = paged_attention(q, qk, qv, tables, pos,
-                             scale_k=sk, scale_v=sv, impl="pallas",
-                             interpret=True)
+    q, k, v, tables, pos = _paged_case(1)
+    qk, sk = quantize_kv(k)
+    qv, sv = quantize_kv(v)
+    pools = (_pages(qk), _pages(qv))
+    scales = dict(scale_k=_pages(sk), scale_v=_pages(sv))
+    dense = paged_attention(q, *pools, tables, pos, impl="dense", **scales)
+    pallas = paged_attention(q, *pools, tables, pos, impl="pallas",
+                             interpret=True, **scales)
     onp.testing.assert_allclose(onp.asarray(pallas), onp.asarray(dense),
                                 atol=2e-5)
     # quantization error itself stays small vs the float pool
-    ref = paged_attention(q, pk, pv, tables, pos, impl="dense")
+    ref = paged_attention(q, _pages(k), _pages(v), tables, pos, impl="dense")
     onp.testing.assert_allclose(onp.asarray(dense), onp.asarray(ref),
                                 atol=0.05)
 
 
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+def test_dense_recipe_is_bit_identical_to_its_former_output(kind):
+    """The pool's layout changes how the dense recipe gathers its view
+    and nothing else: on the same K/V it returns the bits it returned
+    over the ``(num_blocks, H, bs, D)`` pool."""
+    dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+    q, k, v, tables, pos = _paged_case(4, dtype=dtype)
+    if kind == "int8":
+        (k, sk), (v, sv) = quantize_kv(k), quantize_kv(v)
+        former = _former_dense(q, k, v, tables, pos, sk, sv)
+        now = paged_attention_dense(q, _pages(k), _pages(v), tables, pos,
+                                    _pages(sk), _pages(sv))
+    else:
+        former = _former_dense(q, k, v, tables, pos)
+        now = paged_attention_dense(q, _pages(k), _pages(v), tables, pos)
+    assert now.dtype == former.dtype
+    assert onp.array_equal(onp.asarray(now, onp.float32),
+                           onp.asarray(former, onp.float32))
+
+
+def _plain_attention(q, k, v):
+    """One query (H, D) over one sequence's keys and values (T, H, D),
+    in float64 on the host: no pool, no table, no mask."""
+    q, k, v = (onp.asarray(x, onp.float64) for x in (q, k, v))
+    s = onp.einsum("hd,thd->ht", q, k) / math.sqrt(q.shape[-1])
+    p = onp.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return onp.einsum("ht,thd->hd", p, v)
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "int8"])
+def test_pool_written_by_the_programs_write_reads_back(kv8):
+    """The write, the kernel and the dense recipe agree on which bytes
+    a position is: K/V go into the pool position by position through
+    the serving programs' own write — a chunk's window, then single
+    steps — and both impls are held to a plain attention over each
+    sequence's K/V at position 0, a block's last slot, a block's first
+    slot and the sequence's last position, with an inactive lane
+    writing to the scratch block beside them."""
+    from incubator_mxnet_tpu.ops.paged_attention import write_rows
+
+    H, D, bs, nbps, chunk = 2, 16, 8, 3, 8
+    T = nbps * bs
+    ends = [0, bs - 1, bs, T - 1]         # last written position per lane
+    B = len(ends) + 1                     # + the inactive lane
+    nb = 1 + len(ends) * nbps
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    k = jax.random.normal(keys[0], (B, T, H, D))
+    v = jax.random.normal(keys[1], (B, T, H, D))
+    q = jax.random.normal(keys[2], (B, H, D))
+    tables = onp.zeros((B, nbps), onp.int32)      # inactive lane: scratch
+    tables[:len(ends)] = 1 + onp.random.RandomState(0).permutation(
+        len(ends) * nbps).reshape(len(ends), nbps)
+    pools = [jnp.zeros((nb, bs, H * D), jnp.int8 if kv8 else jnp.float32)
+             for _ in range(2)]
+    scales = [jnp.ones((nb, bs, H), jnp.float32) for _ in range(2)]
+
+    def write(wblk, off, kx, vx):
+        for i, x in enumerate((kx, vx)):
+            if kv8:
+                x, sx = quantize_kv(x)
+                scales[i] = write_rows(scales[i], wblk, off, sx)
+            pools[i] = write_rows(pools[i], wblk, off, x)
+
+    # the first block of every lane as one chunk's window (positions
+    # past a lane's end land in scratch, as `serving_prefill_chunk`
+    # points them), the rest as decode steps over all lanes at once
+    for lane, end in enumerate(ends):
+        posw = onp.arange(chunk)
+        ok = posw <= end
+        wblk = onp.where(ok, tables[lane, posw // bs], 0)
+        write(jnp.asarray(wblk), jnp.asarray(posw % bs),
+              k[lane, :chunk], v[lane, :chunk])
+    for t in range(chunk, T):
+        active = onp.array([t <= end for end in ends] + [False])
+        wblk = onp.where(active, tables[:, t // bs], 0)
+        write(jnp.asarray(wblk), jnp.full((B,), t % bs, jnp.int32),
+              k[:, t], v[:, t])
+
+    pos = jnp.asarray(ends + [0], jnp.int32)
+    kw = dict(scale_k=scales[0], scale_v=scales[1]) if kv8 else {}
+    got = {impl: onp.asarray(paged_attention(
+        q, pools[0], pools[1], jnp.asarray(tables), pos, impl=impl,
+        interpret=True, **kw)) for impl in ("pallas", "dense")}
+    for lane, end in enumerate(ends):
+        kk, vv = k[lane, :end + 1], v[lane, :end + 1]
+        if kv8:   # what the pool holds: the quantized values, dequantized
+            kk, vv = (x8.astype(jnp.float32) * sx[..., None]
+                      for x8, sx in (quantize_kv(kk), quantize_kv(vv)))
+        want = _plain_attention(q[lane], kk, vv)
+        for impl in got:
+            onp.testing.assert_allclose(got[impl][lane], want, atol=2e-5,
+                                        err_msg=f"{impl} lane {lane}")
+    # every lane's pages hold its own rows and nothing past its end
+    rows = onp.asarray(pools[0]).reshape(nb, bs, H, D)
+    for lane, end in enumerate(ends):
+        blk, slot = tables[lane, end // bs], end % bs
+        want = quantize_kv(k[lane, end])[0] if kv8 else k[lane, end]
+        assert onp.array_equal(rows[blk, slot], onp.asarray(want))
+        assert not rows[blk, slot + 1:].any()
+    # the inactive lane's writes, and the windows' tails, went to scratch
+    assert rows[0].any()
+    assert all(onp.isfinite(g[-1]).all() for g in got.values())
+
+
 def test_paged_attention_validates_impl():
-    q, pk, pv, tables, pos = _paged_case(2, B=1, nbps=1)
+    q, k, v, tables, pos = _paged_case(2, B=1, nbps=1)
+    pk, pv = _pages(k), _pages(v)
     with pytest.raises(ValueError):
         paged_attention(q, pk, pv, tables, pos, impl="banana")
     assert default_impl("tpu") == "pallas"
