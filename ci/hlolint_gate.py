@@ -33,6 +33,12 @@ evaluates the committed `.hlolint_contracts.json`:
   pool sets and everything stays on-device / collective-free /
   f64-free — speculation is a throughput lever, not a numerics change
 
+* ``serving_*_float_ssm`` — the same two programs for a decoder with
+  recurrent (Mamba) layers: a second donated state, the float32
+  recurrent state and the conv window a layer, rides beside the pools
+  and must be donated with them (a miss doubles the state's memory
+  every step)
+
 Contract context (``ctx``) carries the run's ground truth: the mesh
 size ``D``, the bucket count ``n_buckets``, the global gradient bytes
 ``grad_bytes``, and the quantized weight shapes — so contracts can say
@@ -197,6 +203,17 @@ def _serving_programs():
     net.quantize_for_decode(act_quant="none")
     with ServingEngine(net, **kws) as eng:
         eng.submit(prompt, N).result(timeout=60)   # serving_*_int8
+    from incubator_mxnet_tpu.models.hybrid_ssm import HybridSSMDecoder
+
+    mx.random.seed(7)
+    hybrid = HybridSSMDecoder(
+        vocab_size=V, hidden_size=C, intermediate_size=DFF,
+        num_hidden_layers=2, num_attention_heads=H, num_key_value_heads=1,
+        attn_layer_period=2, attn_layer_offset=1, mamba_d_state=4,
+        mamba_dt_rank=4, max_position_embeddings=MAXLEN, dtype="bfloat16")
+    hybrid.initialize()
+    with ServingEngine(hybrid, **kws) as eng:
+        eng.submit(prompt, N).result(timeout=60)   # serving_*_float_ssm
     return (1, H, MAXLEN)
 
 
@@ -224,7 +241,8 @@ def collect_facts():
             "serving_draft_prefill_chunk_float",
             "serving_draft_step_float",
             "serving_spec_verify_float",
-            "serving_prefill_chunk_int8", "serving_step_int8")
+            "serving_prefill_chunk_int8", "serving_step_int8",
+            "serving_prefill_chunk_float_ssm", "serving_step_float_ssm")
     missing = [p for p in want if p not in texts]
     assert not missing, \
         f"programs not captured (telemetry text capture broken?): " \

@@ -30,12 +30,113 @@ import math
 import os
 import time
 from collections import OrderedDict
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 __all__ = ["lm_generate", "lm_beam_search", "lm_score", "lm_stream",
-           "nmt_translate", "bucket_length"]
+           "nmt_translate", "bucket_length", "DecoderSpec", "SsmSpec",
+           "StackedLayers", "decoder_spec"]
+
+
+class SsmSpec(NamedTuple):
+    """Sizes of a Mamba-1 mixer (`ops.selective_scan`)."""
+    d_inner: int
+    d_state: int
+    d_conv: int
+    dt_rank: int
+
+
+class DecoderSpec(NamedTuple):
+    """What the served programs know of a decoder: a description the net
+    hands over (`net.decoder_spec()`), static and hashable, beside the
+    weight pytree of `net.decoder_params()`.  `serving/` reads this and
+    names no model.
+
+    kinds        per layer, its mixer: "attn" (K/V pages, paged attention)
+                 or "ssm" (conv window, selective scan, recurrent state)
+    acts         per layer, its feed-forward: "gelu" | "relu" (two
+                 matrices) or "silu_gated" (down(silu(gate x) * up x))
+    norm, eps    "layer" (gain and shift) or "rms" (gain)
+    heads, kv_heads, head_dim   query heads, KV heads (a divisor), width
+    positions    a sinusoidal table is added to the embedding
+    embed_scale  what the embedding is multiplied by (1.0: nothing)
+    ssm          `SsmSpec` of the "ssm" layers, None without any
+    vocab, units, max_len       sizes the engine checks requests against
+
+    The weight pytree: ``embed``, ``pe`` (None without positions), ``ln``,
+    ``head`` (the embedding itself when tied) and ``layers``, a dict a
+    layer: ``ln1``, ``ln2``, ``ffn1``, ``ffn2`` (and ``ffn_gate``) always;
+    ``qkv`` (fused, query rows first) and ``proj`` for "attn"; for "ssm"
+    ``in_proj``, ``conv`` ((d_conv, d_inner) taps, bias), ``x_proj``,
+    ``dt_norm``, ``b_norm``, ``c_norm``, ``dt_proj``, ``A`` ((d_state,
+    d_inner) float32, negative), ``D`` and ``out_proj``.  ``layers`` is a
+    list of such dicts or a `StackedLayers`, which reads as one."""
+    kinds: tuple
+    acts: tuple
+    norm: str
+    eps: float
+    heads: int
+    kv_heads: int
+    head_dim: int
+    positions: bool
+    embed_scale: float
+    ssm: Optional[SsmSpec]
+    vocab: int
+    units: int
+    max_len: int
+
+    @property
+    def recurrent(self) -> bool:
+        return "ssm" in self.kinds
+
+
+@jax.tree_util.register_pytree_node_class
+class StackedLayers:
+    """The per-layer weight dicts of `DecoderSpec`, held as one array a
+    kind of leaf: ``groups["all"][name]`` has a row a layer,
+    ``groups["ssm"]`` / ``groups["attn"]`` a row a layer of that kind, in
+    depth order.  Iterating or indexing gives a layer's dict, each leaf
+    the layer's row of its stack.  A program handed this takes a few dozen
+    buffers instead of some 17 a layer (the runtime's cost of a call grows
+    with their number), and a row read by a static index inside a jitted
+    program fuses into its consumer: nothing is copied."""
+
+    def __init__(self, groups: dict, kinds: tuple):
+        self.groups, self.kinds = groups, tuple(kinds)
+
+    def tree_flatten(self):
+        return (self.groups,), self.kinds
+
+    @classmethod
+    def tree_unflatten(cls, kinds, children):
+        return cls(children[0], kinds)
+
+    def __len__(self):
+        return len(self.kinds)
+
+    def __getitem__(self, i):
+        kind = self.kinds[i]
+        j = self.kinds[:i].count(kind)
+        out = jax.tree_util.tree_map(lambda a: a[i], self.groups["all"])
+        out.update(jax.tree_util.tree_map(lambda a: a[j],
+                                          self.groups[kind]))
+        return out
+
+    def __iter__(self):
+        return (self[i] for i in range(len(self)))
+
+
+def decoder_spec(net) -> DecoderSpec:
+    """The net's own description of its decoder."""
+    try:
+        return net.decoder_spec()
+    except AttributeError:
+        raise TypeError(
+            f"{type(net).__name__} does not describe a decoder the serving "
+            "programs can run (decoder_spec / decoder_params / "
+            "decoder_fingerprint)") from None
 
 # LRU caps for the per-net compiled-program / pe-table caches (ADVICE
 # r5 #3: exact-(B, P, N, sampling) keys grow without bound under
@@ -94,12 +195,33 @@ def _ln(x, g, b, eps=1e-5):
             * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def _qkv_heads(qkv, H):
-    """(..., 3C) -> three (..., H, D) tensors, the MHA split order."""
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+def _rms(x, g, eps=1e-6):
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+            * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(spec, x, p):
+    """The decoder's norm on one of its parameter tuples."""
+    return _rms(x, *p, eps=spec.eps) if spec.norm == "rms" \
+        else _ln(x, *p, eps=spec.eps)
+
+
+def _qkv_heads(qkv, H, Hkv=None):
+    """(..., (H + 2 Hkv) D) -> (..., H, D) queries and two (..., Hkv, D)
+    tensors, query rows first; ``Hkv=None``: as many KV heads as query
+    heads, the MHA split order."""
+    # tpulint: disable-next=TPU004 -- head counts are static Python ints
+    if Hkv is None or Hkv == H:
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        Hkv = H
+    else:
+        D = qkv.shape[-1] // (H + 2 * Hkv)
+        q, k, v = jnp.split(qkv, [H * D, (H + Hkv) * D], axis=-1)
     D = q.shape[-1] // H
-    shp = q.shape[:-1] + (H, D)
-    return q.reshape(shp), k.reshape(shp), v.reshape(shp)
+    lead = q.shape[:-1]
+    return (q.reshape(lead + (H, D)), k.reshape(lead + (Hkv, D)),
+            v.reshape(lead + (Hkv, D)))
 
 
 def _wb(layer):
@@ -199,7 +321,8 @@ def _quant_config(net, quantized):
 
 def _gather_params(net, pe_width, qc=None):
     """The weight pytree the compiled program consumes — the live raw
-    arrays of the Block's parameters, in a fixed structure.  With a
+    arrays of the Block's parameters, in the structure `DecoderSpec`
+    sets out, gathered by the net itself (`net.decoder_params`).  With a
     DecodeQuantConfig `qc`, target matmul weights come out as int8+
     scale dicts instead (see `_dense`); stale quantized copies are
     refreshed here, keyed on weight-buffer identity."""
@@ -211,28 +334,7 @@ def _gather_params(net, pe_width, qc=None):
                         else layer.bias.data()._data)
         return _wb(layer)
 
-    layers = []
-    for lyr in net._layers:
-        layers.append({
-            "ln1": (lyr.ln1.gamma.data()._data, lyr.ln1.beta.data()._data),
-            "qkv": d(lyr.attn.qkv),
-            "proj": d(lyr.attn.proj),
-            "ln2": (lyr.ln2.gamma.data()._data, lyr.ln2.beta.data()._data),
-            "ffn1": d(lyr.ffn.ffn_dense1),
-            "ffn2": d(lyr.ffn.ffn_dense2),
-        })
-    # long-context nets (_pe=None) get an eagerly-built table of just
-    # the width this program needs, cached on the net — pe enters the
-    # compiled program as an ARGUMENT here, so the giant-constant
-    # problem the in-program forward avoids does not apply
-    pe = net._pe if net._pe is not None else _pe_table(net, pe_width)
-    return {
-        "embed": net.embed.weight.data()._data,
-        "pe": pe,
-        "ln": (net.ln.gamma.data()._data, net.ln.beta.data()._data),
-        "head": d(net.head),
-        "layers": layers,
-    }
+    return net.decoder_params(pe_width, d)
 
 
 def _params_fingerprint(net):
@@ -243,34 +345,27 @@ def _params_fingerprint(net):
     pytree is alive: it keeps the fingerprinted buffers referenced, so
     a fresh buffer can never recycle one of their ids.  Cost: a few
     id() calls per layer, no device work."""
-    def wid(layer):
-        return (id(layer.weight.data()._data),
-                0 if layer.bias is None else id(layer.bias.data()._data))
-
-    ids = [id(net.embed.weight.data()._data),
-           id(net.ln.gamma.data()._data), id(net.ln.beta.data()._data),
-           *wid(net.head)]
-    for lyr in net._layers:
-        ids.extend((id(lyr.ln1.gamma.data()._data),
-                    id(lyr.ln1.beta.data()._data),
-                    *wid(lyr.attn.qkv), *wid(lyr.attn.proj),
-                    id(lyr.ln2.gamma.data()._data),
-                    id(lyr.ln2.beta.data()._data),
-                    *wid(lyr.ffn.ffn_dense1), *wid(lyr.ffn.ffn_dense2)))
-    return tuple(ids)
+    return net.decoder_fingerprint()
 
 
 def _ffn_fwd(x, lp, act):
     h = _dense(x, *lp["ffn1"])
-    h = jax.nn.gelu(h.astype(jnp.float32),
-                    approximate=True).astype(x.dtype) \
-        if act == "gelu" else jax.nn.relu(h)
+    if act == "silu_gated":
+        g = _dense(x, *lp["ffn_gate"]).astype(jnp.float32)
+        h = (jax.nn.silu(g) * h.astype(jnp.float32)).astype(x.dtype)
+    else:
+        h = jax.nn.gelu(h.astype(jnp.float32),
+                        approximate=True).astype(x.dtype) \
+            if act == "gelu" else jax.nn.relu(h)
     return _dense(h, *lp["ffn2"])
 
 
-def _logits_of(params, h_last):
-    return _dense(_ln(h_last, *params["ln"]), *params["head"],
-                  out_dtype=jnp.float32)
+def _logits_of(params, h_last, spec=None):
+    """Final norm and head, float32 logits; without a `spec` the
+    LayerNorm of the callers that know their net."""
+    x = _ln(h_last, *params["ln"]) if spec is None \
+        else _norm(spec, h_last, params["ln"])
+    return _dense(x, *params["head"], out_dtype=jnp.float32)
 
 
 def _weight_nbytes(params):
@@ -280,21 +375,8 @@ def _weight_nbytes(params):
     Metadata-only (shape/dtype): never touches device data."""
     from ..telemetry import nbytes_of
 
-    def wsz(w):
-        return (nbytes_of(w["w8"]) + nbytes_of(w["s"])
-                if isinstance(w, dict) else nbytes_of(w))
-
-    def pair(v):
-        w, b = v
-        return wsz(w) + (0 if b is None else nbytes_of(b))
-
-    total = sum(nbytes_of(a) for a in params["ln"])
-    total += pair(params["head"])
-    for lp in params["layers"]:
-        for k, v in lp.items():
-            total += (sum(nbytes_of(a) for a in v) if k.startswith("ln")
-                      else pair(v))
-    return total
+    return sum(nbytes_of(a) for a in jax.tree_util.tree_leaves(
+        (params["ln"], params["head"], params["layers"])))
 
 
 def _record_decode_weight_bytes(params, qc):

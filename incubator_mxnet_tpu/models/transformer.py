@@ -237,6 +237,70 @@ class TransformerLM(HybridBlock):
             h = l(h)
         return self.head(self.ln(h))
 
+    # -- what the decode and serving programs read ----------------------- #
+    def decoder_spec(self):
+        """This decoder as `generation.DecoderSpec`: pre-LN layers of
+        fused-QKV attention (as many KV heads as query heads) and a
+        two-matrix FFN, sinusoidal positions on an embedding scaled by
+        sqrt(units), a head of its own."""
+        from .generation import DecoderSpec
+
+        H = self._layers[0].attn._num_heads
+        return DecoderSpec(
+            kinds=("attn",) * len(self._layers),
+            acts=tuple(lyr.ffn._act for lyr in self._layers),
+            norm="layer", eps=1e-5, heads=H, kv_heads=H,
+            head_dim=self._units // H, positions=True,
+            embed_scale=math.sqrt(self._units), ssm=None,
+            vocab=self.embed.weight.shape[0], units=self._units,
+            max_len=self._max_len)
+
+    def decoder_params(self, pe_width, dense):
+        """The weight pytree of `decoder_spec` from the live parameter
+        buffers; ``dense(layer)`` gives a Dense layer's ``(weight,
+        bias)``, quantized where the caller says so."""
+        from .generation import _pe_table
+
+        def ln(layer):
+            return (layer.gamma.data()._data, layer.beta.data()._data)
+
+        layers = [{
+            "ln1": ln(lyr.ln1),
+            "qkv": dense(lyr.attn.qkv),
+            "proj": dense(lyr.attn.proj),
+            "ln2": ln(lyr.ln2),
+            "ffn1": dense(lyr.ffn.ffn_dense1),
+            "ffn2": dense(lyr.ffn.ffn_dense2),
+        } for lyr in self._layers]
+        # long-context nets (_pe=None) get an eagerly-built table of just
+        # the width this program needs, cached on the net — pe enters the
+        # compiled program as an ARGUMENT here, so the giant-constant
+        # problem the in-program forward avoids does not apply
+        pe = self._pe if self._pe is not None else _pe_table(self, pe_width)
+        return {
+            "embed": self.embed.weight.data()._data,
+            "pe": pe,
+            "ln": ln(self.ln),
+            "head": dense(self.head),
+            "layers": layers,
+        }
+
+    def decoder_fingerprint(self):
+        """Identities of the buffers `decoder_params` gathers."""
+        def ids(*params):
+            return [0 if p is None else id(p.data()._data) for p in params]
+
+        out = ids(self.embed.weight, self.ln.gamma, self.ln.beta,
+                  self.head.weight, self.head.bias)
+        for lyr in self._layers:
+            out += ids(lyr.ln1.gamma, lyr.ln1.beta,
+                       lyr.attn.qkv.weight, lyr.attn.qkv.bias,
+                       lyr.attn.proj.weight, lyr.attn.proj.bias,
+                       lyr.ln2.gamma, lyr.ln2.beta,
+                       lyr.ffn.ffn_dense1.weight, lyr.ffn.ffn_dense1.bias,
+                       lyr.ffn.ffn_dense2.weight, lyr.ffn.ffn_dense2.bias)
+        return tuple(out)
+
     def generate(self, prompt, max_new_tokens, **kw):
         """KV-cache autoregressive decode — one compiled prefill+scan
         program; see `models.generation.lm_generate` for options

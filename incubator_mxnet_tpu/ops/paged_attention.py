@@ -31,6 +31,23 @@ block_size, D)`` it held three of each).
   the CPU fallback the eviction-bit-identity and greedy-parity
   contracts rest on: CPU engines keep EXACTLY the old numerics.
 
+Grouped heads: a query of ``Hq`` heads may attend a pool of ``Hkv``
+heads, ``Hq`` a multiple of ``Hkv`` (a row of the pool is ``Hkv * D``
+wide; query head ``h`` reads KV head ``h // (Hq // Hkv)``).  With ``Hq ==
+Hkv`` both impls are what they were, operation for operation.  Otherwise
+the kernel takes the query as ``(Hq, D)`` rows, lays row ``h`` on the
+lanes of its KV head (for one KV head: as it is), and emits ``(Hq, D)``;
+the dense recipe folds the group into the einsums.
+
+A prefill chunk is many queries of ONE sequence: as lanes of the
+single-query kernel its ``T`` positions walk the same pages ``T`` times
+(``T x blocks_per_seq`` grid steps, and a grid step costs more than a
+page's bytes).  `paged_attention_window` walks them once: one grid step a
+page (and KV head), every query row against it — ``(G*T, D) · (D, bs)``
+scores with a per-row position mask.  It needs a page's KV head as a
+block of its own, so ``D`` a multiple of 128 lanes or one KV head; where
+it does not fit, the chunk stays lanes of the single-query kernel.
+
 Both impls take an optional int8 KV pool (per-head symmetric int8 with
 an fp32 scale per (block, slot, head), scale pools ``(num_blocks,
 block_size, H)`` — `contrib.quantization`'s per-channel recipe applied
@@ -61,7 +78,8 @@ from jax.sharding import PartitionSpec as P
 from . import mosaic
 
 __all__ = ["paged_attention", "paged_attention_dense", "default_impl",
-           "pool_shapes", "write_rows"]
+           "pool_shapes", "write_rows", "paged_attention_window",
+           "window_kernel_fits"]
 
 
 def default_impl(platform: Optional[str] = None) -> str:
@@ -105,7 +123,8 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
     operation on them, are those of the ``(num_blocks, H, bs, D)`` pool
     this recipe was written for, bit for bit."""
     B, nbps = tables.shape
-    H, D = q.shape[1:]
+    Hq, D = q.shape[1:]
+    H = pool_k.shape[2] // D            # KV heads
     bs = pool_k.shape[1]
     W = nbps * bs
 
@@ -116,31 +135,41 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
         return g.transpose(0, 3, 1, 2, 4).reshape(B, H, W, D)
 
     gk, gv = view(pool_k, scale_k), view(pool_v, scale_v)
-    s = jnp.einsum("bhd,bhkd->bhk", q, gk,
+    if Hq != H:                         # grouped: (B, Hkv, G, D) queries
+        q = q.reshape(B, H, Hq // H, D)
+        qk, pv, last = "bhgd,bhkd->bhgk", "bhgk,bhkd->bhgd", 3
+    else:
+        qk, pv, last = "bhd,bhkd->bhk", "bhk,bhkd->bhd", 2
+    s = jnp.einsum(qk, q, gk,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(kpos <= pos[:, None, None], s,
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
+    s = jnp.where(kpos <= pos.reshape((B,) + (1,) * last), s,
                   jnp.finfo(jnp.float32).min)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhk,bhkd->bhd", p, gv,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    return jnp.einsum(pv, p, gv, preferred_element_type=jnp.float32
+                      ).astype(q.dtype).reshape(B, Hq, D)
 
 
 def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  bs, heads, kv_quant):
+                  bs, heads, kv_heads, kv_quant):
     """One grid step = one (lane, page), all heads at once.  The page
     arrived via the block-table index map as the pool holds it,
-    ``(bs, H*D)``: a position a row, a head a run of ``D`` lanes.  This
-    body does the online-softmax update, `pl.when`-skipping pages past
-    the lane's length bound.
+    ``(bs, Hkv*D)``: a position a row, a KV head a run of ``D`` lanes.
+    This body does the online-softmax update, `pl.when`-skipping pages
+    past the lane's length bound.
 
     Both dots are plain 2-D MXU matmuls over the page as it lies.  The
-    query is laid out block-diagonally, ``q_bd[h] = q`` on head h's
-    lanes and exact zeros elsewhere, so ``q_bd · page^T`` is each head's
-    own ``(H, bs)`` scores; ``p · page`` weights every head's lanes by
-    every head's row, and `_emit` keeps the diagonal blocks.  int8 pages
-    enter the dots as they are and their fp32 scales multiply the
-    scores and the softmax weights, one per (slot, head)."""
+    query is laid out block-diagonally, ``q_bd[h] = q[h]`` on the lanes
+    of head h's KV head and exact zeros elsewhere, so ``q_bd · page^T``
+    is each head's own ``(Hq, bs)`` scores; ``p · page`` weights every KV
+    head's lanes by every head's row, and `_emit` keeps the diagonal
+    blocks.  int8 pages enter the dots as they are and their fp32 scales
+    multiply the scores and the softmax weights, one per (slot, head).
+
+    With as many KV heads as query heads the query and the output cross
+    the call flattened, ``(1, H*D)``, as the pages have it; with grouped
+    heads they are ``(Hq, D)`` rows (for one KV head the block-diagonal
+    layout is the query itself and nothing is masked)."""
     from jax.experimental import pallas as pl
 
     if kv_quant:
@@ -151,14 +180,25 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     nb = pl.num_programs(1)
     t = pos_ref[b]
-    d = q_ref.shape[-1] // heads
+    group = heads // kv_heads
+    d = acc_ref.shape[-1] // kv_heads
 
     def own():
-        """(H, H*D): the lanes of row h that are head h's.  Built where
-        it is used, so a skipped page pays nothing for it."""
+        """(Hq, Hkv*D): the lanes of row h that are its KV head's.  Built
+        where it is used, so a skipped page pays nothing for it."""
         row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+        if group > 1:
+            row = row // group
         return jnp.logical_and(col >= row * d, col < (row + 1) * d)
+
+    def q_block_diagonal():
+        q = q_ref[0].astype(jnp.float32)
+        if group == 1:                      # (1, H*D) over H rows
+            return jnp.where(own(), q, 0.0)
+        if kv_heads == 1:                   # (Hq, D): as it is
+            return q
+        return jnp.where(own(), jnp.concatenate([q] * kv_heads, axis=1), 0.0)
 
     @pl.when(j == 0)
     def _init():
@@ -173,16 +213,16 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # by emit time.
     @pl.when(j <= t // bs)
     def _update():
-        q_bd = jnp.where(own(), q_ref[0].astype(jnp.float32), 0.0)
         s = jax.lax.dot_general(
-            q_bd, k_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (H, bs)
+            q_block_diagonal(), k_ref[0].astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # (Hq, bs)
         if kv_quant:
             s = s * sk_ref[0].T
         s = s / math.sqrt(d)
         slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(j * bs + slot <= t, s, jnp.finfo(jnp.float32).min)
-        m_prev, l_prev = m_ref[...], l_ref[...]         # (H, 1)
+        m_prev, l_prev = m_ref[...], l_ref[...]         # (Hq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
@@ -196,41 +236,61 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j == nb - 1)
     def _emit():
-        o = jnp.where(own(), acc_ref[...] / l_ref[...], 0.0)
-        o_ref[0] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
+        if group == 1:
+            o = jnp.where(own(), acc_ref[...] / l_ref[...], 0.0)
+            o_ref[0] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
+            return
+        o = acc_ref[...] / l_ref[...]
+        if kv_heads > 1:
+            o = jnp.where(own(), o, 0.0)
+            o = sum(o[:, k * d:(k + 1) * d] for k in range(kv_heads))
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
 def _paged_call(q, pools, tables, pos, interpret):
     """Shared pallas_call: ``pools`` is (pool_k, pool_v) or, for int8
-    pages, (pool_k, pool_v, scale_k, scale_v).  Query and output cross
-    the call with the head axis flattened, as the pages have it."""
+    pages, (pool_k, pool_v, scale_k, scale_v).  With ``Hq == Hkv`` query
+    and output cross the call with the head axis flattened, as the pages
+    have it; grouped heads cross as ``(Hq, D)`` rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    bs = pools[0].shape[1]
+    bs, row = pools[0].shape[1:]
+    Hkv = row // D
+    if row != Hkv * D or H % Hkv:
+        raise ValueError(
+            f"paged_attention: {H} query heads of {D} against a pool row "
+            f"of {row}: the pool must hold a whole number of KV heads that "
+            "divides the query's")
     nbps = tables.shape[1]
     kv_quant = len(pools) == 4
-    kernel = functools.partial(_paged_kernel, bs=bs, heads=H,
+    grouped = H != Hkv
+    if grouped and kv_quant:
+        raise ValueError("paged_attention: the kernel has no int8 pages "
+                         "for grouped heads (impl='dense' does)")
+    kernel = functools.partial(_paged_kernel, bs=bs, heads=H, kv_heads=Hkv,
                                kv_quant=kv_quant)
-    lane = pl.BlockSpec((1, 1, H * D), lambda b, j, t, p: (b, 0, 0))
-    page = pl.BlockSpec((1, bs, H * D), lambda b, j, t, p: (t[b, j], 0, 0))
-    page_scale = pl.BlockSpec((1, bs, H), lambda b, j, t, p: (t[b, j], 0, 0))
+    lane = pl.BlockSpec((1, H, D) if grouped else (1, 1, H * D),
+                        lambda b, j, t, p: (b, 0, 0))
+    page = pl.BlockSpec((1, bs, row), lambda b, j, t, p: (t[b, j], 0, 0))
+    page_scale = pl.BlockSpec((1, bs, Hkv),
+                              lambda b, j, t, p: (t[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nbps),
         in_specs=[lane, page, page] + [page_scale] * (2 * kv_quant),
         out_specs=lane,
-        scratch_shapes=[pltpu.VMEM((H, H * D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((H, row), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + lane.block_shape[1:], q.dtype),
         interpret=interpret,
         name="paged_attention_q8" if kv_quant else "paged_attention",
-    )(tables, pos, q.reshape(B, 1, H * D), *pools)
+    )(tables, pos, q if grouped else q.reshape(B, 1, H * D), *pools)
     return out.reshape(B, H, D)
 
 
@@ -246,12 +306,132 @@ def _paged_core_q8(q, pool_k, pool_v, scale_k, scale_v, tables, pos,
                        interpret)
 
 
+# --- a window of one sequence's queries: each page once ----------------- #
+_WINDOW_VMEM = 48 * 1024 * 1024
+
+
+def window_kernel_fits(T, heads, kv_heads, head_dim) -> bool:
+    """Whether `paged_attention_window` has a kernel for these sizes: a
+    KV head's lanes must be a block of their own (one KV head, or
+    ``head_dim`` a multiple of 128), and a KV head's query rows with their
+    softmax state must fit VMEM."""
+    rows = (heads // kv_heads) * T
+    block_ok = kv_heads == 1 or head_dim % 128 == 0
+    # q (twice: its block and its float32 copy), acc: (rows, D); m, l:
+    # (rows, 1) padded to 128 lanes; all float32
+    return block_ok and rows * (3 * head_dim + 2 * 128) * 4 <= _WINDOW_VMEM
+
+
+def _window_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
+                   acc_ref, m_ref, l_ref, *, bs, T):
+    """One grid step = one (KV head, page): every query row of that KV
+    head against the page.  Row r is the query at position ``start + r %
+    T`` of head ``r // T`` of the group; pages past the window's last
+    position are skipped, and within a page a row sees the slots at or
+    before its own position.  The online softmax is `_paged_kernel`'s."""
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
+    start = start_ref[0]
+    d = q_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, jnp.finfo(jnp.float32).min)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * bs <= start + (T - 1))
+    def _update():
+        s = jax.lax.dot_general(
+            q_ref[0].astype(jnp.float32), k_ref[0].astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) / math.sqrt(d)  # (rows, bs)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(j * bs + slot <= start + row % T, s,
+                      jnp.finfo(jnp.float32).min)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha \
+            + jnp.dot(p, v_ref[0].astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+
+    @pl.when(j == nb - 1)
+    def _emit():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _window_core(q, pool_k, pool_v, table_row, start, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, H, D = q.shape
+    bs, row = pool_k.shape[1:]
+    Hkv = row // D
+    rows = (H // Hkv) * T
+    nbps = table_row.shape[0]
+    # (T, Hkv, G, D) -> (Hkv, G*T, D): a KV head's rows, head-major
+    qh = q.reshape(T, Hkv, H // Hkv, D).transpose(1, 2, 0, 3) \
+        .reshape(Hkv, rows, D)
+    mine = pl.BlockSpec((1, rows, D), lambda h, j, t, s: (h, 0, 0))
+    page = pl.BlockSpec((1, bs, D), lambda h, j, t, s: (t[j], 0, h))
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, bs=bs, T=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(Hkv, nbps),
+            in_specs=[mine, page, page], out_specs=mine,
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WINDOW_VMEM + 16 * 1024 * 1024),
+        interpret=interpret, name="paged_attention_window",
+    )(table_row, start.reshape(1), qh, pool_k, pool_v)
+    return out.reshape(Hkv, H // Hkv, T, D).transpose(2, 0, 1, 3) \
+        .reshape(T, H, D)
+
+
+def paged_attention_window(q, pool_k, pool_v, table_row, start, *,
+                           interpret: Optional[bool] = None):
+    """Attention of a window of ONE sequence's queries ``q`` (T, Hq, D),
+    at positions ``start .. start+T-1``, against that sequence's pages
+    (``table_row`` (blocks_per_seq,)): query t attends slots ``<= start +
+    t``, as `paged_attention` would with every position a lane of the same
+    table, but each page is read once for all of them (a prefill chunk's
+    attention).  Kernel only (`window_kernel_fits` says for which sizes;
+    float pages); a caller without one uses `paged_attention`."""
+    T, H, D = q.shape
+    Hkv = pool_k.shape[2] // D
+    if not window_kernel_fits(T, H, Hkv, D):
+        raise ValueError(
+            f"paged_attention_window: no kernel for {T} queries of {H} "
+            f"heads of {D} over {Hkv} KV heads (window_kernel_fits)")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    heads, = mosaic.split((Hkv,))
+    core = functools.partial(_window_core, interpret=interpret)
+    pool = P(None, None, heads)
+    return mosaic.per_shard(core, (P(None, heads), pool, pool, P(), P()),
+                            P(None, heads))(
+        q, pool_k, pool_v, table_row, jnp.asarray(start, jnp.int32))
+
+
 def paged_attention(q, pool_k, pool_v, tables, pos, *,
                     scale_k=None, scale_v=None,
                     impl: Optional[str] = None,
                     interpret: Optional[bool] = None):
-    """Single-query attention of ``q`` (B, H, D) against the paged KV
-    pool (num_blocks, block_size, H*D) through per-lane block tables
+    """Single-query attention of ``q`` (B, Hq, D) against the paged KV
+    pool (num_blocks, block_size, Hkv*D), ``Hq`` a multiple of ``Hkv``
+    (query head h reads KV head ``h // (Hq // Hkv)``), through per-lane
+    block tables
     (B, blocks_per_seq) at positions ``pos`` (B,), attending slots
     ``<= pos`` — the serving decode-step attention.
 
@@ -271,7 +451,10 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
     # lanes and heads are independent: per shard of both under a mesh
     # (ops/mosaic.py); every shard walks the whole pool of its heads,
     # which are contiguous runs of the pool's last dimension
-    lanes, heads = mosaic.split(q.shape[:2])
+    # (with grouped heads the KV heads are what is split: a shard's
+    # query heads are those of its KV heads)
+    kv_heads = pool_k.shape[2] // q.shape[2]
+    lanes, heads = mosaic.split((q.shape[0], kv_heads))
     lane, pool = P(lanes, heads), P(None, None, heads)
     pools = (pool_k, pool_v) if scale_k is None \
         else (pool_k, pool_v, scale_k, scale_v)

@@ -246,6 +246,7 @@ class Request:
         self._cancel = True
         eng = self._engine
         with eng._work:
+            eng._queue_reap = True
             eng._work.notify_all()
 
     def result(self, timeout: Optional[float] = None) -> list:
@@ -325,7 +326,13 @@ class _PrefillJob:
 
 
 class ServingEngine:
-    """Continuous-batching decode over a `models.TransformerLM`.
+    """Continuous-batching decode over a decoder that describes itself
+    (`net.decoder_spec()`, `models.generation.DecoderSpec`: a
+    `models.TransformerLM`, or a decoder with recurrent layers such as
+    `models.hybrid_ssm.HybridSSMDecoder`, whose per-lane recurrent state
+    the engine keeps beside the paged K/V pool and for which speculation,
+    int8 KV and prefix hits are not built; docs/serving.md, "Two kinds of
+    state").
 
     Parameters (all static — changing them means a new engine):
 
@@ -425,14 +432,15 @@ class ServingEngine:
         if block_size < 1 or (block_size & (block_size - 1)):
             raise ValueError(
                 f"block_size must be a power of two, got {block_size}")
-        msl = int(max_seq_len if max_seq_len is not None else net._max_len)
+        spec = G.decoder_spec(net)
+        msl = int(max_seq_len if max_seq_len is not None else spec.max_len)
         msl = (msl // block_size) * block_size
         if msl < block_size:
             raise ValueError(
                 f"max_seq_len {max_seq_len} < one block ({block_size})")
-        if msl > net._max_len:
+        if msl > spec.max_len:
             raise ValueError(
-                f"max_seq_len {msl} exceeds net.max_len {net._max_len}")
+                f"max_seq_len {msl} exceeds net.max_len {spec.max_len}")
         self._net = net
         self._B = int(max_batch)
         self._bs = int(block_size)
@@ -465,14 +473,14 @@ class ServingEngine:
             raise ValueError(
                 f"speculate_k {self._spec_k} >= max_seq_len {msl}")
         if self._spec and draft_net is not None:
-            if draft_net.embed.weight.shape[0] != net.embed.weight.shape[0]:
+            dspec = G.decoder_spec(draft_net)
+            if dspec.vocab != spec.vocab:
                 raise ValueError(
-                    "draft_net vocab "
-                    f"{draft_net.embed.weight.shape[0]} != target vocab "
-                    f"{net.embed.weight.shape[0]}")
-            if draft_net._max_len < msl:
+                    f"draft_net vocab {dspec.vocab} != target vocab "
+                    f"{spec.vocab}")
+            if dspec.max_len < msl:
                 raise ValueError(
-                    f"draft_net.max_len {draft_net._max_len} < "
+                    f"draft_net.max_len {dspec.max_len} < "
                     f"max_seq_len {msl}")
         self._programs = PagedPrograms(
             net, max_batch=self._B, block_size=self._bs,
@@ -487,20 +495,19 @@ class ServingEngine:
         params = self._programs.gather_params(self._msl)
         G._record_decode_weight_bytes(params, self._programs._qc)
 
-        # device pool: per-layer (num_blocks, bs, H*D), a position a
-        # row and a head a run of D lanes — the one shape the K/V
-        # write, the paged kernel and the donated buffer all take as it
-        # lies, row-major and unpadded (docs/serving.md).  The engine
-        # holds the ONLY reference and replaces it after every donated
-        # call (the buffers really are deleted on XLA:CPU too).  With
-        # kv_dtype="int8" the pages are s8 and fp32 scale pools
-        # (num_blocks, bs, H) ride alongside — also donated.
+        # device pool: per attention layer (num_blocks, bs, Hkv*D), a
+        # position a row and a KV head a run of D lanes — the one shape
+        # the K/V write, the paged kernel and the donated buffer all
+        # take as it lies, row-major and unpadded (docs/serving.md).  The
+        # engine holds the ONLY reference and replaces it after every
+        # donated call (the buffers really are deleted on XLA:CPU too).
+        # With kv_dtype="int8" the pages are s8 and fp32 scale pools
+        # (num_blocks, bs, Hkv) ride alongside — also donated.
         emb = params["embed"]
-        H = net._layers[0].attn._num_heads
         dt = jnp.int8 if self._kv_dtype == "int8" else emb.dtype
-        L = len(net._layers)
-        page, scales = pool_shapes(self._num_blocks, self._bs, H,
-                                   net._units // H)
+        L = spec.kinds.count("attn")
+        page, scales = pool_shapes(self._num_blocks, self._bs,
+                                   spec.kv_heads, spec.head_dim)
         self._pool_k = tuple(jnp.zeros(page, dt) for _ in range(L))
         self._pool_v = tuple(jnp.zeros(page, dt) for _ in range(L))
         if self._kv_dtype == "int8":
@@ -510,6 +517,25 @@ class ServingEngine:
                 jnp.ones(scales, jnp.float32) for _ in range(L))
         else:
             self._scale_k = self._scale_v = ()
+        # the second kind of per-sequence state (docs/serving.md, "Two
+        # kinds of state"): per ssm layer one float32 recurrent state
+        # (B, d_state, d_inner) and one conv window (d_conv-1, B,
+        # d_inner), a row a lane, donated and threaded like the pools.
+        # A lane's row is dead once the lane is free: the next prompt's
+        # first chunk starts it from zero.  `()` without ssm layers.
+        self._recurrent = spec.recurrent
+        self._rec = ()
+        if self._recurrent:
+            Di, Ds, K, _ = spec.ssm
+            n_ssm = spec.kinds.count("ssm")
+            self._rec = (
+                tuple(jnp.zeros((self._B, Ds, Di), jnp.float32)
+                      for _ in range(n_ssm)),
+                tuple(jnp.zeros((K - 1, self._B, Di), emb.dtype)
+                      for _ in range(n_ssm)))
+        self._state_bytes = sum(int(a.size) * a.dtype.itemsize
+                                for kind in self._rec for a in kind)
+        self._state_resets = 0          # first chunks since the last record
         # speculative draft KV pool: per-draft-layer arrays in the
         # draft model's dtype, addressed by the SAME block tables and
         # the same BlockPool ids as the target pool (kv_pool.py), so
@@ -519,13 +545,13 @@ class ServingEngine:
             dnet = self._programs.draft_net
             dparams = self._programs.draft_params(self._msl)
             ddt = dparams["embed"].dtype
-            dH = dnet._layers[0].attn._num_heads
-            dpage, _ = pool_shapes(self._num_blocks, self._bs, dH,
-                                   dnet._units // dH)
+            dspec = self._programs.draft_spec
+            dpage, _ = pool_shapes(self._num_blocks, self._bs,
+                                   dspec.kv_heads, dspec.head_dim)
             self._dpool_k = tuple(
-                jnp.zeros(dpage, ddt) for _ in range(len(dnet._layers)))
+                jnp.zeros(dpage, ddt) for _ in dspec.kinds)
             self._dpool_v = tuple(
-                jnp.zeros(dpage, ddt) for _ in range(len(dnet._layers)))
+                jnp.zeros(dpage, ddt) for _ in dspec.kinds)
         # pool byte footprint is STATIC (donation replaces arrays, never
         # shapes) — freeze it here so ops-side readers never touch the
         # live pool tuples the scheduler thread is rewriting.  Draft
@@ -540,6 +566,9 @@ class ServingEngine:
             telemetry.gauge("serving_kv_bytes_per_token",
                             labels={"engine": self._name}) \
                 .set(self.kv_bytes_per_token)
+            telemetry.gauge("serving_state_bytes_per_seq",
+                            labels={"engine": self._name}) \
+                .set(self.state_bytes_per_seq)
             impl = self._programs.attn_impl
             for path in ("pallas", "dense"):
                 telemetry.gauge("paged_attn_kernel",
@@ -559,6 +588,9 @@ class ServingEngine:
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._queue: deque = deque()
+        # whether the queue may hold a request to reap (a deadline, a
+        # cancel): a backlog of thousands is not walked every iteration
+        self._queue_reap = False
         # admitted-but-unprefilled work, oldest first: each entry is a
         # _PrefillJob whose lane+blocks are already claimed; the
         # scheduler runs one chunk of the head job per iteration
@@ -655,6 +687,19 @@ class ServingEngine:
         Frozen at construction: donation swaps the pool arrays every
         step but never their shapes."""
         return self._kv_pool_bytes
+
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent state (float32 states and conv
+        windows, every ssm layer, every lane); 0 for a decoder of
+        attention layers only.  Whatever the sequences' lengths."""
+        return self._state_bytes
+
+    @property
+    def state_bytes_per_seq(self) -> int:
+        """Recurrent-state bytes one lane holds — the
+        `serving_state_bytes_per_seq` gauge's value."""
+        return self._state_bytes // self._B
 
     @property
     def kv_block_bytes(self) -> int:
@@ -760,6 +805,8 @@ class ServingEngine:
                      "steps": self._stats["steps"],
                      "queue_depth": len(self._queue),
                      "blocks_free": self._pool.num_free,
+                     "kv_pool_bytes": self._kv_pool_bytes,
+                     "state_bytes": self._state_bytes,
                      "prefill_chunks_pending":
                          self._pending_chunks_locked(),
                      "prefix_cache": {
@@ -875,8 +922,11 @@ class ServingEngine:
             "num_blocks": self._num_blocks,
             "max_queue": self._max_queue,
             "prefill_chunk": self._chunk,
-            "prefix_cache": True,
+            # a recurrent layer's state cannot be handed to a prefix hit
+            "prefix_cache": not self._recurrent,
             "kv_pool_bytes": self._kv_pool_bytes,
+            "state_bytes": self._state_bytes,
+            "state_bytes_per_seq": self.state_bytes_per_seq,
             "speculate": spec,
             "eos_id": self._eos,
             "poll_interval_s": self._poll,
@@ -948,6 +998,8 @@ class ServingEngine:
                 self._check_alive()
             req.status = "queued"
             self._queue.append(req)
+            if abs_deadline is not None:
+                self._queue_reap = True
             req.trace.event("queued", queue_depth=len(self._queue))
             self._note_queue_depth_locked()
             self._work.notify_all()
@@ -1014,6 +1066,8 @@ class ServingEngine:
                 "active": int(self._active.sum()),
                 "blocks_free": self._pool.num_free,
                 "blocks_total": self._num_blocks - 1,
+                "kv_pool_bytes": self._kv_pool_bytes,
+                "state_bytes": self._state_bytes,
                 "prefix_cache": {
                     "hits": self._stats["prefix_hits"],
                     "misses": self._stats["prefix_misses"],
@@ -1138,6 +1192,20 @@ class ServingEngine:
     # -- scheduler thread ---------------------------------------------- #
     def _scheduler(self) -> None:
         try:
+            self._run()
+        finally:
+            # an engine whose scheduler has ended serves nothing: let go
+            # of what it held on the device (pools, recurrent state, the
+            # gathered weights, the net), here on the one thread that
+            # writes them, so that a `Request` handle someone still holds
+            # does not keep a model's memory alive through its engine
+            self._pool_k = self._pool_v = self._scale_k = self._scale_v = ()
+            self._dpool_k = self._dpool_v = self._rec = ()
+            self._programs.release()
+            self._net = None
+
+    def _run(self) -> None:
+        try:
             self._loop()
         except BaseException as e:
             with self._err_lock:
@@ -1187,25 +1255,36 @@ class ServingEngine:
                         with prof.phase("wait"):
                             self._work.wait(self._poll)
                     continue
-            if staged is not None:
-                self._run_chunk(staged, hook)
-            if live:
-                if self._spec:
+            # the chunk is handed to the device and committed only once
+            # the decode step has been handed over behind it: the step's
+            # lanes were snapshotted above, so it does not need the
+            # chunk's first token, and its dispatch then runs while the
+            # device works on the chunk, a prompt's last chunk included
+            chunk = self._run_chunk(staged, hook) \
+                if staged is not None else None
+            if live and not self._spec:
+                self._decode_step(snap, live, hook, chunk)
+            else:
+                if chunk is not None:
+                    self._commit_chunk(chunk)
+                if live:
                     self._spec_step(snap, live, hook)
-                else:
-                    self._decode_step(snap, live, hook)
 
     def _reap_locked(self, now: float) -> None:
         # queued requests: cancellation and deadlines apply while waiting
-        if self._queue:
+        if self._queue and self._queue_reap:
             keep = deque()
+            self._queue_reap = False
             for req in self._queue:
                 if req._cancel:
                     req._finish("cancelled", RequestCancelled("cancelled"))
-                elif req.deadline is not None and now > req.deadline:
+                elif req.deadline is None:
+                    keep.append(req)
+                elif now > req.deadline:
                     self._shed_locked(req, "deadline")
                 else:
                     keep.append(req)
+                    self._queue_reap = True     # one to look at again
             if len(keep) != len(self._queue):
                 # mutate in place: the deque identity is shared with
                 # every lock-holding reader (submit/stats/drain)
@@ -1256,7 +1335,10 @@ class ServingEngine:
             # prefix-cache lookup + COW bind: bound blocks are never
             # written by this request (chunks start at cached_len,
             # decode writes at >= P), so sharing needs no copy
-            hits, cached_len = self._pool.lookup(req.prompt)
+            # (a decoder with recurrent layers has no state to hand a hit:
+            # the lookup is a miss, counted as one — docs/serving.md)
+            hits, cached_len = ([], 0) if self._recurrent \
+                else self._pool.lookup(req.prompt)
             self._pool.bind(hits)
             fresh = self._pool.alloc(needed - len(hits))
             if fresh is None:
@@ -1319,16 +1401,15 @@ class ServingEngine:
             return (job, toks, start, n)
         return None
 
-    def _run_chunk(self, staged, hook) -> None:
-        """Run one staged prefill chunk — device call OUTSIDE the lock
-        (mirroring `_decode_step`), so submit()/cancel()/stats() never
-        stall behind prefill compute (fault-hook injected sleeps
-        included).  Re-locks to commit, with a slot identity check in
-        case the request was evicted meanwhile; the FINAL chunk's
-        commit delivers the first token and activates the lane."""
+    def _run_chunk(self, staged, hook):
+        """Hand one staged prefill chunk to the device — OUTSIDE the
+        lock (mirroring `_decode_step`), so submit()/cancel()/stats()
+        never stall behind prefill compute (fault-hook injected sleeps
+        included) — and return what `_commit_chunk` needs.  Nothing is
+        fetched here: the program call returns once the chunk is
+        queued."""
         prof = self._prof
         job, toks, start, n = staged
-        req = job.req
         # weight gather/requantize, timed apart from the device call so
         # a requantize after a weight swap shows up as its own cause
         with prof.phase("gather_params"):
@@ -1340,13 +1421,13 @@ class ServingEngine:
             t0 = time.perf_counter()
             with prof.phase("dispatch"):
                 (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-                 first) = G._timed_decode(
+                 self._rec, first) = G._timed_decode(
                     f"serving_prefill_chunk_{self._label}",
                     f"serving_{self._label}", n,
                     self._programs.prefill_chunk, self._pool_k,
-                    self._pool_v, self._scale_k, self._scale_v, job.row,
-                    toks, np.int32(start), np.int32(job.P), job.key,
-                    params)
+                    self._pool_v, self._scale_k, self._scale_v, self._rec,
+                    job.row, toks, np.int32(start), np.int32(job.P),
+                    job.key, np.int32(job.lane), params)
                 if self._spec:
                     # populate the DRAFT pool with the same chunk too —
                     # the draft's first proposal attends to the full
@@ -1359,6 +1440,17 @@ class ServingEngine:
                         self._dpool_k, self._dpool_v, job.row, toks,
                         np.int32(start), np.int32(job.P), dparams)
             t_handed = time.monotonic()     # the chunk's stamp: no sync
+        return job, start, n, final, first, t0, t_handed
+
+    def _commit_chunk(self, chunk) -> None:
+        """Re-lock and commit a chunk `_run_chunk` handed over, with a
+        slot identity check in case the request was evicted meanwhile;
+        the FINAL chunk's commit fetches the first token (the one wait
+        for the device here), delivers it and activates the lane."""
+        prof = self._prof
+        job, start, n, final, first, t0, t_handed = chunk
+        req = job.req
+        with prof.phase("prefill_chunk"):
             # only the final chunk's first-token pick is consumed —
             # don't force a host sync per intermediate chunk
             tok = int(np.asarray(first)) if final else None
@@ -1371,6 +1463,11 @@ class ServingEngine:
                 self._drop_job_locked(job)
                 return                      # evicted while chunking
             job.next_pos = start + n
+            if self._recurrent and start == 0:
+                # the lane's state began from zero in this chunk
+                self._state_resets += 1
+                if telemetry.enabled():
+                    telemetry.counter("serving_state_resets_total").inc()
             prof.chunk(req.rid, start, n, t_handed)
             self._note_chunk_queue_locked()
             if not final:
@@ -1390,7 +1487,8 @@ class ServingEngine:
             # publish the prompt's full blocks into the prefix
             # cache now their content is final (COW: nothing
             # writes positions < P past this point)
-            self._pool.register(job.prompt, job.row)
+            if not self._recurrent:
+                self._pool.register(job.prompt, job.row)
             if telemetry.enabled():
                 telemetry.counter("serving_admitted_total").inc()
                 telemetry.histogram(
@@ -1434,17 +1532,23 @@ class ServingEngine:
         """The lanes' hold on the pool for the ledger's record: blocks
         reserved, and positions whose K/V the pool holds (`_pos` of a
         decoding lane, `next_pos` of one still prefilling), both summed
-        over the occupied lanes."""
+        over the occupied lanes; and how many lanes hold recurrent state."""
         slots = self._slots
+        # prefill jobs whose lane is still theirs
+        jobs = [j for j in self._prefill_jobs
+                if slots[j.lane] is not None and slots[j.lane].req is j.req]
         return {
             "blocks_reserved": sum(len(s.blocks) for s in slots
                                    if s is not None),
             "blocks_total": self._num_blocks - 1,
             "block_size": self._bs,
-            "positions_written": int(self._pos[self._active].sum()) + sum(
-                j.next_pos for j in self._prefill_jobs
-                if slots[j.lane] is not None
-                and slots[j.lane].req is j.req)}
+            "positions_written": int(self._pos[self._active].sum())
+            + sum(j.next_pos for j in jobs),
+            # lanes holding live recurrent state: the decoding ones and
+            # those whose first chunk has run
+            "state_rows": (int(self._active.sum())
+                           + sum(j.next_pos > 0 for j in jobs))
+            if self._recurrent else 0}
 
     def _retire_locked(self, lane: int) -> None:
         req = self._slots[lane].req
@@ -1455,10 +1559,12 @@ class ServingEngine:
         self._stats["done"] += 1
         self._work.notify_all()             # drain()ers and submitters
 
-    def _decode_step(self, snap, live, hook) -> None:
+    def _decode_step(self, snap, live, hook, chunk=None) -> None:
         """One batched decode step — device call OUTSIDE the lock, so
         submit()/cancel() never block on compute (a fault hook's
-        injected sleep included)."""
+        injected sleep included).  ``chunk``: the prefill chunk handed
+        to the device just before, committed here once the step is
+        queued behind it and before the step's tokens are waited for."""
         prof = self._prof
         with prof.phase("gather_params"):
             params = self._live_params()
@@ -1472,12 +1578,14 @@ class ServingEngine:
             t0 = time.perf_counter()
             with prof.phase("dispatch"):
                 (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-                 nxt) = G._timed_decode(
+                 self._rec, nxt) = G._timed_decode(
                     f"serving_step_{self._label}",
                     f"serving_{self._label}", len(live),
                     self._programs.step, self._pool_k, self._pool_v,
-                    self._scale_k, self._scale_v, tables, toks, pos,
-                    active, keys, params)
+                    self._scale_k, self._scale_v, self._rec, tables, toks,
+                    pos, active, keys, params)
+            if chunk is not None:
+                self._commit_chunk(chunk)
             nxt = np.asarray(nxt)           # sync: tokens are consumed now
             dt = time.perf_counter() - t0
         now = time.monotonic()
@@ -1509,6 +1617,8 @@ class ServingEngine:
                     .set(len(live))
             queue_depth = len(self._queue)
             pool_use = self._pool_use_locked()
+            pool_use["state_resets"], self._state_resets = \
+                self._state_resets, 0
         # close the ledger OUTSIDE the engine lock (it takes its own
         # leaf lock + histogram locks; never nested under self._work)
         prof.end_step(rids=[req.rid for _, req in live],

@@ -34,8 +34,9 @@ families, built only when the engine configures ``speculate_k > 0``:
 * ``serving_draft_prefill_chunk`` — the chunk program on the draft
   weights, filling the draft pool alongside the target's.
 
-Both donate the pool arrays and their scale pools
-(``donate_argnums=(0, 1, 2, 3)``): the K/V pool
+Both donate the pool arrays, their scale pools and the recurrent state
+(``donate_argnums=(0, 1, 2, 3, 4)``; the state is ``()`` for a decoder
+of attention layers only): the K/V pool
 is a ring the engine threads through every call, and an un-donated
 pool would copy the whole cache per token.  Donation coverage is
 CI-pinned via `.hlolint_contracts.json` (serving_* entries).  A pool
@@ -70,17 +71,19 @@ pools and per-lane state enter as arguments.
 """
 from __future__ import annotations
 
-import math
 from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import telemetry
 from ..contrib.quantization import quantize_kv
 from ..models import generation as G
 from ..ops.paged_attention import (default_impl, paged_attention,
-                                   write_rows)
+                                   paged_attention_window,
+                                   window_kernel_fits, write_rows)
+from ..ops.selective_scan import selective_scan
 
 __all__ = ["PagedPrograms"]
 
@@ -149,52 +152,153 @@ def _top_k_logits(logits, temperature, top_k):
     return lg
 
 
-def _layers(params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
-            wblk, off, attend):
-    """The decoder stack of every serving program: per layer qkv, the
-    K/V write into the lanes' pages at ``(wblk, off)`` (quantized first
-    on an int8 pool), attention over the pool — ``attend(q, pk, pv, sk,
-    sv)`` is the one thing the programs differ in — projection and FFN.
-    Each part runs under the `jax.named_scope` a device trace shows it
-    by: ``layer<i>/kv_write``, ``layer<i>/paged_attn``,
-    ``layer<i>/ffn``.  Returns ``(h, new_k, new_v, new_sk, new_sv)``,
-    the scale tuples empty on a float pool."""
-    new_k, new_v, new_sk, new_sv = [], [], [], []
-    for li, (lp, act) in enumerate(zip(params["layers"], acts)):
+def _embed(spec, params, toks, pos):
+    """Token embedding, scaled and given positions as the decoder's
+    description says (`generation.DecoderSpec`)."""
+    dt = params["embed"].dtype
+    h = params["embed"][toks].astype(dt)
+    if spec.embed_scale != 1.0:
+        h = h * spec.embed_scale
+    if spec.positions:
+        h = h + params["pe"][pos].astype(dt)
+    return h
+
+
+def _dense32(x, w, b):
+    """A small projection with a float32 result (`dt`, `B`, `C` stay
+    float32 from here to the scan)."""
+    y = jax.lax.dot_general(x, w.astype(x.dtype),
+                            (((x.ndim - 1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return y if b is None else y + b.astype(jnp.float32)
+
+
+def _ssm_mixer(spec, lp, x, conv, state, row, fresh, ok, impl):
+    """A Mamba mixer over the engine's recurrent state: ``x`` (N, T, C)
+    normed inputs of N sequences, ``conv`` (d_conv - 1, S, d_inner) the
+    lanes' conv windows (a tap a plane, so a lane is a row of each, as in
+    the state), ``state`` (S, d_state, d_inner) float32.
+
+    ``row=None``: sequence i is lane i (the decode step; N == S, T == 1)
+    and ``ok`` (N, 1) says which lanes are live.  Else the one sequence
+    continues lane ``row`` (a prefill chunk), from zero state and an
+    empty window where ``fresh``, and ``ok`` (1, T) marks its valid
+    positions.  A position that is not ok advances neither the state
+    (its ``dt`` is 0: ``exp(0) * s + 0``) nor the window.  ``impl`` is the
+    programs' "pallas" | "dense": the scan's kernel, or its XLA path.  Returns
+    ``(out (N, T, C), conv, state)``, only the rows at hand rewritten."""
+    Di, Ds, K, R = spec.ssm
+    f32 = jnp.float32
+    N, T = x.shape[:2]
+    with jax.named_scope("ssm_conv"):
+        uz = G._dense(x, *lp["in_proj"])
+        u, z = uz[..., :Di], uz[..., Di:]
+        taps, bias = lp["conv"]
+        if row is None:                 # T == 1: a tap a plane of lanes
+            held = jnp.concatenate([conv, jnp.swapaxes(u, 0, 1)])
+            c = sum(held[k].astype(f32) * taps[k].astype(f32)
+                    for k in range(K))[:, None]
+        else:                           # N == 1: the lane's window, then u
+            win = jax.lax.dynamic_index_in_dim(conv, row, 1, keepdims=False)
+            win = jnp.where(fresh, jnp.zeros_like(win), win)
+            held = jnp.concatenate([win, u[0]])         # (K-1+T, Di)
+            c = sum(held[k:k + T].astype(f32) * taps[k].astype(f32)
+                    for k in range(K))[None]
+        if bias is not None:
+            c = c + bias.astype(f32)
+        u = jax.nn.silu(c).astype(x.dtype)
+        dbc = _dense32(u, *lp["x_proj"])
+        eps = spec.eps
+        dt = G._rms(dbc[..., :R], *lp["dt_norm"], eps=eps).astype(x.dtype)
+        Bm = G._rms(dbc[..., R:R + Ds], *lp["b_norm"], eps=eps)
+        Cm = G._rms(dbc[..., R + Ds:], *lp["c_norm"], eps=eps)
+        dt = jnp.where(ok[..., None],
+                       jax.nn.softplus(_dense32(dt, *lp["dt_proj"])), 0.0)
+    with jax.named_scope("ssm_scan"):
+        y, state = selective_scan(
+            u, dt, z, Bm, Cm, lp["A"], lp["D"], state,
+            rows=None if row is None else row[None],
+            reset=None if row is None else fresh.astype(jnp.int32)[None],
+            impl="pallas" if impl == "pallas" else "xla")
+    out = G._dense(y, *lp["out_proj"])
+    with jax.named_scope("state_write"):
+        if row is None:
+            conv = jnp.where(ok[None], held[1:], conv)
+        else:
+            # the last K-1 inputs that are ok: rows n .. n+K-2 of `held`
+            n = jnp.sum(ok.astype(jnp.int32))
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, jax.lax.dynamic_slice_in_dim(held, n, K - 1), row, 1)
+    return out, conv, state
+
+
+def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
+            wblk, off, attend, ssm=None):
+    """The decoder stack of every serving program, a layer at a time by
+    the decoder's description: an "attn" layer is qkv, the K/V write
+    into the lanes' pages at ``(wblk, off)`` (quantized first on an int8
+    pool), attention over the pool — ``attend(q, pk, pv, sk, sv)`` is
+    one thing the programs differ in — and the projection; an "ssm"
+    layer is ``ssm(lp, x, conv, state)``, the other thing
+    (`_ssm_mixer` over the step's lanes or over a chunk's one).  Then
+    the FFN.  Pools are indexed by attention layer, ``rec = (states,
+    conv windows)`` by ssm layer.  Each part runs under the
+    `jax.named_scope` a device trace shows it by:
+    ``layer<i>/kv_write``, ``layer<i>/paged_attn``, ``layer<i>/ssm_conv``,
+    ``layer<i>/ssm_scan``, ``layer<i>/state_write``, ``layer<i>/ffn``.
+    Returns ``(h, new_k, new_v, new_sk, new_sv, new_rec)``, the scale
+    tuples empty on a float pool and ``new_rec`` ``()`` without ssm
+    layers."""
+    new_k, new_v, new_sk, new_sv, new_st, new_cv = [], [], [], [], [], []
+    for li, (lp, kind, act) in enumerate(zip(params["layers"], spec.kinds,
+                                             spec.acts)):
         with jax.named_scope(f"layer{li}"):
-            x = G._ln(h, *lp["ln1"])
-            q, k, v = G._qkv_heads(G._dense(x, *lp["qkv"]), H)
-            # write-then-read, the _cached_self_attn order: a position
-            # is valid by the time the mask admits it
-            with jax.named_scope("kv_write"):
-                if kv8:
-                    k, ks = quantize_kv(k)  # s8 values / f32 scales
-                    v, vs = quantize_kv(v)
-                    sk = write_rows(scale_k[li], wblk, off, ks)
-                    sv = write_rows(scale_v[li], wblk, off, vs)
-                    new_sk.append(sk)
-                    new_sv.append(sv)
-                else:
-                    sk = sv = None
-                pk = write_rows(pool_k[li], wblk, off, k)
-                pv = write_rows(pool_v[li], wblk, off, v)
-            with jax.named_scope("paged_attn"):
-                a = attend(q, pk, pv, sk, sv)
-            h = h + G._dense(a.reshape(h.shape), *lp["proj"])
+            x = G._norm(spec, h, lp["ln1"])
+            if kind == "ssm":
+                j = len(new_st)
+                a, cv, st = ssm(lp, x, rec[1][j], rec[0][j])
+                h = h + a
+                new_st.append(st)
+                new_cv.append(cv)
+            else:
+                ai = len(new_k)
+                q, k, v = G._qkv_heads(G._dense(x, *lp["qkv"]), spec.heads,
+                                       spec.kv_heads)
+                # write-then-read, the _cached_self_attn order: a position
+                # is valid by the time the mask admits it
+                with jax.named_scope("kv_write"):
+                    if kv8:
+                        k, ks = quantize_kv(k)  # s8 values / f32 scales
+                        v, vs = quantize_kv(v)
+                        sk = write_rows(scale_k[ai], wblk, off, ks)
+                        sv = write_rows(scale_v[ai], wblk, off, vs)
+                        new_sk.append(sk)
+                        new_sv.append(sv)
+                    else:
+                        sk = sv = None
+                    pk = write_rows(pool_k[ai], wblk, off, k)
+                    pv = write_rows(pool_v[ai], wblk, off, v)
+                with jax.named_scope("paged_attn"):
+                    a = attend(q, pk, pv, sk, sv)
+                h = h + G._dense(a.reshape(h.shape[:-1] + (-1,)),
+                                 *lp["proj"])
+                new_k.append(pk)
+                new_v.append(pv)
             with jax.named_scope("ffn"):
-                h = h + G._ffn_fwd(G._ln(h, *lp["ln2"]), lp, act)
-            new_k.append(pk)
-            new_v.append(pv)
-    return h, tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv)
+                h = h + G._ffn_fwd(G._norm(spec, h, lp["ln2"]), lp, act)
+    new_rec = (tuple(new_st), tuple(new_cv)) if new_st else ()
+    return (h, tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv),
+            new_rec)
 
 
-def _token_forward(params, acts, H, bs, kv8, attn_impl,
-                   pool_k, pool_v, scale_k, scale_v,
+def _token_forward(params, spec, bs, kv8, attn_impl,
+                   pool_k, pool_v, scale_k, scale_v, rec,
                    tables, toks, pos, active, guard_msl=None):
     """One token's forward over the paged pool — the `serving_step`
-    body minus the pick: embed `toks` at `pos`, write each layer's K/V
-    into the lane's current block, attend, and return
-    ``(new_k, new_v, new_sk, new_sv, logits)``.
+    body minus the pick: embed `toks` at `pos`, write each attention
+    layer's K/V into the lane's current block and attend, advance each
+    ssm layer's state by the token (live lanes only), and return
+    ``(new_k, new_v, new_sk, new_sv, new_rec, logits)``.
 
     ``guard_msl``: the speculative families step positions past the
     engine-committed ones (``pos .. pos+k``), so a full-length lane's
@@ -204,8 +308,6 @@ def _token_forward(params, acts, H, bs, kv8, attn_impl,
     never consumed host-side).  The non-speculative step passes None
     and keeps its original, unguarded ops byte-for-byte.
     """
-    dt = params["embed"].dtype
-    C = params["embed"].shape[1]
     if guard_msl is None:
         pos_c = pos
         blk_idx = pos // bs
@@ -216,41 +318,117 @@ def _token_forward(params, acts, H, bs, kv8, attn_impl,
         ok = active & (pos < guard_msl)
     off = pos_c % bs
     with jax.named_scope("embed"):
-        h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-             + params["pe"][pos_c].astype(dt))              # (B, C)
+        h = _embed(spec, params, toks, pos_c)               # (B, C)
     # the block this step writes: the lane's table entry for its
     # current position — inactive (or guarded-out) lanes are pointed
     # at scratch
     wblk = jnp.take_along_axis(tables, blk_idx[:, None], axis=1)[:, 0]
     wblk = jnp.where(ok, wblk, jnp.int32(0))
-    h, new_k, new_v, new_sk, new_sv = _layers(
-        params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+    def ssm(lp, x, conv, state):
+        a, conv, state = _ssm_mixer(spec, lp, x[:, None], conv, state,
+                                    None, None, ok[:, None], attn_impl)
+        return a[:, 0], conv, state
+
+    h, new_k, new_v, new_sk, new_sv, new_rec = _layers(
+        spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
         wblk, off,
         lambda q, pk, pv, sk, sv: paged_attention(
             q, pk, pv, tables, pos, scale_k=sk, scale_v=sv,
-            impl=attn_impl))                                # (B, H, D)
+            impl=attn_impl),                                # (B, H, D)
+        ssm)
     with jax.named_scope("head"):
-        logits = G._logits_of(params, h)                    # (B, V)
-    return new_k, new_v, new_sk, new_sv, logits
+        logits = G._logits_of(params, h, spec)              # (B, V)
+    return new_k, new_v, new_sk, new_sv, new_rec, logits
 
 
-def _build_step(H, acts, block_size, blocks_per_seq, temperature, top_k,
+class _HostPacked:
+    """A served program, jitted, whose small host arrays travel as ONE
+    buffer.  ``fn(*device, *host, params)``: ``n_device`` leading
+    arguments that live on the device and are donated (pools, scales,
+    recurrent state), then the scheduler's numpy arrays and scalars of a
+    call (block tables, tokens, positions, flags, keys), then the weight
+    pytree.  Every numpy argument of a jitted call is a transfer of its
+    own, 0.12 ms of the host's time each on a v5e (my chip runs, PR 30:
+    six of them 0.6 ms a call, twice an iteration); here they are laid
+    end to end in one int32 array on the host and cut apart again inside
+    the program (static slices, a bitcast for uint32, ``!= 0`` for bool).
+    Called and lowered like the jitted ``fn``; the compiled program keeps
+    ``fn``'s name."""
+
+    def __init__(self, fn, n_device):
+        self._fn, self._n = fn, int(n_device)
+        self._sig = self._jitted = None     # the host signature served
+
+    def _program(self, host):
+        sig = tuple((a.shape, a.dtype.char) for a in host)
+        if sig != self._sig:        # an engine's shapes are fixed: once
+            fn, n = self._fn, self._n
+            cuts, at = [], 0
+            for a in host:
+                cuts.append((at, at + a.size, a.shape, a.dtype))
+                at += a.size
+
+            def program(*args):
+                host = []
+                for lo, hi, shape, dtype in cuts:
+                    a = args[n][lo:hi].reshape(shape)
+                    if dtype == bool:
+                        a = a != 0
+                    elif dtype != jnp.int32:
+                        a = jax.lax.bitcast_convert_type(a, dtype)
+                    host.append(a)
+                return fn(*args[:n], *host, args[n + 1])
+
+            program.__name__ = fn.__name__
+            self._sig, self._jitted = sig, jax.jit(
+                program, donate_argnums=tuple(range(n)))
+        return self._jitted
+
+    def _split(self, args):
+        n = self._n
+        host = [np.asarray(a) for a in args[n:-1]]
+        for a in host:
+            if a.dtype.itemsize != 4 and a.dtype != np.bool_:
+                raise TypeError(f"host argument of dtype {a.dtype}: only "
+                                "bool and 4-byte types are packed")
+        packed = np.concatenate(
+            [(a.astype(np.int32) if a.dtype == np.bool_
+              else a.view(np.int32)).ravel() for a in host])
+        return self._program(host), args[:n] + (packed, args[-1])
+
+    def __call__(self, *args):
+        jitted, args = self._split(args)
+        return jitted(*args)
+
+    def lower(self, *args):
+        jitted, args = self._split(args)
+        return jitted.lower(*args)
+
+
+def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
                 kv_dtype, attn_impl, name):
     """The batched one-token decode program over the paged pool.
 
     Arguments (all traced):
-      pool_k/pool_v    per-layer tuples, each (num_blocks, bs, H*D): a
-                       position a row, a head a run of D lanes — s8
-                       when ``kv_dtype="int8"``, model dtype else
-      scale_k/scale_v  per-layer fp32 scale pools (num_blocks, bs, H)
+      pool_k/pool_v    a tuple entry an attention layer, each
+                       (num_blocks, bs, Hkv*D): a position a row, a KV
+                       head a run of D lanes — s8 when
+                       ``kv_dtype="int8"``, model dtype else
+      scale_k/scale_v  per-layer fp32 scale pools (num_blocks, bs, Hkv)
                        for the int8 pool; EMPTY tuples on the float path
+      rec              ``(states, conv windows)``, a tuple entry an ssm
+                       layer: (B, d_state, d_inner) float32 and
+                       (d_conv-1, B, d_inner), a row a lane; ``()`` for a
+                       decoder without ssm layers
       tables           (B, blocks_per_seq) int32 block ids per lane
       toks             (B,) int32 — token emitted by the previous step
       pos              (B,) int32 — position this step writes/attends to
       active           (B,) bool  — lanes with a live sequence
       keys             (B, 2) uint32 — per-lane PRNG keys
       params           generation._gather_params pytree
-    Returns (new_k, new_v, new_scale_k, new_scale_v, next_tokens).
+    Returns (new_k, new_v, new_scale_k, new_scale_v, new_rec,
+    next_tokens).  Every active lane's state row is rewritten in place;
+    an inactive lane's keeps its value.
 
     ``attn_impl`` ("pallas"|"dense") picks the `ops.paged_attention`
     path; ``name`` becomes the jitted function's __name__ so
@@ -260,20 +438,20 @@ def _build_step(H, acts, block_size, blocks_per_seq, temperature, top_k,
     pick = _row_pick(temperature, top_k)
     kv8 = kv_dtype == "int8"
 
-    def serving_step(pool_k, pool_v, scale_k, scale_v, tables, toks, pos,
-                     active, keys, params):
-        new_k, new_v, new_sk, new_sv, logits = _token_forward(
-            params, acts, H, bs, kv8, attn_impl,
-            pool_k, pool_v, scale_k, scale_v, tables, toks, pos, active)
+    def serving_step(pool_k, pool_v, scale_k, scale_v, rec, tables, toks,
+                     pos, active, keys, params):
+        new_k, new_v, new_sk, new_sv, new_rec, logits = _token_forward(
+            params, spec, bs, kv8, attn_impl,
+            pool_k, pool_v, scale_k, scale_v, rec, tables, toks, pos, active)
         with jax.named_scope("pick"):
             nxt = jax.vmap(pick)(logits, pos, keys)
-        return new_k, new_v, new_sk, new_sv, nxt
+        return new_k, new_v, new_sk, new_sv, new_rec, nxt
 
     serving_step.__name__ = name
     return serving_step
 
 
-def _build_prefill_chunk(H, acts, block_size, blocks_per_seq, chunk,
+def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
                          temperature, top_k, kv_dtype, attn_impl, name):
     """ONE fixed-width prefill chunk (ISSUE 20): positions
     ``start .. start+chunk-1`` of a single sequence's prompt, computed
@@ -284,7 +462,9 @@ def _build_prefill_chunk(H, acts, block_size, blocks_per_seq, chunk,
     The body is the `_build_spec_verify` window recipe at batch 1:
     embed the window, scatter each layer's K/V into the sequence's
     pages (positions >= valid_len land in scratch), then ONE batched
-    `paged_attention` whose per-row ``kpos <= pos`` mask gives every
+    `paged_attention` (or, where the kernel has the sizes for it,
+    `paged_attention_window`: the same mask, each page read once for all
+    the chunk's queries) whose per-row ``kpos <= pos`` mask gives every
     window position exactly its causal prefix — including the
     positions this very chunk just wrote (write-then-read, the
     `serving_step` order).  Because each row's math is lane-local
@@ -297,6 +477,15 @@ def _build_prefill_chunk(H, acts, block_size, blocks_per_seq, chunk,
     on every call; the engine consumes it only from the final chunk.
     With ``kv_dtype="int8"`` K/V quantize per-head before the scatter
     and fp32 scales land in the scale pools.
+
+    An ssm layer continues row ``lane`` of its state and conv window
+    (``rec``, as `_build_step` has it) over the chunk's valid positions,
+    from zero when ``start == 0`` whatever the lane held, and rewrites
+    that one row; positions at or past ``valid_len`` advance nothing.
+    A recurrence is sequential, so the state after a prompt does not
+    depend on how the prompt was chunked either (to float32 roundoff:
+    the scan's order of operations is the same, the matmuls' tiling is
+    not).
     """
     bs = int(block_size)
     nbps = int(blocks_per_seq)
@@ -304,39 +493,54 @@ def _build_prefill_chunk(H, acts, block_size, blocks_per_seq, chunk,
     msl = nbps * bs
     pick = _row_pick(temperature, top_k)
     kv8 = kv_dtype == "int8"
+    # where the kernel has the sizes for it, the chunk's queries walk the
+    # sequence's pages once together and not once each (a KV head's lanes
+    # a block of their own: not with 64-wide heads side by side)
+    window = attn_impl == "pallas" and not kv8 and window_kernel_fits(
+        CH, spec.heads, spec.kv_heads, spec.head_dim)
 
-    def serving_prefill_chunk(pool_k, pool_v, scale_k, scale_v, table_row,
-                              toks, start, valid_len, key, params):
-        dt = params["embed"].dtype
-        C = params["embed"].shape[1]
+    def serving_prefill_chunk(pool_k, pool_v, scale_k, scale_v, rec,
+                              table_row, toks, start, valid_len, key, lane,
+                              params):
         posw = start + jnp.arange(CH, dtype=jnp.int32)         # (CH,)
         ok = posw < valid_len
         posc = jnp.clip(posw, 0, msl - 1)
         with jax.named_scope("embed"):
-            h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-                 + params["pe"][posc].astype(dt))              # (CH, C)
+            h = _embed(spec, params, toks, posc)               # (CH, C)
         blk_idx = jnp.clip(posc // bs, 0, nbps - 1)
         off = posc % bs
         wblk = jnp.where(ok, table_row[blk_idx], jnp.int32(0))
         tables = jnp.broadcast_to(table_row[None, :], (CH, nbps))
-        h, new_k, new_v, new_sk, new_sv = _layers(
-            params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
-            wblk, off,
-            lambda q, pk, pv, sk, sv: paged_attention(
-                q, pk, pv, tables, posc, scale_k=sk, scale_v=sv,
-                impl=attn_impl))                               # (CH,H,D)
+
+        def ssm(lp, x, conv, state):
+            a, conv, state = _ssm_mixer(spec, lp, x[None], conv, state,
+                                        lane, start == 0, ok[None],
+                                        attn_impl)
+            return a[0], conv, state
+
+        if window:
+            def attend(q, pk, pv, sk, sv):
+                return paged_attention_window(q, pk, pv, table_row, start)
+        else:
+            def attend(q, pk, pv, sk, sv):
+                return paged_attention(q, pk, pv, tables, posc, scale_k=sk,
+                                       scale_v=sv, impl=attn_impl)
+
+        h, new_k, new_v, new_sk, new_sv, new_rec = _layers(
+            spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
+            wblk, off, attend, ssm)                            # (CH,H,D)
         with jax.named_scope("head"):
-            logits = G._logits_of(params, h)                   # (CH, V)
+            logits = G._logits_of(params, h, spec)             # (CH, V)
         with jax.named_scope("pick"):
             li_idx = jnp.clip(valid_len - 1 - start, 0, CH - 1)
             first = pick(logits[li_idx], valid_len - 1, key)
-        return new_k, new_v, new_sk, new_sv, first
+        return new_k, new_v, new_sk, new_sv, new_rec, first
 
     serving_prefill_chunk.__name__ = name
     return serving_prefill_chunk
 
 
-def _build_draft_step(H, acts, block_size, k, temperature, top_k,
+def _build_draft_step(spec, block_size, k, temperature, top_k,
                       greedy, attn_impl, msl, name):
     """k unrolled single-token draft steps over the DRAFT KV pool.
 
@@ -361,9 +565,9 @@ def _build_draft_step(H, acts, block_size, k, temperature, top_k,
         cur = toks
         d_toks, d_probs = [], []
         for j in range(k):
-            pk, pv, _, _, logits = _token_forward(
-                params, acts, H, bs, False, attn_impl,
-                pk, pv, (), (), tables, cur, pos + j, active,
+            pk, pv, _, _, _, logits = _token_forward(
+                params, spec, bs, False, attn_impl,
+                pk, pv, (), (), (), tables, cur, pos + j, active,
                 guard_msl=msl)
             with jax.named_scope("pick"):
                 if greedy:
@@ -385,7 +589,7 @@ def _build_draft_step(H, acts, block_size, k, temperature, top_k,
     return serving_draft_step
 
 
-def _build_draft_prefill_chunk(H, acts, block_size, blocks_per_seq,
+def _build_draft_prefill_chunk(spec, block_size, blocks_per_seq,
                                chunk, attn_impl, name):
     """The chunk program on the DRAFT weights, filling the draft pool
     alongside the target's — `_build_prefill_chunk` minus the
@@ -400,20 +604,17 @@ def _build_draft_prefill_chunk(H, acts, block_size, blocks_per_seq,
 
     def serving_draft_prefill_chunk(pool_k, pool_v, table_row, toks,
                                     start, valid_len, params):
-        dt = params["embed"].dtype
-        C = params["embed"].shape[1]
         posw = start + jnp.arange(CH, dtype=jnp.int32)
         ok = posw < valid_len
         posc = jnp.clip(posw, 0, msl - 1)
         with jax.named_scope("embed"):
-            h = (params["embed"][toks].astype(dt) * math.sqrt(C)
-                 + params["pe"][posc].astype(dt))              # (CH, C)
+            h = _embed(spec, params, toks, posc)               # (CH, C)
         blk_idx = jnp.clip(posc // bs, 0, nbps - 1)
         off = posc % bs
         wblk = jnp.where(ok, table_row[blk_idx], jnp.int32(0))
         tables = jnp.broadcast_to(table_row[None, :], (CH, nbps))
-        _, new_k, new_v, _, _ = _layers(
-            params, acts, H, False, h, pool_k, pool_v, (), (), wblk, off,
+        _, new_k, new_v, _, _, _ = _layers(
+            spec, params, False, h, pool_k, pool_v, (), (), (), wblk, off,
             lambda q, pk, pv, sk, sv: paged_attention(
                 q, pk, pv, tables, posc, impl=attn_impl))
         return new_k, new_v
@@ -422,7 +623,7 @@ def _build_draft_prefill_chunk(H, acts, block_size, blocks_per_seq,
     return serving_draft_prefill_chunk
 
 
-def _build_spec_verify(H, acts, block_size, k, temperature, top_k,
+def _build_spec_verify(spec, block_size, k, temperature, top_k,
                        greedy, kv_dtype, attn_impl, msl, name):
     """The speculative verifier: ONE batched forward of every lane's
     (k+1)-token window against the TARGET paged pool, then exact
@@ -467,29 +668,26 @@ def _build_spec_verify(H, acts, block_size, k, temperature, top_k,
     def serving_spec_verify(pool_k, pool_v, scale_k, scale_v, tables,
                             toks, pos, active, keys, draft_toks,
                             draft_probs, params):
-        dt = params["embed"].dtype
-        C = params["embed"].shape[1]
         win = jnp.concatenate([toks[:, None], draft_toks], axis=1)
         posw = (pos[:, None]
                 + jnp.arange(T, dtype=jnp.int32)[None, :])     # (B, T)
         posc = jnp.clip(posw, 0, msl - 1)
         with jax.named_scope("embed"):
-            h = (params["embed"][win].astype(dt) * math.sqrt(C)
-                 + params["pe"][posc].astype(dt))              # (B, T, C)
+            h = _embed(spec, params, win, posc)                # (B, T, C)
         blk_idx = jnp.clip(posc // bs, 0, tables.shape[1] - 1)
         off = posc % bs
         wblk = jnp.take_along_axis(tables, blk_idx, axis=1)    # (B, T)
         wblk = jnp.where(active[:, None] & (posw < msl), wblk,
                          jnp.int32(0))
-        h, new_k, new_v, new_sk, new_sv = _layers(
-            params, acts, H, kv8, h, pool_k, pool_v, scale_k, scale_v,
+        h, new_k, new_v, new_sk, new_sv, _ = _layers(
+            spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, (),
             wblk, off,
             lambda q, pk, pv, sk, sv: jnp.stack(
                 [paged_attention(q[:, j], pk, pv, tables, pos + j,
                                  scale_k=sk, scale_v=sv, impl=attn_impl)
                  for j in range(T)], axis=1))                  # (B,T,H,D)
         with jax.named_scope("head"):
-            logits = G._logits_of(params, h)                   # (B,T,V)
+            logits = G._logits_of(params, h, spec)             # (B,T,V)
 
         with jax.named_scope("pick"):
             if greedy:
@@ -555,8 +753,18 @@ class PagedPrograms:
                 f"attn_impl must be None (auto), 'pallas' or 'dense', "
                 f"got {attn_impl!r}")
         self._net = net
-        self._H = net._layers[0].attn._num_heads
-        self._acts = tuple(lyr.ffn._act for lyr in net._layers)
+        self._spec = G.decoder_spec(net)
+        if self._spec.recurrent:
+            # a recurrence cannot be rolled back to a rejected position,
+            # and its state is not a page a scale could sit beside
+            if int(speculate_k) > 0 or draft_net is not None:
+                raise ValueError(
+                    "speculative decoding (speculate_k / draft_net) is not "
+                    "built for a decoder with recurrent (ssm) layers")
+            if kv_dtype == "int8":
+                raise ValueError(
+                    "kv_dtype='int8' is not built for a decoder with "
+                    "recurrent (ssm) layers")
         self._bs = int(block_size)
         self._nbps = int(blocks_per_seq)
         self._temperature = float(temperature)
@@ -575,7 +783,7 @@ class PagedPrograms:
         sfx = "_kv8" if kv_dtype == "int8" else ""
         self._step_name = "serving_step" + sfx
         self._prefill_name = "serving_prefill_chunk" + sfx
-        self._key = (self._H, self._acts, self._bs, self._nbps,
+        self._key = (self._spec, self._bs, self._nbps,
                      self._temperature, self._top_k, self.path,
                      self._kv_dtype, self._impl)
         self._params = None
@@ -584,11 +792,10 @@ class PagedPrograms:
         step = G._lru_touch(cache, ("step",) + self._key)
         if step is None:
             _note_build("step")
-            step = jax.jit(
-                _build_step(self._H, self._acts, self._bs, self._nbps,
+            step = _HostPacked(
+                _build_step(self._spec, self._bs, self._nbps,
                             self._temperature, self._top_k,
-                            self._kv_dtype, self._impl, self._step_name),
-                donate_argnums=(0, 1, 2, 3))
+                            self._kv_dtype, self._impl, self._step_name), 5)
             G._lru_put(net, cache, ("step",) + self._key, step,
                        "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
                        gauge="serving_program_cache_size")
@@ -597,13 +804,12 @@ class PagedPrograms:
         pfc = G._lru_touch(cache, pkey)
         if pfc is None:
             _note_build("prefill_chunk")
-            pfc = jax.jit(
-                _build_prefill_chunk(self._H, self._acts, self._bs,
+            pfc = _HostPacked(
+                _build_prefill_chunk(self._spec, self._bs,
                                      self._nbps, self._chunk,
                                      self._temperature, self._top_k,
                                      self._kv_dtype, self._impl,
-                                     self._prefill_name),
-                donate_argnums=(0, 1, 2, 3))
+                                     self._prefill_name), 5)
             G._lru_put(net, cache, pkey, pfc,
                        "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
                        gauge="serving_program_cache_size")
@@ -621,7 +827,7 @@ class PagedPrograms:
         self._draft_params = None
         self._draft_params_key = None
         if self._spec_k == 0:
-            self._draft_net = None
+            self._draft_net = self._draft_spec = None
             return
         if self._spec_k < 0:
             raise ValueError(
@@ -638,23 +844,23 @@ class PagedPrograms:
         else:
             self._draft_qc = G._quant_config(draft_net, None)
             self._draft_net = draft_net
-            dL = len(draft_net._layers)
-            self._draft_label = f"net[{dL}x{draft_net._units}]"
-        dnet = self._draft_net
-        self._draft_H = dnet._layers[0].attn._num_heads
-        self._draft_acts = tuple(lyr.ffn._act for lyr in dnet._layers)
+            dspec = G.decoder_spec(draft_net)
+            if dspec.recurrent:
+                raise ValueError("a draft_net with recurrent (ssm) layers "
+                                 "cannot be rolled back")
+            self._draft_label = f"net[{len(dspec.kinds)}x{dspec.units}]"
+        self._draft_spec = G.decoder_spec(self._draft_net)
         msl = self._nbps * self._bs
         k, greedy = self._spec_k, self._spec_greedy
         sfx = "_kv8" if self._kv_dtype == "int8" else ""
         self._verify_name = "serving_spec_verify" + sfx
-        dkey = (self._draft_H, self._draft_acts,
-                G._decode_path(self._draft_qc), k, greedy)
+        dkey = (self._draft_spec, G._decode_path(self._draft_qc), k, greedy)
         cache = _net_program_cache(net)
         draft = G._lru_touch(cache, ("draft_step",) + self._key + dkey)
         if draft is None:
             _note_build("draft_step")
             draft = jax.jit(
-                _build_draft_step(self._draft_H, self._draft_acts,
+                _build_draft_step(self._draft_spec,
                                   self._bs, k, self._temperature,
                                   self._top_k, greedy, self._impl, msl,
                                   "serving_draft_step"),
@@ -669,7 +875,7 @@ class PagedPrograms:
         if verify is None:
             _note_build("spec_verify")
             verify = jax.jit(
-                _build_spec_verify(self._H, self._acts, self._bs, k,
+                _build_spec_verify(self._spec, self._bs, k,
                                    self._temperature, self._top_k,
                                    greedy, self._kv_dtype, self._impl,
                                    msl, self._verify_name),
@@ -680,13 +886,13 @@ class PagedPrograms:
                        gauge="serving_program_cache_size")
         self._spec_verify = verify
         dpkey = (("draft_prefill_chunk", self._chunk) + self._key
-                 + (self._draft_H, self._draft_acts))
+                 + (self._draft_spec,))
         dpfc = G._lru_touch(cache, dpkey)
         if dpfc is None:
             _note_build("draft_prefill_chunk")
             dpfc = jax.jit(
                 _build_draft_prefill_chunk(
-                    self._draft_H, self._draft_acts, self._bs,
+                    self._draft_spec, self._bs,
                     self._nbps, self._chunk, self._impl,
                     "serving_draft_prefill_chunk"),
                 donate_argnums=(0, 1))
@@ -694,6 +900,15 @@ class PagedPrograms:
                        "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
                        gauge="serving_program_cache_size")
         self._draft_prefill_chunk = dpfc
+
+    @property
+    def spec(self):
+        """The target decoder's `generation.DecoderSpec`."""
+        return self._spec
+
+    @property
+    def draft_spec(self):
+        return self._draft_spec
 
     @property
     def path(self) -> str:
@@ -711,11 +926,14 @@ class PagedPrograms:
 
     @property
     def prog_label(self) -> str:
-        """Telemetry/program label: weight path, plus ``_kv8`` for the
-        int8 KV pool and ``_pallas`` when the kernel was forced off its
+        """Telemetry/program label: weight path, plus ``_ssm`` for a
+        decoder with recurrent layers, ``_kv8`` for the int8 KV pool and
+        ``_pallas`` when the kernel was forced off its
         home platform (the hlolint gate compiles that variant on CPU to
         pin the no-dense-probs census)."""
         label = self.path
+        if self._spec.recurrent:
+            label += "_ssm"
         if self._kv_dtype == "int8":
             label += "_kv8"
         if self._impl_forced and self._impl == "pallas":
@@ -733,6 +951,14 @@ class PagedPrograms:
             self._params = G._gather_params(self._net, pe_width, self._qc)
             self._params_key = key
         return self._params
+
+    def release(self):
+        """Let go of the nets and of the gathered weight pytrees (the
+        engine's `close()`): the jitted programs stay in the nets' own
+        caches."""
+        self._net = self._draft_net = None
+        self._params = self._params_key = None
+        self._draft_params = self._draft_params_key = None
 
     @property
     def step(self):

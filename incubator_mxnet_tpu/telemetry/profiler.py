@@ -247,15 +247,21 @@ class Iteration:
     record before: ``n`` prompt tokens from position ``start``, ``t``
     the stamp taken when the chunk's program call RETURNED — the host
     had handed it over; only a prompt's final chunk is ever fetched.
+    For a decoder with recurrent layers ``state_rows`` is the lanes that
+    hold live recurrent state at the commit and ``state_resets`` the
+    first chunks (a lane's state begun from zero) since the record
+    before; both 0 otherwise.
     """
 
     __slots__ = ("engine", "step", "t0", "t1", "causes", "occupancy",
                  "queue_depth", "blocks_reserved", "blocks_total",
-                 "block_size", "positions_written", "chunks")
+                 "block_size", "positions_written", "chunks",
+                 "state_rows", "state_resets")
 
     def __init__(self, engine, step, t0, t1, causes, occupancy=0,
                  queue_depth=0, blocks_reserved=0, blocks_total=0,
-                 block_size=0, positions_written=0, chunks=()):
+                 block_size=0, positions_written=0, chunks=(),
+                 state_rows=0, state_resets=0):
         self.engine = engine
         self.step = step
         self.t0 = t0
@@ -268,6 +274,8 @@ class Iteration:
         self.block_size = block_size
         self.positions_written = positions_written
         self.chunks = chunks
+        self.state_rows = state_rows
+        self.state_resets = state_resets
 
     def as_dict(self) -> dict:
         d = {k: getattr(self, k) for k in self.__slots__}
@@ -471,7 +479,8 @@ class EngineProfiler:
                  queue_depth: int = 0, step: int = 0,
                  blocks_reserved: int = 0, blocks_total: int = 0,
                  block_size: int = 0,
-                 positions_written: int = 0) -> Optional[dict]:
+                 positions_written: int = 0, state_rows: int = 0,
+                 state_resets: int = 0) -> Optional[dict]:
         """Close the iteration at a decode-step commit: compute the
         wall since the previous commit, carve gc + residue, push the
         record, feed histograms, judge the hiccup threshold.  Returns
@@ -501,7 +510,8 @@ class EngineProfiler:
             self.invariant_violations += 1
         rec = Iteration(self.name, step, t0, now, tuple(acc), occupancy,
                         queue_depth, blocks_reserved, blocks_total,
-                        block_size, positions_written, tuple(chunks))
+                        block_size, positions_written, tuple(chunks),
+                        state_rows, state_resets)
         self._ring.push(rec)
         self._totals = tuple(map(operator.add, self._totals, acc))
         self._total_wall += wall

@@ -35,6 +35,7 @@ _flash = importlib.import_module("incubator_mxnet_tpu.ops.flash_attention")
 _paged = importlib.import_module("incubator_mxnet_tpu.ops.paged_attention")
 _xent = importlib.import_module("incubator_mxnet_tpu.ops.xent_kernel")
 _dropout = importlib.import_module("incubator_mxnet_tpu.ops.dropout_kernel")
+_scan = importlib.import_module("incubator_mxnet_tpu.ops.selective_scan")
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -86,6 +87,40 @@ def _paged_int8(S):
         S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
 
 
+# the hybrid cell's widths (jamba2-3b.serve-docs): 20 query heads on one KV
+# head of 128, blocks of 64, 64 lanes of 2816 positions; d_inner 5120,
+# d_state 16, chunks of 256
+_G_B, _G_HQ, _G_D, _G_BS, _G_NBPS, _G_NB = 64, 20, 128, 64, 44, 2817
+_G_PAGE = (_G_NB, _G_BS, _G_D)
+_S_DI, _S_DS, _S_CHUNK = 5120, 16, 256
+
+
+def _paged_grouped(S):
+    """One KV head under 20 query heads: the step's 64 lanes."""
+    return _paged._paged_core.lower(
+        S((_G_B, _G_HQ, _G_D), bf16), S(_G_PAGE, bf16), S(_G_PAGE, bf16),
+        S((_G_B, _G_NBPS), i32), S((_G_B,), i32), interpret=False)
+
+
+def _paged_window(S):
+    """A chunk's 256 queries of 20 heads against each page once."""
+    return _paged._window_core.lower(
+        S((_S_CHUNK, _G_HQ, _G_D), bf16), S(_G_PAGE, bf16), S(_G_PAGE, bf16),
+        S((_G_NBPS,), i32), S((), i32), interpret=False)
+
+
+def _selective_scan(N, T, nb):
+    """(1, chunk) continuing one lane's row, or (lanes, 1) in place."""
+    def lower(S):
+        seq = (N, T, _S_DI)
+        return _scan._scan_core.lower(
+            S(seq, bf16), S(seq, f32), S(seq, bf16), S((N, T, _S_DS), f32),
+            S((N, T, _S_DS), f32), S((_S_DS, _S_DI), f32), S((_S_DI,), f32),
+            S((_G_B, _S_DS, _S_DI), f32), S((N // nb,), i32), S((N,), i32),
+            nb=nb, interpret=False)
+    return lower
+
+
 def _flash_fwd(T, bk):
     def lower(S):
         x = S((2, 16, T, 64), bf16)
@@ -124,6 +159,12 @@ def _dropout_mask(cols):
 _KERNELS = {
     "paged_bf16": (_paged_bf16, ["paged_attention"]),
     "paged_int8": (_paged_int8, ["paged_attention_q8"]),
+    "paged_grouped_step_20q_1kv": (_paged_grouped, ["paged_attention"]),
+    "paged_window_chunk_20q_1kv": (_paged_window, ["paged_attention_window"]),
+    "selective_scan_chunk_1x256": (_selective_scan(1, _S_CHUNK, 1),
+                                   ["selective_scan"]),
+    "selective_scan_step_64x1": (_selective_scan(_G_B, 1, 8),
+                                 ["selective_scan"]),
     # T=2048: K/V stay VMEM-resident; T=16384: streamed over the grid
     "flash_fwd_resident_T2048": (_flash_fwd(2048, 512), ["flash_fwd"]),
     "flash_fwd_streamed_T16384": (_flash_fwd(16384, 1024),
@@ -194,24 +235,25 @@ def cell_weights():
     params = G._gather_params(net, _CELL_NBPS * _BS)
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-    return shapes, tuple(lyr.ffn._act for lyr in net._layers)
+    return shapes, G.decoder_spec(net)
 
 
-def _served_program(program, kv_dtype, acts):
-    """(the real program body, its arguments' shapes after the pools)."""
+def _served_program(program, kv_dtype, spec, B=_CELL_B, bs=_BS,
+                    nbps=_CELL_NBPS, chunk=_CELL_CHUNK):
+    """(the real program body, its arguments' shapes after the pools and
+    the recurrent state)."""
     from incubator_mxnet_tpu.serving import programs as SP
 
     if program == "serving_step":
-        fn = SP._build_step(_H, acts, _BS, _CELL_NBPS, 0.0, 0, kv_dtype,
-                            "pallas", program)
-        rest = [((_CELL_B, _CELL_NBPS), i32), ((_CELL_B,), i32),
-                ((_CELL_B,), i32), ((_CELL_B,), jnp.bool_),
-                ((_CELL_B, 2), jnp.uint32)]
+        fn = SP._build_step(spec, bs, nbps, 0.0, 0, kv_dtype, "pallas",
+                            program)
+        rest = [((B, nbps), i32), ((B,), i32), ((B,), i32),
+                ((B,), jnp.bool_), ((B, 2), jnp.uint32)]
     else:
-        fn = SP._build_prefill_chunk(_H, acts, _BS, _CELL_NBPS, _CELL_CHUNK,
-                                     0.0, 0, kv_dtype, "pallas", program)
-        rest = [((_CELL_NBPS,), i32), ((_CELL_CHUNK,), i32), ((), i32),
-                ((), i32), ((2,), jnp.uint32)]
+        fn = SP._build_prefill_chunk(spec, bs, nbps, chunk, 0.0, 0,
+                                     kv_dtype, "pallas", program)
+        rest = [((nbps,), i32), ((chunk,), i32), ((), i32), ((), i32),
+                ((2,), jnp.uint32), ((), i32)]
     return fn, rest
 
 
@@ -231,7 +273,7 @@ def test_served_program_leaves_the_pool_where_it_lies(
     the ``(num_blocks, H, bs, D)`` pool the same compile held three
     copies of every pool array (12 here, 144 in the 24-layer cell) and
     temporaries of twice the pool."""
-    shapes, acts = cell_weights
+    shapes, spec = cell_weights
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def S(shape, dtype):
@@ -240,10 +282,11 @@ def test_served_program_leaves_the_pool_where_it_lies(
     kv8 = kv_dtype == "int8"
     pool = (S(_CELL_PAGE, i8 if kv8 else bf16),) * _CELL_L
     scale = (S(_CELL_SCALE, f32),) * _CELL_L if kv8 else ()
-    fn, rest = _served_program(program, kv_dtype, acts)
+    fn, rest = _served_program(program, kv_dtype, spec)
     params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), shapes)
-    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3)).lower(
-        pool, pool, scale, scale, *(S(*sd) for sd in rest), params).compile()
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4)).lower(
+        pool, pool, scale, scale, (), *(S(*sd) for sd in rest),
+        params).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == _CELL_L      # the real kernel
     # (a) no copy of a K/V pool array
@@ -260,6 +303,81 @@ def test_served_program_leaves_the_pool_where_it_lies(
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
     for i in range(2 * len(pool) + 2 * len(scale)):
         assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
+
+
+# --- the hybrid cell's programs: two kinds of state, neither copied ----- #
+@pytest.fixture(scope="module")
+def hybrid_weights():
+    """(weight shapes, spec) of three Mamba layers and one attention layer
+    at the hybrid cell's published widths, as `PagedPrograms` hands them
+    over: a stacked leaf a kind of weight, a row a layer."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models import generation as G
+    from incubator_mxnet_tpu.models.hybrid_ssm import HybridSSMDecoder
+
+    mx.random.seed(0)
+    net = HybridSSMDecoder(
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+        num_hidden_layers=4, num_attention_heads=_G_HQ,
+        num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=2,
+        mamba_d_state=_S_DS, mamba_d_conv=4, mamba_expand=2,
+        mamba_dt_rank=160, max_position_embeddings=_G_NBPS * _G_BS,
+        dtype="bfloat16", grad_req="null")
+    net.initialize(mx.init.Zero())
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        G._gather_params(net, _G_NBPS * _G_BS))
+    return shapes, G.decoder_spec(net)
+
+
+@pytest.mark.parametrize("program", ["serving_step", "serving_prefill_chunk"])
+def test_hybrid_program_leaves_both_states_where_they_lie(
+        one_chip, monkeypatch, hybrid_weights, program):
+    """A decoder with recurrent layers threads a second donated state
+    through the same two programs (docs/serving.md, "Two kinds of
+    state").  At the published widths, for the described v5e: both
+    kernels are in the program under their names, no K/V pool, recurrent
+    state or conv window is copied, every one of them comes back in its
+    argument's buffer, and the temporaries stay under one state array.
+    The weights arrive stacked (`generation.StackedLayers`): the program
+    takes two dozen weight buffers however deep the net, and reads a
+    layer's row where it lies, so nothing of a matrix's size is computed
+    at the program's top level but by the fusion that consumes it."""
+    shapes, spec = hybrid_weights
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state, conv = (_G_B, _S_DS, _S_DI), (3, _G_B, _S_DI)
+    pool = (S(_G_PAGE, bf16),)
+    rec = ((S(state, f32),) * 3, (S(conv, bf16),) * 3)
+    fn, rest = _served_program(program, None, spec, B=_G_B, bs=_G_BS,
+                               nbps=_G_NBPS, chunk=_S_CHUNK)
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), shapes)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4)).lower(
+        pool, pool, (), (), rec, *(S(*sd) for sd in rest), params).compile()
+    hlo = compiled.as_text()
+    paged = "paged_attention" if program == "serving_step" \
+        else "paged_attention_window"   # a chunk reads each page once
+    for kernel in (paged, "selective_scan"):
+        assert re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call",
+                          hlo), kernel
+    # (a copy whose layout names a memory space, `S(1)`, is the compiler
+    # prefetching a small array into fast memory, not a second array)
+    for dtype, shape in (("bf16", _G_PAGE), ("f32", state), ("bf16", conv)):
+        assert not [c for c in _copies_of(hlo, dtype, shape)
+                    if "S(" not in c], (dtype, shape)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < math.prod(state) * 4 + 2 * 65536 * _S_CHUNK * 4   # + the logits
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    for i in range(8):              # pool_k, pool_v, 3 states, 3 windows
+        assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
+    entry = hlo[hlo.index("ENTRY"):]
+    assert len(jax.tree_util.tree_leaves(shapes)) == 22
+    assert len(re.findall(r" parameter\(\d+\)", entry)) < 22 + 8 + 8
+    rows = "8192,2560|2560,8192|10240,2560|2560,5120"   # a layer's matrices
+    assert not re.findall(rf"= bf16\[(?:{rows})\]\S* \w[\w-]*\(", entry)
 
 
 # --- the same kernels in a program traced over the 2x2 mesh ------------- #

@@ -102,6 +102,42 @@ def _paged_int8(q, pool, tables, pos, labels):
                            scale_v=sk, impl="pallas")
 
 
+def _paged_grouped(q, pool, tables, pos, labels):
+    """4 query heads on 2 KV heads: the KV heads are what is split."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention
+
+    half = pool[:, :, :pool.shape[2] // 2]
+    return paged_attention(q[:, :, 0], half, half * 0.5, tables, pos,
+                           impl="pallas")
+
+
+def _paged_window(q, pool, tables, pos, labels):
+    """4 queries of 4 heads on one KV head against lane 1's pages, each
+    page once: nothing to split, every shard walks them."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention_window
+
+    one = pool[:, :, :q.shape[3]]
+    return paged_attention_window(q[:, :, 0], one, one * 0.5, tables[1],
+                                  jnp.int32(3))
+
+
+def _scan_step(q, pool, tables, pos, labels):
+    """The decode step's form of the selective scan: a lane a sequence of
+    one token, split over the lanes."""
+    from incubator_mxnet_tpu.ops.selective_scan import selective_scan
+
+    B = q.shape[0]
+    x = q.reshape(B, 1, -1)                                 # (4, 1, 4096)
+    Di, Ds = 256, 4
+    u, z = x[..., :Di], x[..., Di:2 * Di]
+    dt = jax.nn.softplus(x[..., 2 * Di:3 * Di])
+    Bm, Cm = x[..., 3 * Di:3 * Di + Ds], x[..., 3 * Di + Ds:3 * Di + 2 * Ds]
+    A = -jnp.exp(x[0, 0, :Ds * Di].reshape(Ds, Di) * 0.1)
+    state = x[:, 0, :Ds * Di].reshape(B, Ds, Di)
+    return selective_scan(u, dt, z, Bm, Cm, A, x[0, 0, :Di], state,
+                          impl="pallas")
+
+
 def _xent(smoothing):
     def run(q, pool, tables, pos, labels):
         from incubator_mxnet_tpu.ops import xent_kernel as xk
@@ -114,9 +150,11 @@ def _xent(smoothing):
 
 
 @pytest.mark.parametrize("kernel", [_flash, _paged, _paged_int8, _xent(0.0),
-                                    _xent(0.1)],
+                                    _xent(0.1), _paged_grouped, _paged_window,
+                                    _scan_step],
                          ids=["flash_fwd_bwd", "paged", "paged_int8", "xent",
-                              "xent_smoothed"])
+                              "xent_smoothed", "paged_grouped",
+                              "paged_window", "selective_scan_step"])
 def test_kernel_per_shard_matches_one_device(kernel):
     """The kernel under the 2x2 mesh's `shard_map` (interpret mode here)
     against the same kernel called as it is."""

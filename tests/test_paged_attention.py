@@ -170,6 +170,101 @@ def _plain_attention(q, k, v):
     return onp.einsum("ht,thd->hd", p, v)
 
 
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+@pytest.mark.parametrize("group,kv_heads", [(1, 4), (4, 1), (4, 2), (20, 1)],
+                         ids=["1x4kv", "4x1kv", "4x2kv", "20x1kv"])
+def test_grouped_heads_match_plain_attention(group, kv_heads, impl):
+    """``Hq = group x Hkv`` query heads over a pool whose row is ``Hkv *
+    D`` wide: query head h reads KV head ``h // group``.  Both impls
+    against plain attention with the keys and values repeated over the
+    group; with as many KV heads as query heads the dense recipe returns
+    the bits it returned before heads could be grouped."""
+    B, D, bs, nbps = 3, 16, 8, 4
+    q, k, v, tables, pos = _paged_case(7 + group, B=B, heads=kv_heads, D=D,
+                                       bs=bs, nbps=nbps)
+    q = _rand_pool(jax.random.PRNGKey(group), (B, group * kv_heads, D),
+                   jnp.float32)
+    out = paged_attention(q, _pages(k), _pages(v), tables, pos, impl=impl,
+                          interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    for b in range(B):
+        T = int(pos[b]) + 1
+        # (T, Hkv, D) as the sequence holds them, then a copy a query head
+        kb = onp.asarray(k)[onp.asarray(tables[b])].transpose(0, 2, 1, 3) \
+            .reshape(nbps * bs, kv_heads, D)[:T].repeat(group, axis=1)
+        vb = onp.asarray(v)[onp.asarray(tables[b])].transpose(0, 2, 1, 3) \
+            .reshape(nbps * bs, kv_heads, D)[:T].repeat(group, axis=1)
+        onp.testing.assert_allclose(onp.asarray(out[b]),
+                                    _plain_attention(q[b], kb, vb),
+                                    atol=2e-5)
+    if group == 1 and impl == "dense":
+        assert onp.array_equal(onp.asarray(out),
+                               onp.asarray(_former_dense(q, k, v, tables,
+                                                         pos)))
+
+
+@pytest.mark.parametrize("heads,kv_heads,D,start", [
+    (4, 1, 16, 0), (20, 1, 128, 5), (4, 2, 128, 17), (2, 2, 128, 9)],
+    ids=["4x1kv_d16", "20x1kv_d128", "4x2kv_d128", "mha_d128"])
+def test_window_kernel_matches_every_position_as_a_lane(heads, kv_heads, D,
+                                                        start):
+    """A chunk's queries against each page ONCE (`paged_attention_window`,
+    interpret mode) against the same queries as lanes of the dense recipe
+    over the same table: positions ``start .. start+T-1``, pages permuted,
+    the window ending mid-page."""
+    from incubator_mxnet_tpu.ops.paged_attention import (
+        paged_attention_window, window_kernel_fits)
+
+    T, bs, nbps = 16, 8, 6
+    assert window_kernel_fits(T, heads, kv_heads, D)
+    _, k, v, tables, _ = _paged_case(3 + heads, B=1, heads=kv_heads, D=D,
+                                     bs=bs, nbps=nbps)
+    q = _rand_pool(jax.random.PRNGKey(start), (T, heads, D), jnp.float32)
+    pos = start + jnp.arange(T, dtype=jnp.int32)
+    want = paged_attention(q, _pages(k), _pages(v),
+                           jnp.broadcast_to(tables, (T, nbps)), pos,
+                           impl="dense")
+    got = paged_attention_window(q, _pages(k), _pages(v), tables[0],
+                                 jnp.int32(start), interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
+                                atol=2e-5)
+
+
+def test_window_kernel_says_what_it_has_no_sizes_for():
+    from incubator_mxnet_tpu.ops.paged_attention import (
+        paged_attention_window, window_kernel_fits)
+
+    # 64-wide heads side by side (gpt2-medium): a KV head's lanes are no
+    # block of their own, so a chunk stays lanes of the single-query kernel
+    assert not window_kernel_fits(32, 16, 16, 64)
+    assert window_kernel_fits(256, 20, 1, 128)
+    assert not window_kernel_fits(65536, 20, 1, 128)      # VMEM
+    q, k, v, tables, _ = _paged_case(2, heads=2)
+    with pytest.raises(ValueError, match="window_kernel_fits"):
+        paged_attention_window(q, _pages(k), _pages(v), tables[0],
+                               jnp.int32(0), interpret=True)
+
+
+def test_grouped_heads_refuse_what_is_not_built():
+    q, k, v, tables, pos = _paged_case(2, heads=2)
+    q3 = jnp.zeros((q.shape[0], 3, q.shape[2]), q.dtype)
+    with pytest.raises(ValueError, match="KV heads"):
+        paged_attention(q3, _pages(k), _pages(v), tables, pos, impl="pallas",
+                        interpret=True)
+    (k8, sk), (v8, sv) = quantize_kv(k), quantize_kv(v)
+    q4 = jnp.zeros((q.shape[0], 4, q.shape[2]), q.dtype)
+    with pytest.raises(ValueError, match="int8"):
+        paged_attention(q4, _pages(k8), _pages(v8), tables, pos,
+                        scale_k=_pages(sk), scale_v=_pages(sv),
+                        impl="pallas", interpret=True)
+    # the dense recipe has them
+    out = paged_attention(q4, _pages(k8), _pages(v8), tables, pos,
+                          scale_k=_pages(sk), scale_v=_pages(sv),
+                          impl="dense")
+    assert out.shape == q4.shape
+
+
 @pytest.mark.parametrize("kv8", [False, True], ids=["float", "int8"])
 def test_pool_written_by_the_programs_write_reads_back(kv8):
     """The write, the kernel and the dense recipe agree on which bytes

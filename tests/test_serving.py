@@ -205,6 +205,105 @@ def test_deadline_evicts_mid_batch(clean_engine):
 
 
 # --------------------------------------------------------------------- #
+# the host's share of an iteration
+# --------------------------------------------------------------------- #
+def test_a_waiting_backlog_is_walked_only_when_it_may_hold_one_to_reap(net):
+    """Cancellation and deadlines apply to queued requests, but a backlog
+    without either is not looked through every iteration: the flag is up
+    while the queue may hold a request to reap, and down again after."""
+    eng = ServingEngine(net, max_batch=1, block_size=8, poll_interval=_POLL,
+                        fault_hook=_slow_step(0.01))
+    try:
+        head = eng.submit(P1, 40)
+        plain = [eng.submit(P2, 2) for _ in range(3)]
+        assert _wait(lambda: head.status == "running")
+        assert not eng._queue_reap          # nothing queued can be reaped
+        timed = eng.submit(P2, 2, deadline=0.02)
+        with pytest.raises(RequestShed) as ei:
+            timed.result(timeout=30)
+        assert ei.value.reason == "deadline"
+        assert _wait(lambda: not eng._queue_reap)   # looked at, then left
+        plain[1].cancel()
+        with pytest.raises(RequestCancelled):
+            plain[1].result(timeout=30)
+        head.cancel()
+        assert plain[0].result(timeout=30) and plain[2].result(timeout=30)
+        assert not eng._queue_reap
+    finally:
+        eng.close()
+
+
+def test_a_last_chunk_is_committed_behind_the_step_it_shares_an_iteration_with(
+        net):
+    """A chunk and a decode step of one iteration: the step is handed to
+    the device before the chunk's first token is fetched (its lanes were
+    snapshotted before the chunk ran, so it does not need it), and the
+    chunk is committed before the step is.  Tokens are what they were."""
+    order = []
+
+    def hook(phase):
+        order.append((phase, len(second.tokens) if second else None))
+
+    second = None
+    eng = ServingEngine(net, max_batch=2, block_size=8, poll_interval=_POLL,
+                        prefill_chunk=4, fault_hook=hook)
+    try:
+        first = eng.submit(P1, 30)
+        assert _wait(lambda: len(first.tokens) >= 2)
+        second = eng.submit(P2, 6)
+        got = second.result(timeout=30)
+        first.result(timeout=30)
+    finally:
+        eng.close()
+    assert got == onp.asarray(
+        lm_generate(net, P2[None, :], 6))[0, len(P2):].tolist()
+    # the iteration that ran `second`'s only chunk: its "step" hook still
+    # saw no token of it (the commit waits for the step's dispatch), the
+    # next iteration's did
+    i = max(k for k, (phase, n) in enumerate(order)
+            if phase == "prefill" and n == 0)
+    assert order[i + 1] == ("step", 0)
+    assert [n for phase, n in order[i + 2:] if phase == "step"][0] >= 1
+
+
+def test_host_arguments_travel_as_one_buffer():
+    """`programs._HostPacked`: the numpy arguments of a served call are
+    laid end to end in one int32 array and cut apart in the program; the
+    results are those of the plain jitted function, the device arguments
+    are donated, the compiled program keeps the function's name."""
+    import jax
+
+    from incubator_mxnet_tpu.serving.programs import _HostPacked
+
+    def serving_toy(pool, table, flags, keys, start, params):
+        picked = jnp.where(flags, table[:, 0], -1) + start
+        return pool + params["w"].sum(), picked, keys[:, 1] >> 1
+
+    packed = _HostPacked(serving_toy, 1)
+    table = onp.arange(12, dtype=onp.int32).reshape(4, 3)
+    flags = onp.array([True, False, True, True])
+    keys = onp.array([[1, 0xFFFFFFFF]] * 4, onp.uint32)
+    params = {"w": jnp.ones((2, 2))}
+    args = (table, flags, keys, onp.int32(7), params)
+    want = jax.jit(serving_toy)(jnp.zeros(3), *args)
+    pool = jnp.zeros(3)
+    got = packed(pool, *args)
+    for g, w in zip(got, want):
+        onp.testing.assert_array_equal(onp.asarray(g), onp.asarray(w))
+    assert got[2].dtype == jnp.uint32 and pool.is_deleted()
+    program = packed._jitted
+    packed(jnp.zeros(3), *args)
+    assert packed._jitted is program        # one program a signature
+    hlo = packed.lower(jnp.zeros(3), *args).compile().as_text()
+    assert "jit_serving_toy" in hlo
+    entry = hlo[hlo.index("ENTRY"):]
+    assert entry.count(" parameter(") == 3  # pool, ONE host buffer, w
+    with pytest.raises(TypeError, match="4-byte"):
+        packed(jnp.zeros(3), table.astype(onp.int64), flags, keys,
+               onp.int32(7), params)
+
+
+# --------------------------------------------------------------------- #
 # overload: bounded queue, shedding, no deadlock
 # --------------------------------------------------------------------- #
 def test_queue_saturation_sheds_without_deadlock(net):
