@@ -1,10 +1,15 @@
-"""On-chip probe of the paged-attention kernel alone, at the served
-cell's widths (B=32 lanes, H=16, D=64, block 16, 64 blocks a sequence,
-a pool of 1025 blocks laid out ``(num_blocks, block_size, H*D)``).
+"""On-chip probe of the paged-attention kernel alone, at the widths of
+the two served cells:
+
+* ``gpt2-medium`` (`gpt2-medium.serve-batch`): 32 lanes, 16 heads of 64,
+  block 16, 64 blocks a sequence, a pool of 1025 blocks laid out
+  ``(num_blocks, block_size, H*D)``;
+* ``jamba2-3b`` (`jamba2-3b.serve-docs`): 64 lanes, 20 query heads on one
+  KV head of 128, block 64, 44 blocks a sequence, a pool of 2817.
 
     python benchmark/paged_probe.py [seed]   # needs one TPU chip
 
-Prints three JSON lines, each naming the device:
+Prints JSON lines, each naming the device:
 
 * ``numerics`` — max |x − truth| on random bf16 data with every page
   live, where truth is the dense gather on fp32 copies at matmul
@@ -23,9 +28,22 @@ Prints three JSON lines, each naming the device:
   log-uniform over the ranges of ``perf/traffic/serve-batch.json``, a
   lane somewhere along its output, blocks reserved for the whole
   request): what `decode_step_ms` holds of the kernel.
+* ``pages_per_step`` — one line a shape and a run length ``n`` in
+  ``SWEEP`` (the kernel's rule `ops.paged_attention.pages_per_step`
+  replaced by the constant for the line, and the line the rule itself
+  picks marked ``"rule": true``): milliseconds a call, ``CALLS`` chained,
+  with every run live, with one run a lane live, the same over a table
+  twice as long (as many dead runs again and nothing else), and with
+  lanes placed like the cell's; from them **microseconds a live run**
+  (every run live: its copies land under the run before it) **and a
+  dead run** (what the longer table adds), what a lane's one live run
+  costs with nothing to hide its copies under, and beside them the
+  bytes' floor of a live run (its K and V at the chip's peak,
+  ``perf/peaks.json``).
 
 A reading of one run, not a benchmark: no cell, no gate.
 """
+import importlib
 import json
 import os
 import statistics
@@ -42,10 +60,20 @@ from incubator_mxnet_tpu.contrib.quantization import quantize_kv
 from incubator_mxnet_tpu.ops.paged_attention import (paged_attention,
                                                      paged_attention_dense)
 
+# ops/__init__ re-exports the function under the module's name
+_paged = importlib.import_module("incubator_mxnet_tpu.ops.paged_attention")
+
 B, H, D, BS, NBPS, NB = 32, 16, 64, 16, 64, 1025
 REPS, CALLS = 50, 24
-TRAFFIC = os.path.join(os.path.dirname(__file__), "..", "perf", "traffic",
-                       "serve-batch.json")
+SWEEP = (1, 2, 4, 8, 16, 32)
+PERF = os.path.join(os.path.dirname(__file__), "..", "perf")
+TRAFFIC = os.path.join(PERF, "traffic", "serve-batch.json")
+# (lanes, query heads, KV heads, head size, block, blocks a sequence,
+#  blocks in the pool, the cell's traffic)
+SHAPES = {
+    "gpt2-medium": (B, H, H, D, BS, NBPS, NB, "serve-batch"),
+    "jamba2-3b": (64, 20, 1, 128, 64, 44, 2817, "serve-docs"),
+}
 
 
 def _device():
@@ -68,14 +96,15 @@ def _ms(fn, *args):
     return statistics.median(times)
 
 
-def _cell_lanes(rs):
+def _cell_lanes(rs, traffic=TRAFFIC, B=B, BS=BS, NBPS=NBPS, NB=NB):
     """Block tables and positions as the cell's traffic leaves them."""
-    with open(TRAFFIC) as f:
+    with open(traffic) as f:
         mix = json.load(f)
     prompt, output = (
         onp.exp(rs.uniform(onp.log(mix[key]["lo"]), onp.log(mix[key]["hi"]),
                            B)).astype(int)
         for key in ("prompt_len", "output_len"))
+    output = onp.minimum(output, mix["max_total"] - prompt)
     pos = prompt + (output * rs.uniform(0, 1, B)).astype(int)
     reserved = onp.minimum(-(-(prompt + output) // BS), NBPS)
     ids = rs.permutation(NB - 1) + 1
@@ -85,6 +114,61 @@ def _cell_lanes(rs):
         tables[lane, :hi - lo] = ids[lo:hi]
     return (jnp.asarray(tables), jnp.asarray(pos.clip(0, NBPS * BS - 1),
                                              jnp.int32))
+
+
+def _sweep(rs, name):
+    """The ``pages_per_step`` lines of one shape."""
+    B, H, Hkv, D, BS, NBPS, NB, traffic = SHAPES[name]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
+    pk = jax.random.normal(kk, (NB, BS, Hkv * D), jnp.bfloat16)
+    pv = jax.random.normal(kv, (NB, BS, Hkv * D), jnp.bfloat16)
+    # distinct pages as far as the pool has them, then the same again
+    own = jnp.asarray(rs.permutation(B * NBPS) % (NB - 1) + 1,
+                      jnp.int32).reshape(B, NBPS)
+    last = jnp.full((B,), NBPS * BS - 1, jnp.int32)
+    first = jnp.zeros((B,), jnp.int32)
+    placed = {
+        "all_live": (own, last),
+        "one_run_live": (own, first),
+        "one_run_live_table_twice": (jnp.concatenate([own, own], 1), first),
+        "cell": _cell_lanes(rs, os.path.join(PERF, "traffic",
+                                             traffic + ".json"),
+                            B, BS, NBPS, NB)}
+    with open(os.path.join(PERF, "peaks.json")) as f:
+        hbm = json.load(f)["devices"][jax.devices()[0].device_kind][
+            "hbm_bytes_per_s"]
+    rule = _paged.pages_per_step
+    picked = rule(BS, NBPS, Hkv * D * 2)
+    try:
+        for n in SWEEP:
+            _paged.pages_per_step = lambda *shape, n=n: n
+
+            @jax.jit
+            def program(q, pk, pv, tables, pos):
+                for _ in range(CALLS):
+                    q = _paged._paged_call(q, (pk, pv), tables, pos, False)
+                return q
+
+            ms = {key: _ms(program, q, pk, pv, tables, pos) / CALLS
+                  for key, (tables, pos) in placed.items()}
+            runs, runs_twice = -(-NBPS // n), -(-2 * NBPS // n)
+            dead_us = 1e3 * (ms["one_run_live_table_twice"]
+                             - ms["one_run_live"]) / (B * (runs_twice - runs))
+            print(json.dumps({
+                "probe": "pages_per_step", "shape": name, "n": n,
+                "rule": n == picked, "device": _device(),
+                "grid_steps_a_call": B * runs, "ms_a_call": ms,
+                "cell_pages_live": int((placed["cell"][1] // BS + 1).sum()),
+                "us_a_live_run": 1e3 * ms["all_live"] / (B * runs),
+                "us_a_dead_run": dead_us,
+                "us_a_lone_live_run":
+                    1e3 * ms["one_run_live"] / B - (runs - 1) * dead_us,
+                "us_bytes_floor_a_live_run":
+                    1e6 * 2 * n * BS * Hkv * D * 2 / hbm}),
+                flush=True)
+    finally:
+        _paged.pages_per_step = rule
 
 
 def main():
@@ -149,6 +233,9 @@ def main():
                       "pages_walked": B * NBPS,
                       "ms_a_call_kernel_bf16": _ms(program, q, pk, pv)
                       / CALLS}), flush=True)
+
+    for name in SHAPES:
+        _sweep(rs, name)
 
 
 if __name__ == "__main__":

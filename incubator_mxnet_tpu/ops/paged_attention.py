@@ -16,16 +16,22 @@ the device, so a served program holds no copy of a pool array
 block_size, D)`` it held three of each).
 
 * ``paged_attention`` with ``impl="pallas"`` — a Pallas kernel with
-  grid ``(lane, block)``, every head of a page in one step: the KV walk
-  is the innermost grid axis and the index map reads each page DIRECTLY
-  from the pool via the lane's block-table row (scalar-prefetched, the
-  TPU paged-attention idiom) — no dense gather, nothing ``(B, H, max_seq_len)``-shaped is
+  grid ``(lane, run)``, a run `pages_per_step` consecutive entries of
+  the lane's block-table row and every head of its pages in one step:
+  the KV walk is the innermost grid axis, the pools stay in HBM as they
+  lie and the kernel copies each visible page of a run DIRECTLY from the
+  pool via the lane's block-table row (scalar-prefetched, the TPU
+  paged-attention idiom), the next live run's pages under this run's
+  math — no dense gather, nothing ``(B, H, max_seq_len)``-shaped is
   ever materialized.  The query is laid out block-diagonally over the
-  page's ``H*D`` lanes, so both dots are plain 2-D matmuls over the
-  page as it lies.  Online-softmax state (m, l, acc) lives in VMEM
+  pages' ``H*D`` lanes, so both dots are plain 2-D matmuls over the
+  run as it lies.  Online-softmax state (m, l, acc) lives in VMEM
   scratch exactly like `flash_attention._fa_kernel_streamed`, and dead
-  blocks (``block > pos // block_size``) skip their math the same way
-  `_fa_kernel_resident` skips fully-masked causal blocks.
+  runs (``run * n * block_size > pos``) skip their copies and their
+  math the same way `_fa_kernel_resident` skips fully-masked causal
+  blocks.  A grid step's fixed cost is that of some ten pages' bytes,
+  so one page a step (2,048 steps a call at the served widths) ran at
+  a fifteenth of the bytes' pace: `pages_per_step` says how many.
 * ``impl="dense"`` — byte-for-byte the PR 12 recipe (fp32 scores,
   ``finfo.min`` mask, full-width `jax.nn.softmax`, fp32 PV).  This is
   the CPU fallback the eviction-bit-identity and greedy-parity
@@ -150,42 +156,76 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
                       ).astype(q.dtype).reshape(B, Hq, D)
 
 
-def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  bs, heads, kv_heads, kv_quant):
-    """One grid step = one (lane, page), all heads at once.  The page
-    arrived via the block-table index map as the pool holds it,
-    ``(bs, Hkv*D)``: a position a row, a KV head a run of ``D`` lanes.
-    This body does the online-softmax update, `pl.when`-skipping pages
-    past the lane's length bound.
+def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
+                  bs, n, heads, kv_heads, kv_quant):
+    """One grid step = one (lane, run of ``n`` pages), all heads at once.
+    The pools stay where they lie (HBM); the body copies a run's pages,
+    each as the pool holds it — ``(bs, Hkv*D)``: a position a row, a KV
+    head a run of ``D`` lanes — one under the other into one of two VMEM
+    buffers, which makes them the run's ``(n*bs, Hkv*D)`` keys or values.
+    A live step starts the copies of the NEXT live step (the lane's next
+    run, or run 0 of the next lane) into the other buffer before it waits
+    for its own, so they land under its math; a run past the lane's
+    length bound starts nothing and computes nothing.  Of a live run the
+    pages that hold a visible position are copied and no other: what a
+    page past the lane's position holds is never read.  Their places in
+    the buffers keep what an earlier run left: a score there is replaced
+    by the mask whatever the keys are, and the values are an earlier
+    page's or the zeros the buffer starts with, finite under a weight of
+    exactly 0.0, like a live page's tail.
 
-    Both dots are plain 2-D MXU matmuls over the page as it lies.  The
+    Both dots are plain 2-D MXU matmuls over the run as it lies.  The
     query is laid out block-diagonally, ``q_bd[h] = q[h]`` on the lanes
-    of head h's KV head and exact zeros elsewhere, so ``q_bd · page^T``
-    is each head's own ``(Hq, bs)`` scores; ``p · page`` weights every KV
+    of head h's KV head and exact zeros elsewhere, so ``q_bd · run^T``
+    is each head's own ``(Hq, n*bs)`` scores; ``p · run`` weights every KV
     head's lanes by every head's row, and `_emit` keeps the diagonal
     blocks.  int8 pages enter the dots as they are and their fp32 scales
-    multiply the scores and the softmax weights, one per (slot, head).
+    multiply the scores and the softmax weights, one per (slot, head);
+    a page's ``(bs, Hkv)`` scale block is too narrow to be cut out of HBM
+    by a copy of the kernel's own, so the scale pools cross the call
+    once a page of the run under a one-page block spec each, entry ``j*n
+    + i`` of the row, and the pipeline brings them.
 
     With as many KV heads as query heads the query and the output cross
     the call flattened, ``(1, H*D)``, as the pages have it; with grouped
     heads they are ``(Hq, D)`` rows (for one KV head the block-diagonal
     layout is the query itself and nothing is masked)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    if kv_quant:
-        sk_ref, sv_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+    pools, rest = rest[:2], rest[2:]                    # K and V, in HBM
+    if kv_quant:       # a scale block a page of the run, K's then V's
+        scales, rest = (rest[:n], rest[n:2 * n]), rest[2 * n:]
+    o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
+    lanes = pl.num_programs(0)
     nb = pl.num_programs(1)
     t = pos_ref[b]
+    run = n * bs
     group = heads // kv_heads
     d = acc_ref.shape[-1] // kv_heads
 
+    def run_copies(lane, first, slot, start):
+        """Start, or wait for, the copies into buffer ``slot`` of the live
+        run of ``lane`` whose first table entry is ``first``: K's and V's
+        page for each entry that holds a visible position (the run's
+        first always does).  A wait needs a copy's size alone."""
+        for i in range(n):
+            def page_i(i=i):
+                page = tables_ref[lane, first + i] if start else 0
+                for pool, buf in zip(pools, (k_buf, v_buf)):
+                    copy = pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, i], sem.at[slot])
+                    copy.start() if start else copy.wait()
+            if i == 0:
+                page_i()
+            else:
+                pl.when((first + i) * bs <= pos_ref[lane])(page_i)
+
     def own():
         """(Hq, Hkv*D): the lanes of row h that are its KV head's.  Built
-        where it is used, so a skipped page pays nothing for it."""
+        where it is used, so a skipped run pays nothing for it."""
         row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
         if group > 1:
@@ -206,32 +246,61 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, jnp.finfo(jnp.float32).min)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # length bound: pages past the lane's current position hold no
-    # visible slot — skip their math entirely (same trick as
-    # _fa_kernel_resident's nk_live; the DMA still lands, compute
-    # doesn't).  Page j==0 is always live (t >= 0), so m/l are finite
-    # by emit time.
-    @pl.when(j <= t // bs)
+    @pl.when(jnp.logical_and(b == 0, j == 0))
+    def _first():                           # no step before it to fetch it
+        slot_ref[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        run_copies(0, 0, 0, start=True)
+
+    # length bound: runs past the lane's current position hold no
+    # visible slot — skip their copies and their math entirely (same
+    # trick as _fa_kernel_resident's nk_live).  Run j==0 is live
+    # whatever the position says (every lane's first run is fetched by
+    # the step before it and must be waited for), and t >= 0 makes m/l
+    # finite by emit time.  A live run's first slot is visible, so its
+    # row maximum is finite and masked slots weigh exactly 0.0.
+    @pl.when(jnp.logical_or(j == 0, j * run <= t))
     def _update():
+        slot = slot_ref[0]
+        more = jnp.logical_and(j + 1 < nb, (j + 1) * run <= t)
+        nxt_lane = jnp.where(more, b, b + 1)
+
+        @pl.when(nxt_lane < lanes)
+        def _prefetch():
+            run_copies(nxt_lane, jnp.where(more, (j + 1) * n, 0), 1 - slot,
+                       start=True)
+        slot_ref[0] = 1 - slot
+        run_copies(b, j * n, slot, start=False)
+
+        def pages(buf):
+            """The run one page under the other, float32: (n*bs, Hkv*D)."""
+            return jnp.concatenate(
+                [buf[slot, i].astype(jnp.float32) for i in range(n)], axis=0)
+
+        def scale(refs):
+            """(Hkv, n*bs): the run's scales, a column a position."""
+            return jnp.concatenate([r[0] for r in refs], axis=0).T
+
         s = jax.lax.dot_general(
-            q_block_diagonal(), k_ref[0].astype(jnp.float32),
+            q_block_diagonal(), pages(k_buf),
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (Hq, bs)
+            preferred_element_type=jnp.float32)         # (Hq, n*bs)
         if kv_quant:
-            s = s * sk_ref[0].T
+            s = s * scale(scales[0])
         s = s / math.sqrt(d)
-        slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(j * bs + slot <= t, s, jnp.finfo(jnp.float32).min)
+        at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = j * run + at <= t
+        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
         m_prev, l_prev = m_ref[...], l_ref[...]         # (Hq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
         m_ref[...] = m_new
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if kv_quant:
-            p = p * sv_ref[0].T
+        if kv_quant:    # (the pipeline brought the scales of unseen pages)
+            p = jnp.where(seen, p * scale(scales[1]), 0.0)
         acc_ref[...] = acc_ref[...] * alpha \
-            + jnp.dot(p, v_ref[0].astype(jnp.float32),
+            + jnp.dot(p, pages(v_buf),
                       preferred_element_type=jnp.float32)
 
     @pl.when(j == nb - 1)
@@ -247,11 +316,40 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0] = o.astype(o_ref.dtype)
 
 
+# what one grid step of the single-query kernel covers: the pages that
+# bring a step's fixed cost under their own (the sweep of
+# benchmark/paged_probe.py on a v5e, PERF.md section 6: best at both
+# served widths, block 16 x row 1024 and block 64 x row 128), and of VMEM
+# no more than this for the runs of K and V, two buffers each
+_RUN_PAGES = 16
+_RUN_VMEM = 4 * 1024 * 1024
+
+
+def pages_per_step(block_size, blocks_per_seq, row_bytes) -> int:
+    """How many consecutive block-table entries of a lane one grid step
+    of the single-query kernel covers, from the shapes alone:
+    `_RUN_PAGES`, never more than the sequence has, nor than `_RUN_VMEM`
+    holds of the runs of K and V (two buffers each).  1 where nothing
+    larger fits: the same kernel body over one page."""
+    fit = _RUN_VMEM // (4 * block_size * row_bytes)
+    return max(1, min(_RUN_PAGES, blocks_per_seq, fit))
+
+
 def _paged_call(q, pools, tables, pos, interpret):
     """Shared pallas_call: ``pools`` is (pool_k, pool_v) or, for int8
     pages, (pool_k, pool_v, scale_k, scale_v).  With ``Hq == Hkv`` query
     and output cross the call with the head axis flattened, as the pages
-    have it; grouped heads cross as ``(Hq, D)`` rows."""
+    have it; grouped heads cross as ``(Hq, D)`` rows.
+
+    Grid ``(lanes, runs)``, a run `pages_per_step` consecutive entries of
+    the lane's table row, aligned to the table's index (so a position is
+    the same bits whichever call attends it: a step, or a lane of a
+    chunk wherever the chunk began).  The pages of a run are anywhere in
+    the pool, so the pools cross the call in HBM as they lie (no block,
+    no copy of a pool array) and the kernel fetches a run's pages itself.
+    A row that is no whole number of runs is padded with the scratch
+    block (block 0, which entries no sequence has reserved already
+    name); no position lies there, so the entries are never fetched."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -263,34 +361,42 @@ def _paged_call(q, pools, tables, pos, interpret):
             f"paged_attention: {H} query heads of {D} against a pool row "
             f"of {row}: the pool must hold a whole number of KV heads that "
             "divides the query's")
-    nbps = tables.shape[1]
     kv_quant = len(pools) == 4
     grouped = H != Hkv
     if grouped and kv_quant:
         raise ValueError("paged_attention: the kernel has no int8 pages "
                          "for grouped heads (impl='dense' does)")
-    kernel = functools.partial(_paged_kernel, bs=bs, heads=H, kv_heads=Hkv,
-                               kv_quant=kv_quant)
+    nbps = tables.shape[1]
+    n = pages_per_step(bs, nbps, row * pools[0].dtype.itemsize)
+    if nbps % n:
+        tables = jnp.pad(tables, ((0, 0), (0, -nbps % n)))
+    kernel = functools.partial(_paged_kernel, bs=bs, n=n, heads=H,
+                               kv_heads=Hkv, kv_quant=kv_quant)
     lane = pl.BlockSpec((1, H, D) if grouped else (1, 1, H * D),
                         lambda b, j, t, p: (b, 0, 0))
-    page = pl.BlockSpec((1, bs, row), lambda b, j, t, p: (t[b, j], 0, 0))
-    page_scale = pl.BlockSpec((1, bs, Hkv),
-                              lambda b, j, t, p: (t[b, j], 0, 0))
+    scale_run = [pl.BlockSpec((1, bs, Hkv),
+                              lambda b, j, t, p, i=i: (t[b, j * n + i], 0, 0))
+                 for i in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nbps),
-        in_specs=[lane, page, page] + [page_scale] * (2 * kv_quant),
+        grid=(B, tables.shape[1] // n),
+        in_specs=[lane] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+        + scale_run * (2 * kv_quant),
         out_specs=lane,
-        scratch_shapes=[pltpu.VMEM((H, row), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((2, n, bs, row), pool.dtype)
+                        for pool in pools[:2]]
+        + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32),
+           pltpu.VMEM((H, row), jnp.float32),
+           pltpu.VMEM((H, 1), jnp.float32),
+           pltpu.VMEM((H, 1), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + lane.block_shape[1:], q.dtype),
         interpret=interpret,
         name="paged_attention_q8" if kv_quant else "paged_attention",
-    )(tables, pos, q if grouped else q.reshape(B, 1, H * D), *pools)
+    )(tables, pos, q if grouped else q.reshape(B, 1, H * D), *pools[:2],
+      *(scale for scale in pools[2:] for _ in range(n)))
     return out.reshape(B, H, D)
 
 

@@ -83,7 +83,7 @@ import numpy as np
 
 from .. import telemetry
 from ..models import generation as G
-from ..ops.paged_attention import pool_shapes
+from ..ops.paged_attention import pages_per_step, pool_shapes
 from .kv_pool import SCRATCH_BLOCK, BlockPool
 from .programs import PagedPrograms
 
@@ -510,6 +510,12 @@ class ServingEngine:
                                    spec.kv_heads, spec.head_dim)
         self._pool_k = tuple(jnp.zeros(page, dt) for _ in range(L))
         self._pool_v = tuple(jnp.zeros(page, dt) for _ in range(L))
+        # block-table entries a grid step of the single-query kernel
+        # covers (the kernel's own rule, from these shapes); 0 on the
+        # dense path, which runs no kernel
+        self._pages_per_step = pages_per_step(
+            self._bs, self._nbps, page[2] * jnp.dtype(dt).itemsize) \
+            if self._programs.attn_impl == "pallas" else 0
         if self._kv_dtype == "int8":
             self._scale_k = tuple(
                 jnp.ones(scales, jnp.float32) for _ in range(L))
@@ -916,6 +922,7 @@ class ServingEngine:
             "prog_label": self._label,
             "kv_dtype": self._kv_dtype or "model",
             "attn_impl": self._programs.attn_impl,
+            "paged_pages_per_step": self._pages_per_step,
             "max_batch": self._B,
             "block_size": self._bs,
             "max_seq_len": self._msl,

@@ -74,17 +74,24 @@ _PAGE = (_NB, _BS, _H * _D)     # a position a row, a head a run of D
 _SCALE = (_NB, _BS, _H)
 
 
-def _paged_bf16(S):
-    return _paged._paged_core.lower(
-        S((_B, _H, _D), bf16), S(_PAGE, bf16), S(_PAGE, bf16),
-        S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
+# the served cell's table and pool (gpt2-medium.serve-batch): 64 blocks a
+# sequence, 1025 in the pool
+_CELL_NBPS, _CELL_NB = 64, 1025
 
 
-def _paged_int8(S):
-    return _paged._paged_core_q8.lower(
-        S((_B, _H, _D), bf16), S(_PAGE, i8), S(_PAGE, i8),
-        S(_SCALE, f32), S(_SCALE, f32),
-        S((_B, _NBPS), i32), S((_B,), i32), interpret=False)
+def _paged_bf16(nbps=_NBPS, nb=_NB):
+    page = (nb,) + _PAGE[1:]
+    return lambda S: _paged._paged_core.lower(
+        S((_B, _H, _D), bf16), S(page, bf16), S(page, bf16),
+        S((_B, nbps), i32), S((_B,), i32), interpret=False)
+
+
+def _paged_int8(nbps=_NBPS, nb=_NB):
+    page, scale = (nb,) + _PAGE[1:], (nb,) + _SCALE[1:]
+    return lambda S: _paged._paged_core_q8.lower(
+        S((_B, _H, _D), bf16), S(page, i8), S(page, i8),
+        S(scale, f32), S(scale, f32),
+        S((_B, nbps), i32), S((_B,), i32), interpret=False)
 
 
 # the hybrid cell's widths (jamba2-3b.serve-docs): 20 query heads on one KV
@@ -157,8 +164,12 @@ def _dropout_mask(cols):
 
 # (lowering, the `name=` of each Mosaic kernel the program must contain)
 _KERNELS = {
-    "paged_bf16": (_paged_bf16, ["paged_attention"]),
-    "paged_int8": (_paged_int8, ["paged_attention_q8"]),
+    "paged_bf16": (_paged_bf16(), ["paged_attention"]),
+    "paged_int8": (_paged_int8(), ["paged_attention_q8"]),
+    "paged_bf16_cell_64_blocks": (_paged_bf16(_CELL_NBPS, _CELL_NB),
+                                  ["paged_attention"]),
+    "paged_int8_cell_64_blocks": (_paged_int8(_CELL_NBPS, _CELL_NB),
+                                  ["paged_attention_q8"]),
     "paged_grouped_step_20q_1kv": (_paged_grouped, ["paged_attention"]),
     "paged_window_chunk_20q_1kv": (_paged_window, ["paged_attention_window"]),
     "selective_scan_chunk_1x256": (_selective_scan(1, _S_CHUNK, 1),
@@ -208,9 +219,20 @@ def test_kernel_keeps_its_name_for_v5e(one_chip, name):
         assert calls, (name, kernel)
 
 
+def test_pages_a_grid_step_at_the_cells_shapes():
+    """`pages_per_step` at the two served cells' shapes, which the cases
+    above compile with: a later change of the rule shows here.  2,048
+    grid steps a call become 128, and 2,816 become 192 (44 entries a
+    row padded to 48)."""
+    assert _paged.pages_per_step(_BS, _CELL_NBPS, _H * _D * 2) == 16
+    assert _paged.pages_per_step(_BS, _CELL_NBPS, _H * _D) == 16    # int8
+    assert _paged.pages_per_step(_BS, _NBPS, _H * _D * 2) == 16     # smoke
+    assert _paged.pages_per_step(_G_BS, _G_NBPS, _G_D * 2) == 16
+
+
 # --- the served programs: one layout for the KV pool -------------------- #
 # the benchmark cell's engine (gpt2-medium.serve-batch) at two layers
-_CELL_B, _CELL_NBPS, _CELL_NB, _CELL_CHUNK, _CELL_L = 32, 64, 1025, 32, 2
+_CELL_B, _CELL_CHUNK, _CELL_L = 32, 32, 2
 _CELL_PAGE = (_CELL_NB, _BS, _H * _D)
 _CELL_SCALE = (_CELL_NB, _BS, _H)
 

@@ -5,10 +5,13 @@ attention.
 The load-bearing contracts:
 
 * **Kernel/dense parity** — the online-softmax Pallas kernel (grid over
-  (lane, page), KV pages read straight from the pool) agrees with the
-  dense-gather reference to fp32 roundoff for ragged per-lane lengths
-  and permuted block tables, in f32 and bf16, with and without int8
-  pages.
+  (lane, run of pages), KV pages read straight from the pool) agrees
+  with the dense-gather reference to fp32 roundoff for ragged per-lane
+  lengths and permuted block tables, in f32 and bf16, with and without
+  int8 pages, with one run a lane and with several, whole or padded.
+* **Runs of pages** — a run's pages past the lane's position are never
+  read, and a position is the same bits as a step's lane and as a lane
+  of a chunk, wherever the chunk began.
 * **One layout** — the pool is ``(num_blocks, block_size, H*D)``; the
   serving programs' write, the kernel and the dense recipe read a
   position as the same bytes, and the dense recipe's output is bit for
@@ -187,20 +190,197 @@ def test_grouped_heads_match_plain_attention(group, kv_heads, impl):
     out = paged_attention(q, _pages(k), _pages(v), tables, pos, impl=impl,
                           interpret=True)
     assert out.shape == q.shape and out.dtype == q.dtype
-    for b in range(B):
-        T = int(pos[b]) + 1
-        # (T, Hkv, D) as the sequence holds them, then a copy a query head
-        kb = onp.asarray(k)[onp.asarray(tables[b])].transpose(0, 2, 1, 3) \
-            .reshape(nbps * bs, kv_heads, D)[:T].repeat(group, axis=1)
-        vb = onp.asarray(v)[onp.asarray(tables[b])].transpose(0, 2, 1, 3) \
-            .reshape(nbps * bs, kv_heads, D)[:T].repeat(group, axis=1)
-        onp.testing.assert_allclose(onp.asarray(out[b]),
-                                    _plain_attention(q[b], kb, vb),
-                                    atol=2e-5)
+    onp.testing.assert_allclose(
+        onp.asarray(out), _plain_lanes(q, k, v, tables, pos, group),
+        atol=2e-5)
     if group == 1 and impl == "dense":
         assert onp.array_equal(onp.asarray(out),
                                onp.asarray(_former_dense(q, k, v, tables,
                                                          pos)))
+
+
+# --- several pages a grid step (`pages_per_step`) ------------------------ #
+def _run_case(seed, heads, kv_heads, D, bs, nbps, pos, dtype=jnp.float32):
+    """K/V by (block, head, slot) in a pool whose block 0 is the scratch
+    block and holds real K/V; a lane a position of ``pos`` over a permuted
+    table reserved as far as its position needs and scratch beyond, and
+    one more lane that is idle: position 0, every entry scratch."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    B = len(pos) + 1
+    nblocks = 1 + len(pos) * nbps
+    k = _rand_pool(keys[0], (nblocks, kv_heads, bs, D), dtype)
+    v = _rand_pool(keys[1], (nblocks, kv_heads, bs, D), dtype)
+    q = _rand_pool(keys[2], (B, heads, D), dtype)
+    tables = onp.zeros((B, nbps), onp.int32)
+    ids = 1 + onp.asarray(jax.random.permutation(keys[3], len(pos) * nbps))
+    for lane, t in enumerate(pos):
+        live = t // bs + 1
+        tables[lane, :live] = ids[lane * nbps:lane * nbps + live]
+    return (q, k, v, jnp.asarray(tables),
+            jnp.asarray(list(pos) + [0], jnp.int32))
+
+
+def _plain_lanes(q, k, v, tables, pos, group):
+    """Every lane by `_plain_attention` over its own sequence: (T, Hkv,
+    D) as the sequence holds them, then a copy a query head."""
+    nb, kv_heads, bs, D = k.shape
+    out = []
+    for b in range(q.shape[0]):
+        T = int(pos[b]) + 1
+        kb, vb = (onp.asarray(x, onp.float32)[onp.asarray(tables[b])]
+                  .transpose(0, 2, 1, 3).reshape(-1, kv_heads, D)[:T]
+                  .repeat(group, axis=1) for x in (k, v))
+        out.append(_plain_attention(q[b], kb, vb))
+    return onp.stack(out)
+
+
+# (heads, KV heads, D, block, blocks a sequence): the rule's run length
+# over float32 pages
+_RUN_SHAPES = {
+    "mha_bs16_nbps32": (2, 2, 16, 16, 32, 16),      # two whole runs
+    "20x1kv_bs64_nbps44": (20, 1, 128, 64, 44, 16),  # the hybrid cell's table
+    "4x2kv_bs32_nbps19": (4, 2, 16, 32, 19, 16),    # a prime: padded to 32
+    "mha_bs8_nbps37": (2, 2, 16, 8, 37, 16),        # a prime: padded to 48
+    "mha_bs128_nbps19": (2, 2, 128, 128, 19, 8),    # VMEM: 8 pages, to 24
+}
+
+
+@pytest.mark.parametrize("shape,kv8", [
+    (shape, kv8) for shape, dims in _RUN_SHAPES.items()
+    for kv8 in (False, True)
+    if not kv8 or dims[0] == dims[1]],   # no int8 pages for grouped heads
+    ids=lambda x: x if isinstance(x, str) else ("int8" if x else "float"))
+def test_kernel_walks_runs_of_pages(shape, kv8):
+    """The kernel with several pages a grid step against plain attention
+    and the dense recipe: a lane at position 0, at a run's last slot, at
+    a run's first slot, inside a run's second page, at the sequence's
+    last position, and an idle lane on the scratch block; tables whose
+    length is no whole number of runs; grouped heads; int8 pages."""
+    from incubator_mxnet_tpu.ops.paged_attention import pages_per_step
+
+    heads, kv_heads, D, bs, nbps, n = _RUN_SHAPES[shape]
+    if kv8:     # a row a quarter as wide: VMEM may hold more of them
+        n = pages_per_step(bs, nbps, kv_heads * D)
+    assert pages_per_step(bs, nbps, kv_heads * D * (1 if kv8 else 4)) == n > 1
+    run = n * bs
+    pos = [0, run - 1, run, run + bs + 1, nbps * bs - 1]
+    q, k, v, tables, pos = _run_case(5, heads, kv_heads, D, bs, nbps, pos)
+    kw = {}
+    if kv8:
+        (k8, sk), (v8, sv) = quantize_kv(k), quantize_kv(v)
+        kw = dict(scale_k=_pages(sk), scale_v=_pages(sv))
+        pools = (_pages(k8), _pages(v8))
+        k, v = (x8.astype(jnp.float32) * sx[..., None]
+                for x8, sx in ((k8, sk), (v8, sv)))
+    else:
+        pools = (_pages(k), _pages(v))
+    got = paged_attention(q, *pools, tables, pos, impl="pallas",
+                          interpret=True, **kw)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    dense = paged_attention(q, *pools, tables, pos, impl="dense", **kw)
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(dense),
+                                atol=2e-5)
+    onp.testing.assert_allclose(
+        onp.asarray(got), _plain_lanes(q, k, v, tables, pos,
+                                       heads // kv_heads), atol=2e-5)
+
+
+def test_kernel_with_runs_matches_dense_in_bf16():
+    heads, kv_heads, D, bs, nbps, n = _RUN_SHAPES["mha_bs16_nbps32"]
+    pos = [0, n * bs - 1, n * bs, nbps * bs - 1]
+    q, k, v, tables, pos = _run_case(6, heads, kv_heads, D, bs, nbps, pos,
+                                     dtype=jnp.bfloat16)
+    pk, pv = _pages(k), _pages(v)
+    dense = paged_attention(q, pk, pv, tables, pos, impl="dense")
+    got = paged_attention(q, pk, pv, tables, pos, impl="pallas",
+                          interpret=True)
+    assert got.dtype == jnp.bfloat16
+    onp.testing.assert_allclose(onp.asarray(got, onp.float32),
+                                onp.asarray(dense, onp.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["float", "int8"])
+def test_pages_past_a_lanes_position_are_never_read(kv8):
+    """A live run ends in pages that hold no visible position (reserved
+    for the request's later tokens, or another sequence's by now): the
+    kernel fetches the run's visible pages alone, so what those pages
+    hold, NaN included, is nothing to the lane; within the last visible
+    page the slots past the position weigh exactly 0.0."""
+    heads, kv_heads, D, bs, nbps, n = _RUN_SHAPES["mha_bs16_nbps32"]
+    run = n * bs
+    pos = [3, run - bs - 1, run + 1, 2 * run + bs]
+    q, k, v, tables, pos = _run_case(8, heads, kv_heads, D, bs, nbps, pos)
+    # every entry a page of its own, so that the pages past a position
+    # can be spoiled: lane b's entry e is block 1 + b*nbps + e
+    tables = 1 + jnp.arange((len(pos) - 1) * nbps, dtype=jnp.int32) \
+        .reshape(-1, nbps)
+    tables = jnp.concatenate([tables, jnp.zeros((1, nbps), jnp.int32)])
+    dead = onp.zeros(k.shape[0], bool)
+    for b, t in enumerate(onp.asarray(pos[:-1])):
+        dead[1 + b * nbps + t // bs + 1:1 + (b + 1) * nbps] = True
+    kw, kw_bad = {}, {}
+    if kv8:
+        (k, sk), (v, sv) = quantize_kv(k), quantize_kv(v)
+        kw = dict(scale_k=_pages(sk), scale_v=_pages(sv))
+        kw_bad = {name: _pages(jnp.where(dead[:, None, None], jnp.nan, sx))
+                  for name, sx in (("scale_k", sk), ("scale_v", sv))}
+        k_bad, v_bad = k, v
+    else:
+        k_bad, v_bad = (jnp.where(dead[:, None, None, None], jnp.nan, x)
+                        for x in (k, v))
+    want = paged_attention(q, _pages(k), _pages(v), tables, pos,
+                           impl="pallas", interpret=True, **kw)
+    got = paged_attention(q, _pages(k_bad), _pages(v_bad), tables, pos,
+                          impl="pallas", interpret=True, **kw_bad)
+    assert onp.isfinite(onp.asarray(got)).all()
+    assert onp.array_equal(onp.asarray(got), onp.asarray(want))
+
+
+def test_pages_per_step_follows_the_shapes():
+    from incubator_mxnet_tpu.ops.paged_attention import pages_per_step
+
+    assert pages_per_step(16, 64, 2048) == 16        # gpt2-medium's pool
+    assert pages_per_step(64, 44, 256) == 16         # jamba2-3b's
+    assert pages_per_step(8, 4, 128) == 4            # no more than there are
+    assert pages_per_step(8, 1, 128) == 1
+    assert pages_per_step(16, 64, 16 * 1024) == 4    # VMEM: what it holds
+    assert pages_per_step(128, 16, 2048) == 4
+    assert pages_per_step(16, 64, 128 * 1024) == 1   # nothing larger fits
+    assert pages_per_step(256, 16, 8192) == 1
+
+
+@pytest.mark.parametrize("chunk_start", [0, 240, 256],
+                         ids=["from_0", "across_runs", "at_a_run"])
+def test_position_as_a_lane_of_a_chunk_equals_the_step_bit_for_bit(
+        chunk_start):
+    """Runs are aligned to the table's index, not to a chunk's start: a
+    position attended as one lane of a chunk (every position of the chunk
+    a lane over the same table row, the chunk's K/V already in the pool)
+    is, bit for bit, the position attended by a step beside strangers
+    (the later positions not yet written), wherever the chunk began."""
+    heads, kv_heads, D, bs, nbps, n = _RUN_SHAPES["mha_bs16_nbps32"]
+    CH = 32
+    end = chunk_start + CH - 1
+    q1, k, v, tables, _ = _run_case(9, heads, kv_heads, D, bs, nbps,
+                                    [end, nbps * bs - 1])
+    q = _rand_pool(jax.random.PRNGKey(chunk_start), (CH, heads, D),
+                   jnp.float32)
+    pk, pv = _pages(k), _pages(v)
+    posw = chunk_start + jnp.arange(CH, dtype=jnp.int32)
+    chunk = paged_attention(q, pk, pv, jnp.broadcast_to(tables[0], (CH, nbps)),
+                            posw, impl="pallas", interpret=True)
+    for i in (0, 7, CH - 1):
+        t = chunk_start + i
+        # the step: position t's K/V are the last this sequence has, the
+        # slots after them hold other bits, the other lanes are strangers
+        blk, off = tables[0, (t + 1) // bs], (t + 1) % bs
+        spk = pk.at[blk, off:].set(3.0) if t < end else pk
+        spv = pv.at[blk, off:].set(-2.0) if t < end else pv
+        qs = q1.at[0].set(q[i])
+        step = paged_attention(qs, spk, spv, tables,
+                               jnp.asarray([t, 5, 0], jnp.int32),
+                               impl="pallas", interpret=True)
+        assert onp.array_equal(onp.asarray(step[0]), onp.asarray(chunk[i])), i
 
 
 @pytest.mark.parametrize("heads,kv_heads,D,start", [
@@ -418,6 +598,21 @@ def test_pallas_engine_cobatched_matches_dense_engine(net, pallas_engine):
     pallas_engine.drain(timeout=30)
     hits = sum(x == y for x, y in zip(got_a + got_b, base_a + base_b))
     assert hits / 20 >= 0.9, (got_a, got_b, base_a, base_b)
+
+
+def test_engine_says_how_many_pages_a_grid_step_walks(net, pallas_engine):
+    """`varz_config()["paged_pages_per_step"]`: the kernel's own rule at
+    the engine's shapes, static for an engine; 0 where no kernel runs."""
+    from incubator_mxnet_tpu.ops.paged_attention import pages_per_step
+
+    cfg = pallas_engine.varz_config()
+    assert cfg["attn_impl"] == "pallas"
+    nbps = cfg["max_seq_len"] // cfg["block_size"]
+    assert cfg["paged_pages_per_step"] == nbps > 1        # one run a lane
+    assert cfg["paged_pages_per_step"] == pages_per_step(
+        cfg["block_size"], nbps, C * 4)
+    with net.serve(max_batch=2, block_size=8, poll_interval=_POLL) as dense:
+        assert dense.varz_config()["paged_pages_per_step"] == 0
 
 
 def test_eviction_bit_identity_under_pallas(pallas_engine):
