@@ -201,7 +201,8 @@ def _paged_vs_dense(engine, num_heads, seed=0):
     from incubator_mxnet_tpu.ops.paged_attention import (
         paged_attention, paged_attention_dense)
 
-    pk, pv = engine._pool_k[0], engine._pool_v[0]
+    pool_k, pool_v, scale_k, scale_v = engine._programs.kv_pools
+    pk, pv = pool_k[0], pool_v[0]
     nb, bs, HD = pk.shape                  # a position a row of H*D
     H, D = num_heads, HD // num_heads
     written = onp.flatnonzero(onp.asarray(jnp.any(pk != 0, axis=(1, 2))))
@@ -214,7 +215,7 @@ def _paged_vs_dense(engine, num_heads, seed=0):
     q = jax.random.normal(jax.random.PRNGKey(seed), (B, H, D),
                           jnp.bfloat16).astype(jnp.float32)
     if engine.kv_dtype == "int8":
-        scales = (engine._scale_k[0], engine._scale_v[0])
+        scales = (scale_k[0], scale_v[0])
         v = pv[written].astype(jnp.float32).reshape(-1, bs, H, D) \
             * scales[1][written][..., None]
         # dequantized K (8-bit integer × fp32 scale) is not bf16-exact

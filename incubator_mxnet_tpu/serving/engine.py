@@ -60,8 +60,10 @@ hook puts the in-flight table + recent traces into SIGTERM bundles.
 
 Thread-safety: ONE lock (`self._lock`, shared by the `self._work`
 condition and every request's condition) guards the queue, slots,
-stats and pool accounting.  The scheduler thread is the only toucher
-of the device-side pool arrays, so device calls run lock-free; only
+stats and pool accounting.  Everything on the device — programs, K/V
+pools, recurrent state, weights — belongs to `programs.PagedPrograms`
+(this module imports no JAX); the scheduler thread is the only caller
+of its program calls, so device calls run lock-free; only
 bookkeeping holds the lock.  That includes prefill (tpulint TPU015):
 admission claims the lane + blocks under the lock (binding any
 cache-hit prefix blocks), each chunk is stage (under the lock) →
@@ -78,12 +80,9 @@ import time
 from collections import OrderedDict, deque
 from typing import Optional
 
-import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
-from ..models import generation as G
-from ..ops.paged_attention import pages_per_step, pool_shapes
 from .kv_pool import SCRATCH_BLOCK, BlockPool
 from .programs import PagedPrograms
 
@@ -91,20 +90,18 @@ __all__ = ["ServingError", "RequestShed", "RequestTimedOut",
            "RequestCancelled", "RequestFailed", "Request", "ServingEngine",
            "default_engine"]
 
-_POLL_S = float(os.environ.get("MXTPU_SERVING_POLL", "0.002"))
-_MAX_QUEUE = int(os.environ.get("MXTPU_SERVING_QUEUE", "16"))
-# prefill-chunk width in tokens (the scheduler's prefill budget per
-# iteration): one chunk of at most this many prompt positions runs
-# between consecutive decode steps
-_PREFILL_CHUNK = int(os.environ.get("MXTPU_SERVING_PREFILL_CHUNK", "32")
-                     or 32)
-# one trace mark per N decode steps per request (0 disables the marks;
-# admission/terminal events always record)
-_TRACE_EVERY = int(os.environ.get("MXTPU_SERVING_TRACE_EVERY", "8"))
-# default TTFT SLO target (seconds) for the burn-rate tracker when
-# neither slo_ttft nor ttft_budget is given
-_SLO_TTFT_S = float(os.environ.get("MXTPU_SERVING_SLO_TTFT", "1.0"))
-_SLO_TPOT_S = os.environ.get("MXTPU_SERVING_SLO_TPOT", "")
+# defaults of the constructor options poll_interval, max_queue,
+# prefill_chunk (tokens: the scheduler's prefill budget per iteration —
+# one chunk of at most this many prompt positions runs between
+# consecutive decode steps) and slo_ttft (seconds, when ttft_budget is
+# not given either)
+_POLL_S = 0.002
+_MAX_QUEUE = 16
+_PREFILL_CHUNK = 32
+_SLO_TTFT_S = 1.0
+# one trace mark per N decode steps per request (admission/terminal
+# events always record)
+_TRACE_EVERY = 8
 
 # engine names for the HTTP/flight-recorder provider registries
 _engine_ids = itertools.count(1)
@@ -342,8 +339,7 @@ class ServingEngine:
                     ``net._max_len`` rounded down to a block multiple.
     num_blocks      pool size; default fits ``max_batch`` full-length
                     sequences plus the scratch block.
-    max_queue       admission queue bound (default env
-                    ``MXTPU_SERVING_QUEUE`` = 16).
+    max_queue       admission queue bound (default 16).
     temperature/top_k/eos_id   sampling config (compiled into the
                     programs, as in `lm_generate`).
     ttft_budget     SLO seconds; estimated-late requests are shed.
@@ -363,10 +359,9 @@ class ServingEngine:
                     many prompt positions before the next decode step,
                     so a long arrival costs resident sequences one
                     chunk of latency per token, never a full prefill.
-                    Default env ``MXTPU_SERVING_PREFILL_CHUNK`` = 32,
-                    clamped to ``max_seq_len``.  ONE chunk program per
-                    engine — no pow2 bucket ladder, no recompiles for
-                    unseen prompt lengths.
+                    Default 32, clamped to ``max_seq_len``.  ONE chunk
+                    program per engine — no pow2 bucket ladder, no
+                    recompiles for unseen prompt lengths.
     speculate_k     speculative decoding window (ISSUE 19): a draft
                     model proposes k tokens per lane per scheduler
                     iteration and the target verifies all lanes'
@@ -387,18 +382,16 @@ class ServingEngine:
                     temperature>0 (a throughput-over-sampling debug
                     knob; output becomes greedy).  temperature<=0
                     implies it.
-    poll_interval   scheduler idle/wait tick (default env
-                    ``MXTPU_SERVING_POLL`` = 2 ms).
+    poll_interval   scheduler idle/wait tick (default 2 ms).
     fault_hook      callable(phase: str) invoked before each
                     "prefill"/"step" device call — the fault-injection
                     seam the load harness and tests use (sleep = slow
                     step, raise = scheduler failure).
     slo_ttft        TTFT target (s) for the burn-rate tracker (default
-                    ``MXTPU_SERVING_SLO_TTFT``, else ``ttft_budget``,
-                    else 1.0 — the tracker is always on so
-                    ``serving_slo_fraction{window=}`` always exists).
-    slo_tpot        mean-TPOT target (s); default
-                    ``MXTPU_SERVING_SLO_TPOT`` else None (off).
+                    ``ttft_budget``, else 1.0 — the tracker is always
+                    on so ``serving_slo_fraction{window=}`` always
+                    exists).
+    slo_tpot        mean-TPOT target (s); default None (off).
     slo_windows     burn-rate window lengths in seconds (default
                     (60, 600)); slo_objective the good-fraction target
                     (default 0.99, i.e. a 1% error budget).
@@ -432,23 +425,31 @@ class ServingEngine:
         if block_size < 1 or (block_size & (block_size - 1)):
             raise ValueError(
                 f"block_size must be a power of two, got {block_size}")
-        spec = G.decoder_spec(net)
-        msl = int(max_seq_len if max_seq_len is not None else spec.max_len)
-        msl = (msl // block_size) * block_size
-        if msl < block_size:
-            raise ValueError(
-                f"max_seq_len {max_seq_len} < one block ({block_size})")
-        if msl > spec.max_len:
-            raise ValueError(
-                f"max_seq_len {msl} exceeds net.max_len {spec.max_len}")
-        self._net = net
         self._B = int(max_batch)
         self._bs = int(block_size)
-        self._msl = msl
-        self._nbps = msl // block_size
-        nb_default = self._B * self._nbps + 1
-        self._num_blocks = int(num_blocks if num_blocks is not None
-                               else nb_default)
+        # the device side — programs, pools, recurrent state, weights —
+        # is this object's; it also resolves the sizes that depend on
+        # the net's description (max_seq_len in whole blocks, the pool's
+        # default size, the chunk clamped to a sequence)
+        self._programs = PagedPrograms(
+            net, max_batch=self._B, block_size=self._bs,
+            max_seq_len=max_seq_len, num_blocks=num_blocks,
+            temperature=temperature, top_k=top_k, quantized=quantized,
+            kv_dtype=kv_dtype, attn_impl=attn_impl,
+            prefill_chunk=prefill_chunk if prefill_chunk is not None
+            else _PREFILL_CHUNK, speculate_k=speculate_k,
+            draft_net=draft_net, spec_greedy=spec_greedy)
+        self._msl = self._programs.max_seq_len
+        self._nbps = self._msl // self._bs
+        self._num_blocks = self._programs.num_blocks
+        self._chunk = self._programs.prefill_chunk_len
+        self._spec_k = int(speculate_k)
+        self._spec = self._spec_k > 0
+        self._path = self._programs.path          # "float" / "int8"
+        self._kv_dtype = self._programs.kv_dtype
+        # a recurrent layer's state cannot be handed to a prefix hit
+        self._recurrent = self._programs.spec.recurrent
+        self._state_resets = 0          # first chunks since the last record
         self._max_queue = int(max_queue if max_queue is not None
                               else _MAX_QUEUE)
         self._eos = int(eos_id)
@@ -457,116 +458,6 @@ class ServingEngine:
         self._poll = float(poll_interval if poll_interval is not None
                            else _POLL_S)
         self._fault_hook = fault_hook
-        if prefill_chunk is not None and int(prefill_chunk) < 1:
-            raise ValueError(
-                f"prefill_chunk must be >= 1, got {prefill_chunk}")
-        self._chunk = min(int(prefill_chunk if prefill_chunk is not None
-                              else _PREFILL_CHUNK), msl)
-        self._chunk = max(1, self._chunk)
-
-        self._spec_k = int(speculate_k)
-        self._spec = self._spec_k > 0
-        if self._spec_k < 0:
-            raise ValueError(
-                f"speculate_k must be >= 0, got {speculate_k}")
-        if self._spec and self._spec_k >= msl:
-            raise ValueError(
-                f"speculate_k {self._spec_k} >= max_seq_len {msl}")
-        if self._spec and draft_net is not None:
-            dspec = G.decoder_spec(draft_net)
-            if dspec.vocab != spec.vocab:
-                raise ValueError(
-                    f"draft_net vocab {dspec.vocab} != target vocab "
-                    f"{spec.vocab}")
-            if dspec.max_len < msl:
-                raise ValueError(
-                    f"draft_net.max_len {dspec.max_len} < "
-                    f"max_seq_len {msl}")
-        self._programs = PagedPrograms(
-            net, max_batch=self._B, block_size=self._bs,
-            blocks_per_seq=self._nbps, temperature=temperature,
-            top_k=top_k, quantized=quantized, kv_dtype=kv_dtype,
-            attn_impl=attn_impl, prefill_chunk=self._chunk,
-            speculate_k=self._spec_k,
-            draft_net=draft_net, spec_greedy=spec_greedy)
-        self._path = self._programs.path          # "float" / "int8"
-        self._label = self._programs.prog_label   # + _kv8/_pallas
-        self._kv_dtype = self._programs.kv_dtype
-        params = self._programs.gather_params(self._msl)
-        G._record_decode_weight_bytes(params, self._programs._qc)
-
-        # device pool: per attention layer (num_blocks, bs, Hkv*D), a
-        # position a row and a KV head a run of D lanes — the one shape
-        # the K/V write, the paged kernel and the donated buffer all
-        # take as it lies, row-major and unpadded (docs/serving.md).  The
-        # engine holds the ONLY reference and replaces it after every
-        # donated call (the buffers really are deleted on XLA:CPU too).
-        # With kv_dtype="int8" the pages are s8 and fp32 scale pools
-        # (num_blocks, bs, Hkv) ride alongside — also donated.
-        emb = params["embed"]
-        dt = jnp.int8 if self._kv_dtype == "int8" else emb.dtype
-        L = spec.kinds.count("attn")
-        page, scales = pool_shapes(self._num_blocks, self._bs,
-                                   spec.kv_heads, spec.head_dim)
-        self._pool_k = tuple(jnp.zeros(page, dt) for _ in range(L))
-        self._pool_v = tuple(jnp.zeros(page, dt) for _ in range(L))
-        # block-table entries a grid step of the single-query kernel
-        # covers (the kernel's own rule, from these shapes); 0 on the
-        # dense path, which runs no kernel
-        self._pages_per_step = pages_per_step(
-            self._bs, self._nbps, page[2] * jnp.dtype(dt).itemsize) \
-            if self._programs.attn_impl == "pallas" else 0
-        if self._kv_dtype == "int8":
-            self._scale_k = tuple(
-                jnp.ones(scales, jnp.float32) for _ in range(L))
-            self._scale_v = tuple(
-                jnp.ones(scales, jnp.float32) for _ in range(L))
-        else:
-            self._scale_k = self._scale_v = ()
-        # the second kind of per-sequence state (docs/serving.md, "Two
-        # kinds of state"): per ssm layer one float32 recurrent state
-        # (B, d_state, d_inner) and one conv window (d_conv-1, B,
-        # d_inner), a row a lane, donated and threaded like the pools.
-        # A lane's row is dead once the lane is free: the next prompt's
-        # first chunk starts it from zero.  `()` without ssm layers.
-        self._recurrent = spec.recurrent
-        self._rec = ()
-        if self._recurrent:
-            Di, Ds, K, _ = spec.ssm
-            n_ssm = spec.kinds.count("ssm")
-            self._rec = (
-                tuple(jnp.zeros((self._B, Ds, Di), jnp.float32)
-                      for _ in range(n_ssm)),
-                tuple(jnp.zeros((K - 1, self._B, Di), emb.dtype)
-                      for _ in range(n_ssm)))
-        self._state_bytes = sum(int(a.size) * a.dtype.itemsize
-                                for kind in self._rec for a in kind)
-        self._state_resets = 0          # first chunks since the last record
-        # speculative draft KV pool: per-draft-layer arrays in the
-        # draft model's dtype, addressed by the SAME block tables and
-        # the same BlockPool ids as the target pool (kv_pool.py), so
-        # one lane allocation covers both and eviction frees both
-        self._dpool_k = self._dpool_v = ()
-        if self._spec:
-            dnet = self._programs.draft_net
-            dparams = self._programs.draft_params(self._msl)
-            ddt = dparams["embed"].dtype
-            dspec = self._programs.draft_spec
-            dpage, _ = pool_shapes(self._num_blocks, self._bs,
-                                   dspec.kv_heads, dspec.head_dim)
-            self._dpool_k = tuple(
-                jnp.zeros(dpage, ddt) for _ in dspec.kinds)
-            self._dpool_v = tuple(
-                jnp.zeros(dpage, ddt) for _ in dspec.kinds)
-        # pool byte footprint is STATIC (donation replaces arrays, never
-        # shapes) — freeze it here so ops-side readers never touch the
-        # live pool tuples the scheduler thread is rewriting.  Draft
-        # pages count: they are resident HBM spent per token position.
-        self._kv_pool_bytes = sum(
-            int(a.size) * a.dtype.itemsize
-            for a in (*self._pool_k, *self._pool_v,
-                      *self._scale_k, *self._scale_v,
-                      *self._dpool_k, *self._dpool_v))
         self._pool = BlockPool(self._num_blocks, self._bs)
         if telemetry.enabled():
             telemetry.gauge("serving_kv_bytes_per_token",
@@ -619,11 +510,8 @@ class ServingEngine:
         # SLO burn-rate tracker: always on (host-side booleans; the
         # gauges it feeds still honour the telemetry disabled path)
         if slo_ttft is None:
-            slo_ttft = float(os.environ.get("MXTPU_SERVING_SLO_TTFT", "")
-                             or (ttft_budget if ttft_budget is not None
-                                 else _SLO_TTFT_S))
-        if slo_tpot is None and _SLO_TPOT_S:
-            slo_tpot = float(_SLO_TPOT_S)
+            slo_ttft = float(ttft_budget if ttft_budget is not None
+                             else _SLO_TTFT_S)
         self._slo = telemetry.slo.SloTracker(
             ttft_target=slo_ttft, tpot_target=slo_tpot,
             windows=slo_windows if slo_windows is not None
@@ -692,20 +580,20 @@ class ServingEngine:
         all layers) — the denominator of the int8 capacity win.
         Frozen at construction: donation swaps the pool arrays every
         step but never their shapes."""
-        return self._kv_pool_bytes
+        return self._programs.kv_pool_bytes
 
     @property
     def state_bytes(self) -> int:
         """Device bytes of the recurrent state (float32 states and conv
         windows, every ssm layer, every lane); 0 for a decoder of
         attention layers only.  Whatever the sequences' lengths."""
-        return self._state_bytes
+        return self._programs.state_bytes
 
     @property
     def state_bytes_per_seq(self) -> int:
         """Recurrent-state bytes one lane holds — the
         `serving_state_bytes_per_seq` gauge's value."""
-        return self._state_bytes // self._B
+        return self.state_bytes // self._B
 
     @property
     def kv_block_bytes(self) -> int:
@@ -718,14 +606,6 @@ class ServingEngine:
         """Pool bytes one token position costs across all layers —
         the `serving_kv_bytes_per_token` gauge's value."""
         return self.kv_block_bytes // self._bs
-
-    def _live_params(self):
-        """The weight pytree for the next program call — delegated to
-        `PagedPrograms.gather_params`, which caches on the
-        weight-buffer fingerprint: weight swaps (training, set_data)
-        are picked up at the next call, while the steady state costs
-        id() checks only (no per-token gather or requantize)."""
-        return self._programs.gather_params(self._msl)
 
     @property
     def http(self) -> Optional["telemetry.http.TelemetryServer"]:
@@ -811,8 +691,8 @@ class ServingEngine:
                      "steps": self._stats["steps"],
                      "queue_depth": len(self._queue),
                      "blocks_free": self._pool.num_free,
-                     "kv_pool_bytes": self._kv_pool_bytes,
-                     "state_bytes": self._state_bytes,
+                     "kv_pool_bytes": self.kv_pool_bytes,
+                     "state_bytes": self.state_bytes,
                      "prefill_chunks_pending":
                          self._pending_chunks_locked(),
                      "prefix_cache": {
@@ -919,10 +799,10 @@ class ServingEngine:
         return {
             "engine": self._name,
             "path": self._path,
-            "prog_label": self._label,
+            "prog_label": self._programs.prog_label,
             "kv_dtype": self._kv_dtype or "model",
             "attn_impl": self._programs.attn_impl,
-            "paged_pages_per_step": self._pages_per_step,
+            "paged_pages_per_step": self._programs.pages_per_step,
             "max_batch": self._B,
             "block_size": self._bs,
             "max_seq_len": self._msl,
@@ -931,8 +811,8 @@ class ServingEngine:
             "prefill_chunk": self._chunk,
             # a recurrent layer's state cannot be handed to a prefix hit
             "prefix_cache": not self._recurrent,
-            "kv_pool_bytes": self._kv_pool_bytes,
-            "state_bytes": self._state_bytes,
+            "kv_pool_bytes": self.kv_pool_bytes,
+            "state_bytes": self.state_bytes,
             "state_bytes_per_seq": self.state_bytes_per_seq,
             "speculate": spec,
             "eos_id": self._eos,
@@ -1073,8 +953,8 @@ class ServingEngine:
                 "active": int(self._active.sum()),
                 "blocks_free": self._pool.num_free,
                 "blocks_total": self._num_blocks - 1,
-                "kv_pool_bytes": self._kv_pool_bytes,
-                "state_bytes": self._state_bytes,
+                "kv_pool_bytes": self.kv_pool_bytes,
+                "state_bytes": self.state_bytes,
                 "prefix_cache": {
                     "hits": self._stats["prefix_hits"],
                     "misses": self._stats["prefix_misses"],
@@ -1203,13 +1083,10 @@ class ServingEngine:
         finally:
             # an engine whose scheduler has ended serves nothing: let go
             # of what it held on the device (pools, recurrent state, the
-            # gathered weights, the net), here on the one thread that
+            # gathered weights, the nets), here on the one thread that
             # writes them, so that a `Request` handle someone still holds
             # does not keep a model's memory alive through its engine
-            self._pool_k = self._pool_v = self._scale_k = self._scale_v = ()
-            self._dpool_k = self._dpool_v = self._rec = ()
             self._programs.release()
-            self._net = None
 
     def _run(self) -> None:
         try:
@@ -1420,32 +1297,15 @@ class ServingEngine:
         # weight gather/requantize, timed apart from the device call so
         # a requantize after a weight swap shows up as its own cause
         with prof.phase("gather_params"):
-            params = self._live_params()
+            self._programs.gather_params()
         final = start + n >= job.P
         with prof.phase("prefill_chunk"):
             if hook is not None:
                 hook("prefill")             # fault seam: once per chunk
             t0 = time.perf_counter()
             with prof.phase("dispatch"):
-                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-                 self._rec, first) = G._timed_decode(
-                    f"serving_prefill_chunk_{self._label}",
-                    f"serving_{self._label}", n,
-                    self._programs.prefill_chunk, self._pool_k,
-                    self._pool_v, self._scale_k, self._scale_v, self._rec,
-                    job.row, toks, np.int32(start), np.int32(job.P),
-                    job.key, np.int32(job.lane), params)
-                if self._spec:
-                    # populate the DRAFT pool with the same chunk too —
-                    # the draft's first proposal attends to the full
-                    # prompt.  Same table row.
-                    dparams = self._programs.draft_params(self._msl)
-                    (self._dpool_k, self._dpool_v) = G._timed_decode(
-                        f"serving_draft_prefill_chunk_{self._label}",
-                        f"serving_{self._label}", n,
-                        self._programs.draft_prefill_chunk,
-                        self._dpool_k, self._dpool_v, job.row, toks,
-                        np.int32(start), np.int32(job.P), dparams)
+                first = self._programs.prefill_chunk(
+                    job.row, toks, start, job.P, job.key, job.lane, n)
             t_handed = time.monotonic()     # the chunk's stamp: no sync
         return job, start, n, final, first, t0, t_handed
 
@@ -1574,8 +1434,7 @@ class ServingEngine:
         queued behind it and before the step's tokens are waited for."""
         prof = self._prof
         with prof.phase("gather_params"):
-            params = self._live_params()
-        tables, toks, pos, active, keys = snap
+            self._programs.gather_params()
         # the ledger's device_step cause includes the fault hook (an
         # injected stall IS device time to the requests waiting on it);
         # the tpot histogram keeps the pure device call, as before
@@ -1584,22 +1443,14 @@ class ServingEngine:
                 hook("step")                # fault seam: counts as device
             t0 = time.perf_counter()
             with prof.phase("dispatch"):
-                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-                 self._rec, nxt) = G._timed_decode(
-                    f"serving_step_{self._label}",
-                    f"serving_{self._label}", len(live),
-                    self._programs.step, self._pool_k, self._pool_v,
-                    self._scale_k, self._scale_v, self._rec, tables, toks,
-                    pos, active, keys, params)
+                nxt = self._programs.step(*snap, len(live))
             if chunk is not None:
                 self._commit_chunk(chunk)
             nxt = np.asarray(nxt)           # sync: tokens are consumed now
             dt = time.perf_counter() - t0
         now = time.monotonic()
-        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
-            self._stats["steps"] += 1
-            step_no = self._stats["steps"]
-            mark = _TRACE_EVERY > 0 and step_no % _TRACE_EVERY == 0
+
+        def deliver(mark):
             for lane, req in live:
                 slot = self._slots[lane]
                 if slot is None or slot.req is not req:
@@ -1616,10 +1467,25 @@ class ServingEngine:
                 if tok == self._eos \
                         or len(req.tokens) >= req.max_new_tokens:
                     self._retire_locked(lane)
+            return dt
+
+        self._commit_step(live, deliver)
+
+    def _commit_step(self, live, deliver) -> None:
+        """The commit `_decode_step` and `_spec_step` share: re-lock,
+        count the step, let ``deliver(mark)`` hand the lanes their tokens
+        (``mark``: this step leaves a trace mark; it returns the seconds a
+        token took, for ``serving_tpot_seconds``), note occupancy, queue
+        and the pool's use, then close the ledger's iteration."""
+        prof = self._prof
+        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
+            self._stats["steps"] += 1
+            step_no = self._stats["steps"]
+            tpot = deliver(step_no % _TRACE_EVERY == 0)
             if telemetry.enabled():
                 telemetry.histogram("serving_tpot_seconds",
                                     labels={"path": self._path}) \
-                    .observe(dt)
+                    .observe(tpot)
                 telemetry.gauge("serving_batch_occupancy") \
                     .set(len(live))
             queue_depth = len(self._queue)
@@ -1657,43 +1523,29 @@ class ServingEngine:
         prof = self._prof
         k = self._spec_k
         with prof.phase("gather_params"):
-            params = self._live_params()
-            dparams = self._programs.draft_params(self._msl)
-        tables, toks, pos, active, keys = snap
+            self._programs.gather_params()
         t_h = time.perf_counter()
         with prof.phase("draft_step"):
             if hook is not None:
                 hook("draft")               # fault seam: draft stream
             with prof.phase("dispatch"):
-                (self._dpool_k, self._dpool_v, d_toks,
-                 d_probs) = G._timed_decode(
-                    f"serving_draft_step_{self._label}",
-                    f"serving_{self._label}", len(live) * k,
-                    self._programs.draft_step, self._dpool_k,
-                    self._dpool_v, tables, toks, pos, active, keys,
-                    dparams)
+                d_toks, d_probs = self._programs.draft_step(
+                    *snap, len(live))
         dt_draft = time.perf_counter() - t_h
         with prof.phase("verify_step"):
             if hook is not None:
                 hook("step")                # fault seam: target stream
             t0 = time.perf_counter()
             with prof.phase("dispatch"):
-                (self._pool_k, self._pool_v, self._scale_k, self._scale_v,
-                 out, alen) = G._timed_decode(
-                    f"serving_spec_verify_{self._label}",
-                    f"serving_{self._label}", len(live),
-                    self._programs.spec_verify, self._pool_k,
-                    self._pool_v, self._scale_k, self._scale_v, tables,
-                    toks, pos, active, keys, d_toks, d_probs, params)
+                out, alen = self._programs.spec_verify(
+                    *snap, d_toks, d_probs, len(live))
             out = np.asarray(out)           # sync: tokens consumed now
             alen = np.asarray(alen)
             dt = time.perf_counter() - t0
         now = time.monotonic()
-        with prof.phase("lock_wait"), self._work, prof.phase("commit"):
-            self._stats["steps"] += 1
+
+        def deliver(mark):
             self._stats["spec_steps"] += 1
-            step_no = self._stats["steps"]
-            mark = _TRACE_EVERY > 0 and step_no % _TRACE_EVERY == 0
             proposed = accepted = delivered_total = 0
             for lane, req in live:
                 slot = self._slots[lane]
@@ -1752,23 +1604,12 @@ class ServingEngine:
                     telemetry.gauge("serving_spec_accept_rate",
                                     labels={"engine": self._name}) \
                         .set(self._stats["spec_ewma"])
-            if telemetry.enabled():
-                # per-token time: the iteration's device time over the
-                # mean tokens a lane actually got out of it
-                per_tok = (dt + dt_draft) \
-                    / max(1.0, delivered_total / max(1, len(live)))
-                telemetry.histogram("serving_tpot_seconds",
-                                    labels={"path": self._path}) \
-                    .observe(per_tok)
-                telemetry.gauge("serving_batch_occupancy") \
-                    .set(len(live))
-            queue_depth = len(self._queue)
-            pool_use = self._pool_use_locked()
-        prof.end_step(rids=[req.rid for _, req in live],
-                      occupancy=len(live), queue_depth=queue_depth,
-                      step=step_no, **pool_use)
-        if telemetry.enabled() and step_no % 8 == 0:
-            telemetry.profiler.snapshot_lock_witness()
+            # per-token time: the iteration's device time over the
+            # mean tokens a lane actually got out of it
+            return (dt + dt_draft) \
+                / max(1.0, delivered_total / max(1, len(live)))
+
+        self._commit_step(live, deliver)
 
 
 def default_engine(net, **kw) -> ServingEngine:

@@ -81,8 +81,9 @@ from .. import telemetry
 from ..contrib.quantization import quantize_kv
 from ..models import generation as G
 from ..ops.paged_attention import (default_impl, paged_attention,
-                                   paged_attention_window,
-                                   window_kernel_fits, write_rows)
+                                   paged_attention_window, pages_per_step,
+                                   pool_shapes, window_kernel_fits,
+                                   write_rows)
 from ..ops.selective_scan import selective_scan
 
 __all__ = ["PagedPrograms"]
@@ -732,18 +733,50 @@ def _build_spec_verify(spec, block_size, k, temperature, top_k,
     return serving_spec_verify
 
 
-class PagedPrograms:
-    """The engine's compiled-program surface: one jitted step program
-    plus ONE fixed-width prefill-chunk program, all resolved through a
-    net-level LRU keyed by the full static config — rebuilding an
-    engine with the same config reuses the compiled programs.  Holds
-    only static config — the engine owns the pool arrays and the
-    weights pytree."""
+def _nbytes(arrays) -> int:
+    return sum(int(a.size) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(arrays))
 
-    def __init__(self, net, *, max_batch, block_size, blocks_per_seq,
-                 temperature, top_k, quantized, kv_dtype=None,
-                 attn_impl=None, prefill_chunk=32, speculate_k=0,
-                 draft_net=None, spec_greedy=False):
+
+class PagedPrograms:
+    """The engine's only handle on the device: the compiled programs, the
+    arrays they thread through every call, and the calls themselves.
+
+    Programs: one jitted step program plus ONE fixed-width prefill-chunk
+    program (and the speculative three), all resolved through a net-level
+    LRU keyed by the full static config — rebuilding an engine with the
+    same config reuses the compiled programs.
+
+    Arrays: the K/V pools, a tuple entry an attention layer,
+    ``(num_blocks, bs, Hkv*D)`` — a position a row and a KV head a run of
+    D lanes, the one shape the K/V write, the paged kernel and the
+    donated buffer all take as it lies (docs/serving.md) — with fp32
+    scale pools ``(num_blocks, bs, Hkv)`` beside int8 pages; the second
+    kind of per-sequence state (docs/serving.md, "Two kinds of state"),
+    per ssm layer one float32 recurrent state ``(B, d_state, d_inner)``
+    and one conv window ``(d_conv-1, B, d_inner)``, a row a lane, which a
+    prompt's first chunk starts from zero; when speculating, the draft's
+    K/V pools in the draft model's dtype, addressed by the SAME block
+    tables and `BlockPool` ids as the target's, so one lane allocation
+    covers both and eviction frees both.  This object holds the ONLY
+    reference to each and rebinds it after every donated call (the
+    buffers really are deleted on XLA:CPU too).  And the gathered weight
+    pytrees, cached on the nets' weight-buffer fingerprints.
+
+    Calls: a method a program family (`prefill_chunk`, `step`,
+    `draft_step`, `spec_verify`), each taking what a scheduler knows
+    (tables, tokens, positions) and returning what it consumes, still on
+    the device; `gather_params()` refreshes the weights they read.
+
+    The arrays are touched by ONE thread only, the engine's scheduler:
+    the call methods, `gather_params` and `release` are its alone.  What
+    other threads read (`kv_pool_bytes`, `state_bytes`,
+    `pages_per_step`, the labels) is frozen at construction."""
+
+    def __init__(self, net, *, max_batch, block_size, temperature, top_k,
+                 quantized, max_seq_len=None, num_blocks=None,
+                 kv_dtype=None, attn_impl=None, prefill_chunk=32,
+                 speculate_k=0, draft_net=None, spec_greedy=False):
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None (model dtype) or 'int8', "
@@ -765,56 +798,72 @@ class PagedPrograms:
                 raise ValueError(
                     "kv_dtype='int8' is not built for a decoder with "
                     "recurrent (ssm) layers")
+        self._B = int(max_batch)
         self._bs = int(block_size)
-        self._nbps = int(blocks_per_seq)
+        msl = int(max_seq_len if max_seq_len is not None
+                  else self._spec.max_len)
+        msl = (msl // self._bs) * self._bs
+        if msl < self._bs:
+            raise ValueError(
+                f"max_seq_len {max_seq_len} < one block ({block_size})")
+        if msl > self._spec.max_len:
+            raise ValueError(
+                f"max_seq_len {msl} exceeds net.max_len "
+                f"{self._spec.max_len}")
+        self._msl = msl
+        self._nbps = msl // self._bs
+        self._num_blocks = int(num_blocks if num_blocks is not None
+                               else self._B * self._nbps + 1)
         self._temperature = float(temperature)
         self._top_k = int(top_k)
         self._qc = G._quant_config(net, quantized)
         self._kv_dtype = kv_dtype
         self._impl_forced = attn_impl is not None
         self._impl = attn_impl or default_impl()
-        # distinct def names per KV family: RetraceGuard budgets
-        # compiles BY NAME, so the int8-KV programs must not count
-        # against (or hide behind) the float-KV budget
         if int(prefill_chunk) < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {prefill_chunk}")
-        self._chunk = int(prefill_chunk)
+        self._chunk = min(int(prefill_chunk), msl)
+        # distinct def names per KV family: RetraceGuard budgets
+        # compiles BY NAME, so the int8-KV programs must not count
+        # against (or hide behind) the float-KV budget
         sfx = "_kv8" if kv_dtype == "int8" else ""
-        self._step_name = "serving_step" + sfx
-        self._prefill_name = "serving_prefill_chunk" + sfx
         self._key = (self._spec, self._bs, self._nbps,
                      self._temperature, self._top_k, self.path,
                      self._kv_dtype, self._impl)
+        self._label = self.path + ("_ssm" if self._spec.recurrent else "") \
+            + sfx + ("_pallas" if self._impl_forced
+                     and self._impl == "pallas" else "")
         self._params = None
         self._params_key = None
-        cache = _net_program_cache(net)
-        step = G._lru_touch(cache, ("step",) + self._key)
-        if step is None:
-            _note_build("step")
-            step = _HostPacked(
+        self._step = self._program(
+            ("step",) + self._key, lambda: _HostPacked(
                 _build_step(self._spec, self._bs, self._nbps,
                             self._temperature, self._top_k,
-                            self._kv_dtype, self._impl, self._step_name), 5)
-            G._lru_put(net, cache, ("step",) + self._key, step,
-                       "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
-                       gauge="serving_program_cache_size")
-        self._step = step
-        pkey = ("prefill_chunk", self._chunk) + self._key
-        pfc = G._lru_touch(cache, pkey)
-        if pfc is None:
-            _note_build("prefill_chunk")
-            pfc = _HostPacked(
-                _build_prefill_chunk(self._spec, self._bs,
-                                     self._nbps, self._chunk,
-                                     self._temperature, self._top_k,
-                                     self._kv_dtype, self._impl,
-                                     self._prefill_name), 5)
-            G._lru_put(net, cache, pkey, pfc,
-                       "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
-                       gauge="serving_program_cache_size")
-        self._prefill_chunk = pfc
+                            self._kv_dtype, self._impl,
+                            "serving_step" + sfx), 5))
+        self._prefill_chunk = self._program(
+            ("prefill_chunk", self._chunk) + self._key, lambda: _HostPacked(
+                _build_prefill_chunk(self._spec, self._bs, self._nbps,
+                                     self._chunk, self._temperature,
+                                     self._top_k, self._kv_dtype,
+                                     self._impl,
+                                     "serving_prefill_chunk" + sfx), 5))
         self._init_speculative(net, speculate_k, draft_net, spec_greedy)
+        self._allocate()
+
+    def _program(self, key, build):
+        """The jitted program under ``key`` (its first entry the family)
+        in the net's cache, built on a miss."""
+        cache = _net_program_cache(self._net)
+        prog = G._lru_touch(cache, key)
+        if prog is None:
+            _note_build(key[0])
+            prog = build()
+            G._lru_put(self._net, cache, key, prog,
+                       "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
+                       gauge="serving_program_cache_size")
+        return prog
 
     def _init_speculative(self, net, speculate_k, draft_net, spec_greedy):
         """Resolve the draft model and build the speculative program
@@ -822,16 +871,20 @@ class PagedPrograms:
         through PR 7's int8 weight path (requires
         `net.quantize_for_decode` and a float target — an int8 target
         drafting for itself would verify its own proposals)."""
-        self._spec_k = int(speculate_k)
-        self._spec_greedy = bool(spec_greedy) or self._temperature <= 0.0
+        self._spec_k = k = int(speculate_k)
+        self._spec_greedy = greedy = \
+            bool(spec_greedy) or self._temperature <= 0.0
         self._draft_params = None
         self._draft_params_key = None
-        if self._spec_k == 0:
-            self._draft_net = self._draft_spec = None
+        self._draft_net = self._draft_spec = None
+        if k == 0:
             return
-        if self._spec_k < 0:
+        msl = self._msl
+        if k < 0:
             raise ValueError(
                 f"speculate_k must be >= 0, got {speculate_k}")
+        if k >= msl:
+            raise ValueError(f"speculate_k {k} >= max_seq_len {msl}")
         if draft_net is None:
             if self.path != "float":
                 raise ValueError(
@@ -840,75 +893,114 @@ class PagedPrograms:
                     "pass a distinct draft_net")
             self._draft_qc = G._quant_config(net, True)
             self._draft_net = net
+            self._draft_spec = dspec = self._spec
             self._draft_label = "self-int8"
         else:
             self._draft_qc = G._quant_config(draft_net, None)
             self._draft_net = draft_net
-            dspec = G.decoder_spec(draft_net)
+            self._draft_spec = dspec = G.decoder_spec(draft_net)
             if dspec.recurrent:
                 raise ValueError("a draft_net with recurrent (ssm) layers "
                                  "cannot be rolled back")
+            if dspec.vocab != self._spec.vocab:
+                raise ValueError(
+                    f"draft_net vocab {dspec.vocab} != target vocab "
+                    f"{self._spec.vocab}")
+            if dspec.max_len < msl:
+                raise ValueError(
+                    f"draft_net.max_len {dspec.max_len} < "
+                    f"max_seq_len {msl}")
             self._draft_label = f"net[{len(dspec.kinds)}x{dspec.units}]"
-        self._draft_spec = G.decoder_spec(self._draft_net)
-        msl = self._nbps * self._bs
-        k, greedy = self._spec_k, self._spec_greedy
         sfx = "_kv8" if self._kv_dtype == "int8" else ""
-        self._verify_name = "serving_spec_verify" + sfx
-        dkey = (self._draft_spec, G._decode_path(self._draft_qc), k, greedy)
-        cache = _net_program_cache(net)
-        draft = G._lru_touch(cache, ("draft_step",) + self._key + dkey)
-        if draft is None:
-            _note_build("draft_step")
-            draft = jax.jit(
-                _build_draft_step(self._draft_spec,
-                                  self._bs, k, self._temperature,
+        self._draft_step = self._program(
+            ("draft_step",) + self._key
+            + (dspec, G._decode_path(self._draft_qc), k, greedy),
+            lambda: jax.jit(
+                _build_draft_step(dspec, self._bs, k, self._temperature,
                                   self._top_k, greedy, self._impl, msl,
                                   "serving_draft_step"),
-                donate_argnums=(0, 1))
-            G._lru_put(net, cache, ("draft_step",) + self._key + dkey,
-                       draft, "_serving_program_cache_cap",
-                       _PROGRAM_CACHE_CAP,
-                       gauge="serving_program_cache_size")
-        self._draft_step = draft
-        verify = G._lru_touch(cache, ("spec_verify",) + self._key
-                              + (k, greedy))
-        if verify is None:
-            _note_build("spec_verify")
-            verify = jax.jit(
+                donate_argnums=(0, 1)))
+        self._spec_verify = self._program(
+            ("spec_verify",) + self._key + (k, greedy),
+            lambda: jax.jit(
                 _build_spec_verify(self._spec, self._bs, k,
                                    self._temperature, self._top_k,
                                    greedy, self._kv_dtype, self._impl,
-                                   msl, self._verify_name),
-                donate_argnums=(0, 1, 2, 3))
-            G._lru_put(net, cache, ("spec_verify",) + self._key
-                       + (k, greedy), verify,
-                       "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
-                       gauge="serving_program_cache_size")
-        self._spec_verify = verify
-        dpkey = (("draft_prefill_chunk", self._chunk) + self._key
-                 + (self._draft_spec,))
-        dpfc = G._lru_touch(cache, dpkey)
-        if dpfc is None:
-            _note_build("draft_prefill_chunk")
-            dpfc = jax.jit(
+                                   msl, "serving_spec_verify" + sfx),
+                donate_argnums=(0, 1, 2, 3)))
+        self._draft_prefill_chunk = self._program(
+            ("draft_prefill_chunk", self._chunk) + self._key + (dspec,),
+            lambda: jax.jit(
                 _build_draft_prefill_chunk(
-                    self._draft_spec, self._bs,
-                    self._nbps, self._chunk, self._impl,
+                    dspec, self._bs, self._nbps, self._chunk, self._impl,
                     "serving_draft_prefill_chunk"),
-                donate_argnums=(0, 1))
-            G._lru_put(net, cache, dpkey, dpfc,
-                       "_serving_program_cache_cap", _PROGRAM_CACHE_CAP,
-                       gauge="serving_program_cache_size")
-        self._draft_prefill_chunk = dpfc
+                donate_argnums=(0, 1)))
 
+    def _allocate(self):
+        """The device arrays (class docstring), zeroed, and their byte
+        counts.  ``self._kv`` is ``[pool_k, pool_v, scale_k, scale_v,
+        rec]`` in the order the target's programs take and return them,
+        ``self._draft_kv`` the draft's ``[pool_k, pool_v]``; empty tuples
+        stand for what this configuration has none of."""
+        spec, B, bs = self._spec, self._B, self._bs
+        params = self.gather_params()
+        G._record_decode_weight_bytes(params, self._qc)
+        emb = params["embed"]
+        kv8 = self._kv_dtype == "int8"
+        dt = jnp.int8 if kv8 else emb.dtype
+        L = spec.kinds.count("attn")
+        page, scales = pool_shapes(self._num_blocks, bs, spec.kv_heads,
+                                   spec.head_dim)
+
+        def each(n, shape, dtype, make=jnp.zeros):
+            return tuple(make(shape, dtype) for _ in range(n))
+
+        rec = ()
+        if spec.recurrent:
+            Di, Ds, K, _ = spec.ssm
+            n_ssm = spec.kinds.count("ssm")
+            rec = (each(n_ssm, (B, Ds, Di), jnp.float32),
+                   each(n_ssm, (K - 1, B, Di), emb.dtype))
+        n_sc = L if kv8 else 0
+        self._kv = [each(L, page, dt), each(L, page, dt),
+                    each(n_sc, scales, jnp.float32, jnp.ones),
+                    each(n_sc, scales, jnp.float32, jnp.ones), rec]
+        self._draft_kv = [(), ()]
+        if self._spec_k:
+            dspec = self._draft_spec
+            ddt = self._draft_params["embed"].dtype
+            dpage, _ = pool_shapes(self._num_blocks, bs, dspec.kv_heads,
+                                   dspec.head_dim)
+            n = len(dspec.kinds)
+            self._draft_kv = [each(n, dpage, ddt), each(n, dpage, ddt)]
+        # block-table entries a grid step of the single-query kernel
+        # covers (the kernel's own rule, from these shapes); 0 on the
+        # dense path, which runs no kernel
+        self.pages_per_step = pages_per_step(
+            bs, self._nbps, page[2] * jnp.dtype(dt).itemsize) \
+            if self._impl == "pallas" else 0
+        # the footprints are STATIC (donation replaces arrays, never
+        # shapes): frozen here so readers on other threads never touch
+        # the live tuples the scheduler thread is rewriting.  Draft pages
+        # count: they are resident HBM spent per token position.
+        self.kv_pool_bytes = _nbytes(self._kv[:4] + self._draft_kv)
+        self.state_bytes = _nbytes(rec)
+
+    # -- static description (any thread) ------------------------------- #
     @property
     def spec(self):
         """The target decoder's `generation.DecoderSpec`."""
         return self._spec
 
     @property
-    def draft_spec(self):
-        return self._draft_spec
+    def max_seq_len(self) -> int:
+        """Cap on prompt+generated per sequence: the asked-for length (or
+        the net's) rounded down to whole blocks."""
+        return self._msl
+
+    @property
+    def num_blocks(self) -> int:
+        return self._num_blocks
 
     @property
     def path(self) -> str:
@@ -931,55 +1023,12 @@ class PagedPrograms:
         ``_pallas`` when the kernel was forced off its
         home platform (the hlolint gate compiles that variant on CPU to
         pin the no-dense-probs census)."""
-        label = self.path
-        if self._spec.recurrent:
-            label += "_ssm"
-        if self._kv_dtype == "int8":
-            label += "_kv8"
-        if self._impl_forced and self._impl == "pallas":
-            label += "_pallas"
-        return label
-
-    def gather_params(self, pe_width):
-        """The live weight pytree the programs consume, cached on the
-        weight-buffer identity fingerprint (PR 7 idiom): the engine may
-        call this every step — training/`set_data` swaps are picked up,
-        but an unchanged net costs ~a dozen id() calls and the int8
-        requantize never runs per-token."""
-        key = (G._params_fingerprint(self._net), int(pe_width))
-        if self._params_key != key:
-            self._params = G._gather_params(self._net, pe_width, self._qc)
-            self._params_key = key
-        return self._params
-
-    def release(self):
-        """Let go of the nets and of the gathered weight pytrees (the
-        engine's `close()`): the jitted programs stay in the nets' own
-        caches."""
-        self._net = self._draft_net = None
-        self._params = self._params_key = None
-        self._draft_params = self._draft_params_key = None
-
-    @property
-    def step(self):
-        return self._step
-
-    @property
-    def prefill_chunk(self):
-        """The jitted fixed-width prefill-chunk program (ONE per
-        engine config — no bucket ladder)."""
-        return self._prefill_chunk
+        return self._label
 
     @property
     def prefill_chunk_len(self) -> int:
-        """Static chunk width in tokens."""
+        """Static chunk width in tokens (never over `max_seq_len`)."""
         return self._chunk
-
-    # -- speculative decoding (ISSUE 19) ------------------------------- #
-    @property
-    def speculate_k(self) -> int:
-        """Draft window length (0 = speculation off)."""
-        return self._spec_k
 
     @property
     def spec_greedy(self) -> bool:
@@ -993,30 +1042,105 @@ class PagedPrograms:
         draft net's shape)."""
         return self._draft_label
 
+    # -- the device arrays, for a reader on the scheduler's thread or of
+    # an engine at rest (tests, chip_smoke.py) ------------------------- #
     @property
-    def draft_net(self):
-        return self._draft_net
+    def kv_pools(self) -> tuple:
+        """``(pool_k, pool_v, scale_k, scale_v)``, each a tuple entry an
+        attention layer (the scales empty on a float pool)."""
+        return tuple(self._kv[:4])
 
     @property
-    def draft_step(self):
-        return self._draft_step
+    def recurrent_state(self) -> tuple:
+        """``(states, conv windows)``, a tuple entry an ssm layer; ``()``
+        for a decoder without any."""
+        return self._kv[4]
 
     @property
-    def spec_verify(self):
-        return self._spec_verify
+    def draft_pools(self) -> tuple:
+        """The draft's ``(pool_k, pool_v)``; empty without speculation."""
+        return tuple(self._draft_kv)
 
-    def draft_params(self, pe_width):
-        """The draft weight pytree, cached on the draft net's
-        weight-buffer fingerprint (same idiom as `gather_params` —
-        the self-draft int8 requantize never runs per-iteration)."""
-        key = (G._params_fingerprint(self._draft_net), int(pe_width))
-        if self._draft_params_key != key:
-            self._draft_params = G._gather_params(
-                self._draft_net, pe_width, self._draft_qc)
-            self._draft_params_key = key
-        return self._draft_params
+    # -- scheduler thread only ----------------------------------------- #
+    def gather_params(self):
+        """Refresh the weight pytrees the calls below read — the
+        target's, which is returned, and the draft's when speculating —
+        each cached on its net's weight-buffer identity fingerprint (PR 7
+        idiom): the engine calls this every iteration — training/
+        `set_data` swaps are picked up, but an unchanged net costs ~a
+        dozen id() calls and the int8 requantize never runs per-token."""
+        key = G._params_fingerprint(self._net)
+        if self._params_key != key:
+            self._params = G._gather_params(self._net, self._msl, self._qc)
+            self._params_key = key
+        if self._spec_k:
+            key = G._params_fingerprint(self._draft_net)
+            if self._draft_params_key != key:
+                self._draft_params = G._gather_params(
+                    self._draft_net, self._msl, self._draft_qc)
+                self._draft_params_key = key
+        return self._params
 
-    @property
-    def draft_prefill_chunk(self):
-        """The jitted DRAFT prefill-chunk program (speculation only)."""
-        return self._draft_prefill_chunk
+    def release(self):
+        """Let go of the device arrays, the gathered weight pytrees and
+        the nets (the engine's scheduler calls this as it ends, on the
+        one thread that writes them): the jitted programs stay in the
+        nets' own caches."""
+        self._kv = [(), (), (), (), ()]
+        self._draft_kv = [(), ()]
+        self._net = self._draft_net = None
+        self._params = self._params_key = None
+        self._draft_params = self._draft_params_key = None
+
+    def _call(self, kind, n_tokens, fn, held, *args):
+        """One program call: ``fn(*held, *args)`` under the family's
+        telemetry label, its leading outputs — the donated arrays,
+        replaced — bound back into ``held`` in place.  Returns the other
+        outputs (still on the device; nothing is waited for)."""
+        out = G._timed_decode(f"serving_{kind}_{self._label}",
+                              f"serving_{self._label}", n_tokens,
+                              fn, *held, *args)
+        held[:] = out[:len(held)]
+        return out[len(held):]
+
+    def prefill_chunk(self, row, toks, start, P, key, lane, n):
+        """Positions ``start .. start+n-1`` of lane ``lane``'s prompt of
+        ``P`` tokens (``toks`` the chunk-wide window, ``row`` the lane's
+        block-table row) into the pool — and into the draft's pool when
+        speculating: the draft's first proposal attends to the full
+        prompt.  Returns the first-token pick, meaningful on a prompt's
+        final chunk."""
+        start, P = np.int32(start), np.int32(P)
+        first, = self._call("prefill_chunk", n, self._prefill_chunk,
+                            self._kv, row, toks, start, P, key,
+                            np.int32(lane), self._params)
+        if self._spec_k:
+            self._call("draft_prefill_chunk", n, self._draft_prefill_chunk,
+                       self._draft_kv, row, toks, start, P,
+                       self._draft_params)
+        return first
+
+    def step(self, tables, toks, pos, active, keys, n_live):
+        """One decode step of every lane; returns the next tokens (B,)."""
+        nxt, = self._call("step", n_live, self._step, self._kv, tables,
+                          toks, pos, active, keys, self._params)
+        return nxt
+
+    def draft_step(self, tables, toks, pos, active, keys, n_live):
+        """k draft steps of every lane on the draft's pool; returns
+        ``(d_toks (B, k), d_probs)`` for `spec_verify`."""
+        return self._call("draft_step", n_live * self._spec_k,
+                          self._draft_step, self._draft_kv, tables, toks,
+                          pos, active, keys, self._draft_params)
+
+    def spec_verify(self, tables, toks, pos, active, keys, d_toks, d_probs,
+                    n_live):
+        """The target's verdict on the lanes' windows; returns ``(out
+        (B, k+1), accept_len (B,))``.  The recurrent state is no part of
+        it (no recurrent decoder speculates)."""
+        pools = self._kv[:4]
+        out = self._call("spec_verify", n_live, self._spec_verify, pools,
+                         tables, toks, pos, active, keys, d_toks, d_probs,
+                         self._params)
+        self._kv[:4] = pools
+        return out

@@ -191,7 +191,7 @@ def test_engine_in_bfloat16_stays_within_its_rounding(ref):
 
 
 def _state_of(eng, lane):
-    states, convs = eng._rec
+    states, convs = eng._programs.recurrent_state
     return ([onp.asarray(s[lane]) for s in states],
             [onp.asarray(c[:, lane]) for c in convs])
 
@@ -406,7 +406,8 @@ def test_an_attention_only_engine_reports_no_state():
     t0 = time.monotonic()
     with ServingEngine(lm, max_batch=2, block_size=8, max_seq_len=64) as eng:
         eng.submit(onp.arange(5, dtype=onp.int32), 4).result(timeout=300)
-        assert eng.state_bytes == 0 and eng._rec == ()
+        assert eng.state_bytes == 0
+        assert eng._programs.recurrent_state == ()
         assert eng.varz_config()["prefix_cache"] is True
         from incubator_mxnet_tpu import telemetry
 
@@ -482,10 +483,12 @@ def test_a_closed_engine_lets_go_of_the_device(seeded):
     eng = ServingEngine(net, max_batch=2, block_size=8, max_seq_len=64)
     handle = eng.submit(_prompts((6,))[0], 3)
     assert handle.result(timeout=300)
-    assert eng._rec and eng._pool_k and eng._programs._params is not None
+    progs = eng._programs
+    assert progs.recurrent_state and all(progs.kv_pools[:2])
+    assert progs._params is not None
     eng.close()
     assert handle._engine is eng
-    assert eng._rec == () and eng._pool_k == () and eng._pool_v == ()
-    assert eng._net is None and eng._programs._params is None
+    assert progs.recurrent_state == () and progs.kv_pools == ((),) * 4
+    assert progs._net is None and progs._params is None
     assert eng.state_bytes > 0          # what it held is still reported
     eng.close()                         # idempotent

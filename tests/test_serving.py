@@ -417,9 +417,9 @@ def test_submit_validation(clean_engine):
     with pytest.raises(ValueError):
         clean_engine.submit(P1, MAXLEN)    # P + N > max_seq_len
     with pytest.raises(ValueError):
-        ServingEngine(clean_engine._net, max_batch=0)
+        ServingEngine(clean_engine._programs._net, max_batch=0)
     with pytest.raises(ValueError):
-        ServingEngine(clean_engine._net, block_size=12)  # not a pow2
+        ServingEngine(clean_engine._programs._net, block_size=12)  # not a pow2
 
 
 def test_concurrent_submitters_are_thread_safe(net, clean_engine):
@@ -496,3 +496,89 @@ def test_int8_engine_matches_quantized_lm_generate():
         assert eng.submit(P1, 8).result(timeout=60) == ref.tolist()
         # serve() caches and reuses the engine for equal config
         assert net.serve() is eng
+
+
+# --------------------------------------------------------------------- #
+# the seam: the engine schedules, PagedPrograms holds the device (ISSUE 33)
+# --------------------------------------------------------------------- #
+def test_engine_module_knows_no_device_library():
+    """The scheduler's module binds nothing of JAX, of the kernels or of
+    `models.generation`: arrows point engine -> programs -> ops, models."""
+    import importlib
+
+    mod = importlib.import_module("incubator_mxnet_tpu.serving.engine")
+    assert not {"jnp", "jax", "pool_shapes", "pages_per_step", "G"} \
+        & set(vars(mod))
+
+
+def test_paged_programs_alone_serve_a_prompt(net, clean_engine):
+    """`PagedPrograms` built by hand owns what its calls need: a chunk
+    and a step give the engine's tokens, and it reports the engine's
+    bytes."""
+    from incubator_mxnet_tpu.serving import PagedPrograms
+    from incubator_mxnet_tpu.serving.kv_pool import SCRATCH_BLOCK
+
+    progs = PagedPrograms(net, max_batch=2, block_size=8, temperature=0.0,
+                          top_k=0, quantized=None)
+    try:
+        assert progs.kv_pool_bytes == clean_engine.kv_pool_bytes > 0
+        assert progs.state_bytes == clean_engine.state_bytes == 0
+        assert progs.max_seq_len == clean_engine.max_seq_len
+        nbps, P = progs.max_seq_len // 8, len(P1)
+        row = onp.full((nbps,), SCRATCH_BLOCK, onp.int32)
+        row[0] = 1                          # positions 0..7: prompt + a step
+        toks = onp.zeros((progs.prefill_chunk_len,), onp.int32)
+        toks[:P] = P1
+        keys = onp.zeros((2, 2), onp.uint32)            # seed 0
+        progs.gather_params()
+        first = int(progs.prefill_chunk(row, toks, 0, P, keys[0], 0, P))
+        tables = onp.full((2, nbps), SCRATCH_BLOCK, onp.int32)
+        tables[0] = row
+        nxt = progs.step(tables, onp.array([first, 0], onp.int32),
+                         onp.array([P, 0], onp.int32),
+                         onp.array([True, False]), keys, 1)
+        want = clean_engine.submit(P1, 2, seed=0).result(timeout=60)
+        assert [first, int(nxt[0])] == want
+    finally:
+        progs.release()
+    assert progs.kv_pools == ((),) * 4 and progs.kv_pool_bytes > 0
+
+
+def _device_arrays(obj):
+    import jax
+
+    return [x for x in jax.tree_util.tree_leaves(vars(obj))
+            if isinstance(x, jax.Array)]
+
+
+@pytest.mark.parametrize("flavour", ["int8_kv", "speculative"])
+def test_a_closed_engine_holds_no_device_array(net, flavour):
+    """`close()` leaves the programs without pools, scales, draft pools
+    or gathered weights (the hybrid net's recurrent state:
+    tests/test_hybrid_ssm.py); what they held is still reported."""
+    kw = {"kv_dtype": "int8"}
+    if flavour == "speculative":
+        mx.random.seed(99)
+        draft = TransformerLM(vocab=V, units=8, hidden_size=16,
+                              num_layers=1, num_heads=1, max_len=MAXLEN,
+                              dropout=0.0)
+        draft.initialize()
+        draft(NDArray(jnp.ones((1, 4), jnp.int32)))
+        kw = {"speculate_k": 2, "draft_net": draft}
+    eng = ServingEngine(net, max_batch=2, block_size=8, poll_interval=_POLL,
+                        **kw)
+    progs = eng._programs
+    assert eng.submit(P1, 3).result(timeout=60)
+    pool_k, pool_v, scale_k, scale_v = progs.kv_pools
+    assert pool_k and pool_v and _device_arrays(progs)
+    assert bool(scale_k and scale_v) == (flavour == "int8_kv")
+    assert all(progs.draft_pools) == (flavour == "speculative")
+    held = eng.kv_pool_bytes
+    eng.close()
+    assert _device_arrays(progs) == []
+    assert progs.kv_pools == ((),) * 4 and progs.draft_pools == ((), ())
+    assert progs.recurrent_state == () and progs._net is None
+    assert eng.kv_pool_bytes == held > 0
+    assert not any(hasattr(eng, a) for a in (
+        "_pool_k", "_pool_v", "_scale_k", "_scale_v", "_rec", "_dpool_k",
+        "_dpool_v"))

@@ -448,30 +448,35 @@ def test_profiler_enabled_overhead_budget():
     machine gets the budget scaled by what its disabled loop reads."""
     lock = threading.Lock()
 
-    def per_iteration(p, n=1000, rounds=7):
-        best = float("inf")
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            for i in range(n):
-                with p.phase("lock_wait"), lock, p.phase("bookkeeping"):
+    def one_round(p, n=100):
+        t0 = time.perf_counter()
+        for i in range(n):
+            with p.phase("lock_wait"), lock, p.phase("bookkeeping"):
+                pass
+            with p.phase("gather_params"):
+                pass
+            with p.phase("device_step"):
+                with p.phase("dispatch"):
                     pass
-                with p.phase("gather_params"):
-                    pass
-                with p.phase("device_step"):
-                    with p.phase("dispatch"):
-                        pass
-                with p.phase("lock_wait"), lock, p.phase("commit"):
-                    pass
-                p.end_step(rids=(1, 2), occupancy=2, queue_depth=3,
-                           step=i + 1, blocks_reserved=10, blocks_total=16,
-                           positions_written=70)
-            best = min(best, (time.perf_counter() - t0) / n)
-        return best
+            with p.phase("lock_wait"), lock, p.phase("commit"):
+                pass
+            p.end_step(rids=(1, 2), occupancy=2, queue_depth=3,
+                       step=i + 1, blocks_reserved=10, blocks_total=16,
+                       positions_written=70)
+        return (time.perf_counter() - t0) / n
 
     telemetry.disable()
-    off = per_iteration(EngineProfiler("off", enabled=False))
     ring = StepRing()
-    on = per_iteration(EngineProfiler("on", enabled=True, steps=ring))
+    p_off = EngineProfiler("off", enabled=False)
+    p_on = EngineProfiler("on", enabled=True, steps=ring)
+    # the two loops take turns, so that a busy spell of the host (six
+    # test workers share it) falls on both, in rounds short enough (a
+    # millisecond) to fit between two preemptions; each loop is judged
+    # by its own quietest round
+    off = on = float("inf")
+    for _ in range(70):
+        off = min(off, one_round(p_off))
+        on = min(on, one_round(p_on))
     budget = 15e-6 * max(1.0, off / 2.5e-6)
     assert on < budget, (f"an iteration's ledger costs {on * 1e6:.1f} us "
                          f"(disabled loop {off * 1e6:.1f} us)")
@@ -584,14 +589,21 @@ def test_engine_injected_stall_flagged_as_hiccup(engine):
     prof = engine.profiler
     before = prof.hiccups_total
     # warm the rolling window, then inject one slow device step via the
-    # fault-hook seam; it must be flagged with device_step dominating
-    fired = {"n": 0}
+    # fault-hook seam; it must be flagged with device_step dominating.
+    # The stall is a multiple of the p50 the ledger has OBSERVED (the hook
+    # runs on the scheduler's thread, the ledger's one writer), so the
+    # verdict does not hang on how loaded the host is; and the record is
+    # found by its step, not by being the newest
+    fired = {"n": 0, "step": None, "slept": 0.0}
 
     def hook(phase):
         if phase == "step":
             fired["n"] += 1
             if fired["n"] == 12:
-                time.sleep(0.25)
+                fired["step"] = engine._stats["steps"] + 1
+                fired["slept"] = max(
+                    0.25, 4 * prof.hiccup_k * (prof._p50 or 0.0))
+                time.sleep(fired["slept"])
 
     engine.set_fault_hook(hook)
     try:
@@ -600,8 +612,9 @@ def test_engine_injected_stall_flagged_as_hiccup(engine):
             r.result(timeout=120)
     finally:
         engine.set_fault_hook(None)
-    assert prof.hiccups_total > before
-    hic = prof.recent_stalls()[-1]
+    assert fired["step"] is not None and prof.hiccups_total > before
+    [hic] = [h for h in prof.recent_stalls() if h["step"] == fired["step"]]
+    assert hic["causes"]["device_step"] >= fired["slept"]
     assert hic["dominant"] == "device_step"
     assert sum(hic["causes"].values()) == pytest.approx(
         hic["wall_s"], rel=0.05, abs=1e-6)

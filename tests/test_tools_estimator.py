@@ -57,13 +57,31 @@ def test_parse_log(tmp_path):
     assert ep["validation-accuracy"] == pytest.approx(0.55)
 
 
-def test_bandwidth_tool_runs():
-    sys.path.insert(0, os.path.join(_ROOT, "tools", "bandwidth"))
-    import importlib
+@pytest.mark.parametrize("sizes_mb,n_devices",
+                         [([0.25], 8), ([0.5, 1.0], 4)],
+                         ids=["runs", "runs_and_reports"])
+def test_bandwidth_tool(sizes_mb, n_devices):
+    """tools/bandwidth/measure.py produces structured GB/s results on the
+    CPU mesh (where it measures host memcpy — documented caveat; the
+    tool is validated structurally, numbers are meaningful on ICI).
+    Nothing here judges how fast the host is: a rate is positive however
+    slow the run, and rate times time is the bytes the ring moves."""
+    import importlib.util
 
-    measure = importlib.import_module("measure")
-    res = measure.measure([0.25], n_devices=8, runs=2)
-    assert res and res[0]["GBps"] > 0
+    spec = importlib.util.spec_from_file_location(
+        "bw_measure",
+        os.path.join(_ROOT, "tools", "bandwidth", "measure.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    res = mod.measure(sizes_mb, n_devices=n_devices, runs=2)
+    assert [r["size_mb"] for r in res] == sizes_mb
+    n = n_devices
+    for r in res:
+        assert set(r) == {"size_mb", "time_ms", "GBps"}
+        assert r["time_ms"] > 0 and r["GBps"] > 0
+        moved = r["size_mb"] * 2 ** 20 / n * 2 * (n - 1) / n / 1e9
+        assert r["GBps"] * r["time_ms"] / 1e3 == pytest.approx(moved,
+                                                               rel=0.02)
 
 
 def test_estimator_handlers_and_early_stopping(tmp_path):
@@ -127,25 +145,3 @@ def test_estimator_early_stopping_fires():
                                                          patience=2)])
     history = est.fit(batches, val_data=batches, epochs=50)
     assert len(history) < 50  # stopped early (metric flat at lr=0)
-
-
-def test_bandwidth_tool_runs_and_reports():
-    """tools/bandwidth/measure.py produces structured GB/s results on the
-    CPU mesh (where it measures host memcpy — documented caveat; the
-    tool is validated structurally, numbers are meaningful on ICI)."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bw_measure",
-        os.path.join(os.path.dirname(__file__), "..", "tools", "bandwidth",
-                     "measure.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    res = mod.measure([0.5, 1.0], n_devices=4, runs=2)
-    assert len(res) == 2
-    for r in res:
-        assert set(r) == {"size_mb", "time_ms", "GBps"}
-        assert r["time_ms"] > 0 and r["GBps"] > 0
-    # bigger buffers should not report wildly discontinuous bandwidth
-    assert 0.01 < res[1]["GBps"] / max(res[0]["GBps"], 1e-9) < 100

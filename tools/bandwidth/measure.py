@@ -47,8 +47,9 @@ def measure(sizes_mb, n_devices=None, runs=5):
         # per-device shard is x.size/n; ring allreduce moves 2*(n-1)/n
         # of THAT buffer per device
         gbytes = (x.size / n) * 4 * 2 * (n - 1) / n / 1e9
-        results.append({"size_mb": mb, "time_ms": round(dt * 1e3, 3),
-                        "GBps": round(gbytes / dt, 3)})
+        # four significant digits: a slow host must not round a rate to 0
+        results.append({"size_mb": mb, "time_ms": float(f"{dt * 1e3:.4g}"),
+                        "GBps": float(f"{gbytes / dt:.4g}")})
         print(results[-1])
     return results
 
