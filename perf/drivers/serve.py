@@ -6,7 +6,11 @@ weights, starts the engine with the cell's settings, compiles both programs
 with two small requests, submits the backlog whole and opens the window
 once `warm_finished` requests have finished (lanes then sit at staggered
 phases).  The window then only watches: tokens are stamped by the engine
-(`Request.t_tokens`), admissions by its request trace.
+(`Request.t_tokens`), admissions by its request trace.  With a tracer the
+traced slice (`trace_seconds`) follows the window's close and the
+profiler's stop follows the last reading of the queue: window, trace,
+stall.  A queue that is empty at the window's close or at the slice's end
+ends the run with exit code 1 and no result.
 
 After the window the engine is closed and freed, and the plain reference
 runs once over a sample, drawn from the seed, of the requests the window
@@ -22,7 +26,7 @@ import time
 
 import numpy as np
 
-from perf.work import served
+from perf.work import ledger, served
 
 now = time.monotonic      # the clock of Request.t_submit / t_tokens
 
@@ -86,34 +90,60 @@ class Driver:
 
     # ------------------------------------------------------------------ #
     def run(self, seconds: float, tracer) -> dict:
-        record = {}
-        if tracer is not None:
-            # traced first, outside the window: stopping the profiler
-            # stalls this thread (the engine's own keeps serving)
-            record["trace_t0"] = now()
-            tracer.start()
-            time.sleep(self.cell["trace_seconds"])
-            record["trace_t1"] = now()
-            tracer.stop()
         s0 = self.engine.stats()
         t_open = now()
         time.sleep(seconds)
         t_close = now()
-        s1 = self.engine.stats()
-        if not s1["queue_depth"]:
-            self.release()
-            sys.exit("the backlog ran out inside the window: the mix's "
-                     "`count` is too small for this program")
-        # the prompts being prefilled when the window closed are placed by
-        # their first tokens' stamps: wait for those (a second or two)
-        due = s1["admitted"] + s1["prefill_chunk"]["jobs"]
+        s1 = last = self.engine.stats()
+        self._backlog_held(s0, s1, last, t_close - t_open, "the window")
+        record = {}
+        if tracer is not None:
+            # traced behind the window, over the same backlog: a traced
+            # run's window is then the stretch an untraced run's is, and
+            # stopping the profiler, which stalls this thread some 30 s
+            # while the engine serves on, lies behind all that is measured
+            record["trace_t0"] = now()
+            tracer.start()
+            time.sleep(self.cell["trace_seconds"])
+            tracer.mark()
+            record["trace_t1"] = now()
+            last = self.engine.stats()
+            # the ring's readers run after the stall: what they read is
+            # copied now, while the ring still holds the window's opening
+            record["ring"] = ledger.read_ring(t_open)
+            try:
+                self._backlog_held(s0, s1, last, record["trace_t1"] - t_open,
+                                   "the traced slice behind the window")
+            finally:
+                tracer.stop()
+        # the prompts being prefilled at the last reading (and so those at
+        # the window's close) are placed by their first tokens' stamps:
+        # wait for those (a second or two)
+        due = last["admitted"] + last["prefill_chunk"]["jobs"]
         t_give_up = now() + 60.0
         while now() < t_give_up and self.engine.stats()["admitted"] < due:
             time.sleep(0.05)
-        record.update(self._measure(t_open, t_close, s0, s1))
+        record.update(self._measure(t_open, t_close, s0, s1, last))
         return record
 
-    def _measure(self, t_open, t_close, s0, s1) -> dict:
+    def _backlog_held(self, s0, s1, last, elapsed, where):
+        """Ends the run, exit code 1 and no result, where the queue is
+        empty at the close of `where`: lanes then run empty, and a window
+        or a trace of an emptying engine is no measurement."""
+        if last["queue_depth"]:
+            return
+        self.release()
+        used = s0["queue_depth"] - last["queue_depth"]
+        sys.exit(
+            f"the backlog ran out inside {where}: mix "
+            f"{self.mix.get('name', '?')!r}, count {self.mix['count']}, "
+            f"queue {s0['queue_depth']} at the window's open, "
+            f"{s1['queue_depth']} at its close, {last['queue_depth']} at "
+            f"the last reading, {used / elapsed:.1f} requests consumed a "
+            "second. The mix's `count` is too small for this program: "
+            "perf/README.md wants four times what a traced run consumes")
+
+    def _measure(self, t_open, t_close, s0, s1, last) -> dict:
         window = t_close - t_open
         rows = [(len(r["prompt"]), admitted_at(h), list(h.t_tokens), h)
                 for r, h in self.handles]
@@ -131,6 +161,7 @@ class Driver:
                (work["output_tokens"] + work["prompt_tokens"]) / window}
         if gaps:
             e2e["gap_p95_ms"] = 1e3 * float(np.percentile(gaps, 95))
+        left = last["queue_depth"] / self.mix["count"]
         bs = self.cell["engine"]["block_size"]
         # positions written in the pool when the window closed
         live = sum(P + sum(t < t_close for t in stamps)
@@ -145,6 +176,10 @@ class Driver:
             "requests_ok": len(self.finished),
             "steps": s1["steps"] - s0["steps"],
             "queue_depth_open_close": [s0["queue_depth"], s1["queue_depth"]],
+            # at the last check (the end of the traced slice, else the
+            # window's close), over the mix's count
+            "queue_depth_last": last["queue_depth"],
+            "backlog_left_share": left,
             "pool_reserved_share_open_close": [
                 1 - s["blocks_free"] / s["blocks_total"] for s in (s0, s1)],
             "pool_live_share_close": live / (bs * s1["blocks_total"]),
@@ -158,6 +193,7 @@ class Driver:
         return dict(
             attempted=len(counted), failed=len(counted) - len(self.finished),
             window_s=window, t_open=t_open, t_close=t_close, work=work,
+            backlog_left_share=left,
             steps=s1["steps"] - s0["steps"],
             max_batch=self.cell["engine"]["max_batch"], chunk=self.chunk,
             config=self.cfg, requests=requests, end_to_end=e2e)

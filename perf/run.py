@@ -119,7 +119,8 @@ def build_driver(workload: str, seed: int):
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     say(json.dumps({"cell": entry["name"], "seed": seed, "device": device,
                     "compile_cache": cache_dir}))
-    traffic = load_json("traffic", entry["traffic"] + ".json")
+    traffic = dict(load_json("traffic", entry["traffic"] + ".json"),
+                   name=entry["traffic"])
     driver = load_file("drivers", cell["driver"]).Driver(
         cell=cell, config=load_json("configs", entry["config"] + ".json"),
         traffic=traffic, seed=seed,

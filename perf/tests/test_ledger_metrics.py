@@ -94,6 +94,26 @@ def test_a_ring_that_lost_the_opening_says_nothing(ring, capsys, name):
 
 
 @pytest.mark.parametrize("name", NEW)
+def test_a_copy_taken_in_time_outlasts_the_ring(ring, capsys, name):
+    from perf.work import ledger
+
+    for k in range(6):      # commits at 11 to 16; the ring holds all six
+        ring.push(iteration(k))
+    rec = record(11.5, 14.5, prompt_tokens=150, chunks=6)
+    before = reader(name)(rec)
+    kept = dict(rec, ring=ledger.read_ring(rec["t_open"]))
+    assert [r.step for r in kept["ring"][0]] == [2, 3, 4, 5, 6]
+    for k in range(6, 10):  # the engine serves on: iterations 0, 1 drop
+        ring.push(iteration(k))
+    assert reader(name)(rec) is None
+    assert "no longer holds" in capsys.readouterr().out
+    assert reader(name)(kept) == before is not None
+    # a program without a ring: the copy is the reason, said by the reader
+    assert reader(name)(dict(rec, ring="no ring here")) is None
+    assert "no ring here" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("name", NEW)
 def test_an_empty_ring_or_none_at_all_says_nothing(ring, capsys,
                                                    monkeypatch, name):
     assert reader(name)(record(11.5, 14.5)) is None        # ledger off

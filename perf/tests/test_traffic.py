@@ -1,3 +1,4 @@
+import json
 import os
 
 import numpy as np
@@ -7,6 +8,13 @@ from perf import run
 
 MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(run.HERE, "traffic"))
                if f.endswith(".json"))
+# (prompt, output) of `shape_seed` 27's block: means 173.9 and 207.3
+SERVE_BATCH_BLOCK = [
+    (33, 68), (45, 297), (53, 362), (56, 125), (72, 287), (74, 149),
+    (76, 108), (78, 154), (86, 126), (98, 79), (221, 277), (286, 365),
+    (348, 423), (396, 66), (423, 225), (438, 206)]
+with open(os.path.join(run.ROOT, "BENCHMARK.json")) as _f:
+    CELLS = [(w["name"], w["traffic"]) for w in json.load(_f)["workloads"]]
 
 
 def _load(mix):
@@ -70,3 +78,33 @@ def test_clipping_to_max_total_and_a_block_that_does_not_divide():
     assert any(r["max_new"] < 30 for r in rs)
     with pytest.raises(ValueError, match="does not divide"):
         generate.requests(dict(m, block=7), 5, 100)
+
+
+@pytest.mark.parametrize("cell, mix", CELLS)
+def test_a_backlog_holds_four_times_what_a_traced_run_consumes(cell, mix):
+    """A closed backlog may not run out: the driver ends such a run with
+    exit code 1.  The cell's file says how many requests a traced run took
+    off the queue by its last reading when it was last read on the chip
+    (every window line prints `queue_depth_last`); whoever reads a faster
+    program writes the new number there, and this says whether `count`
+    still holds four times that, before a check does."""
+    m = run.load_json("traffic", mix + ".json")
+    if m["generator"] != "backlog":
+        pytest.skip("not a closed backlog")
+    used = run.load_json("workloads", cell + ".json")[
+        "consumed_by_a_traced_run"]
+    assert used["origin"]
+    assert m["count"] >= 4 * used["requests"] > 0
+
+
+def test_the_sizes_a_run_of_serve_batch_draws():
+    """`shape_seed` 27's block of 16: what every window of
+    `gpt2-medium.serve-batch` has held since PR 27, whatever `count` is."""
+    m, generate = _load("serve-batch")
+    rs = generate.requests(m, 3, 50257)
+    assert len(rs) == 7680
+    sizes = sorted((len(r["prompt"]), r["max_new"]) for r in rs[:16])
+    assert sizes == SERVE_BATCH_BLOCK
+    assert sorted((len(r["prompt"]), r["max_new"])
+                  for r in rs[-16:]) == SERVE_BATCH_BLOCK
+    assert sum(p + n for p, n in sizes) / 16 == pytest.approx(381.2, abs=0.1)

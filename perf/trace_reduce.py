@@ -53,11 +53,18 @@ class Tracer:
         self._window.__enter__()
         self._t0 = time.perf_counter()
 
+    def mark(self):
+        """Ends the traced window; the profiler runs on until `stop()`,
+        which may stall its caller for tens of seconds."""
+        self.host_window_s = time.perf_counter() - self._t0
+        self._window.__exit__(None, None, None)
+        self._window = None
+
     def stop(self):
         import jax
 
-        self.host_window_s = time.perf_counter() - self._t0
-        self._window.__exit__(None, None, None)
+        if self._window is not None:
+            self.mark()
         jax.profiler.stop_trace()
 
     def reduce(self, chips: int) -> dict:
