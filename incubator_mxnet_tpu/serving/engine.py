@@ -6,7 +6,12 @@ evicts timed-out/cancelled ones, admits queued requests, runs ONE
 fixed-width prefill chunk for the oldest admitted-but-unprefilled
 request, then executes ONE batched decode step for every live lane —
 so a long prompt costs each resident sequence at most one chunk of
-extra latency per token, never its whole prefill (ISSUE 20).  The KV
+extra latency per token, never its whole prefill (ISSUE 20).  The
+decode loop runs one step AHEAD of its reads: a step is handed to the
+device before the tokens of the step before it are read, so the host's
+bookkeeping and dispatch overlap the device's work (docs/serving.md,
+"One step ahead"; an EOS, a cancel and a freed lane are learnt one
+step late, each exactly).  The KV
 cache is a paged pool (`kv_pool`, `programs`): admission and eviction
 move *block table entries*, never array shapes, so after warmup
 nothing recompiles — ci/serving_smoke.py pins this with a zero-budget
@@ -68,8 +73,9 @@ bookkeeping holds the lock.  That includes prefill (tpulint TPU015):
 admission claims the lane + blocks under the lock (binding any
 cache-hit prefix blocks), each chunk is stage (under the lock) →
 device call (unlocked) → commit (re-lock, slot-identity check), and
-the final chunk's commit delivers the first token — mirroring
-`_decode_step`'s snapshot/step/commit shape.
+the final chunk's commit delivers the first token — mirroring the
+decode step's snapshot (`_loop`) / hand-over (`_decode_step`) / commit
+(`_land_step`) shape.
 """
 from __future__ import annotations
 
@@ -480,6 +486,17 @@ class ServingEngine:
         self._pos = np.zeros((B,), np.int32)
         self._active = np.zeros((B,), bool)
         self._keys = np.zeros((B, 2), np.uint32)
+        # lanes whose `_toks` entry a prompt's final chunk set since the
+        # last step was handed over: the step takes their token from the
+        # host, every other lane's from the step before, on the device
+        self._fresh = np.zeros((B,), bool)
+        # the position at which a lane needs no further step: its last
+        # token by count (`max_new_tokens`) is then delivered or in flight
+        self._end = np.zeros((B,), np.int32)
+        # the step handed over whose tokens are not read yet (scheduler
+        # thread only): (tokens on the device, its lanes, when, 1 where
+        # the step before it was still unread); at most one
+        self._flying = None
         self._slots: list = [None] * B
 
         self._lock = threading.Lock()
@@ -498,7 +515,7 @@ class ServingEngine:
         self._pending_err: Optional[BaseException] = None
         self._prefill_ewma: Optional[float] = None
         self._stats = {"admitted": 0, "done": 0, "steps": 0,
-                       "prefix_hits": 0, "prefix_misses": 0,
+                       "steps_ahead": 0, "prefix_hits": 0, "prefix_misses": 0,
                        "cached_tokens": 0,
                        "shed": OrderedDict(), "evicted": OrderedDict()}
         if self._spec:
@@ -809,6 +826,9 @@ class ServingEngine:
             "num_blocks": self._num_blocks,
             "max_queue": self._max_queue,
             "prefill_chunk": self._chunk,
+            # decode steps the scheduler hands over before it reads their
+            # tokens (a speculating engine reads each window first)
+            "steps_in_flight": 0 if self._spec else 1,
             # a recurrent layer's state cannot be handed to a prefix hit
             "prefix_cache": not self._recurrent,
             "kv_pool_bytes": self.kv_pool_bytes,
@@ -947,6 +967,9 @@ class ServingEngine:
                 "admitted": self._stats["admitted"],
                 "done": self._stats["done"],
                 "steps": self._stats["steps"],
+                # of them, those handed over while the step before was
+                # still unread (the decode loop runs one step ahead)
+                "steps_ahead": self._stats["steps_ahead"],
                 "shed": dict(self._stats["shed"]),
                 "evicted": dict(self._stats["evicted"]),
                 "queue_depth": len(self._queue),
@@ -1054,8 +1077,10 @@ class ServingEngine:
         self._slots[i] = None               # blocks survive in-cache
         self._tables[i, :] = SCRATCH_BLOCK
         self._active[i] = False
+        self._fresh[i] = False
         self._toks[i] = 0
         self._pos[i] = 0
+        self._end[i] = 0
         if telemetry.enabled():
             telemetry.gauge("serving_kv_blocks_in_use") \
                 .set(self._pool.num_allocated)
@@ -1094,6 +1119,12 @@ class ServingEngine:
         except BaseException as e:
             with self._err_lock:
                 self._pending_err = e
+            # tokens of a step already handed over are the requests': they
+            # are read and delivered before the requests are failed
+            try:
+                self._land_step()
+            except Exception:   # the device is what failed: `e` says it
+                pass
             failure = RequestFailed("serving scheduler failed")
             failure.__cause__ = e
             with self._work:
@@ -1116,25 +1147,44 @@ class ServingEngine:
         # ONE prefill chunk → run ONE decode step over live lanes.
         # Interleaving chunk and decode per iteration is what bounds a
         # resident sequence's tpot spike to one chunk of compute.
+        #
+        # The decode loop runs ONE step ahead of its reads (ISSUE 35,
+        # docs/serving.md "One step ahead"): iteration k hands over
+        # chunk k and step k, and only then reads and commits step k-1,
+        # then commits chunk k.  Step k needs nothing of that read: a
+        # lane's token is step k-1's output where it lies on the device,
+        # its position is one further, and a lane that ends by count
+        # leaves the snapshot by `_end`; so the host's bookkeeping and
+        # dispatch run while the device works.  Known one step late: an
+        # EOS, a cancel or deadline, a freed lane.
         prof = self._prof
         while True:
             with prof.phase("lock_wait"), self._work, \
                     prof.phase("bookkeeping"):
                 if self._stop.is_set():
-                    return
+                    break
                 now = time.monotonic()
                 self._last_tick = now       # health(): liveness heartbeat
                 self._reap_locked(now)
                 while self._admit_locked(now):
                     pass
                 staged = self._stage_chunk_locked()
+                # lanes the next step runs: a lane at its `_end` has its
+                # last token delivered or in flight
+                stepping = self._active & (self._pos < self._end)
                 live = [(i, s.req) for i, s in enumerate(self._slots)
-                        if s is not None and self._active[i]]
+                        if s is not None and stepping[i]]
                 snap = (self._tables.copy(), self._toks.copy(),
-                        self._pos.copy(), self._active.copy(),
+                        self._pos.copy(), stepping,
                         self._keys.copy()) if live else None
+                if live and not self._spec:
+                    # what the NEXT hand-over needs is settled here, not
+                    # at the commit: it needs no token
+                    snap += (self._fresh.copy(),)
+                    self._fresh[:] = False
+                    self._pos[stepping] += 1
                 hook = self._fault_hook
-                if staged is None and not live:
+                if staged is None and not live and self._flying is None:
                     if not self._queue:
                         with prof.phase("wait"):
                             self._work.wait(self._poll)
@@ -1146,13 +1196,19 @@ class ServingEngine:
             # device works on the chunk, a prompt's last chunk included
             chunk = self._run_chunk(staged, hook) \
                 if staged is not None else None
-            if live and not self._spec:
-                self._decode_step(snap, live, hook, chunk)
-            else:
+            if self._spec:
                 if chunk is not None:
                     self._commit_chunk(chunk)
                 if live:
                     self._spec_step(snap, live, hook)
+            else:
+                # with no lane live this only lands the step in flight:
+                # its lanes are freed (drain() waits on that) before the
+                # scheduler idles
+                self._decode_step(snap, live, hook)
+                if chunk is not None:
+                    self._commit_chunk(chunk)
+        self._land_step()       # stopped: no token handed over is lost
 
     def _reap_locked(self, now: float) -> None:
         # queued requests: cancellation and deadlines apply while waiting
@@ -1306,8 +1362,10 @@ class ServingEngine:
             with prof.phase("dispatch"):
                 first = self._programs.prefill_chunk(
                     job.row, toks, start, job.P, job.key, job.lane, n)
-            t_handed = time.monotonic()     # the chunk's stamp: no sync
-        return job, start, n, final, first, t0, t_handed
+            # the chunk's stamp, in the ledger's record of the iteration
+            # that handed it over: no sync
+            prof.chunk(job.req.rid, start, n, time.monotonic())
+        return job, start, n, final, first, t0
 
     def _commit_chunk(self, chunk) -> None:
         """Re-lock and commit a chunk `_run_chunk` handed over, with a
@@ -1315,7 +1373,7 @@ class ServingEngine:
         the FINAL chunk's commit fetches the first token (the one wait
         for the device here), delivers it and activates the lane."""
         prof = self._prof
-        job, start, n, final, first, t0, t_handed = chunk
+        job, start, n, final, first, t0 = chunk
         req = job.req
         with prof.phase("prefill_chunk"):
             # only the final chunk's first-token pick is consumed —
@@ -1335,7 +1393,6 @@ class ServingEngine:
                 self._state_resets += 1
                 if telemetry.enabled():
                     telemetry.counter("serving_state_resets_total").inc()
-            prof.chunk(req.rid, start, n, t_handed)
             self._note_chunk_queue_locked()
             if not final:
                 return
@@ -1370,7 +1427,9 @@ class ServingEngine:
                 return
             self._tables[job.lane, :] = job.row
             self._toks[job.lane] = tok
+            self._fresh[job.lane] = True    # the next step reads `_toks`
             self._pos[job.lane] = job.P
+            self._end[job.lane] = job.P + req.max_new_tokens - 1
             self._active[job.lane] = True
             self._keys[job.lane, :] = job.key
 
@@ -1398,8 +1457,9 @@ class ServingEngine:
     def _pool_use_locked(self) -> dict:
         """The lanes' hold on the pool for the ledger's record: blocks
         reserved, and positions whose K/V the pool holds (`_pos` of a
-        decoding lane, `next_pos` of one still prefilling), both summed
-        over the occupied lanes; and how many lanes hold recurrent state."""
+        decoding lane, the step in flight's position with it, `next_pos`
+        of one still prefilling), both summed over the occupied lanes; and
+        how many lanes hold recurrent state."""
         slots = self._slots
         # prefill jobs whose lane is still theirs
         jobs = [j for j in self._prefill_jobs
@@ -1426,26 +1486,40 @@ class ServingEngine:
         self._stats["done"] += 1
         self._work.notify_all()             # drain()ers and submitters
 
-    def _decode_step(self, snap, live, hook, chunk=None) -> None:
-        """One batched decode step — device call OUTSIDE the lock, so
-        submit()/cancel() never block on compute (a fault hook's
-        injected sleep included).  ``chunk``: the prefill chunk handed
-        to the device just before, committed here once the step is
-        queued behind it and before the step's tokens are waited for."""
+    def _decode_step(self, snap, live, hook) -> None:
+        """Hand one batched decode step over (when a lane is live), THEN
+        read and commit the step handed over an iteration ago — device
+        calls OUTSIDE the lock, so submit()/cancel() never block on
+        compute (a fault hook's injected sleep included)."""
         prof = self._prof
-        with prof.phase("gather_params"):
-            self._programs.gather_params()
-        # the ledger's device_step cause includes the fault hook (an
-        # injected stall IS device time to the requests waiting on it);
-        # the tpot histogram keeps the pure device call, as before
-        with prof.phase("device_step"):
-            if hook is not None:
-                hook("step")                # fault seam: counts as device
-            t0 = time.perf_counter()
-            with prof.phase("dispatch"):
-                nxt = self._programs.step(*snap, len(live))
-            if chunk is not None:
-                self._commit_chunk(chunk)
+        handed = None
+        if live:
+            with prof.phase("gather_params"):
+                self._programs.gather_params()
+            # the ledger's device_step cause includes the fault hook (an
+            # injected stall IS device time to the requests waiting on
+            # it) and the wait for the tokens in `_land_step`; the tpot
+            # histogram keeps hand-over to tokens read
+            with prof.phase("device_step"):
+                if hook is not None:
+                    hook("step")            # fault seam: counts as device
+                t0 = time.perf_counter()
+                with prof.phase("dispatch"):
+                    nxt = self._programs.step(*snap, len(live))
+            handed = (nxt, live, t0, int(self._flying is not None))
+        self._land_step()
+        self._flying = handed
+
+    def _land_step(self) -> None:
+        """Read the tokens of the step in flight, if there is one, and
+        commit it: deliver, stamp, EOS test, retire.  A lane whose slot
+        changed hands since the hand-over (evicted, cancelled, retired on
+        an EOS the step before) is skipped: the token it ran for is
+        discarded."""
+        if self._flying is None:
+            return
+        (nxt, live, t0, ahead), self._flying = self._flying, None
+        with self._prof.phase("device_step"):
             nxt = np.asarray(nxt)           # sync: tokens are consumed now
             dt = time.perf_counter() - t0
         now = time.monotonic()
@@ -1457,11 +1531,11 @@ class ServingEngine:
                     continue                # evicted while stepping
                 tok = int(nxt[lane])
                 req._deliver(tok, now)
-                self._pos[lane] += 1
                 self._toks[lane] = tok
                 if mark:                    # every Nth step: cheap marks
                     req.trace.event("decode", t=now,
-                                    pos=int(self._pos[lane]),
+                                    pos=req.prompt.shape[0]
+                                    + len(req.tokens) - 1,
                                     tokens=len(req.tokens),
                                     occupancy=len(live))
                 if tok == self._eos \
@@ -1469,17 +1543,20 @@ class ServingEngine:
                     self._retire_locked(lane)
             return dt
 
-        self._commit_step(live, deliver)
+        self._commit_step(live, deliver, ahead)
 
-    def _commit_step(self, live, deliver) -> None:
-        """The commit `_decode_step` and `_spec_step` share: re-lock,
-        count the step, let ``deliver(mark)`` hand the lanes their tokens
-        (``mark``: this step leaves a trace mark; it returns the seconds a
-        token took, for ``serving_tpot_seconds``), note occupancy, queue
-        and the pool's use, then close the ledger's iteration."""
+    def _commit_step(self, live, deliver, ahead=0) -> None:
+        """The commit `_land_step` and `_spec_step` share: re-lock,
+        count the step (``ahead``: 1 where it was handed over while the
+        step before was unread), let ``deliver(mark)`` hand the lanes their
+        tokens (``mark``: this step leaves a trace mark; it returns the
+        seconds a token took, for ``serving_tpot_seconds``), note
+        occupancy, queue and the pool's use, then close the ledger's
+        iteration."""
         prof = self._prof
         with prof.phase("lock_wait"), self._work, prof.phase("commit"):
             self._stats["steps"] += 1
+            self._stats["steps_ahead"] += ahead
             step_no = self._stats["steps"]
             tpot = deliver(step_no % _TRACE_EVERY == 0)
             if telemetry.enabled():
@@ -1496,7 +1573,7 @@ class ServingEngine:
         # leaf lock + histogram locks; never nested under self._work)
         prof.end_step(rids=[req.rid for _, req in live],
                       occupancy=len(live), queue_depth=queue_depth,
-                      step=step_no, **pool_use)
+                      step=step_no, ahead=ahead, **pool_use)
         if telemetry.enabled() and step_no % 8 == 0:
             # keep lock_witness_edges_total / lock_contention_seconds
             # scrapeable mid-run, not only after an end-of-run snapshot

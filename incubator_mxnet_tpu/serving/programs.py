@@ -345,19 +345,22 @@ def _token_forward(params, spec, bs, kv8, attn_impl,
 class _HostPacked:
     """A served program, jitted, whose small host arrays travel as ONE
     buffer.  ``fn(*device, *host, params)``: ``n_device`` leading
-    arguments that live on the device and are donated (pools, scales,
-    recurrent state), then the scheduler's numpy arrays and scalars of a
-    call (block tables, tokens, positions, flags, keys), then the weight
-    pytree.  Every numpy argument of a jitted call is a transfer of its
-    own, 0.12 ms of the host's time each on a v5e (my chip runs, PR 30:
-    six of them 0.6 ms a call, twice an iteration); here they are laid
-    end to end in one int32 array on the host and cut apart again inside
-    the program (static slices, a bitcast for uint32, ``!= 0`` for bool).
+    arguments that live on the device, the first ``n_donated`` of them
+    (all, unless told) donated (pools, scales, recurrent state; the step
+    before's tokens are read and left), then the scheduler's numpy arrays
+    and scalars of a call (block tables, tokens, positions, flags, keys),
+    then the weight pytree.  Every numpy argument of a jitted call is a
+    transfer of its own, 0.12 ms of the host's time each on a v5e (my
+    chip runs, PR 30: six of them 0.6 ms a call, twice an iteration);
+    here they are laid end to end in one int32 array on the host and cut
+    apart again inside the program (static slices, a bitcast for uint32,
+    ``!= 0`` for bool).
     Called and lowered like the jitted ``fn``; the compiled program keeps
     ``fn``'s name."""
 
-    def __init__(self, fn, n_device):
+    def __init__(self, fn, n_device, n_donated=None):
         self._fn, self._n = fn, int(n_device)
+        self._donated = self._n if n_donated is None else int(n_donated)
         self._sig = self._jitted = None     # the host signature served
 
     def _program(self, host):
@@ -382,7 +385,7 @@ class _HostPacked:
 
             program.__name__ = fn.__name__
             self._sig, self._jitted = sig, jax.jit(
-                program, donate_argnums=tuple(range(n)))
+                program, donate_argnums=tuple(range(self._donated)))
         return self._jitted
 
     def _split(self, args):
@@ -421,8 +424,13 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
                        layer: (B, d_state, d_inner) float32 and
                        (d_conv-1, B, d_inner), a row a lane; ``()`` for a
                        decoder without ssm layers
+      prev             (B,) int32 — the step before's ``next_tokens``, the
+                       device array they still are (not donated)
       tables           (B, blocks_per_seq) int32 block ids per lane
-      toks             (B,) int32 — token emitted by the previous step
+      toks             (B,) int32 — the host's token of a lane
+      fresh            (B,) bool — lanes whose input token is the host's
+                       (a prompt's final chunk picked it); the others
+                       take ``prev``, which the host may not have read yet
       pos              (B,) int32 — position this step writes/attends to
       active           (B,) bool  — lanes with a live sequence
       keys             (B, 2) uint32 — per-lane PRNG keys
@@ -439,8 +447,9 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
     pick = _row_pick(temperature, top_k)
     kv8 = kv_dtype == "int8"
 
-    def serving_step(pool_k, pool_v, scale_k, scale_v, rec, tables, toks,
-                     pos, active, keys, params):
+    def serving_step(pool_k, pool_v, scale_k, scale_v, rec, prev, tables,
+                     toks, fresh, pos, active, keys, params):
+        toks = jnp.where(fresh, toks, prev)
         new_k, new_v, new_sk, new_sv, new_rec, logits = _token_forward(
             params, spec, bs, kv8, attn_impl,
             pool_k, pool_v, scale_k, scale_v, rec, tables, toks, pos, active)
@@ -760,8 +769,11 @@ class PagedPrograms:
     tables and `BlockPool` ids as the target's, so one lane allocation
     covers both and eviction frees both.  This object holds the ONLY
     reference to each and rebinds it after every donated call (the
-    buffers really are deleted on XLA:CPU too).  And the gathered weight
-    pytrees, cached on the nets' weight-buffer fingerprints.
+    buffers really are deleted on XLA:CPU too).  Beside them the last
+    step's next tokens, which the next step takes as its input where they
+    lie (not donated: the scheduler reads them a step late).  And the
+    gathered weight pytrees, cached on the nets' weight-buffer
+    fingerprints.
 
     Calls: a method a program family (`prefill_chunk`, `step`,
     `draft_step`, `spec_verify`), each taking what a scheduler knows
@@ -841,7 +853,7 @@ class PagedPrograms:
                 _build_step(self._spec, self._bs, self._nbps,
                             self._temperature, self._top_k,
                             self._kv_dtype, self._impl,
-                            "serving_step" + sfx), 5))
+                            "serving_step" + sfx), 6, 5))
         self._prefill_chunk = self._program(
             ("prefill_chunk", self._chunk) + self._key, lambda: _HostPacked(
                 _build_prefill_chunk(self._spec, self._bs, self._nbps,
@@ -965,6 +977,9 @@ class PagedPrograms:
         self._kv = [each(L, page, dt), each(L, page, dt),
                     each(n_sc, scales, jnp.float32, jnp.ones),
                     each(n_sc, scales, jnp.float32, jnp.ones), rec]
+        # the last step's next tokens, which the next step reads where
+        # they lie (before any step: no lane takes its token from them)
+        self._last = jnp.zeros((B,), jnp.int32)
         self._draft_kv = [(), ()]
         if self._spec_k:
             dspec = self._draft_spec
@@ -1088,6 +1103,7 @@ class PagedPrograms:
         nets' own caches."""
         self._kv = [(), (), (), (), ()]
         self._draft_kv = [(), ()]
+        self._last = None
         self._net = self._draft_net = None
         self._params = self._params_key = None
         self._draft_params = self._draft_params_key = None
@@ -1120,10 +1136,15 @@ class PagedPrograms:
                        self._draft_params)
         return first
 
-    def step(self, tables, toks, pos, active, keys, n_live):
-        """One decode step of every lane; returns the next tokens (B,)."""
-        nxt, = self._call("step", n_live, self._step, self._kv, tables,
-                          toks, pos, active, keys, self._params)
+    def step(self, tables, toks, pos, active, keys, fresh, n_live):
+        """One decode step of every lane; returns the next tokens (B,).
+        A lane's input token is ``toks``' where ``fresh``, else what the
+        step before returned for it, taken on the device: the caller need
+        not have read that yet."""
+        nxt, = self._call("step", n_live, self._step, self._kv, self._last,
+                          tables, toks, fresh, pos, active, keys,
+                          self._params)
+        self._last = nxt
         return nxt
 
     def draft_step(self, tables, toks, pos, active, keys, n_live):

@@ -15,7 +15,8 @@ decomposing the wall time since the previous commit (so prefill
 interleave, lock waits and idle polls between steps are attributed,
 not lost) into:
 
-    device_step     decode step: fault hook + the blocking token fetch
+    device_step     decode step: fault hook + the blocking fetch of the
+                    tokens of the step handed over an iteration before
     draft_step      speculative draft call (fault hook included)
     verify_step     speculative verify: fault hook + the blocking fetch
     prefill_chunk   prefill chunk: fault hook + the final chunk's fetch
@@ -42,7 +43,7 @@ records) that outlives the engines: `iterations(since, until)` cuts it
 to a window of ``time.monotonic()`` — the clock of `Request.t_tokens` —
 and says whether the ring still held the window's start.  A record also
 carries the lanes' pool use (`blocks_reserved`, `positions_written`)
-and a stamp for every prefill chunk committed since the record before
+and a stamp for every prefill chunk handed over since the record before
 it.  `recent_steps()`, the merged trace's scheduler lane and
 ``/profilez`` are served from the same ring.
 
@@ -243,25 +244,27 @@ class Iteration:
     ``positions_written <= blocks_reserved * block_size`` always);
     ``blocks_total`` is the pool's allocatable blocks, of
     ``block_size`` positions each.  ``chunks`` is
-    ``(rid, start, n, t)`` for every prefill chunk committed since the
+    ``(rid, start, n, t)`` for every prefill chunk handed over since the
     record before: ``n`` prompt tokens from position ``start``, ``t``
     the stamp taken when the chunk's program call RETURNED — the host
     had handed it over; only a prompt's final chunk is ever fetched.
     For a decoder with recurrent layers ``state_rows`` is the lanes that
     hold live recurrent state at the commit and ``state_resets`` the
     first chunks (a lane's state begun from zero) since the record
-    before; both 0 otherwise.
+    before; both 0 otherwise.  ``ahead`` is 1 where the step this record
+    commits was handed to the device while the step before it was still
+    unread (the decode loop runs one step ahead of its reads), else 0.
     """
 
     __slots__ = ("engine", "step", "t0", "t1", "causes", "occupancy",
                  "queue_depth", "blocks_reserved", "blocks_total",
                  "block_size", "positions_written", "chunks",
-                 "state_rows", "state_resets")
+                 "state_rows", "state_resets", "ahead")
 
     def __init__(self, engine, step, t0, t1, causes, occupancy=0,
                  queue_depth=0, blocks_reserved=0, blocks_total=0,
                  block_size=0, positions_written=0, chunks=(),
-                 state_rows=0, state_resets=0):
+                 state_rows=0, state_resets=0, ahead=0):
         self.engine = engine
         self.step = step
         self.t0 = t0
@@ -276,6 +279,7 @@ class Iteration:
         self.chunks = chunks
         self.state_rows = state_rows
         self.state_resets = state_resets
+        self.ahead = ahead
 
     def as_dict(self) -> dict:
         d = {k: getattr(self, k) for k in self.__slots__}
@@ -471,7 +475,7 @@ class EngineProfiler:
             self._acc[_INDEX[cause]] += dur
 
     def chunk(self, rid: int, start: int, n: int, t: float) -> None:
-        """Stamp one committed prefill chunk (see `Iteration.chunks`)."""
+        """Stamp one prefill chunk handed over (see `Iteration.chunks`)."""
         if self._enabled:
             self._chunks.append((rid, start, n, t))
 
@@ -480,7 +484,7 @@ class EngineProfiler:
                  blocks_reserved: int = 0, blocks_total: int = 0,
                  block_size: int = 0,
                  positions_written: int = 0, state_rows: int = 0,
-                 state_resets: int = 0) -> Optional[dict]:
+                 state_resets: int = 0, ahead: int = 0) -> Optional[dict]:
         """Close the iteration at a decode-step commit: compute the
         wall since the previous commit, carve gc + residue, push the
         record, feed histograms, judge the hiccup threshold.  Returns
@@ -511,7 +515,7 @@ class EngineProfiler:
         rec = Iteration(self.name, step, t0, now, tuple(acc), occupancy,
                         queue_depth, blocks_reserved, blocks_total,
                         block_size, positions_written, tuple(chunks),
-                        state_rows, state_resets)
+                        state_rows, state_resets, ahead)
         self._ring.push(rec)
         self._totals = tuple(map(operator.add, self._totals, acc))
         self._total_wall += wall

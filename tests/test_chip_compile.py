@@ -269,8 +269,12 @@ def _served_program(program, kv_dtype, spec, B=_CELL_B, bs=_BS,
     if program == "serving_step":
         fn = SP._build_step(spec, bs, nbps, 0.0, 0, kv_dtype, "pallas",
                             program)
-        rest = [((B, nbps), i32), ((B,), i32), ((B,), i32),
-                ((B,), jnp.bool_), ((B, 2), jnp.uint32)]
+        # the step before's tokens (a device array, not donated), then the
+        # host's: tables, tokens, which lanes take the host's, positions,
+        # live lanes, keys
+        rest = [((B,), i32), ((B, nbps), i32), ((B,), i32),
+                ((B,), jnp.bool_), ((B,), i32), ((B,), jnp.bool_),
+                ((B, 2), jnp.uint32)]
     else:
         fn = SP._build_prefill_chunk(spec, bs, nbps, chunk, 0.0, 0,
                                      kv_dtype, "pallas", program)
@@ -323,8 +327,11 @@ def test_served_program_leaves_the_pool_where_it_lies(
     assert compiled.memory_analysis().temp_size_in_bytes < page_bytes
     # (c) every pool output aliases its argument
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
-    for i in range(2 * len(pool) + 2 * len(scale)):
+    held = 2 * len(pool) + 2 * len(scale)
+    for i in range(held):
         assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
+    # and nothing else does: the step before's tokens are read and left
+    assert len(re.findall(r"\{\d+\}: \(", aliases)) == held, aliases
 
 
 # --- the hybrid cell's programs: two kinds of state, neither copied ----- #

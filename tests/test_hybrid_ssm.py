@@ -239,12 +239,14 @@ def test_cobatched_lanes_do_not_touch_each_other(seeded):
         onp.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("how", ["finish", "cancel", "evict"])
+@pytest.mark.parametrize("how", ["finish", "cancel", "evict", "eos"])
 def test_a_reused_lane_starts_from_zero_state(seeded, how):
     """Whatever a lane's last request left in its row — it finished, was
-    cancelled mid-decode or ran into its deadline — the next request's
-    first chunk starts from zero: its tokens are those of a fresh engine.
-    Nothing is cleared at release; the first chunk does it."""
+    cancelled mid-decode, ran into its deadline, or met an EOS and had its
+    row advanced once more by the step already handed over (the decode
+    loop learns an EOS one step late) — the next request's first chunk
+    starts from zero: its tokens are those of a fresh engine.  Nothing is
+    cleared at release; the first chunk does it."""
     net, _ = seeded
     first, second = _prompts((18, 12), seed=4)
     kw = dict(max_batch=1, block_size=8, max_seq_len=64, prefill_chunk=8)
@@ -253,6 +255,20 @@ def test_a_reused_lane_starts_from_zero_state(seeded, how):
     with ServingEngine(net, **kw) as eng:
         if how == "finish":
             eng.submit(first, 6).result(timeout=300)
+        elif how == "eos":
+            eng.set_fault_hook(lambda phase: time.sleep(0.02))
+            h = eng.submit(first, 40)
+            while len(h.tokens) < 3:
+                time.sleep(0.01)
+            # (this net repeats a token: the next step read ends on it,
+            # with the step after it already handed over)
+            eng._eos = h.tokens[-1]
+            got = h.result(timeout=300)
+            assert h.status == "done" and 3 <= len(got) < 40
+            assert eng.drain(timeout=60)        # the step past it has landed
+            assert len(h.tokens) == len(got) and eng.stats()["steps_ahead"]
+            eng._eos = -1
+            eng.set_fault_hook(None)
         else:
             eng.set_fault_hook(lambda phase: time.sleep(0.02))
             h = eng.submit(first, 40,

@@ -142,6 +142,15 @@ def test_eos_and_single_token_retire(net, clean_engine):
     clean_engine._eos = eos
     try:
         assert clean_engine.submit(P1, 8).result(timeout=60) == [eos]
+        # an eos met in a decode step is learnt one step late (the step
+        # after is already handed over): that step's token is discarded
+        j = next(j for j in range(2, 8) if full[j] not in full[:j])
+        clean_engine._eos = int(full[j])
+        req = clean_engine.submit(P1, 8)
+        assert req.result(timeout=60) == full[:j + 1].tolist()
+        assert clean_engine.drain(timeout=30)
+        assert len(req.tokens) == len(req.t_tokens) == j + 1
+        assert req.t_tokens[-1] <= req.t_done
     finally:
         clean_engine._eos = old
 
@@ -168,12 +177,17 @@ def test_mid_batch_eviction_leaves_survivor_bit_identical(clean_engine):
     with pytest.raises(RequestCancelled):
         rb.result(timeout=60)
     eng.set_fault_hook(None)
+    # the step in flight when the cancel was reaped ran the lane once
+    # more: its token went nowhere
+    assert eng.drain(timeout=30)
+    assert len(rb.tokens) == len(rb.t_tokens) < 10
+    assert rb.t_tokens[-1] <= rb.t_done
     # run C: solo — scratch-block garbage from the neighbour never
     # reaches the survivor (masked positions contribute exactly 0)
     assert eng.submit(P1, 10).result(timeout=60) == base
 
 
-def test_evicted_blocks_are_reused(clean_engine):
+def test_evicted_blocks_are_reused(net, clean_engine):
     eng = clean_engine
     eng.set_fault_hook(_slow_step(0.02))
     r1 = eng.submit(P1, 20)
@@ -185,7 +199,10 @@ def test_evicted_blocks_are_reused(clean_engine):
         r1.result(timeout=30)
     eng.set_fault_hook(None)
     r3 = eng.submit(P2, 6)
-    r3.result(timeout=60)
+    # the blocks' next holder reads nothing of what the evicted lane, or
+    # the step that was in flight for it, wrote there
+    assert r3.result(timeout=60) == onp.asarray(
+        lm_generate(net, P2[None, :], 6))[0, len(P2):].tolist()
     assert set(r3.block_ids) & held       # freed blocks re-allocated
     st = eng.stats()
     assert st["blocks_free"] == st["blocks_total"]
@@ -238,7 +255,9 @@ def test_a_last_chunk_is_committed_behind_the_step_it_shares_an_iteration_with(
     """A chunk and a decode step of one iteration: the step is handed to
     the device before the chunk's first token is fetched (its lanes were
     snapshotted before the chunk ran, so it does not need it), and the
-    chunk is committed before the step is.  Tokens are what they were."""
+    chunk is committed behind the read of the step BEFORE that one (the
+    loop runs one step ahead: the step handed over here is read in the
+    next iteration).  Tokens are what they were."""
     order = []
 
     def hook(phase):
@@ -263,7 +282,11 @@ def test_a_last_chunk_is_committed_behind_the_step_it_shares_an_iteration_with(
     i = max(k for k, (phase, n) in enumerate(order)
             if phase == "prefill" and n == 0)
     assert order[i + 1] == ("step", 0)
-    assert [n for phase, n in order[i + 2:] if phase == "step"][0] >= 1
+    assert [n for phase, n in order[i + 2:] if phase == "step"][0] == 1
+    # and the first step that ran `second`'s lane was unread at the hook
+    # after it: two hooks saw the chunk's one token
+    assert [n for phase, n in order[i + 2:] if phase == "step"][:3] \
+        == [1, 1, 2]
 
 
 def test_host_arguments_travel_as_one_buffer():
@@ -301,6 +324,206 @@ def test_host_arguments_travel_as_one_buffer():
     with pytest.raises(TypeError, match="4-byte"):
         packed(jnp.zeros(3), table.astype(onp.int64), flags, keys,
                onp.int32(7), params)
+    # a device argument that is read and left (the step before's tokens):
+    # a parameter of its own, not donated
+    def serving_two(pool, prev, table, params):
+        return pool + params["w"].sum(), prev + table[0, 0]
+
+    kept = _HostPacked(serving_two, 2, 1)
+    pool, prev = jnp.zeros(3), jnp.arange(4, dtype=jnp.int32)
+    out = kept(pool, prev, table + 5, params)
+    assert pool.is_deleted() and not prev.is_deleted()
+    onp.testing.assert_array_equal(onp.asarray(out[1]), onp.arange(4) + 5)
+    entry = kept.lower(jnp.zeros(3), prev, table, params).compile().as_text()
+    # pool, the kept array, ONE host buffer, w
+    assert entry[entry.index("ENTRY"):].count(" parameter(") == 4
+
+
+# --------------------------------------------------------------------- #
+# the decode loop runs one step ahead of its reads (ISSUE 35)
+# --------------------------------------------------------------------- #
+def _truncated(tokens, eos):
+    """`tokens` up to and including the first `eos`."""
+    return tokens[:tokens.index(eos) + 1] if eos in tokens else tokens
+
+
+def test_a_step_is_handed_over_while_the_step_before_is_unread(net):
+    """The "step" hook of step N+1 fires while step N's token is still
+    undelivered, every step but a batch's first; `stats()["steps_ahead"]`
+    and the ring's `ahead` count them; the tokens are `lm_generate`'s."""
+    from incubator_mxnet_tpu.telemetry import profiler
+
+    seen, box = [], []
+
+    def hook(phase):
+        if phase == "step" and box:
+            seen.append(len(box[0].tokens))
+
+    eng = ServingEngine(net, max_batch=2, block_size=8, poll_interval=_POLL,
+                        fault_hook=hook)
+    try:
+        assert eng.varz_config()["steps_in_flight"] == 1
+        since = time.monotonic()
+        box.append(eng.submit(P1, 6))
+        got = box[0].result(timeout=30)
+        assert eng.drain(timeout=30)
+        st = eng.stats()
+        name = eng._name
+    finally:
+        eng.close()
+    assert got == onp.asarray(
+        lm_generate(net, P1[None, :], 6))[0, len(P1):].tolist()
+    # step 1 saw the chunk's token; step 2 was handed over before step
+    # 1's token was read, step 3 before step 2's, ...
+    assert seen == [1, 1, 2, 3, 4]
+    assert (st["steps"], st["steps_ahead"]) == (5, 4)
+    records, _held = profiler.iterations(since, None, engine=name)
+    assert [r.ahead for r in records] == [0, 1, 1, 1, 1]
+    assert records[0].as_dict()["ahead"] == 0
+
+
+@pytest.mark.parametrize("sampling", [{}, {"temperature": 0.9, "top_k": 7}],
+                         ids=["greedy", "sampled"])
+def test_tokens_are_lm_generates_one_step_ahead(net, sampling):
+    """Five requests of staggered arrival through two lanes, greedy and
+    sampled: every request's tokens are `lm_generate`'s for its seed,
+    those that end on an EOS included (the lane ran one step more: that
+    token is not delivered), a freed lane's blocks serve the next
+    request, and the ledger's causes still sum to the wall."""
+    mix = [(P1, 11), (P2, 12), (P1[::-1].copy(), 13), (P2 + 1, 14),
+           ((P1 * 2) % V, 15)]
+    plain = [onp.asarray(lm_generate(net, p[None, :], 10, seed=seed,
+                                     **sampling))[0, len(p):].tolist()
+             for p, seed in mix]
+    # an EOS that ends the first request mid-way, by a decode step
+    j = next(j for j in range(2, 10) if plain[0][j] not in plain[0][:j])
+    eos = plain[0][j]
+    # (the tokens of a shorter request are a prefix of the longer one's:
+    # a pick depends on the seed and the position alone)
+    counts = (10, 7, 10, 8, 4)
+    want = [_truncated(t[:n], eos) for t, n in zip(plain, counts)]
+    assert len(want[0]) == j + 1
+    assert {w[-1] == eos for w in want} == {True, False}    # and by count
+    eng = ServingEngine(net, max_batch=2, block_size=8, poll_interval=_POLL,
+                        prefill_chunk=4, eos_id=eos, **sampling)
+    try:
+        handles = []
+        for i, ((p, seed), n) in enumerate(zip(mix, counts)):
+            handles.append(eng.submit(p, n, seed=seed))
+            if i % 2:
+                time.sleep(0.01)
+        got = [h.result(timeout=60) for h in handles]
+        assert eng.drain(timeout=30)
+        st = eng.stats()
+        violations = eng.profiler.invariant_violations
+    finally:
+        eng.close()
+    assert got == want
+    for h, w in zip(handles, want):
+        assert len(h.tokens) == len(h.t_tokens) == len(w)
+        assert h.status == "done" and h.t_tokens[-1] <= h.t_done
+    early = set(handles[0].block_ids) | set(handles[1].block_ids)
+    assert any(set(h.block_ids) & early for h in handles[2:])
+    assert st["blocks_free"] == st["blocks_total"] and st["active"] == 0
+    assert 0 < st["steps_ahead"] < st["steps"]
+    assert violations == 0
+
+
+def test_an_eos_one_step_before_a_neighbours_last_token(net):
+    """Two lanes, the iterations pinned through the fault hook: request A
+    meets its EOS in the step whose read falls one iteration before B's
+    last token (by count).  The step handed over in between still runs
+    A's lane; A gets no token from it, B gets its last, both are what
+    `lm_generate` gives."""
+    full = onp.asarray(lm_generate(net, P1[None, :], 8))[0, len(P1):].tolist()
+    a = next(j for j in range(2, 8) if full[j] not in full[:j]) + 1
+    both_in = threading.Event()
+
+    def hook(phase):            # A's chunk waits until B is queued behind it
+        if phase == "prefill":
+            assert both_in.wait(30)
+
+    eng = ServingEngine(net, max_batch=2, block_size=8, poll_interval=_POLL,
+                        eos_id=full[a - 1], fault_hook=hook)
+    try:
+        # A's step s is handed over in iteration s+1 and read in s+2; B's
+        # chunk runs one iteration after A's: B's a-th token (its last)
+        # is read in iteration a+2, A's a-th (the EOS) in a+1
+        ra = eng.submit(P1, 8)
+        rb = eng.submit(P2, a)
+        both_in.set()
+        got_a, got_b = ra.result(timeout=30), rb.result(timeout=30)
+        assert eng.drain(timeout=30)
+        st = eng.stats()
+    finally:
+        eng.close()
+    assert got_a == full[:a] and len(ra.tokens) == a
+    assert got_b == onp.asarray(
+        lm_generate(net, P2[None, :], a))[0, len(P2):].tolist()
+    # a steps in all: B's a-1, the first of them A's second; A's lane ran
+    # in the a-th too (its EOS was unread when that was handed over)
+    assert st["steps"] == a and st["steps_ahead"] == a - 1
+    assert ra.t_done <= rb.t_tokens[-1]
+    assert st["blocks_free"] == st["blocks_total"]
+
+
+@pytest.mark.parametrize("how", ["close", "drain", "cancel", "deadline"])
+def test_no_token_is_lost_or_late_with_a_step_in_flight(net, how):
+    """`close()`, `drain()`, a cancel and a deadline arrive while a step
+    is handed over and unread (the scheduler sits in the next step's
+    hook): no handle hangs, a step handed over before the request ended
+    still delivers, none delivers after, and the ledger's causes sum to
+    the wall."""
+    handed, at_third, go_on = [], threading.Event(), threading.Event()
+
+    def hook(phase):
+        if phase == "step":
+            handed.append(phase)
+            if len(handed) == 3:        # step 2 is in flight, unread
+                at_third.set()
+                assert go_on.wait(30)
+
+    eng = ServingEngine(net, max_batch=1, block_size=8, poll_interval=_POLL)
+    try:
+        eng.submit(P2, 2).result(timeout=60)        # both programs compiled
+        eng.set_fault_hook(hook)
+        req = eng.submit(P1, 6 if how == "drain" else 40,
+                         deadline=2.0 if how == "deadline" else None)
+        assert at_third.wait(30)
+        assert len(req.tokens) == 2     # the chunk's and step 1's
+        if how == "close":
+            closer = threading.Thread(target=eng.close)
+            closer.start()
+            assert _wait(lambda: eng.closed)
+        elif how == "cancel":
+            req.cancel()
+        elif how == "deadline":
+            time.sleep(max(0.0, req.deadline - time.monotonic()) + 0.01)
+        go_on.set()
+        if how == "close":
+            closer.join(30)
+            assert not closer.is_alive()
+        if how == "drain":
+            assert eng.drain(timeout=30)
+            assert req.result(timeout=30) == onp.asarray(lm_generate(
+                net, P1[None, :], 6))[0, len(P1):].tolist()
+        else:
+            with pytest.raises(RequestTimedOut if how == "deadline"
+                               else RequestCancelled):
+                req.result(timeout=30)
+            # steps 2 and 3 were handed over before the request ended.
+            # close() reads both before it aborts; a reap finds step 2
+            # read and step 3 in flight: its token goes nowhere
+            assert len(req.tokens) == (4 if how == "close" else 3)
+        n = len(req.tokens)
+        assert eng.profiler.invariant_violations == 0
+    finally:
+        go_on.set()
+        eng.close()
+    assert req.finished and len(req.tokens) == len(req.t_tokens) == n
+    assert req.t_tokens[-1] <= req.t_done
+    st = eng.stats()
+    assert st["blocks_free"] == st["blocks_total"] and st["active"] == 0
 
 
 # --------------------------------------------------------------------- #
@@ -526,7 +749,7 @@ def test_paged_programs_alone_serve_a_prompt(net, clean_engine):
         assert progs.max_seq_len == clean_engine.max_seq_len
         nbps, P = progs.max_seq_len // 8, len(P1)
         row = onp.full((nbps,), SCRATCH_BLOCK, onp.int32)
-        row[0] = 1                          # positions 0..7: prompt + a step
+        row[0] = 1                          # positions 0..7: prompt + steps
         toks = onp.zeros((progs.prefill_chunk_len,), onp.int32)
         toks[:P] = P1
         keys = onp.zeros((2, 2), onp.uint32)            # seed 0
@@ -534,11 +757,16 @@ def test_paged_programs_alone_serve_a_prompt(net, clean_engine):
         first = int(progs.prefill_chunk(row, toks, 0, P, keys[0], 0, P))
         tables = onp.full((2, nbps), SCRATCH_BLOCK, onp.int32)
         tables[0] = row
+        live = onp.array([True, False])
         nxt = progs.step(tables, onp.array([first, 0], onp.int32),
-                         onp.array([P, 0], onp.int32),
-                         onp.array([True, False]), keys, 1)
-        want = clean_engine.submit(P1, 2, seed=0).result(timeout=60)
-        assert [first, int(nxt[0])] == want
+                         onp.array([P, 0], onp.int32), live, keys, live, 1)
+        # the step after takes its token from the one before, where it
+        # lies: the host's entry (a wrong one here) is not looked at
+        last = progs.step(tables, onp.array([V - 1, 0], onp.int32),
+                          onp.array([P + 1, 0], onp.int32), live, keys,
+                          onp.array([False, False]), 1)
+        want = clean_engine.submit(P1, 3, seed=0).result(timeout=60)
+        assert [first, int(nxt[0]), int(last[0])] == want
     finally:
         progs.release()
     assert progs.kv_pools == ((),) * 4 and progs.kv_pool_bytes > 0
@@ -569,6 +797,10 @@ def test_a_closed_engine_holds_no_device_array(net, flavour):
                         **kw)
     progs = eng._programs
     assert eng.submit(P1, 3).result(timeout=60)
+    # a speculating engine reads each window before it hands the next over
+    ahead = 0 if flavour == "speculative" else 1
+    assert eng.varz_config()["steps_in_flight"] == ahead
+    assert eng.stats()["steps_ahead"] == ahead      # of the 2 steps it ran
     pool_k, pool_v, scale_k, scale_v = progs.kv_pools
     assert pool_k and pool_v and _device_arrays(progs)
     assert bool(scale_k and scale_v) == (flavour == "int8_kv")
