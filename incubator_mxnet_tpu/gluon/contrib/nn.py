@@ -70,6 +70,11 @@ class MoEFFN(HybridBlock):
     ``forward(x)`` with x (B, T, D) returns ``(out, aux_loss)``: add
     ``aux_weight * aux_loss`` to your loss (the Switch load-balancing
     term) or routing collapses to one expert.
+
+    A token past an expert's capacity is dropped.  The routed layer the
+    serving programs run is another one, dropless: `ops.moe_experts`
+    behind `serving.programs._layers`, for a decoder that describes it
+    (`models.generation.MoeSpec`, `models.routed_window`).
     """
 
     def __init__(self, units, hidden_size, num_experts,

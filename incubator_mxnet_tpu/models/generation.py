@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 __all__ = ["lm_generate", "lm_beam_search", "lm_score", "lm_stream",
            "nmt_translate", "bucket_length", "DecoderSpec", "SsmSpec",
-           "StackedLayers", "decoder_spec"]
+           "AttnSpec", "MoeSpec", "StackedLayers", "decoder_spec"]
 
 
 class SsmSpec(NamedTuple):
@@ -46,6 +46,31 @@ class SsmSpec(NamedTuple):
     d_state: int
     d_conv: int
     dt_rank: int
+
+
+class AttnSpec(NamedTuple):
+    """One attention layer where the layers differ: its KV heads, its
+    window (0: every earlier position; W: positions ``t-W+1 .. t``, whose
+    pages go back behind it), whether a learned logit a query head joins
+    the softmax's denominator (``sink``), and its rotary base."""
+    kv_heads: int
+    window: int
+    sink: bool
+    rope_base: float
+
+
+class MoeSpec(NamedTuple):
+    """Sizes of a routed feed-forward ("routed" in ``acts``): the router
+    scores ``experts`` of them by a sigmoid and takes the ``top_k`` largest
+    of score + selection bias; weights are the selected scores over their
+    sum; of the experts this program holds ``held`` from ``first`` on,
+    each a gated SiLU MLP of ``width``.  What the others would add is left
+    out (another chip's share)."""
+    experts: int
+    first: int
+    held: int
+    top_k: int
+    width: int
 
 
 class DecoderSpec(NamedTuple):
@@ -57,13 +82,28 @@ class DecoderSpec(NamedTuple):
     kinds        per layer, its mixer: "attn" (K/V pages, paged attention)
                  or "ssm" (conv window, selective scan, recurrent state)
     acts         per layer, its feed-forward: "gelu" | "relu" (two
-                 matrices) or "silu_gated" (down(silu(gate x) * up x))
+                 matrices), "silu_gated" (down(silu(gate x) * up x)) or
+                 "routed" (``moe``: a router and the experts held here)
     norm, eps    "layer" (gain and shift) or "rms" (gain)
     heads, kv_heads, head_dim   query heads, KV heads (a divisor), width
     positions    a sinusoidal table is added to the embedding
     embed_scale  what the embedding is multiplied by (1.0: nothing)
     ssm          `SsmSpec` of the "ssm" layers, None without any
     vocab, units, max_len       sizes the engine checks requests against
+    attn         an `AttnSpec` an attention layer, in depth order, where
+                 they differ (``()``: each is ``kv_heads`` KV heads, no
+                 window, no sink, no rotary).  With it a layer's weights
+                 are ``q``, ``k``, ``v`` (three matrices, keys ``head_dim``
+                 and values ``v_dim`` wide) and ``sink`` ((heads,), where
+                 the layer has one) in place of ``qkv``
+    v_dim        width of a value head (0: ``head_dim``)
+    rope_dim     leading lanes of every query and key head that rotate
+                 with the position (halves-rotated form), 0: none
+    value_scale  what an attention layer's output is multiplied by
+    moe          `MoeSpec` of the "routed" feed-forwards, None without any;
+                 such a layer holds ``router`` ((experts, units) matrix,
+                 (experts,) selection bias) and ``experts`` (gate, up
+                 (held, width, units), down (held, units, width))
 
     The weight pytree: ``embed``, ``pe`` (None without positions), ``ln``,
     ``head`` (the embedding itself when tied) and ``layers``, a dict a
@@ -86,10 +126,28 @@ class DecoderSpec(NamedTuple):
     vocab: int
     units: int
     max_len: int
+    attn: tuple = ()
+    v_dim: int = 0
+    rope_dim: int = 0
+    value_scale: float = 1.0
+    moe: Optional[MoeSpec] = None
 
     @property
     def recurrent(self) -> bool:
         return "ssm" in self.kinds
+
+    @property
+    def window(self) -> int:
+        """The window of the attention layers that have one (they share
+        it), 0 where every layer attends every earlier position."""
+        return max((a.window for a in self.attn), default=0)
+
+    @property
+    def carried(self) -> bool:
+        """Whether a sequence carries state a block table does not name
+        (a recurrence, a window's ring) or the programs carry counts
+        (routed experts): no prefix hit, no speculation, no int8 K/V."""
+        return self.recurrent or self.window > 0 or self.moe is not None
 
 
 @jax.tree_util.register_pytree_node_class
@@ -199,6 +257,24 @@ def _rms(x, g, eps=1e-6):
     xf = x.astype(jnp.float32)
     return (xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
             * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, pos, width, base):
+    """Rotary positions on the first ``width`` lanes of every head of
+    ``x`` (..., H, D), halves-rotated form: lane j < width/2 pairs with
+    lane j + width/2 and turns by ``pos * base**(-2j/width)``; the other
+    lanes pass.  ``pos`` has ``x``'s leading dims.  Float32 angles, the
+    result in ``x``'s dtype."""
+    half = width // 2
+    theta = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                    * (-2.0 / width * math.log(base)))
+    ang = pos.astype(jnp.float32)[..., None, None] * theta  # (..., 1, half)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:width].astype(jnp.float32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), x[..., width:]], axis=-1)
 
 
 def _norm(spec, x, p):

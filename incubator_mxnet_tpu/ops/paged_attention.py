@@ -85,7 +85,7 @@ from . import mosaic
 
 __all__ = ["paged_attention", "paged_attention_dense", "default_impl",
            "pool_shapes", "write_rows", "paged_attention_window",
-           "window_kernel_fits"]
+           "window_kernel_fits", "softmax_with_sink"]
 
 
 def default_impl(platform: Optional[str] = None) -> str:
@@ -117,8 +117,19 @@ def _dequant(pages, scales):
     return pages.astype(jnp.float32) * scales[..., None]
 
 
+def softmax_with_sink(s, sink=None):
+    """Softmax over the last axis of float32 scores ``s``; with ``sink``
+    (broadcastable to ``s`` less its last axis, kept as 1) a logit that
+    joins each row's denominator and carries no value."""
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    b = jnp.broadcast_to(sink.astype(jnp.float32), s.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([s, b], -1), axis=-1)[..., :-1]
+
+
 def paged_attention_dense(q, pool_k, pool_v, tables, pos,
-                          scale_k=None, scale_v=None):
+                          scale_k=None, scale_v=None, *, first=None,
+                          sink=None, value_scale=1.0):
     """The PR 12 dense-gather recipe, verbatim: gather the lane's pages
     into a (B, H, W, D) view, fp32 scores / sqrt(D), iota position mask
     at ``finfo(f32).min``, full-width fp32 softmax, fp32 PV — masked
@@ -127,20 +138,24 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
     dequantized after the gather (fp32), same score math.  The pool's
     layout only changes how the view is gathered: its values, and every
     operation on them, are those of the ``(num_blocks, H, bs, D)`` pool
-    this recipe was written for, bit for bit."""
+    this recipe was written for, bit for bit.  The options of
+    `paged_attention` (``first``, ``sink``, ``value_scale``, values of
+    another width than the keys) each add their one operation and, left
+    out, none."""
     B, nbps = tables.shape
     Hq, D = q.shape[1:]
     H = pool_k.shape[2] // D            # KV heads
+    Dv = pool_v.shape[2] // H
     bs = pool_k.shape[1]
     W = nbps * bs
 
-    def view(pool, scale):
-        g = pool[tables].reshape(B, nbps, bs, H, D)
+    def view(pool, scale, d):
+        g = pool[tables].reshape(B, nbps, bs, H, d)
         if scale is not None:
             g = _dequant(g, scale[tables])
-        return g.transpose(0, 3, 1, 2, 4).reshape(B, H, W, D)
+        return g.transpose(0, 3, 1, 2, 4).reshape(B, H, W, d)
 
-    gk, gv = view(pool_k, scale_k), view(pool_v, scale_v)
+    gk, gv = view(pool_k, scale_k, D), view(pool_v, scale_v, Dv)
     if Hq != H:                         # grouped: (B, Hkv, G, D) queries
         q = q.reshape(B, H, Hq // H, D)
         qk, pv, last = "bhgd,bhkd->bhgk", "bhgk,bhkd->bhgd", 3
@@ -149,15 +164,21 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
     s = jnp.einsum(qk, q, gk,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
     kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
-    s = jnp.where(kpos <= pos.reshape((B,) + (1,) * last), s,
-                  jnp.finfo(jnp.float32).min)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum(pv, p, gv, preferred_element_type=jnp.float32
-                      ).astype(q.dtype).reshape(B, Hq, D)
+    seen = kpos <= pos.reshape((B,) + (1,) * last)
+    if first is not None:
+        seen = seen & (kpos >= first.reshape((B,) + (1,) * last))
+    s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
+    p = softmax_with_sink(
+        s, None if sink is None else sink.reshape(s.shape[1:-1] + (1,)))
+    o = jnp.einsum(pv, p, gv, preferred_element_type=jnp.float32)
+    if value_scale != 1.0:
+        o = o * value_scale
+    return o.astype(q.dtype).reshape(B, Hq, Dv)
 
 
-def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
-                  bs, n, heads, kv_heads, kv_quant):
+def _paged_kernel(tables_ref, pos_ref, *rest,
+                  bs, n, heads, kv_heads, kv_quant, windowed=False,
+                  sink=False, value_scale=1.0):
     """One grid step = one (lane, run of ``n`` pages), all heads at once.
     The pools stay where they lie (HBM); the body copies a run's pages,
     each as the pool holds it — ``(bs, Hkv*D)``: a position a row, a KV
@@ -189,10 +210,22 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
     With as many KV heads as query heads the query and the output cross
     the call flattened, ``(1, H*D)``, as the pages have it; with grouped
     heads they are ``(Hq, D)`` rows (for one KV head the block-diagonal
-    layout is the query itself and nothing is masked)."""
+    layout is the query itself and nothing is masked).
+
+    Keys and values may differ in width (a KV head a run of ``dk`` lanes
+    of a key row, of ``dv`` of a value row).  ``windowed``: a third
+    prefetched scalar a lane, its first visible position, which lies in
+    the row's first entry (`paged_attention`), so a live run still holds
+    a visible slot.  ``sink``: an ``(Hq, 1)`` logit joins each row's
+    denominator at the end of the walk."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if windowed:
+        first_ref, rest = rest[0], rest[1:]
+    q_ref, rest = rest[0], rest[1:]
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     pools, rest = rest[:2], rest[2:]                    # K and V, in HBM
     if kv_quant:       # a scale block a page of the run, K's then V's
         scales, rest = (rest[:n], rest[n:2 * n]), rest[2 * n:]
@@ -204,7 +237,8 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
     t = pos_ref[b]
     run = n * bs
     group = heads // kv_heads
-    d = acc_ref.shape[-1] // kv_heads
+    dv = acc_ref.shape[-1] // kv_heads
+    d = k_buf.shape[-1] // kv_heads
 
     def run_copies(lane, first, slot, start):
         """Start, or wait for, the copies into buffer ``slot`` of the live
@@ -223,11 +257,12 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
             else:
                 pl.when((first + i) * bs <= pos_ref[lane])(page_i)
 
-    def own():
-        """(Hq, Hkv*D): the lanes of row h that are its KV head's.  Built
+    def own(d=dv):
+        """(Hq, Hkv*d): the lanes of row h that are its KV head's.  Built
         where it is used, so a skipped run pays nothing for it."""
-        row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+        shape = (heads, kv_heads * d)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         if group > 1:
             row = row // group
         return jnp.logical_and(col >= row * d, col < (row + 1) * d)
@@ -235,10 +270,11 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
     def q_block_diagonal():
         q = q_ref[0].astype(jnp.float32)
         if group == 1:                      # (1, H*D) over H rows
-            return jnp.where(own(), q, 0.0)
+            return jnp.where(own(d), q, 0.0)
         if kv_heads == 1:                   # (Hq, D): as it is
             return q
-        return jnp.where(own(), jnp.concatenate([q] * kv_heads, axis=1), 0.0)
+        return jnp.where(own(d), jnp.concatenate([q] * kv_heads, axis=1),
+                         0.0)
 
     @pl.when(j == 0)
     def _init():
@@ -290,6 +326,8 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
         s = s / math.sqrt(d)
         at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         seen = j * run + at <= t
+        if windowed:
+            seen = jnp.logical_and(seen, j * run + at >= first_ref[b])
         s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
         m_prev, l_prev = m_ref[...], l_ref[...]         # (Hq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -305,14 +343,19 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, *rest,
 
     @pl.when(j == nb - 1)
     def _emit():
+        l = l_ref[...]
+        if sink:
+            l = l + jnp.exp(sink_ref[...] - m_ref[...])
+        if value_scale != 1.0:
+            l = l * (1.0 / value_scale)
         if group == 1:
-            o = jnp.where(own(), acc_ref[...] / l_ref[...], 0.0)
+            o = jnp.where(own(), acc_ref[...] / l, 0.0)
             o_ref[0] = jnp.sum(o, axis=0, keepdims=True).astype(o_ref.dtype)
             return
-        o = acc_ref[...] / l_ref[...]
+        o = acc_ref[...] / l
         if kv_heads > 1:
             o = jnp.where(own(), o, 0.0)
-            o = sum(o[:, k * d:(k + 1) * d] for k in range(kv_heads))
+            o = sum(o[:, k * dv:(k + 1) * dv] for k in range(kv_heads))
         o_ref[0] = o.astype(o_ref.dtype)
 
 
@@ -335,7 +378,8 @@ def pages_per_step(block_size, blocks_per_seq, row_bytes) -> int:
     return max(1, min(_RUN_PAGES, blocks_per_seq, fit))
 
 
-def _paged_call(q, pools, tables, pos, interpret):
+def _paged_call(q, pools, tables, pos, interpret, first=None, sink=None,
+                value_scale=1.0):
     """Shared pallas_call: ``pools`` is (pool_k, pool_v) or, for int8
     pages, (pool_k, pool_v, scale_k, scale_v).  With ``Hq == Hkv`` query
     and output cross the call with the head axis flattened, as the pages
@@ -349,55 +393,72 @@ def _paged_call(q, pools, tables, pos, interpret):
     no copy of a pool array) and the kernel fetches a run's pages itself.
     A row that is no whole number of runs is padded with the scratch
     block (block 0, which entries no sequence has reserved already
-    name); no position lies there, so the entries are never fetched."""
+    name); no position lies there, so the entries are never fetched.
+
+    ``first`` (lanes,), ``sink`` (Hq,) and ``value_scale`` as
+    `paged_attention` has them; the value rows may be of another width
+    than the key rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
     bs, row = pools[0].shape[1:]
     Hkv = row // D
-    if row != Hkv * D or H % Hkv:
+    row_v = pools[1].shape[2]
+    Dv = row_v // Hkv
+    if row != Hkv * D or H % Hkv or row_v != Hkv * Dv:
         raise ValueError(
-            f"paged_attention: {H} query heads of {D} against a pool row "
-            f"of {row}: the pool must hold a whole number of KV heads that "
-            "divides the query's")
+            f"paged_attention: {H} query heads of {D} against pool rows "
+            f"of {row} and {row_v}: the pools must hold a whole number of "
+            "KV heads that divides the query's")
     kv_quant = len(pools) == 4
     grouped = H != Hkv
     if grouped and kv_quant:
         raise ValueError("paged_attention: the kernel has no int8 pages "
                          "for grouped heads (impl='dense' does)")
     nbps = tables.shape[1]
-    n = pages_per_step(bs, nbps, row * pools[0].dtype.itemsize)
+    n = pages_per_step(bs, nbps,
+                       (row + row_v) * pools[0].dtype.itemsize // 2)
     if nbps % n:
         tables = jnp.pad(tables, ((0, 0), (0, -nbps % n)))
+    windowed, sunk = first is not None, sink is not None
     kernel = functools.partial(_paged_kernel, bs=bs, n=n, heads=H,
-                               kv_heads=Hkv, kv_quant=kv_quant)
+                               kv_heads=Hkv, kv_quant=kv_quant,
+                               windowed=windowed, sink=sunk,
+                               value_scale=float(value_scale))
+    # index maps take the grid's indices, then the prefetched scalars
     lane = pl.BlockSpec((1, H, D) if grouped else (1, 1, H * D),
-                        lambda b, j, t, p: (b, 0, 0))
+                        lambda b, j, *_: (b, 0, 0))
+    out = pl.BlockSpec((1, H, Dv) if grouped else (1, 1, H * Dv),
+                       lambda b, j, *_: (b, 0, 0))
     scale_run = [pl.BlockSpec((1, bs, Hkv),
-                              lambda b, j, t, p, i=i: (t[b, j * n + i], 0, 0))
+                              lambda b, j, t, *_, i=i: (t[b, j * n + i], 0, 0))
                  for i in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + windowed,
         grid=(B, tables.shape[1] // n),
-        in_specs=[lane] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+        in_specs=[lane]
+        + [pl.BlockSpec((H, 1), lambda b, j, *_: (0, 0))] * sunk
+        + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
         + scale_run * (2 * kv_quant),
-        out_specs=lane,
-        scratch_shapes=[pltpu.VMEM((2, n, bs, row), pool.dtype)
+        out_specs=out,
+        scratch_shapes=[pltpu.VMEM((2, n) + pool.shape[1:], pool.dtype)
                         for pool in pools[:2]]
         + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32),
-           pltpu.VMEM((H, row), jnp.float32),
+           pltpu.VMEM((H, row_v), jnp.float32),
            pltpu.VMEM((H, 1), jnp.float32),
            pltpu.VMEM((H, 1), jnp.float32)],
     )
-    out = pl.pallas_call(
+    res = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,) + lane.block_shape[1:], q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + out.block_shape[1:], q.dtype),
         interpret=interpret,
         name="paged_attention_q8" if kv_quant else "paged_attention",
-    )(tables, pos, q if grouped else q.reshape(B, 1, H * D), *pools[:2],
-      *(scale for scale in pools[2:] for _ in range(n)))
-    return out.reshape(B, H, D)
+    )(tables, pos, *((first,) if windowed else ()),
+      q if grouped else q.reshape(B, 1, H * D),
+      *((sink.astype(jnp.float32).reshape(H, 1),) if sunk else ()),
+      *pools[:2], *(scale for scale in pools[2:] for _ in range(n)))
+    return res.reshape(B, H, Dv)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -410,6 +471,15 @@ def _paged_core_q8(q, pool_k, pool_v, scale_k, scale_v, tables, pos,
                    interpret):
     return _paged_call(q, (pool_k, pool_v, scale_k, scale_v), tables, pos,
                        interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "value_scale"))
+def _paged_core_opts(q, pool_k, pool_v, tables, pos, first, sink, interpret,
+                     value_scale):
+    """`_paged_core` with a first visible position a lane and, or, a sink
+    logit a head (either may be None) and a value scale."""
+    return _paged_call(q, (pool_k, pool_v), tables, pos, interpret, first,
+                       sink, value_scale)
 
 
 # --- a window of one sequence's queries: each page once ----------------- #
@@ -531,7 +601,8 @@ def paged_attention_window(q, pool_k, pool_v, table_row, start, *,
 
 
 def paged_attention(q, pool_k, pool_v, tables, pos, *,
-                    scale_k=None, scale_v=None,
+                    scale_k=None, scale_v=None, first=None, sink=None,
+                    value_scale: float = 1.0,
                     impl: Optional[str] = None,
                     interpret: Optional[bool] = None):
     """Single-query attention of ``q`` (B, Hq, D) against the paged KV
@@ -545,11 +616,26 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
     PR 12 gather recipe), or None for `default_impl`.  Pass
     ``scale_k/scale_v`` (num_blocks, block_size, H) fp32 when the pool
     is int8 (per-head symmetric quantization).
+
+    The value pool's rows may be of another width than the key pool's
+    (``Hkv * Dv``): the result is ``(B, Hq, Dv)``.  ``first`` (B,): the
+    first visible position of each lane (slots ``first .. pos`` are
+    attended: a sliding window); it must lie in the row's first entry, so
+    a caller with a window hands each lane's table from its first visible
+    block on, positions counted from that block's start.  ``sink`` (Hq,):
+    a logit a query head that joins the softmax's denominator and carries
+    no value.  ``value_scale`` multiplies the result.  None of the three
+    with int8 pages.
     """
     impl = impl or default_impl()
+    opts = first is not None or sink is not None or value_scale != 1.0
+    if opts and scale_k is not None:
+        raise ValueError("paged_attention: first / sink / value_scale are "
+                         "not built for int8 pages")
     if impl == "dense":
         return paged_attention_dense(q, pool_k, pool_v, tables, pos,
-                                     scale_k, scale_v)
+                                     scale_k, scale_v, first=first,
+                                     sink=sink, value_scale=value_scale)
     if impl != "pallas":
         raise ValueError(f"paged_attention impl {impl!r} (pallas|dense)")
     if interpret is None:
@@ -562,6 +648,14 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
     kv_heads = pool_k.shape[2] // q.shape[2]
     lanes, heads = mosaic.split((q.shape[0], kv_heads))
     lane, pool = P(lanes, heads), P(None, None, heads)
+    if opts:
+        core = functools.partial(_paged_core_opts, interpret=interpret,
+                                 value_scale=float(value_scale))
+        in_specs = (lane, pool, pool, P(lanes), P(lanes),
+                    None if first is None else P(lanes),
+                    None if sink is None else P(heads))
+        return mosaic.per_shard(core, in_specs, lane)(
+            q, pool_k, pool_v, tables, pos, first, sink)
     pools = (pool_k, pool_v) if scale_k is None \
         else (pool_k, pool_v, scale_k, scale_v)
     core = functools.partial(_paged_core if scale_k is None

@@ -7,6 +7,13 @@ over the `expert` mesh axis via all_to_all, processed by the local
 expert FFN (one big MXU matmul per expert), and combined back weighted
 by router probabilities.  Static shapes throughout (capacity-padded) —
 XLA-friendly, no dynamic gathers.
+
+This is capacity routing WITH dropping: a token past an expert's capacity
+loses that expert.  The served programs' routed layer is the dropless one
+(a sigmoid router over all experts, top-k by score + selection bias, every
+pair whose expert this chip holds computed by the ``moe_experts`` kernel,
+no capacity): `ops/moe_experts.py`, called from `serving/programs._layers`
+for a decoder that describes one (`models.generation.MoeSpec`).
 """
 from __future__ import annotations
 

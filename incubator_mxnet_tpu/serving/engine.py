@@ -335,7 +335,11 @@ class ServingEngine:
     `models.hybrid_ssm.HybridSSMDecoder`, whose per-lane recurrent state
     the engine keeps beside the paged K/V pool and for which speculation,
     int8 KV and prefix hits are not built; docs/serving.md, "Two kinds of
-    state").
+    state"; or one with sliding-window layers and routed experts such as
+    `models.routed_window.RoutedWindowDecoder`, whose window layers keep
+    a ring of pages a lane beside the block tables' pool and which is
+    refused the same three; docs/serving.md, "Window layers and routed
+    experts").
 
     Parameters (all static — changing them means a new engine):
 
@@ -453,9 +457,20 @@ class ServingEngine:
         self._spec = self._spec_k > 0
         self._path = self._programs.path          # "float" / "int8"
         self._kv_dtype = self._programs.kv_dtype
-        # a recurrent layer's state cannot be handed to a prefix hit
-        self._recurrent = self._programs.spec.recurrent
+        # a recurrent layer's state cannot be handed to a prefix hit, nor
+        # can a window layer's ring, which has given the prefix's pages
+        # away
+        spec = self._programs.spec
+        self._recurrent = spec.recurrent
+        self._carried = spec.carried
+        self._window = spec.window
+        self._moe = spec.moe
+        # routed experts held here, of those the router scores (0, 0: none)
+        self._share = (spec.moe.held, spec.moe.experts) \
+            if spec.moe is not None else (0, 0)
         self._state_resets = 0          # first chunks since the last record
+        # the experts' counts the last landed step brought with its tokens
+        self._expert_counts = (0, 0, 0)
         self._max_queue = int(max_queue if max_queue is not None
                               else _MAX_QUEUE)
         self._eos = int(eos_id)
@@ -472,6 +487,12 @@ class ServingEngine:
             telemetry.gauge("serving_state_bytes_per_seq",
                             labels={"engine": self._name}) \
                 .set(self.state_bytes_per_seq)
+            telemetry.gauge("serving_window_pool_bytes",
+                            labels={"engine": self._name}) \
+                .set(self.window_pool_bytes)
+            telemetry.gauge("serving_experts_held",
+                            labels={"engine": self._name}) \
+                .set(self._share[0])
             impl = self._programs.attn_impl
             for path in ("pallas", "dense"):
                 telemetry.gauge("paged_attn_kernel",
@@ -598,6 +619,14 @@ class ServingEngine:
         Frozen at construction: donation swaps the pool arrays every
         step but never their shapes."""
         return self._programs.kv_pool_bytes
+
+    @property
+    def window_pool_bytes(self) -> int:
+        """Device bytes of the window layers' rings (K and V, every window
+        layer, `window_blocks` a lane and the scratch block): whatever
+        `max_seq_len`; 0 for a decoder without window layers.  Not part of
+        `kv_pool_bytes`, which is the pool the block tables name."""
+        return self._programs.window_pool_bytes
 
     @property
     def state_bytes(self) -> int:
@@ -829,9 +858,18 @@ class ServingEngine:
             # decode steps the scheduler hands over before it reads their
             # tokens (a speculating engine reads each window first)
             "steps_in_flight": 0 if self._spec else 1,
-            # a recurrent layer's state cannot be handed to a prefix hit
-            "prefix_cache": not self._recurrent,
+            # a recurrent layer's state cannot be handed to a prefix hit,
+            # nor a window layer's ring
+            "prefix_cache": not self._carried,
             "kv_pool_bytes": self.kv_pool_bytes,
+            "window_pool_bytes": self.window_pool_bytes,
+            # 0: every attention layer attends every earlier position
+            "attention_window": self._window,
+            "window_blocks_per_lane": self._programs.window_blocks,
+            # routed experts: those this engine's net holds, of those its
+            # router scores (0, 0: no routed layer)
+            "experts_held": self._share[0],
+            "experts_published": self._share[1],
             "state_bytes": self.state_bytes,
             "state_bytes_per_seq": self.state_bytes_per_seq,
             "speculate": spec,
@@ -977,6 +1015,7 @@ class ServingEngine:
                 "blocks_free": self._pool.num_free,
                 "blocks_total": self._num_blocks - 1,
                 "kv_pool_bytes": self.kv_pool_bytes,
+                "window_pool_bytes": self.window_pool_bytes,
                 "state_bytes": self.state_bytes,
                 "prefix_cache": {
                     "hits": self._stats["prefix_hits"],
@@ -1275,9 +1314,10 @@ class ServingEngine:
             # prefix-cache lookup + COW bind: bound blocks are never
             # written by this request (chunks start at cached_len,
             # decode writes at >= P), so sharing needs no copy
-            # (a decoder with recurrent layers has no state to hand a hit:
-            # the lookup is a miss, counted as one — docs/serving.md)
-            hits, cached_len = ([], 0) if self._recurrent \
+            # (a decoder with recurrent or window layers has no state to
+            # hand a hit: the lookup is a miss, counted as one —
+            # docs/serving.md)
+            hits, cached_len = ([], 0) if self._carried \
                 else self._pool.lookup(req.prompt)
             self._pool.bind(hits)
             fresh = self._pool.alloc(needed - len(hits))
@@ -1411,7 +1451,7 @@ class ServingEngine:
             # publish the prompt's full blocks into the prefix
             # cache now their content is final (COW: nothing
             # writes positions < P past this point)
-            if not self._recurrent:
+            if not self._carried:
                 self._pool.register(job.prompt, job.row)
             if telemetry.enabled():
                 telemetry.counter("serving_admitted_total").inc()
@@ -1459,12 +1499,31 @@ class ServingEngine:
         reserved, and positions whose K/V the pool holds (`_pos` of a
         decoding lane, the step in flight's position with it, `next_pos`
         of one still prefilling), both summed over the occupied lanes; and
-        how many lanes hold recurrent state."""
+        how many lanes hold recurrent state.  With window layers, beside
+        them the blocks of the lanes' rings that hold a visible position
+        (the pool fields then speak of the full layers' pool alone)."""
         slots = self._slots
         # prefill jobs whose lane is still theirs
         jobs = [j for j in self._prefill_jobs
                 if slots[j.lane] is not None and slots[j.lane].req is j.req]
+        window = {}
+        if self._window:
+            # blocks of a lane's ring that hold a visible position: those
+            # of the last `window - 1` written positions (the next query
+            # sees them and itself); never more than the ring has
+            bs, W1 = self._bs, self._window - 1
+            wrote = np.concatenate([
+                self._pos[self._active],
+                np.fromiter((j.next_pos for j in jobs), np.int32,
+                            len(jobs))])
+            wrote = wrote[wrote > 0]
+            window = {
+                "window_blocks_held": int(np.sum(
+                    (wrote - 1) // bs - np.maximum(wrote - W1, 0) // bs + 1)),
+                "window_blocks_total":
+                    self._B * self._programs.window_blocks}
         return {
+            **window,
             "blocks_reserved": sum(len(s.blocks) for s in slots
                                    if s is not None),
             "blocks_total": self._num_blocks - 1,
@@ -1522,6 +1581,9 @@ class ServingEngine:
         with self._prof.phase("device_step"):
             nxt = np.asarray(nxt)           # sync: tokens are consumed now
             dt = time.perf_counter() - t0
+        if self._moe is not None:
+            # behind the lanes' tokens: pairs, tokens routed, busiest
+            self._expert_counts = tuple(int(c) for c in nxt[self._B:])
         now = time.monotonic()
 
         def deliver(mark):
@@ -1569,6 +1631,9 @@ class ServingEngine:
             pool_use = self._pool_use_locked()
             pool_use["state_resets"], self._state_resets = \
                 self._state_resets, 0
+            if self._moe is not None:
+                (pool_use["expert_pairs"], pool_use["expert_tokens"],
+                 pool_use["expert_busiest"]) = self._expert_counts
         # close the ledger OUTSIDE the engine lock (it takes its own
         # leaf lock + histogram locks; never nested under self._work)
         prof.end_step(rids=[req.rid for _, req in live],

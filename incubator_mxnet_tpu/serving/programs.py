@@ -82,8 +82,9 @@ from ..contrib.quantization import quantize_kv
 from ..models import generation as G
 from ..ops.paged_attention import (default_impl, paged_attention,
                                    paged_attention_window, pages_per_step,
-                                   pool_shapes, window_kernel_fits,
-                                   write_rows)
+                                   pool_shapes, softmax_with_sink,
+                                   window_kernel_fits, write_rows)
+from ..ops.moe_experts import routed_experts
 from ..ops.selective_scan import selective_scan
 
 __all__ = ["PagedPrograms"]
@@ -233,8 +234,68 @@ def _ssm_mixer(spec, lp, x, conv, state, row, fresh, ok, impl):
     return out, conv, state
 
 
+def ring_blocks(window, block_size):
+    """Blocks a lane's ring holds for a window of ``window`` positions:
+    those the visible positions ``t-window+1 .. t`` can lie in, whatever
+    ``t``.  The block written at ``t`` then takes the place of one that
+    lies wholly behind the window."""
+    return (window - 2) // block_size + 2
+
+
+def _ring_block(lane, blk, n):
+    """The pool block that holds block ``blk`` of lane ``lane``'s
+    sequence in a window layer: a ring of ``n`` a lane behind the scratch
+    block, so a block behind the window gives its place to the one being
+    written (docs/serving.md, "Window layers")."""
+    return 1 + lane * n + blk % n
+
+
+def _route(moe, x, router):
+    """The router of a routed feed-forward over tokens ``x`` (N, C):
+    sigmoid scores over all experts in float32, the ``top_k`` largest of
+    score + selection bias, the selected scores over their sum.  Returns
+    ``(idx (N, K) int32, weights (N, K) float32)``."""
+    w, bias = router
+    g = jax.nn.sigmoid(jax.lax.dot_general(
+        x, w.astype(x.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32))
+    _, idx = jax.lax.top_k(g + bias.astype(jnp.float32), moe.top_k)
+    sel = jnp.take_along_axis(g, idx, axis=1)
+    return idx.astype(jnp.int32), sel / jnp.sum(sel, axis=1, keepdims=True)
+
+
+def _band_attention(q, k, v, start, window, sink, value_scale):
+    """Attention of a chunk's queries ``q`` (T, Hq, D), at positions
+    ``start .. start+T-1``, in a window layer: over ``k``, ``v`` (window-1
+    + T, Hkv, .), the positions ``start-window+1 .. start+T-1`` (the
+    window's reach before the chunk, then the chunk's own), each query
+    seeing the ``window`` positions up to its own that exist (>= 0).  A
+    block of queries at a time against the keys it can see; float32
+    scores and softmax, the sink logit a head in the denominator."""
+    T, Hq, D = q.shape
+    Hkv = k.shape[1]
+    G, W1 = Hq // Hkv, window - 1
+    bq = 128 if T % 128 == 0 else T
+    nb = T // bq
+    at = jnp.arange(nb)[:, None] * bq + jnp.arange(bq + W1)[None, :]
+    kb, vb = k[at], v[at]                       # (nb, bq+W1, Hkv, .)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(nb, bq, Hkv, G, D), kb,
+                   preferred_element_type=jnp.float32) / np.sqrt(D)
+    # query i of a block and key j of its keys lie i + W1 - j apart
+    gap = jnp.arange(bq)[:, None] + W1 - jnp.arange(bq + W1)[None, :]
+    seen = (gap >= 0) & (gap <= W1)                         # (bq, bq+W1)
+    exists = start - W1 + at >= 0                           # (nb, bq+W1)
+    s = jnp.where(seen[None, None, None] & exists[:, None, None, None], s,
+                  jnp.finfo(jnp.float32).min)
+    p = softmax_with_sink(
+        s, None if sink is None else sink.reshape(Hkv, G, 1, 1))
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, vb,
+                   preferred_element_type=jnp.float32) * value_scale
+    return o.astype(q.dtype).reshape(T, Hq, -1)
+
+
 def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
-            wblk, off, attend, ssm=None):
+            wblk, off, attend, ssm=None, pos=None, ok=None, impl=None):
     """The decoder stack of every serving program, a layer at a time by
     the decoder's description: an "attn" layer is qkv, the K/V write
     into the lanes' pages at ``(wblk, off)`` (quantized first on an int8
@@ -249,8 +310,22 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
     ``layer<i>/ssm_scan``, ``layer<i>/state_write``, ``layer<i>/ffn``.
     Returns ``(h, new_k, new_v, new_sk, new_sv, new_rec)``, the scale
     tuples empty on a float pool and ``new_rec`` ``()`` without ssm
-    layers."""
+    layers.
+
+    Where the attention layers differ (``spec.attn``) a layer is three
+    projections, rotary positions on queries and keys at ``pos``
+    (``layer<i>/rope``), and ``attend(a, sink, q, k, v, pool_k, pool_v) ->
+    (out, pool_k, pool_v)``, which writes and attends by the layer's
+    `AttnSpec` ``a``: the full layers through the lanes' block tables,
+    the window layers through their rings.  A "routed" feed-forward is the
+    router (``layer<i>/router``) and the experts held here
+    (``layer<i>/experts``: ``impl`` "pallas" is the ``moe_experts``
+    kernel) over the tokens that are ``ok``; ``rec`` is then ``(counts,)``,
+    three int32 the programs carry: pairs computed, tokens routed (a
+    layer each), the most pairs one expert of one layer took, since the
+    last decode step reported them."""
     new_k, new_v, new_sk, new_sv, new_st, new_cv = [], [], [], [], [], []
+    routed = []                         # pairs by held expert, a layer
     for li, (lp, kind, act) in enumerate(zip(params["layers"], spec.kinds,
                                              spec.acts)):
         with jax.named_scope(f"layer{li}"):
@@ -261,6 +336,21 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
                 h = h + a
                 new_st.append(st)
                 new_cv.append(cv)
+            elif spec.attn:
+                a = spec.attn[len(new_k)]
+                lead = h.shape[:-1]
+                q = G._dense(x, *lp["q"]).reshape(lead + (spec.heads, -1))
+                k = G._dense(x, *lp["k"]).reshape(lead + (a.kv_heads, -1))
+                v = G._dense(x, *lp["v"]).reshape(lead + (a.kv_heads, -1))
+                if spec.rope_dim and a.rope_base:
+                    with jax.named_scope("rope"):
+                        q = G._rope(q, pos, spec.rope_dim, a.rope_base)
+                        k = G._rope(k, pos, spec.rope_dim, a.rope_base)
+                o, pk, pv = attend(a, lp.get("sink"), q, k, v,
+                                   pool_k[len(new_k)], pool_v[len(new_k)])
+                h = h + G._dense(o.reshape(lead + (-1,)), *lp["proj"])
+                new_k.append(pk)
+                new_v.append(pv)
             else:
                 ai = len(new_k)
                 q, k, v = G._qkv_heads(G._dense(x, *lp["qkv"]), spec.heads,
@@ -285,11 +375,61 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
                                  *lp["proj"])
                 new_k.append(pk)
                 new_v.append(pv)
+            if act == "routed":
+                x = G._norm(spec, h, lp["ln2"])
+                with jax.named_scope("router"):
+                    idx, wts = _route(spec.moe, x, lp["router"])
+                with jax.named_scope("experts"):
+                    y, counts = routed_experts(
+                        x, idx, wts, ok, *lp["experts"],
+                        first=spec.moe.first, experts=spec.moe.experts,
+                        impl="pallas" if impl == "pallas" else "xla")
+                h = h + y
+                routed.append(counts)
+                continue
             with jax.named_scope("ffn"):
                 h = h + G._ffn_fwd(G._norm(spec, h, lp["ln2"]), lp, act)
     new_rec = (tuple(new_st), tuple(new_cv)) if new_st else ()
+    if routed:
+        counts = jnp.stack(routed)                      # (layers, held)
+        new_rec = (jnp.stack([
+            rec[0][0] + jnp.sum(counts),
+            rec[0][1] + len(routed) * jnp.sum(ok.astype(jnp.int32)),
+            jnp.maximum(rec[0][2], jnp.max(counts))]).astype(jnp.int32),)
     return (h, tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv),
             new_rec)
+
+
+def _step_attend(spec, bs, attn_impl, tables, pos, ok, wblk, off):
+    """`_layers`' ``attend`` of a decode step where the attention layers
+    differ: write the token's K/V, then the single-query kernel.  A full
+    layer goes through the lanes' block tables as ever.  A window layer's
+    pages are a ring a lane (`_ring_block`): its table is reckoned here
+    from the lane and its position, from the first visible block on, with
+    positions counted from that block's start, which is the form
+    `paged_attention` takes a first visible position in."""
+    W = spec.window
+    if W:
+        n = ring_blocks(W, bs)
+        lane = jnp.arange(pos.shape[0], dtype=jnp.int32)
+        lo = jnp.maximum(pos - (W - 1), 0)
+        base = lo // bs
+        tables_w = _ring_block(
+            lane[:, None], base[:, None] + jnp.arange(n, dtype=jnp.int32), n)
+        pos_w, first_w = pos - base * bs, lo - base * bs
+        wblk_w = jnp.where(ok, _ring_block(lane, pos // bs, n), jnp.int32(0))
+
+    def attend(a, sink, q, k, v, pk, pv):
+        tb, wb, p, first = (tables_w, wblk_w, pos_w, first_w) if a.window \
+            else (tables, wblk, pos, None)
+        with jax.named_scope("kv_write"):
+            pk, pv = write_rows(pk, wb, off, k), write_rows(pv, wb, off, v)
+        with jax.named_scope("paged_attn"):
+            o = paged_attention(q, pk, pv, tb, p, first=first, sink=sink,
+                                value_scale=spec.value_scale, impl=attn_impl)
+        return o, pk, pv
+
+    return attend
 
 
 def _token_forward(params, spec, bs, kv8, attn_impl,
@@ -330,13 +470,17 @@ def _token_forward(params, spec, bs, kv8, attn_impl,
                                     None, None, ok[:, None], attn_impl)
         return a[:, 0], conv, state
 
+    if spec.attn:
+        attend = _step_attend(spec, bs, attn_impl, tables, pos, ok, wblk,
+                              off)
+    else:
+        def attend(q, pk, pv, sk, sv):
+            return paged_attention(q, pk, pv, tables, pos, scale_k=sk,
+                                   scale_v=sv, impl=attn_impl)  # (B, H, D)
+
     h, new_k, new_v, new_sk, new_sv, new_rec = _layers(
         spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
-        wblk, off,
-        lambda q, pk, pv, sk, sv: paged_attention(
-            q, pk, pv, tables, pos, scale_k=sk, scale_v=sv,
-            impl=attn_impl),                                # (B, H, D)
-        ssm)
+        wblk, off, attend, ssm, pos=pos_c, ok=ok, impl=attn_impl)
     with jax.named_scope("head"):
         logits = G._logits_of(params, h, spec)              # (B, V)
     return new_k, new_v, new_sk, new_sv, new_rec, logits
@@ -425,7 +569,9 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
                        (d_conv-1, B, d_inner), a row a lane; ``()`` for a
                        decoder without ssm layers
       prev             (B,) int32 — the step before's ``next_tokens``, the
-                       device array they still are (not donated)
+                       device array they still are (not donated); with
+                       routed layers three counts follow the tokens, in
+                       and out (`_layers`), and ``rec`` is their carry
       tables           (B, blocks_per_seq) int32 block ids per lane
       toks             (B,) int32 — the host's token of a lane
       fresh            (B,) bool — lanes whose input token is the host's
@@ -449,16 +595,70 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
 
     def serving_step(pool_k, pool_v, scale_k, scale_v, rec, prev, tables,
                      toks, fresh, pos, active, keys, params):
+        if spec.moe is not None:
+            prev = prev[:toks.shape[0]]         # its counts follow it
         toks = jnp.where(fresh, toks, prev)
         new_k, new_v, new_sk, new_sv, new_rec, logits = _token_forward(
             params, spec, bs, kv8, attn_impl,
             pool_k, pool_v, scale_k, scale_v, rec, tables, toks, pos, active)
         with jax.named_scope("pick"):
             nxt = jax.vmap(pick)(logits, pos, keys)
+        if spec.moe is not None:
+            # the experts' counts since the step before (this step's and
+            # the chunk's before it) leave with the tokens, and the carry
+            # starts again from zero
+            nxt = jnp.concatenate([nxt, new_rec[0]])
+            new_rec = (jnp.zeros_like(new_rec[0]),)
         return new_k, new_v, new_sk, new_sv, new_rec, nxt
 
     serving_step.__name__ = name
     return serving_step
+
+
+def _chunk_attend(spec, bs, attn_impl, tables, posc, ok, wblk, off, start,
+                  valid_len, lane):
+    """`_layers`' ``attend`` of a prefill chunk where the attention layers
+    differ.  A full layer writes the chunk's K/V into the sequence's pages
+    and attends them as lanes of the single-query kernel.  A window layer
+    attends densely (`_band_attention`): its own keys, and before them the
+    window's reach behind the chunk, read from the lane's ring as the
+    chunk before left it; then it writes into the ring only what a later
+    query can still see, the ``window - 1`` positions before the chunk's
+    end.  So a chunk needs no pages of its own in a window layer, however
+    long it is (docs/serving.md, "Window layers")."""
+    W = spec.window
+    if W:
+        n, W1 = ring_blocks(W, bs), W - 1
+        before = start - W1 + jnp.arange(W1, dtype=jnp.int32)
+        b_blk = _ring_block(lane, jnp.maximum(before, 0) // bs, n)
+        b_off = jnp.maximum(before, 0) % bs
+        end = jnp.minimum(start + posc.shape[0], valid_len)
+        tail = ok & (posc >= end - W1)
+        wblk_w = jnp.where(tail, _ring_block(lane, posc // bs, n),
+                           jnp.int32(0))
+
+    def attend(a, sink, q, k, v, pk, pv):
+        if not a.window:
+            with jax.named_scope("kv_write"):
+                pk, pv = write_rows(pk, wblk, off, k), \
+                    write_rows(pv, wblk, off, v)
+            with jax.named_scope("paged_attn"):
+                o = paged_attention(q, pk, pv, tables, posc,
+                                    value_scale=spec.value_scale,
+                                    impl=attn_impl)
+            return o, pk, pv
+        with jax.named_scope("paged_attn"):
+            kb = pk[b_blk, b_off].reshape((W1,) + k.shape[1:])
+            vb = pv[b_blk, b_off].reshape((W1,) + v.shape[1:])
+            o = _band_attention(q, jnp.concatenate([kb, k]),
+                                jnp.concatenate([vb, v]), start, W, sink,
+                                spec.value_scale)
+        with jax.named_scope("kv_write"):
+            pk, pv = write_rows(pk, wblk_w, off, k), \
+                write_rows(pv, wblk_w, off, v)
+        return o, pk, pv
+
+    return attend
 
 
 def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
@@ -506,8 +706,8 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
     # where the kernel has the sizes for it, the chunk's queries walk the
     # sequence's pages once together and not once each (a KV head's lanes
     # a block of their own: not with 64-wide heads side by side)
-    window = attn_impl == "pallas" and not kv8 and window_kernel_fits(
-        CH, spec.heads, spec.kv_heads, spec.head_dim)
+    window = attn_impl == "pallas" and not kv8 and not spec.attn \
+        and window_kernel_fits(CH, spec.heads, spec.kv_heads, spec.head_dim)
 
     def serving_prefill_chunk(pool_k, pool_v, scale_k, scale_v, rec,
                               table_row, toks, start, valid_len, key, lane,
@@ -528,7 +728,10 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
                                         attn_impl)
             return a[0], conv, state
 
-        if window:
+        if spec.attn:
+            attend = _chunk_attend(spec, bs, attn_impl, tables, posc, ok,
+                                   wblk, off, start, valid_len, lane)
+        elif window:
             def attend(q, pk, pv, sk, sv):
                 return paged_attention_window(q, pk, pv, table_row, start)
         else:
@@ -538,7 +741,8 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
 
         h, new_k, new_v, new_sk, new_sv, new_rec = _layers(
             spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
-            wblk, off, attend, ssm)                            # (CH,H,D)
+            wblk, off, attend, ssm, pos=posc, ok=ok,
+            impl=attn_impl)                                    # (CH,H,D)
         with jax.named_scope("head"):
             logits = G._logits_of(params, h, spec)             # (CH, V)
         with jax.named_scope("pick"):
@@ -799,17 +1003,24 @@ class PagedPrograms:
                 f"got {attn_impl!r}")
         self._net = net
         self._spec = G.decoder_spec(net)
-        if self._spec.recurrent:
+        if self._spec.carried:
             # a recurrence cannot be rolled back to a rejected position,
-            # and its state is not a page a scale could sit beside
+            # nor can a ring that has given a block's place away, and
+            # neither state is a page a scale could sit beside; the
+            # speculative programs carry no experts' counts
+            what = "recurrent (ssm) layers" if self._spec.recurrent \
+                else "window layers or routed experts"
             if int(speculate_k) > 0 or draft_net is not None:
                 raise ValueError(
                     "speculative decoding (speculate_k / draft_net) is not "
-                    "built for a decoder with recurrent (ssm) layers")
+                    f"built for a decoder with {what}")
             if kv_dtype == "int8":
                 raise ValueError(
                     "kv_dtype='int8' is not built for a decoder with "
-                    "recurrent (ssm) layers")
+                    f"{what}")
+            if self._spec.recurrent and self._spec.moe is not None:
+                raise ValueError("a decoder with both recurrent (ssm) "
+                                 "layers and routed experts is not built")
         self._B = int(max_batch)
         self._bs = int(block_size)
         msl = int(max_seq_len if max_seq_len is not None
@@ -844,6 +1055,8 @@ class PagedPrograms:
                      self._temperature, self._top_k, self.path,
                      self._kv_dtype, self._impl)
         self._label = self.path + ("_ssm" if self._spec.recurrent else "") \
+            + ("_win" if self._spec.window else "") \
+            + ("_moe" if self._spec.moe is not None else "") \
             + sfx + ("_pallas" if self._impl_forced
                      and self._impl == "pallas" else "")
         self._params = None
@@ -911,9 +1124,10 @@ class PagedPrograms:
             self._draft_qc = G._quant_config(draft_net, None)
             self._draft_net = draft_net
             self._draft_spec = dspec = G.decoder_spec(draft_net)
-            if dspec.recurrent:
-                raise ValueError("a draft_net with recurrent (ssm) layers "
-                                 "cannot be rolled back")
+            if dspec.carried:
+                raise ValueError("a draft_net with recurrent (ssm) layers, "
+                                 "window layers or routed experts cannot "
+                                 "be rolled back")
             if dspec.vocab != self._spec.vocab:
                 raise ValueError(
                     f"draft_net vocab {dspec.vocab} != target vocab "
@@ -973,13 +1187,36 @@ class PagedPrograms:
             n_ssm = spec.kinds.count("ssm")
             rec = (each(n_ssm, (B, Ds, Di), jnp.float32),
                    each(n_ssm, (K - 1, B, Di), emb.dtype))
+        elif spec.moe is not None:
+            rec = (jnp.zeros((3,), jnp.int32),)     # the experts' counts
         n_sc = L if kv8 else 0
-        self._kv = [each(L, page, dt), each(L, page, dt),
+        # a window layer's pages: a ring of `window_blocks` a lane behind
+        # the scratch block, whatever `max_seq_len` (0: no window layer)
+        self.window_blocks = ring_blocks(spec.window, bs) \
+            if spec.window else 0
+        if spec.attn:
+            dv = spec.v_dim or spec.head_dim
+            nb_w = B * self.window_blocks + 1
+            pools_k = tuple(jnp.zeros(
+                (nb_w if a.window else self._num_blocks, bs,
+                 a.kv_heads * spec.head_dim), dt) for a in spec.attn)
+            pools_v = tuple(jnp.zeros(
+                (nb_w if a.window else self._num_blocks, bs,
+                 a.kv_heads * dv), dt) for a in spec.attn)
+            full = [i for i, a in enumerate(spec.attn) if not a.window]
+            if full:    # the kernel's rule takes the mean of the two rows
+                page = page[:2] + ((pools_k[full[0]].shape[2]
+                                    + pools_v[full[0]].shape[2]) // 2,)
+        else:
+            pools_k, pools_v = each(L, page, dt), each(L, page, dt)
+        self._kv = [pools_k, pools_v,
                     each(n_sc, scales, jnp.float32, jnp.ones),
                     each(n_sc, scales, jnp.float32, jnp.ones), rec]
         # the last step's next tokens, which the next step reads where
-        # they lie (before any step: no lane takes its token from them)
-        self._last = jnp.zeros((B,), jnp.int32)
+        # they lie (before any step: no lane takes its token from them);
+        # behind them the experts' counts of a decoder with routed layers
+        self._last = jnp.zeros((B + (3 if spec.moe is not None else 0),),
+                               jnp.int32)
         self._draft_kv = [(), ()]
         if self._spec_k:
             dspec = self._draft_spec
@@ -997,9 +1234,15 @@ class PagedPrograms:
         # the footprints are STATIC (donation replaces arrays, never
         # shapes): frozen here so readers on other threads never touch
         # the live tuples the scheduler thread is rewriting.  Draft pages
-        # count: they are resident HBM spent per token position.
-        self.kv_pool_bytes = _nbytes(self._kv[:4] + self._draft_kv)
-        self.state_bytes = _nbytes(rec)
+        # count: they are resident HBM spent per token position.  The
+        # window layers' rings are counted apart: their bytes do not grow
+        # with a sequence's length.
+        windowed = [i for i, a in enumerate(spec.attn) if a.window]
+        self.window_pool_bytes = _nbytes(
+            [(pools_k[i], pools_v[i]) for i in windowed])
+        self.kv_pool_bytes = _nbytes(self._kv[:4] + self._draft_kv) \
+            - self.window_pool_bytes
+        self.state_bytes = _nbytes(rec) if spec.recurrent else 0
 
     # -- static description (any thread) ------------------------------- #
     @property

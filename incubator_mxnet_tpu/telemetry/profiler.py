@@ -254,17 +254,31 @@ class Iteration:
     before; both 0 otherwise.  ``ahead`` is 1 where the step this record
     commits was handed to the device while the step before it was still
     unread (the decode loop runs one step ahead of its reads), else 0.
+    For a decoder with routed experts, counted on the device and read
+    with the step's tokens: ``expert_pairs`` the token-expert pairs the
+    experts held here computed in this iteration's step and chunk (all
+    routed layers), ``expert_tokens`` the tokens that went through a
+    router (a routed layer each), ``expert_busiest`` the most pairs one
+    held expert of one layer took in one program.  For one with window
+    layers ``window_blocks_held`` is the blocks of the lanes' rings that
+    hold a visible position at the commit, of ``window_blocks_total``;
+    the three pool fields above then speak of the full layers' pool.  All
+    five 0 otherwise.
     """
 
     __slots__ = ("engine", "step", "t0", "t1", "causes", "occupancy",
                  "queue_depth", "blocks_reserved", "blocks_total",
                  "block_size", "positions_written", "chunks",
-                 "state_rows", "state_resets", "ahead")
+                 "state_rows", "state_resets", "ahead", "expert_pairs",
+                 "expert_tokens", "expert_busiest", "window_blocks_held",
+                 "window_blocks_total")
 
     def __init__(self, engine, step, t0, t1, causes, occupancy=0,
                  queue_depth=0, blocks_reserved=0, blocks_total=0,
                  block_size=0, positions_written=0, chunks=(),
-                 state_rows=0, state_resets=0, ahead=0):
+                 state_rows=0, state_resets=0, ahead=0, expert_pairs=0,
+                 expert_tokens=0, expert_busiest=0, window_blocks_held=0,
+                 window_blocks_total=0):
         self.engine = engine
         self.step = step
         self.t0 = t0
@@ -280,6 +294,11 @@ class Iteration:
         self.state_rows = state_rows
         self.state_resets = state_resets
         self.ahead = ahead
+        self.expert_pairs = expert_pairs
+        self.expert_tokens = expert_tokens
+        self.expert_busiest = expert_busiest
+        self.window_blocks_held = window_blocks_held
+        self.window_blocks_total = window_blocks_total
 
     def as_dict(self) -> dict:
         d = {k: getattr(self, k) for k in self.__slots__}
@@ -484,7 +503,10 @@ class EngineProfiler:
                  blocks_reserved: int = 0, blocks_total: int = 0,
                  block_size: int = 0,
                  positions_written: int = 0, state_rows: int = 0,
-                 state_resets: int = 0, ahead: int = 0) -> Optional[dict]:
+                 state_resets: int = 0, ahead: int = 0,
+                 expert_pairs: int = 0, expert_tokens: int = 0,
+                 expert_busiest: int = 0, window_blocks_held: int = 0,
+                 window_blocks_total: int = 0) -> Optional[dict]:
         """Close the iteration at a decode-step commit: compute the
         wall since the previous commit, carve gc + residue, push the
         record, feed histograms, judge the hiccup threshold.  Returns
@@ -515,7 +537,9 @@ class EngineProfiler:
         rec = Iteration(self.name, step, t0, now, tuple(acc), occupancy,
                         queue_depth, blocks_reserved, blocks_total,
                         block_size, positions_written, tuple(chunks),
-                        state_rows, state_resets, ahead)
+                        state_rows, state_resets, ahead, expert_pairs,
+                        expert_tokens, expert_busiest, window_blocks_held,
+                        window_blocks_total)
         self._ring.push(rec)
         self._totals = tuple(map(operator.add, self._totals, acc))
         self._total_wall += wall

@@ -36,6 +36,7 @@ _paged = importlib.import_module("incubator_mxnet_tpu.ops.paged_attention")
 _xent = importlib.import_module("incubator_mxnet_tpu.ops.xent_kernel")
 _dropout = importlib.import_module("incubator_mxnet_tpu.ops.dropout_kernel")
 _scan = importlib.import_module("incubator_mxnet_tpu.ops.selective_scan")
+_moe = importlib.import_module("incubator_mxnet_tpu.ops.moe_experts")
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -128,6 +129,47 @@ def _selective_scan(N, T, nb):
     return lower
 
 
+# the routed cell's widths (mimo-v2-flash.serve-mixed): 64 query heads, keys
+# 192 and values 128 wide, on 4 KV heads through block tables (full layers)
+# and on 8 through a ring of 3 blocks a lane (window layers, a sink logit a
+# head), blocks of 64, 96 lanes of 9,216 positions, chunks of 512; 16
+# experts of width 2,048 held, top-8 of 256
+_M_B, _M_HQ, _M_DK, _M_DV, _M_BS, _M_NBPS, _M_CHUNK = 96, 64, 192, 128, 64, \
+    144, 512
+_M_NB, _M_RING = _M_B * _M_NBPS + 1, 3
+_M_NBW = _M_B * _M_RING + 1
+_M_C, _M_FE, _M_E, _M_K, _M_EALL = 4096, 2048, 16, 8, 256
+
+
+def _paged_wide(lanes, kv_heads, nbps, nb, window):
+    """Keys wider than values; with a window a first visible position a
+    lane and a sink logit a head; the value scale."""
+    def lower(S):
+        return _paged._paged_core_opts.lower(
+            S((lanes, _M_HQ, _M_DK), bf16),
+            S((nb, _M_BS, kv_heads * _M_DK), bf16),
+            S((nb, _M_BS, kv_heads * _M_DV), bf16),
+            S((lanes, nbps), i32), S((lanes,), i32),
+            S((lanes,), i32) if window else None,
+            S((_M_HQ,), f32) if window else None,
+            interpret=False, value_scale=0.707)
+    return lower
+
+
+def _moe_experts(tokens):
+    """The experts' kernel over the rows `plan` lays out for ``tokens``
+    tokens (a step's 96 lanes, a chunk's 512 positions)."""
+    def lower(S):
+        tm = _moe.tile_rows(tokens, _M_K, _M_EALL)
+        tiles = -(-tokens * _M_K // tm) + _M_E
+        return _moe._experts_core.lower(
+            S((tiles * tm, _M_C), bf16), S((tiles * tm, 1), f32),
+            S((tiles,), i32), S((tiles,), i32),
+            S((_M_E, _M_FE, _M_C), bf16), S((_M_E, _M_FE, _M_C), bf16),
+            S((_M_E, _M_C, _M_FE), bf16), tm=tm, interpret=False)
+    return lower
+
+
 def _flash_fwd(T, bk):
     def lower(S):
         x = S((2, 16, T, 64), bf16)
@@ -172,6 +214,15 @@ _KERNELS = {
                                   ["paged_attention_q8"]),
     "paged_grouped_step_20q_1kv": (_paged_grouped, ["paged_attention"]),
     "paged_window_chunk_20q_1kv": (_paged_window, ["paged_attention_window"]),
+    "paged_step_64q_4kv_k192_v128": (
+        _paged_wide(_M_B, 4, _M_NBPS, _M_NB, False), ["paged_attention"]),
+    "paged_step_64q_8kv_ring_window_sink": (
+        _paged_wide(_M_B, 8, _M_RING, _M_NBW, True), ["paged_attention"]),
+    "paged_chunk_lanes_64q_4kv_k192_v128": (
+        _paged_wide(_M_CHUNK, 4, _M_NBPS, _M_NB, False), ["paged_attention"]),
+    "moe_experts_step_96x8_of_256": (_moe_experts(_M_B), ["moe_experts"]),
+    "moe_experts_chunk_512x8_of_256": (_moe_experts(_M_CHUNK),
+                                       ["moe_experts"]),
     "selective_scan_chunk_1x256": (_selective_scan(1, _S_CHUNK, 1),
                                    ["selective_scan"]),
     "selective_scan_step_64x1": (_selective_scan(_G_B, 1, 8),
@@ -409,6 +460,108 @@ def test_hybrid_program_leaves_both_states_where_they_lie(
     assert not re.findall(rf"= bf16\[(?:{rows})\]\S* \w[\w-]*\(", entry)
 
 
+# --- the routed cell's programs: two kinds of pool, the experts' kernel -- #
+@pytest.fixture(scope="module")
+def routed_weights():
+    """(weight shapes, spec) of the routed cell's seven layers at its
+    published widths, as `PagedPrograms` hands them over: a list, a dict a
+    layer.  Three layers are built (full + dense, window + routed, full +
+    routed: every form the seven take) and their shapes laid out in the
+    cell's order."""
+    import json
+
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models import generation as G
+    from incubator_mxnet_tpu.models.routed_window import RoutedWindowDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "mimo-v2-flash.json")) as f:
+        cfg = json.load(f)
+    kw = {k: cfg[v] for k, v in cfg["program"]["kwargs"].items()}
+    kw.update(cfg["program"]["constants"], num_hidden_layers=3,
+              hybrid_layer_pattern=[0, 1, 0], moe_layer_freq=[0, 1, 1],
+              max_position_embeddings=_M_NBPS * _M_BS)
+    net = RoutedWindowDecoder(**kw)
+    net.initialize(mx.init.Zero())
+    three = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        G._gather_params(net, _M_NBPS * _M_BS))
+    spec3 = G.decoder_spec(net)
+    order = [0 if not win and not moe else 1 if win else 2
+             for win, moe in zip(cfg["hybrid_layer_pattern"],
+                                 cfg["moe_layer_freq"])]
+    shapes = dict(three, layers=[three["layers"][i] for i in order])
+    spec = spec3._replace(kinds=("attn",) * len(order),
+                          acts=tuple(spec3.acts[i] for i in order),
+                          attn=tuple(spec3.attn[i] for i in order))
+    return shapes, spec
+
+
+@pytest.mark.parametrize("program", ["serving_step", "serving_prefill_chunk"])
+def test_routed_program_leaves_both_kinds_of_pool_where_they_lie(
+        one_chip, monkeypatch, routed_weights, program):
+    """A decoder with window layers and routed experts through the same
+    two programs (docs/serving.md, "Window layers and routed experts"), at
+    the cell's widths and depth for the described v5e: the single-query
+    kernel is there for every attention layer of the step and for the
+    chunk's two full layers (a window layer's chunk attends its own keys
+    densely), the experts' kernel for each of the six routed layers; no
+    pool array of either kind is copied (the block tables' 13,825 blocks,
+    the rings' 289) and every one comes back in its argument's buffer, the
+    experts' counts with them; the temporaries stay under the chunk's
+    logits and a layer's scores."""
+    shapes, spec = routed_weights
+    assert spec.window == 128 and spec.moe.held == _M_E
+    assert [a.kv_heads for a in spec.attn] == [4, 8, 8, 8, 8, 4, 8]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools(d):
+        return tuple(S((_M_NBW if a.window else _M_NB, _M_BS,
+                        a.kv_heads * d), bf16) for a in spec.attn)
+
+    fn, rest = _served_program(program, None, spec, B=_M_B, bs=_M_BS,
+                               nbps=_M_NBPS, chunk=_M_CHUNK)
+    if program == "serving_step":       # the counts follow the tokens
+        rest[0] = ((_M_B + 3,), i32)
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), shapes)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4)).lower(
+        pools(_M_DK), pools(_M_DV), (), (), (S((3,), i32),),
+        *(S(*sd) for sd in rest), params).compile()
+    hlo = compiled.as_text()
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call", hlo))
+
+    assert calls("moe_experts") == 6
+    assert calls("paged_attention") == (7 if program == "serving_step"
+                                        else 2)
+    for shape in ((_M_NB, _M_BS, 4 * _M_DK), (_M_NB, _M_BS, 4 * _M_DV),
+                  (_M_NBW, _M_BS, 8 * _M_DK), (_M_NBW, _M_BS, 8 * _M_DV)):
+        assert not [c for c in _copies_of(hlo, "bf16", shape)
+                    if "S(" not in c], shape
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    for i in range(15):             # 7 key pools, 7 value pools, the counts
+        assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        32 if program == "serving_step" else 96) * 2 ** 20
+    # a layer's leaves are the net's own buffers, parameters of the
+    # program as they are: nothing of a matrix's size is computed at the
+    # program's top level (no copy, no transpose, no stack)
+    assert len(jax.tree_util.tree_leaves(shapes)) == 7 * 6 + 5 + 3 + 6 * 5 + 3
+    entry = hlo[hlo.index("ENTRY"):]
+    rows = "12288,4096|4096,8192|16384,4096|16,2048,4096|16,4096,2048"
+    # (a layout that names a memory space, `S(1)`, is the compiler's
+    # prefetch of a matrix into fast memory, not a second array in HBM)
+    assert not [m for m in re.findall(
+        rf"= bf16\[(?:{rows})\]\S* (?!parameter\()\w[\w-]*\(", entry)
+        if "S(" not in m]
+
+
 # --- the same kernels in a program traced over the 2x2 mesh ------------- #
 def _mesh_xent(x, labels):
     def loss(x):
@@ -431,6 +584,18 @@ def _mesh_paged(q, pool, tables, pos):
     return _paged.paged_attention(q, pool, pool, tables, pos, impl="pallas")
 
 
+def _mesh_paged_ring(q, pool_k, pool_v, tables, pos, sink):
+    return _paged.paged_attention(q, pool_k, pool_v, tables, pos,
+                                  first=pos % _M_BS, sink=sink,
+                                  value_scale=0.707, impl="pallas")
+
+
+def _mesh_moe(x, idx, wts, gate, up, down):
+    return _moe.routed_experts(x, idx, wts, jnp.ones(x.shape[:1], bool),
+                               gate, up, down, experts=_M_EALL,
+                               impl="pallas")
+
+
 # (function, (shape, dtype, spec) per argument, Mosaic kernels it must hold)
 _MESH_PROGRAMS = {
     # BERT-large MLM logits, vocab-sharded as the TP rules leave them
@@ -446,6 +611,19 @@ _MESH_PROGRAMS = {
                                  (_PAGE, bf16, P()),
                                  ((_B, _NBPS), i32, P()),
                                  ((_B,), i32, P())], 1),
+    # a window layer's step: lanes over `data`, the 8 KV heads over `model`
+    "paged_ring_window_sink": (_mesh_paged_ring, [
+        ((_M_B, _M_HQ, _M_DK), bf16, P()),
+        ((_M_NBW, _M_BS, 8 * _M_DK), bf16, P()),
+        ((_M_NBW, _M_BS, 8 * _M_DV), bf16, P()),
+        ((_M_B, _M_RING), i32, P()), ((_M_B,), i32, P()),
+        ((_M_HQ,), f32, P())], 1),
+    # a step's experts: the 64 tiles of rows over both axes
+    "moe_experts_step": (_mesh_moe, [
+        ((_M_B, _M_C), bf16, P()), ((_M_B, _M_K), i32, P()),
+        ((_M_B, _M_K), f32, P()), ((_M_E, _M_FE, _M_C), bf16, P()),
+        ((_M_E, _M_FE, _M_C), bf16, P()),
+        ((_M_E, _M_C, _M_FE), bf16, P())], 1),
 }
 
 

@@ -138,6 +138,39 @@ def _scan_step(q, pool, tables, pos, labels):
                           impl="pallas")
 
 
+def _paged_options(q, pool, tables, pos, labels):
+    """4 query heads on 2 KV heads, keys 32 and values 16 wide, a first
+    visible position a lane, a sink logit a head, a value scale: lanes and
+    KV heads are split, the sink with the heads."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention
+
+    q2 = jnp.concatenate([q[:, :, 0], q[:, :, 1]], -1)      # (4, 4, 32)
+    return paged_attention(q2, pool, pool[:, :, :32] * 0.5, tables, pos,
+                           first=pos // 2 % 4, sink=q[0, :, 2, 0],
+                           value_scale=0.707, impl="pallas")
+
+
+def _paged_sink_alone(q, pool, tables, pos, labels):
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention
+
+    return paged_attention(q[:, :, 0], pool, pool * 0.5, tables, pos,
+                           sink=q[0, :, 2, 0], impl="pallas")
+
+
+def _moe_experts(q, pool, tables, pos, labels):
+    """64 tokens, top-2 of 16 experts of which 4 are held: the tiles of
+    rows are what is split."""
+    from incubator_mxnet_tpu.ops.moe_experts import routed_experts
+
+    x = q[:, :, :16].reshape(64, 64)
+    idx = (labels[:, None] * jnp.array([3, 5]) + jnp.array([0, 1])) % 16
+    wts = jax.nn.sigmoid(x[:, :2])
+    w = q.reshape(-1)[-3 * 4 * 16 * 64:].reshape(3, 4, 16, 64) * 0.2
+    return routed_experts(x, idx.astype(jnp.int32), wts, labels % 7 != 0,
+                          w[0], w[1], jnp.swapaxes(w[2], 1, 2), first=2,
+                          experts=16, impl="pallas")
+
+
 def _xent(smoothing):
     def run(q, pool, tables, pos, labels):
         from incubator_mxnet_tpu.ops import xent_kernel as xk
@@ -151,10 +184,13 @@ def _xent(smoothing):
 
 @pytest.mark.parametrize("kernel", [_flash, _paged, _paged_int8, _xent(0.0),
                                     _xent(0.1), _paged_grouped, _paged_window,
-                                    _scan_step],
+                                    _scan_step, _paged_options,
+                                    _paged_sink_alone, _moe_experts],
                          ids=["flash_fwd_bwd", "paged", "paged_int8", "xent",
                               "xent_smoothed", "paged_grouped",
-                              "paged_window", "selective_scan_step"])
+                              "paged_window", "selective_scan_step",
+                              "paged_window_sink_scale", "paged_sink",
+                              "moe_experts"])
 def test_kernel_per_shard_matches_one_device(kernel):
     """The kernel under the 2x2 mesh's `shard_map` (interpret mode here)
     against the same kernel called as it is."""

@@ -758,3 +758,141 @@ def test_small_t_dispatch_gate():
     assert not _use_small_t("tpu", 512, 512, bf16)      # Pallas crossover
     assert not _use_small_t("cpu", 160, 160, bf16)      # never on CPU
     assert not _use_small_t("tpu", 160, 160, f32)       # bf16-only path
+
+
+# --- keys wider than values, a window, a sink logit, a value scale -------- #
+def _plain_windowed(q, k, v, pos, group, first=None, sink=None, scale=1.0):
+    """Every lane in numpy: q (B, Hq, Dk), its sequence's k (B, T, Hkv, Dk)
+    and v (B, T, Hkv, Dv); slots ``first .. pos`` are seen, the sink logit
+    a head joins the denominator alone, the result is scaled."""
+    q, k, v = (onp.asarray(x, onp.float64) for x in (q, k, v))
+    B, Hq, Dk = q.shape
+    out = onp.zeros((B, Hq, v.shape[-1]))
+    for b in range(B):
+        lo = 0 if first is None else int(first[b])
+        for h in range(Hq):
+            kv = h // group
+            s = k[b, lo:int(pos[b]) + 1, kv] @ q[b, h] / math.sqrt(Dk)
+            e = onp.exp(s - s.max())
+            den = e.sum() + (0.0 if sink is None
+                             else math.exp(float(sink[h]) - s.max()))
+            out[b, h] = scale * (e / den) @ v[b, lo:int(pos[b]) + 1, kv]
+    return out
+
+
+def _wide_case(seed, Hq, Hkv, Dk, Dv, bs, nbps, pos, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    B = len(pos)
+    nb = 1 + B * nbps
+    k = _rand_pool(keys[0], (nb, Hkv, bs, Dk), dtype)
+    v = _rand_pool(keys[1], (nb, Hkv, bs, Dv), dtype)
+    q = _rand_pool(keys[2], (B, Hq, Dk), dtype)
+    tables = 1 + jax.random.permutation(keys[3], B * nbps).reshape(B, nbps)
+    sink = jax.random.normal(keys[4], (Hq,))
+    seqs = [onp.asarray(x, onp.float32)[onp.asarray(tables)]
+            .transpose(0, 1, 3, 2, 4).reshape(B, nbps * bs, Hkv, -1)
+            for x in (k, v)]
+    return (q, _pages(k), _pages(v), tables.astype(jnp.int32),
+            jnp.asarray(pos, jnp.int32), sink, seqs)
+
+
+_OPTION_CASES = {
+    # (Hq, Hkv, Dk, Dv): groups of 16 and of 8 query heads a KV head
+    "k24_v16_16x4kv": (64, 4, 24, 16, ()),
+    "k24_v16_8x8kv": (64, 8, 24, 16, ()),
+    "window": (8, 2, 16, 16, ("first",)),
+    "sink": (8, 2, 16, 16, ("sink",)),
+    "value_scale": (8, 2, 16, 16, ("scale",)),
+    "all_16x4kv": (64, 4, 24, 16, ("first", "sink", "scale")),
+    "all_8x8kv": (64, 8, 24, 16, ("first", "sink", "scale")),
+    "all_mha": (4, 4, 24, 16, ("first", "sink", "scale")),
+}
+
+
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+@pytest.mark.parametrize("case", list(_OPTION_CASES))
+def test_keys_wider_than_values_window_sink_and_scale(case, impl):
+    """The options a decoder with window layers asks of the single-query
+    attention, each alone and all together, both impls against plain
+    attention and the kernel against `paged_attention_dense`: a value row
+    of another width than the key row, a first visible position a lane
+    (in the row's first entry: the caller hands the row from its first
+    visible block on), a sink logit a head, a value scale."""
+    Hq, Hkv, Dk, Dv, opts = _OPTION_CASES[case]
+    bs, nbps = 8, 4
+    pos = [0, 5, 13, 22, 31]
+    q, pk, pv, tables, pos, sink, (ks, vs) = _wide_case(
+        3, Hq, Hkv, Dk, Dv, bs, nbps, pos)
+    first = jnp.asarray([0, 3, 7, 6, 2], jnp.int32)     # each < bs
+    kw = {}
+    if "first" in opts:
+        kw["first"] = first
+    if "sink" in opts:
+        kw["sink"] = sink
+    if "scale" in opts:
+        kw["value_scale"] = 0.707
+    out = paged_attention(q, pk, pv, tables, pos, impl=impl, interpret=True,
+                          **kw)
+    assert out.shape == (len(pos), Hq, Dv) and out.dtype == q.dtype
+    want = _plain_windowed(q, ks, vs, pos, Hq // Hkv, kw.get("first"),
+                           kw.get("sink"), kw.get("value_scale", 1.0))
+    onp.testing.assert_allclose(onp.asarray(out), want, atol=3e-5)
+    if impl == "pallas":
+        dense = paged_attention_dense(q, pk, pv, tables, pos, **kw)
+        onp.testing.assert_allclose(onp.asarray(out), onp.asarray(dense),
+                                    atol=3e-5)
+
+
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+def test_dropping_the_sink_or_the_window_changes_the_result(impl):
+    q, pk, pv, tables, pos, sink, _ = _wide_case(
+        5, 8, 2, 24, 16, 8, 4, [9, 20, 31])
+    first = jnp.asarray([4, 5, 1], jnp.int32)
+    full = paged_attention(q, pk, pv, tables, pos, first=first, sink=sink,
+                           impl=impl, interpret=True)
+    for kw in (dict(first=first), dict(sink=sink)):
+        less = paged_attention(q, pk, pv, tables, pos, impl=impl,
+                               interpret=True, **kw)
+        assert float(jnp.abs(full - less).max()) > 1e-2, sorted(kw)
+
+
+def test_options_are_not_built_for_int8_pages():
+    q, k, v, tables, pos = _paged_case(2)
+    k8, ks = quantize_kv(jnp.swapaxes(k, 1, 2))
+    v8, vs = quantize_kv(jnp.swapaxes(v, 1, 2))
+    flat = [x.reshape(x.shape[:2] + (-1,)) for x in (k8, v8, ks, vs)]
+    with pytest.raises(ValueError, match="int8"):
+        paged_attention(q, flat[0], flat[1], tables, pos, scale_k=flat[2],
+                        scale_v=flat[3], first=jnp.zeros_like(pos),
+                        impl="dense")
+
+
+@pytest.mark.parametrize("width,base", [(4, 10000.0), (8, 5000000.0),
+                                        (16, 10000.0), (16, 100.0)])
+def test_rotary_positions_turn_the_leading_lanes(width, base):
+    """`generation._rope`, the rotary form of every served program:
+    lane j < width/2 of a head pairs with lane j + width/2 and turns by
+    ``pos * base**(-2j/width)``, the other lanes pass; so the product of a
+    rotated query and key depends on their distance alone."""
+    _rope = G._rope
+
+    rng = onp.random.default_rng(width)
+    D, half = 16, width // 2
+    x = rng.normal(size=(5, 3, D)).astype(onp.float32)
+    pos = onp.array([0, 1, 7, 130, 9000])
+    got = onp.asarray(_rope(jnp.asarray(x), jnp.asarray(pos), width, base))
+    theta = base ** (-2.0 * onp.arange(half) / width)
+    z = (x[..., :half] + 1j * x[..., half:width]) \
+        * onp.exp(1j * pos[:, None, None] * theta)
+    onp.testing.assert_allclose(got[..., :half], z.real, atol=2e-3)
+    onp.testing.assert_allclose(got[..., half:width], z.imag, atol=2e-3)
+    onp.testing.assert_array_equal(got[..., width:], x[..., width:])
+    # q at t+d against k at t: the same for every t
+    q, k = jnp.asarray(x[:1, :1]), jnp.asarray(x[1:2, :1])
+    dots = [float(jnp.sum(_rope(q, jnp.asarray([t + 5]), width, base)
+                          * _rope(k, jnp.asarray([t]), width, base)))
+            for t in (0, 3, 200)]
+    assert max(dots) - min(dots) < 1e-3
+    other = float(jnp.sum(_rope(q, jnp.asarray([9]), width, base)
+                          * _rope(k, jnp.asarray([0]), width, base)))
+    assert abs(other - dots[0]) > 1e-3
