@@ -47,12 +47,21 @@ the dense recipe folds the group into the einsums.
 
 A prefill chunk is many queries of ONE sequence: as lanes of the
 single-query kernel its ``T`` positions walk the same pages ``T`` times
-(``T x blocks_per_seq`` grid steps, and a grid step costs more than a
-page's bytes).  `paged_attention_window` walks them once: one grid step a
-page (and KV head), every query row against it — ``(G*T, D) · (D, bs)``
-scores with a per-row position mask.  It needs a page's KV head as a
-block of its own, so ``D`` a multiple of 128 lanes or one KV head; where
-it does not fit, the chunk stays lanes of the single-query kernel.
+and multiply them ``T`` times.  `paged_attention_window` walks them once
+for a whole tile of the chunk's query rows: grid ``(row tile, run)``, the
+runs fetched exactly as the single-query kernel fetches them (the pools
+in HBM, whole pages copied by the kernel itself, the next live run's
+under this run's math: `_run_copies`, one routine for both kernels) and
+the heads separated in VMEM: a KV head's ``G*T`` query rows meet that
+head's lanes of the run, a static slice of the buffer, and no other
+head's (the block-diagonal query of the single-query kernel would cost a
+chunk ``Hkv`` times the work).  So it takes heads of any width side by
+side in a row, values narrower than keys and a value scale; where a KV
+head's rows do not fit VMEM at once they go in tiles, each tile reading
+the pages again (`_window_rows`; `window_kernel_fits` says whether a
+tiling exists).  Both kernels share one online-softmax update
+(`_softmax_update`).  A chunk stays lanes of the single-query kernel
+where the pages are int8 (the window form has no scales).
 
 Both impls take an optional int8 KV pool (per-head symmetric int8 with
 an fp32 scale per (block, slot, head), scale pools ``(num_blocks,
@@ -176,6 +185,53 @@ def paged_attention_dense(q, pool_k, pool_v, tables, pos,
     return o.astype(q.dtype).reshape(B, Hq, Dv)
 
 
+def _run_copies(pools, bufs, sem, slot, page, first, last, n, bs, start):
+    """Start, or wait for, the copies into buffer ``slot`` of the live run
+    whose first table entry is ``first``: K's and V's page, as the pool
+    holds it, for each of the run's ``n`` entries that holds a position at
+    or before ``last``: its first always does, and the others that do are
+    the entries behind it up to ``last // bs``; ``page(e)`` reads entry
+    ``e`` of the row.  A wait needs a copy's size alone.  The loop over
+    those pages is the kernel's own, as many turns as pages are copied: a
+    traced kernel holds one page's copies and not ``n`` conditional ones
+    (three such loops a kernel; the kernels are traced and lowered at
+    every start of a process, whatever the compile cache holds).  The one
+    fetching routine of both kernels."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def page_i(i, _=None):
+        src = page(first + i) if start else 0
+        for pool, buf in zip(pools, bufs):
+            copy = pltpu.make_async_copy(
+                pool.at[src], buf.at[slot, i], sem.at[slot])
+            copy.start() if start else copy.wait()
+
+    page_i(0)
+    jax.lax.fori_loop(1, jnp.minimum(n, last // bs - first + 1), page_i, None)
+
+
+def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, scale_v=None):
+    """One run's online-softmax update, both kernels': ``s`` float32
+    scores ``(rows, run)``, ``seen`` their mask, ``v`` the run's values
+    ``(run, lanes)`` in float32; the state ``m``, ``l`` ``(rows, 1)`` and
+    ``acc`` ``(rows, lanes)`` is float32.  A row's update reads nothing of
+    another row, and a run that a row sees nothing of leaves its state bit
+    for bit (``alpha`` 1.0, weights 0.0) once an earlier run gave it a
+    finite maximum.  ``scale_v``: int8 values' scales, a column a
+    position."""
+    s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)       # masked slots underflow to exactly 0.0
+    m_ref[...] = m_new
+    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    if scale_v is not None:  # (the pipeline brought unseen pages' scales)
+        p = jnp.where(seen, p * scale_v, 0.0)
+    acc_ref[...] = acc_ref[...] * alpha \
+        + jnp.dot(p, v, preferred_element_type=jnp.float32)
+
+
 def _paged_kernel(tables_ref, pos_ref, *rest,
                   bs, n, heads, kv_heads, kv_quant, windowed=False,
                   sink=False, value_scale=1.0):
@@ -219,7 +275,6 @@ def _paged_kernel(tables_ref, pos_ref, *rest,
     a visible slot.  ``sink``: an ``(Hq, 1)`` logit joins each row's
     denominator at the end of the walk."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     if windowed:
         first_ref, rest = rest[0], rest[1:]
@@ -241,21 +296,9 @@ def _paged_kernel(tables_ref, pos_ref, *rest,
     d = k_buf.shape[-1] // kv_heads
 
     def run_copies(lane, first, slot, start):
-        """Start, or wait for, the copies into buffer ``slot`` of the live
-        run of ``lane`` whose first table entry is ``first``: K's and V's
-        page for each entry that holds a visible position (the run's
-        first always does).  A wait needs a copy's size alone."""
-        for i in range(n):
-            def page_i(i=i):
-                page = tables_ref[lane, first + i] if start else 0
-                for pool, buf in zip(pools, (k_buf, v_buf)):
-                    copy = pltpu.make_async_copy(
-                        pool.at[page], buf.at[slot, i], sem.at[slot])
-                    copy.start() if start else copy.wait()
-            if i == 0:
-                page_i()
-            else:
-                pl.when((first + i) * bs <= pos_ref[lane])(page_i)
+        _run_copies(pools, (k_buf, v_buf), sem, slot,
+                    lambda e: tables_ref[lane, e], first, pos_ref[lane], n,
+                    bs, start)
 
     def own(d=dv):
         """(Hq, Hkv*d): the lanes of row h that are its KV head's.  Built
@@ -328,18 +371,8 @@ def _paged_kernel(tables_ref, pos_ref, *rest,
         seen = j * run + at <= t
         if windowed:
             seen = jnp.logical_and(seen, j * run + at >= first_ref[b])
-        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
-        m_prev, l_prev = m_ref[...], l_ref[...]         # (Hq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if kv_quant:    # (the pipeline brought the scales of unseen pages)
-            p = jnp.where(seen, p * scale(scales[1]), 0.0)
-        acc_ref[...] = acc_ref[...] * alpha \
-            + jnp.dot(p, pages(v_buf),
-                      preferred_element_type=jnp.float32)
+        _softmax_update(s, seen, pages(v_buf), m_ref, l_ref, acc_ref,
+                        scale(scales[1]) if kv_quant else None)
 
     @pl.when(j == nb - 1)
     def _emit():
@@ -484,33 +517,95 @@ def _paged_core_opts(q, pool_k, pool_v, tables, pos, first, sink, interpret,
 
 # --- a window of one sequence's queries: each page once ----------------- #
 _WINDOW_VMEM = 48 * 1024 * 1024
+# a row tile's float32 scores against one run, in elements: what bounds
+# the rows a grid step of the window form takes
+_WINDOW_SCORES = 1024 * 1024
 
 
-def window_kernel_fits(T, heads, kv_heads, head_dim) -> bool:
-    """Whether `paged_attention_window` has a kernel for these sizes: a
-    KV head's lanes must be a block of their own (one KV head, or
-    ``head_dim`` a multiple of 128), and a KV head's query rows with their
-    softmax state must fit VMEM."""
-    rows = (heads // kv_heads) * T
-    block_ok = kv_heads == 1 or head_dim % 128 == 0
-    # q (twice: its block and its float32 copy), acc: (rows, D); m, l:
-    # (rows, 1) padded to 128 lanes; all float32
-    return block_ok and rows * (3 * head_dim + 2 * 128) * 4 <= _WINDOW_VMEM
+def _window_rows(T, group, run):
+    """Rows of a KV head (``group`` heads x ``T`` queries, head-major) one
+    grid step of the window form takes against a run of ``run`` positions:
+    all of them where their scores fit `_WINDOW_SCORES`, else whole heads,
+    else a part of one head's queries that the sublane tiling can cut (a
+    multiple of 16 that divides ``T``).  A tile changes which rows share a
+    matmul and nothing of a row's own arithmetic."""
+    cap = max(16, _WINDOW_SCORES // run)
+    # tpulint: disable-next=TPU004 -- T, group and run are static shape ints
+    if group * T <= cap:
+        return group * T
+    # tpulint: disable-next=TPU004 -- T and cap are static shape ints
+    if T <= cap:
+        return T * max(g for g in range(1, group + 1)
+                       if group % g == 0 and g * T <= cap)
+    parts = [r for r in range(16, cap + 1, 16) if T % r == 0]
+    return max(parts) if parts else T
 
 
-def _window_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, bs, T):
-    """One grid step = one (KV head, page): every query row of that KV
-    head against the page.  Row r is the query at position ``start + r %
-    T`` of head ``r // T`` of the group; pages past the window's last
-    position are skipped, and within a page a row sees the slots at or
-    before its own position.  The online softmax is `_paged_kernel`'s."""
+def _window_vmem(rows, run, kv_heads, dk, dv):
+    """Bytes of VMEM a grid step of ``rows`` rows a KV head holds beside
+    the runs' buffers (`_RUN_VMEM`), everything counted as float32 and a
+    last dimension padded to 128 lanes: the query's and the output's block
+    (two buffers each), the accumulator, ``m`` and ``l``, and three arrays
+    of a tile's scores."""
+    def lanes(d):
+        return -(-d // 128) * 128
+    state = kv_heads * rows * (2 * lanes(dk) + 3 * lanes(dv) + 2 * 128)
+    return 4 * (state + 3 * rows * run)
+
+
+def window_kernel_fits(T, heads, kv_heads, head_dim, v_dim=None) -> bool:
+    """Whether `paged_attention_window` has a kernel for these sizes, from
+    the shapes alone: ``heads`` a multiple of ``kv_heads``, and a tiling
+    of a KV head's ``heads // kv_heads x T`` query rows (`_window_rows`)
+    whose blocks, softmax state and scores fit `_WINDOW_VMEM` beside the
+    runs' buffers, whatever the pages' size (the longest run `_RUN_VMEM`
+    lets pages of 2 bytes an element have).  Any head width: the kernel
+    cuts a KV head's lanes out of the run itself."""
+    v_dim = head_dim if v_dim is None else v_dim
+    if kv_heads < 1 or heads % kv_heads:
+        return False
+    run = max(16, _RUN_VMEM // (4 * kv_heads * (head_dim + v_dim)))
+    rows = _window_rows(T, heads // kv_heads, run)
+    return _RUN_VMEM + _window_vmem(rows, run, kv_heads, head_dim, v_dim) \
+        <= _WINDOW_VMEM
+
+
+def _window_kernel(table_ref, start_ref, q_ref, pool_k, pool_v, o_ref,
+                   k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
+                   bs, n, T, value_scale):
+    """One grid step = one (tile of query rows, run of ``n`` pages), every
+    KV head of the run.  The pools stay in HBM and the run's pages are
+    fetched as `_paged_kernel` fetches them (`_run_copies`: whole pages
+    into one of two buffers, the next live step's under this step's math;
+    the next live step is this tile's next run or the next tile's run 0);
+    a run wholly past the window's last position starts nothing and
+    computes nothing, and of a live run only the pages that hold a
+    position at or before it are copied.
+
+    The heads are separated in VMEM: KV head ``h``'s rows (``q_ref[h]``:
+    its ``Hq/Hkv`` heads x the tile's queries) meet the lanes ``h*D ..
+    (h+1)*D`` of the run's keys and the lanes ``h*Dv .. (h+1)*Dv`` of its
+    values and no other head's, a static slice of the buffer.  Row ``r``
+    of tile ``i`` is row ``i*rows + r`` of the KV head's ``G*T``
+    head-major rows: the query at position ``start + (i*rows + r) % T``,
+    which sees the slots at or before its own.  The query and the keys
+    enter ``q . k^T`` as bf16 where both are stored so (each product of
+    two bf16 values is exact in float32, so this is the float32 dot's
+    products and float32 accumulation); scores, softmax state, weights
+    and the accumulator are float32 (`_softmax_update`)."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
+    i, j = pl.program_id(0), pl.program_id(1)
+    tiles, nb = pl.num_programs(0), pl.num_programs(1)
+    kv_heads, rows, dk = q_ref.shape
+    dv = o_ref.shape[-1]
+    run = n * bs
     start = start_ref[0]
-    d = q_ref.shape[-1]
+    last = start + (T - 1)
+
+    def run_copies(first, slot, start):
+        _run_copies((pool_k, pool_v), (k_buf, v_buf), sem, slot,
+                    lambda e: table_ref[e], first, last, n, bs, start)
 
     @pl.when(j == 0)
     def _init():
@@ -518,82 +613,141 @@ def _window_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, jnp.finfo(jnp.float32).min)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j * bs <= start + (T - 1))
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _first():                           # no step before it to fetch it
+        slot_ref[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        run_copies(0, 0, start=True)
+
+    # run 0 is live whatever the positions say (start >= 0: every row sees
+    # its first slot, so every row's maximum is finite from run 0 on)
+    @pl.when(jnp.logical_or(j == 0, j * run <= last))
     def _update():
-        s = jax.lax.dot_general(
-            q_ref[0].astype(jnp.float32), k_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / math.sqrt(d)  # (rows, bs)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(j * bs + slot <= start + row % T, s,
-                      jnp.finfo(jnp.float32).min)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)   # masked slots underflow to exactly 0.0
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha \
-            + jnp.dot(p, v_ref[0].astype(jnp.float32),
-                      preferred_element_type=jnp.float32)
+        slot = slot_ref[0]
+        more = jnp.logical_and(j + 1 < nb, (j + 1) * run <= last)
+
+        @pl.when(jnp.logical_or(more, i + 1 < tiles))
+        def _prefetch():
+            run_copies(jnp.where(more, (j + 1) * n, 0), 1 - slot, start=True)
+        slot_ref[0] = 1 - slot
+        run_copies(j * n, slot, start=False)
+
+        shape = (rows, run)
+        # a tile is whole heads, or a part of one head's queries
+        at = jax.lax.broadcasted_iota(jnp.int32, shape, 0) % T \
+            if rows % T == 0 else \
+            (i * rows) % T + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        seen = j * run + jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+            <= start + at
+
+        def head(buf, h, d):
+            """KV head h's lanes of the run, one page under the other."""
+            return buf[slot, :, :, h * d:(h + 1) * d].reshape(run, d)
+
+        for h in range(kv_heads):
+            q, k = q_ref[h], head(k_buf, h, dk)
+            if not q.dtype == k.dtype == jnp.bfloat16:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / math.sqrt(dk)
+            _softmax_update(s, seen, head(v_buf, h, dv).astype(jnp.float32),
+                            m_ref.at[h], l_ref.at[h], acc_ref.at[h])
 
     @pl.when(j == nb - 1)
     def _emit():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        l = l_ref[...]
+        if value_scale != 1.0:
+            l = l * (1.0 / value_scale)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _window_core(q, pool_k, pool_v, table_row, start, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "value_scale"))
+def _window_core(q, pool_k, pool_v, table_row, start, interpret,
+                 value_scale=1.0):
+    """Grid ``(row tiles, runs)``, a run `pages_per_step` consecutive
+    entries of the sequence's table row, aligned to the table's index and
+    not to the chunk's start, so a position's output is the same bits
+    wherever the chunk that computes it began.  A row that is no whole
+    number of runs is padded with the scratch block, as `_paged_call`
+    pads it: no position lies there."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, H, D = q.shape
     bs, row = pool_k.shape[1:]
     Hkv = row // D
-    rows = (H // Hkv) * T
+    row_v = pool_v.shape[2]
+    Dv = row_v // Hkv
+    G = H // Hkv
     nbps = table_row.shape[0]
+    n = pages_per_step(bs, nbps, (row + row_v) * pool_k.dtype.itemsize // 2)
+    if nbps % n:
+        table_row = jnp.pad(table_row, (0, -nbps % n))
+    rows = _window_rows(T, G, n * bs)
     # (T, Hkv, G, D) -> (Hkv, G*T, D): a KV head's rows, head-major
-    qh = q.reshape(T, Hkv, H // Hkv, D).transpose(1, 2, 0, 3) \
-        .reshape(Hkv, rows, D)
-    mine = pl.BlockSpec((1, rows, D), lambda h, j, t, s: (h, 0, 0))
-    page = pl.BlockSpec((1, bs, D), lambda h, j, t, s: (t[j], 0, h))
+    qh = q.reshape(T, Hkv, G, D).transpose(1, 2, 0, 3).reshape(Hkv, G * T, D)
+
+    def mine(d):
+        return pl.BlockSpec((Hkv, rows, d), lambda i, j, *_: (0, i, 0))
+
     out = pl.pallas_call(
-        functools.partial(_window_kernel, bs=bs, T=T),
+        functools.partial(_window_kernel, bs=bs, n=n, T=T,
+                          value_scale=float(value_scale)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(Hkv, nbps),
-            in_specs=[mine, page, page], out_specs=mine,
-            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+            num_scalar_prefetch=2,
+            grid=(G * T // rows, table_row.shape[0] // n),
+            in_specs=[mine(D)] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2,
+            out_specs=mine(Dv),
+            scratch_shapes=[pltpu.VMEM((2, n) + pool.shape[1:], pool.dtype)
+                            for pool in (pool_k, pool_v)]
+            + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32),
+               pltpu.VMEM((Hkv, rows, Dv), jnp.float32),
+               pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+               pltpu.VMEM((Hkv, rows, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((Hkv, G * T, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WINDOW_VMEM + 16 * 1024 * 1024),
         interpret=interpret, name="paged_attention_window",
     )(table_row, start.reshape(1), qh, pool_k, pool_v)
-    return out.reshape(Hkv, H // Hkv, T, D).transpose(2, 0, 1, 3) \
-        .reshape(T, H, D)
+    return out.reshape(Hkv, G, T, Dv).transpose(2, 0, 1, 3).reshape(T, H, Dv)
 
 
 def paged_attention_window(q, pool_k, pool_v, table_row, start, *,
+                           value_scale: float = 1.0,
                            interpret: Optional[bool] = None):
     """Attention of a window of ONE sequence's queries ``q`` (T, Hq, D),
     at positions ``start .. start+T-1``, against that sequence's pages
     (``table_row`` (blocks_per_seq,)): query t attends slots ``<= start +
     t``, as `paged_attention` would with every position a lane of the same
-    table, but each page is read once for all of them (a prefill chunk's
-    attention).  Kernel only (`window_kernel_fits` says for which sizes;
-    float pages); a caller without one uses `paged_attention`."""
+    table, but each page is read once for a whole tile of them (a prefill
+    chunk's attention).  The value pool's rows may be narrower than the
+    key pool's (the result is ``(T, Hq, Dv)``) and ``value_scale``
+    multiplies the result, as in `paged_attention`; no first visible
+    position and no sink.  Kernel only (`window_kernel_fits` says for
+    which sizes; float pages); a caller without one uses
+    `paged_attention`."""
     T, H, D = q.shape
-    Hkv = pool_k.shape[2] // D
-    if not window_kernel_fits(T, H, Hkv, D):
+    row, row_v = pool_k.shape[2], pool_v.shape[2]
+    Hkv = row // D
+    if Hkv < 1 or row != Hkv * D or H % Hkv or row_v % Hkv:
+        raise ValueError(
+            f"paged_attention_window: {H} query heads of {D} against pool "
+            f"rows of {row} and {row_v}: the pools must hold a whole number "
+            "of KV heads that divides the query's")
+    if not window_kernel_fits(T, H, Hkv, D, row_v // Hkv):
         raise ValueError(
             f"paged_attention_window: no kernel for {T} queries of {H} "
             f"heads of {D} over {Hkv} KV heads (window_kernel_fits)")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    heads, = mosaic.split((Hkv,))
-    core = functools.partial(_window_core, interpret=interpret)
+    # KV heads are independent: per shard of them under a mesh, a shard's
+    # heads a contiguous run of a page's lanes that stays whole 128-lane
+    # tiles of both pools' rows (what the kernel's copies can cut out)
+    whole = math.lcm(*(128 // math.gcd(128, d) for d in (D, row_v // Hkv)))
+    heads, = mosaic.split((Hkv,), (whole,))
+    core = functools.partial(_window_core, interpret=interpret,
+                             value_scale=float(value_scale))
     pool = P(None, None, heads)
     return mosaic.per_shard(core, (P(None, heads), pool, pool, P(), P()),
                             P(None, heads))(

@@ -498,6 +498,10 @@ class ServingEngine:
                 telemetry.gauge("paged_attn_kernel",
                                 labels={"path": path}) \
                     .set(1.0 if path == impl else 0.0)
+            for form in ("window", "lanes", "dense"):
+                telemetry.gauge("paged_attn_chunk",
+                                labels={"form": form}) \
+                    .set(1.0 if form == self._programs.chunk_attn else 0.0)
 
         # per-lane step inputs (scheduler thread only; snapshots are
         # passed to the program, so the jit never closes over state)
@@ -849,6 +853,9 @@ class ServingEngine:
             "kv_dtype": self._kv_dtype or "model",
             "attn_impl": self._programs.attn_impl,
             "paged_pages_per_step": self._programs.pages_per_step,
+            # how a prefill chunk attends the sequence's pages: "window"
+            # (once for all its queries), "lanes" (once a query) or "dense"
+            "chunk_attn": self._programs.chunk_attn,
             "max_batch": self._B,
             "block_size": self._bs,
             "max_seq_len": self._msl,

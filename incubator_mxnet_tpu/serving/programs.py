@@ -615,11 +615,30 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
     return serving_step
 
 
-def _chunk_attend(spec, bs, attn_impl, tables, posc, ok, wblk, off, start,
-                  valid_len, lane):
+def chunk_attention(spec, chunk, kv_dtype, attn_impl) -> str:
+    """How a prefill chunk of ``chunk`` queries attends the sequence's
+    pages in the table-named layers, from the shapes, the K/V dtype and
+    the implementation alone: ``"window"`` (`paged_attention_window`: the
+    chunk's queries walk the pages once together), ``"lanes"`` (every
+    position a lane of the single-query kernel: int8 pages, which the
+    window form has no scales for, and sizes it has no tiling for) or
+    ``"dense"`` (no kernel)."""
+    if attn_impl != "pallas":
+        return "dense"
+    full = {a.kv_heads for a in spec.attn if not a.window} if spec.attn \
+        else {spec.kv_heads}
+    fits = kv_dtype != "int8" and all(
+        window_kernel_fits(chunk, spec.heads, kv_heads, spec.head_dim,
+                           spec.v_dim or None) for kv_heads in full)
+    return "window" if fits else "lanes"
+
+
+def _chunk_attend(spec, bs, attn_impl, window, tables, table_row, posc, ok,
+                  wblk, off, start, valid_len, lane):
     """`_layers`' ``attend`` of a prefill chunk where the attention layers
     differ.  A full layer writes the chunk's K/V into the sequence's pages
-    and attends them as lanes of the single-query kernel.  A window layer
+    and attends them: with ``window`` through `paged_attention_window`,
+    else as lanes of the single-query kernel.  A window layer
     attends densely (`_band_attention`): its own keys, and before them the
     window's reach behind the chunk, read from the lane's ring as the
     chunk before left it; then it writes into the ring only what a later
@@ -643,9 +662,14 @@ def _chunk_attend(spec, bs, attn_impl, tables, posc, ok, wblk, off, start,
                 pk, pv = write_rows(pk, wblk, off, k), \
                     write_rows(pv, wblk, off, v)
             with jax.named_scope("paged_attn"):
-                o = paged_attention(q, pk, pv, tables, posc,
-                                    value_scale=spec.value_scale,
-                                    impl=attn_impl)
+                if window:
+                    o = paged_attention_window(
+                        q, pk, pv, table_row, start,
+                        value_scale=spec.value_scale)
+                else:
+                    o = paged_attention(q, pk, pv, tables, posc,
+                                        value_scale=spec.value_scale,
+                                        impl=attn_impl)
             return o, pk, pv
         with jax.named_scope("paged_attn"):
             kb = pk[b_blk, b_off].reshape((W1,) + k.shape[1:])
@@ -672,9 +696,10 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
     The body is the `_build_spec_verify` window recipe at batch 1:
     embed the window, scatter each layer's K/V into the sequence's
     pages (positions >= valid_len land in scratch), then ONE batched
-    `paged_attention` (or, where the kernel has the sizes for it,
+    `paged_attention` (or, on the kernel path with float pages,
     `paged_attention_window`: the same mask, each page read once for all
-    the chunk's queries) whose per-row ``kpos <= pos`` mask gives every
+    the chunk's queries, whatever the heads' width: `chunk_attention`)
+    whose per-row ``kpos <= pos`` mask gives every
     window position exactly its causal prefix — including the
     positions this very chunk just wrote (write-then-read, the
     `serving_step` order).  Because each row's math is lane-local
@@ -704,10 +729,9 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
     pick = _row_pick(temperature, top_k)
     kv8 = kv_dtype == "int8"
     # where the kernel has the sizes for it, the chunk's queries walk the
-    # sequence's pages once together and not once each (a KV head's lanes
-    # a block of their own: not with 64-wide heads side by side)
-    window = attn_impl == "pallas" and not kv8 and not spec.attn \
-        and window_kernel_fits(CH, spec.heads, spec.kv_heads, spec.head_dim)
+    # sequence's pages once together and not once each, at any head width
+    # (the kernel cuts a KV head's lanes out of a run of whole pages)
+    window = chunk_attention(spec, CH, kv_dtype, attn_impl) == "window"
 
     def serving_prefill_chunk(pool_k, pool_v, scale_k, scale_v, rec,
                               table_row, toks, start, valid_len, key, lane,
@@ -729,8 +753,9 @@ def _build_prefill_chunk(spec, block_size, blocks_per_seq, chunk,
             return a[0], conv, state
 
         if spec.attn:
-            attend = _chunk_attend(spec, bs, attn_impl, tables, posc, ok,
-                                   wblk, off, start, valid_len, lane)
+            attend = _chunk_attend(spec, bs, attn_impl, window, tables,
+                                   table_row, posc, ok, wblk, off, start,
+                                   valid_len, lane)
         elif window:
             def attend(q, pk, pv, sk, sv):
                 return paged_attention_window(q, pk, pv, table_row, start)
@@ -987,7 +1012,8 @@ class PagedPrograms:
     The arrays are touched by ONE thread only, the engine's scheduler:
     the call methods, `gather_params` and `release` are its alone.  What
     other threads read (`kv_pool_bytes`, `state_bytes`,
-    `pages_per_step`, the labels) is frozen at construction."""
+    `pages_per_step`, `chunk_attn`, the labels) is frozen at
+    construction."""
 
     def __init__(self, net, *, max_batch, block_size, temperature, top_k,
                  quantized, max_seq_len=None, num_blocks=None,
@@ -1225,6 +1251,10 @@ class PagedPrograms:
                                    dspec.head_dim)
             n = len(dspec.kinds)
             self._draft_kv = [each(n, dpage, ddt), each(n, dpage, ddt)]
+        # how a prefill chunk attends the table-named pages: "window",
+        # "lanes" or "dense" (`chunk_attention`), static for an engine
+        self.chunk_attn = chunk_attention(spec, self._chunk,
+                                          self._kv_dtype, self._impl)
         # block-table entries a grid step of the single-query kernel
         # covers (the kernel's own rule, from these shapes); 0 on the
         # dense path, which runs no kernel

@@ -110,11 +110,16 @@ def _paged_grouped(S):
         S((_G_B, _G_NBPS), i32), S((_G_B,), i32), interpret=False)
 
 
-def _paged_window(S):
-    """A chunk's 256 queries of 20 heads against each page once."""
-    return _paged._window_core.lower(
-        S((_S_CHUNK, _G_HQ, _G_D), bf16), S(_G_PAGE, bf16), S(_G_PAGE, bf16),
-        S((_G_NBPS,), i32), S((), i32), interpret=False)
+def _paged_window(queries, heads, kv_heads, dk, dv, bs, nbps, nb,
+                  value_scale=1.0):
+    """A chunk's queries against each page of their sequence once: runs
+    of whole pages, the heads cut out of them inside the kernel."""
+    def lower(S):
+        return _paged._window_core.lower(
+            S((queries, heads, dk), bf16), S((nb, bs, kv_heads * dk), bf16),
+            S((nb, bs, kv_heads * dv), bf16), S((nbps,), i32), S((), i32),
+            interpret=False, value_scale=value_scale)
+    return lower
 
 
 def _selective_scan(N, T, nb):
@@ -213,13 +218,25 @@ _KERNELS = {
     "paged_int8_cell_64_blocks": (_paged_int8(_CELL_NBPS, _CELL_NB),
                                   ["paged_attention_q8"]),
     "paged_grouped_step_20q_1kv": (_paged_grouped, ["paged_attention"]),
-    "paged_window_chunk_20q_1kv": (_paged_window, ["paged_attention_window"]),
+    "paged_window_chunk_20q_1kv": (
+        _paged_window(_S_CHUNK, _G_HQ, 1, _G_D, _G_D, _G_BS, _G_NBPS, _G_NB),
+        ["paged_attention_window"]),
+    # gpt2-medium.serve-batch's chunk: 32 queries, 16 heads of 64 side by
+    # side in a row of 1,024 lanes
+    "paged_window_chunk_16q_16kv_d64": (
+        _paged_window(32, _H, _H, _D, _D, _BS, _CELL_NBPS, _CELL_NB),
+        ["paged_attention_window"]),
     "paged_step_64q_4kv_k192_v128": (
         _paged_wide(_M_B, 4, _M_NBPS, _M_NB, False), ["paged_attention"]),
     "paged_step_64q_8kv_ring_window_sink": (
         _paged_wide(_M_B, 8, _M_RING, _M_NBW, True), ["paged_attention"]),
     "paged_chunk_lanes_64q_4kv_k192_v128": (
         _paged_wide(_M_CHUNK, 4, _M_NBPS, _M_NB, False), ["paged_attention"]),
+    # mimo-v2-flash.serve-mixed's chunk in a full layer: 8,192 rows a KV
+    # head in tiles, keys 192 and values 128 wide
+    "paged_window_chunk_64q_4kv_k192_v128": (
+        _paged_window(_M_CHUNK, _M_HQ, 4, _M_DK, _M_DV, _M_BS, _M_NBPS,
+                      _M_NB, value_scale=0.707), ["paged_attention_window"]),
     "moe_experts_step_96x8_of_256": (_moe_experts(_M_B), ["moe_experts"]),
     "moe_experts_chunk_512x8_of_256": (_moe_experts(_M_CHUNK),
                                        ["moe_experts"]),
@@ -366,6 +383,13 @@ def test_served_program_leaves_the_pool_where_it_lies(
         params).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == _CELL_L      # the real kernel
+    # a float chunk's queries walk the pages once together; int8 pages
+    # (no scales in the window form) stay lanes of the single-query kernel
+    kernel = "paged_attention" + (
+        "_q8" if kv8 else "_window" if program == "serving_prefill_chunk"
+        else "")
+    assert len(re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call",
+                          hlo)) == _CELL_L, kernel
     # (a) no copy of a K/V pool array
     assert not _copies_of(hlo, "s8" if kv8 else "bf16", _CELL_PAGE)
     # the fp32 scale pools keep one copy in and one out: with 16 heads in
@@ -504,9 +528,10 @@ def test_routed_program_leaves_both_kinds_of_pool_where_they_lie(
     """A decoder with window layers and routed experts through the same
     two programs (docs/serving.md, "Window layers and routed experts"), at
     the cell's widths and depth for the described v5e: the single-query
-    kernel is there for every attention layer of the step and for the
-    chunk's two full layers (a window layer's chunk attends its own keys
-    densely), the experts' kernel for each of the six routed layers; no
+    kernel is there for every attention layer of the step and the window
+    form for the chunk's two full layers (a window layer's chunk attends
+    its own keys densely), the experts' kernel for each of the six routed
+    layers; no
     pool array of either kind is copied (the block tables' 13,825 blocks,
     the rings' 289) and every one comes back in its argument's buffer, the
     experts' counts with them; the temporaries stay under the chunk's
@@ -538,8 +563,10 @@ def test_routed_program_leaves_both_kinds_of_pool_where_they_lie(
             rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call", hlo))
 
     assert calls("moe_experts") == 6
-    assert calls("paged_attention") == (7 if program == "serving_step"
-                                        else 2)
+    # the chunk's two full layers walk their pages once for all 512
+    # queries (a window layer's chunk attends its own keys densely)
+    assert (calls("paged_attention"), calls("paged_attention_window")) == (
+        (7, 0) if program == "serving_step" else (0, 2))
     for shape in ((_M_NB, _M_BS, 4 * _M_DK), (_M_NB, _M_BS, 4 * _M_DV),
                   (_M_NBW, _M_BS, 8 * _M_DK), (_M_NBW, _M_BS, 8 * _M_DV)):
         assert not [c for c in _copies_of(hlo, "bf16", shape)
@@ -590,6 +617,11 @@ def _mesh_paged_ring(q, pool_k, pool_v, tables, pos, sink):
                                   value_scale=0.707, impl="pallas")
 
 
+def _mesh_window(q, pool_k, pool_v, row, start):
+    return _paged.paged_attention_window(q, pool_k, pool_v, row, start,
+                                         value_scale=0.707)
+
+
 def _mesh_moe(x, idx, wts, gate, up, down):
     return _moe.routed_experts(x, idx, wts, jnp.ones(x.shape[:1], bool),
                                gate, up, down, experts=_M_EALL,
@@ -618,6 +650,18 @@ _MESH_PROGRAMS = {
         ((_M_NBW, _M_BS, 8 * _M_DV), bf16, P()),
         ((_M_B, _M_RING), i32, P()), ((_M_B,), i32, P()),
         ((_M_HQ,), f32, P())], 1),
+    # a chunk's window form: the KV heads over `model`, a shard's a
+    # contiguous run of a page's lanes (8 of gpt2-medium's 16 heads of 64;
+    # 2 of mimo-v2-flash's 4, keys 192 and values 128 wide)
+    "paged_window_16kv_d64": (_mesh_window, [
+        ((32, _H, _D), bf16, P()), ((_CELL_NB,) + _PAGE[1:], bf16, P()),
+        ((_CELL_NB,) + _PAGE[1:], bf16, P()), ((_CELL_NBPS,), i32, P()),
+        ((), i32, P())], 1),
+    "paged_window_4kv_k192_v128": (_mesh_window, [
+        ((_M_CHUNK, _M_HQ, _M_DK), bf16, P()),
+        ((_M_NB, _M_BS, 4 * _M_DK), bf16, P()),
+        ((_M_NB, _M_BS, 4 * _M_DV), bf16, P()),
+        ((_M_NBPS,), i32, P()), ((), i32, P())], 1),
     # a step's experts: the 64 tiles of rows over both axes
     "moe_experts_step": (_mesh_moe, [
         ((_M_B, _M_C), bf16, P()), ((_M_B, _M_K), i32, P()),

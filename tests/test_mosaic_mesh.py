@@ -121,6 +121,19 @@ def _paged_window(q, pool, tables, pos, labels):
                                   jnp.int32(3))
 
 
+def _paged_window_heads(q, pool, tables, pos, labels):
+    """4 queries of 4 heads of 128 on 4 KV heads, a value scale: the KV
+    heads are split, a shard's a run of whole 128-lane tiles of a page's
+    row (heads of 16 side by side would stay on one shard: a shard's row
+    must be something the kernel's copies can cut out of the pool)."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention_window
+
+    wide = jnp.tile(pool, (1, 1, 8))                        # (17, 4, 512)
+    return paged_attention_window(q[:, :, :8].reshape(4, 4, 128), wide,
+                                  wide * 0.5, tables[1], jnp.int32(3),
+                                  value_scale=0.707)
+
+
 def _scan_step(q, pool, tables, pos, labels):
     """The decode step's form of the selective scan: a lane a sequence of
     one token, split over the lanes."""
@@ -184,11 +197,13 @@ def _xent(smoothing):
 
 @pytest.mark.parametrize("kernel", [_flash, _paged, _paged_int8, _xent(0.0),
                                     _xent(0.1), _paged_grouped, _paged_window,
+                                    _paged_window_heads,
                                     _scan_step, _paged_options,
                                     _paged_sink_alone, _moe_experts],
                          ids=["flash_fwd_bwd", "paged", "paged_int8", "xent",
                               "xent_smoothed", "paged_grouped",
-                              "paged_window", "selective_scan_step",
+                              "paged_window", "paged_window_heads_split",
+                              "selective_scan_step",
                               "paged_window_sink_scale", "paged_sink",
                               "moe_experts"])
 def test_kernel_per_shard_matches_one_device(kernel):

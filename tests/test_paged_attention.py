@@ -383,47 +383,167 @@ def test_position_as_a_lane_of_a_chunk_equals_the_step_bit_for_bit(
         assert onp.array_equal(onp.asarray(step[0]), onp.asarray(chunk[i])), i
 
 
-@pytest.mark.parametrize("heads,kv_heads,D,start", [
-    (4, 1, 16, 0), (20, 1, 128, 5), (4, 2, 128, 17), (2, 2, 128, 9)],
-    ids=["4x1kv_d16", "20x1kv_d128", "4x2kv_d128", "mha_d128"])
-def test_window_kernel_matches_every_position_as_a_lane(heads, kv_heads, D,
-                                                        start):
+def _window_case(seed, T, heads, kv_heads, Dk, Dv, bs, nbps,
+                 dtype=jnp.float32):
+    """One sequence's pools (block 0 the scratch block, every entry of the
+    row a page of its own, permuted) and a chunk's queries."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = _rand_pool(keys[0], (1 + nbps, kv_heads, bs, Dk), dtype)
+    v = _rand_pool(keys[1], (1 + nbps, kv_heads, bs, Dv), dtype)
+    q = _rand_pool(keys[2], (T, heads, Dk), dtype)
+    row = 1 + jax.random.permutation(keys[3], nbps).astype(jnp.int32)
+    return q, _pages(k), _pages(v), row
+
+
+# (heads, KV heads, key width, value width, queries, block, blocks a
+#  sequence, first position, value scale)
+_WINDOW_CASES = {
+    "4x1kv_d16": (4, 1, 16, 16, 16, 8, 6, 0, 1.0),
+    "20x1kv_d128": (20, 1, 128, 128, 16, 8, 6, 5, 1.0),
+    "4x2kv_d128": (4, 2, 128, 128, 16, 8, 6, 17, 1.0),
+    "mha_d128": (2, 2, 128, 128, 16, 8, 6, 9, 1.0),
+    # what the form takes since it cuts the heads out of the run itself
+    "mha16_d64": (16, 16, 64, 64, 32, 16, 8, 70, 1.0),
+    "8x2kv_k48_v32_scaled": (8, 2, 48, 32, 16, 8, 6, 21, 0.707),
+    # a row of three runs of 16 pages, the chunk across the first's edge
+    "across_a_run": (4, 2, 16, 16, 32, 8, 37, 110, 1.0),
+    # 20 x 64 rows against runs of 1,024 positions: two tiles of 10 heads
+    "tiles_of_heads": (20, 1, 16, 16, 64, 64, 20, 1000, 1.0),
+    # 2,048 queries of one head: two tiles of 1,024 of them
+    "tiles_of_queries": (1, 1, 16, 16, 2048, 64, 34, 100, 1.0),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES))
+def test_window_kernel_matches_every_position_as_a_lane(case):
     """A chunk's queries against each page ONCE (`paged_attention_window`,
     interpret mode) against the same queries as lanes of the dense recipe
     over the same table: positions ``start .. start+T-1``, pages permuted,
-    the window ending mid-page."""
+    the window ending mid-page; heads of any width side by side in a row,
+    values narrower than keys, a value scale, several runs, several row
+    tiles."""
     from incubator_mxnet_tpu.ops.paged_attention import (
-        paged_attention_window, window_kernel_fits)
+        _window_rows, pages_per_step, paged_attention_window,
+        window_kernel_fits)
 
-    T, bs, nbps = 16, 8, 6
-    assert window_kernel_fits(T, heads, kv_heads, D)
-    _, k, v, tables, _ = _paged_case(3 + heads, B=1, heads=kv_heads, D=D,
-                                     bs=bs, nbps=nbps)
-    q = _rand_pool(jax.random.PRNGKey(start), (T, heads, D), jnp.float32)
+    heads, kv_heads, Dk, Dv, T, bs, nbps, start, scale = _WINDOW_CASES[case]
+    assert window_kernel_fits(T, heads, kv_heads, Dk, Dv)
+    n = pages_per_step(bs, nbps, kv_heads * (Dk + Dv) * 2)
+    tiles = heads // kv_heads * T // _window_rows(T, heads // kv_heads,
+                                                   n * bs)
+    assert (tiles > 1) == case.startswith("tiles"), tiles
+    assert (nbps > n) == (case in ("across_a_run", "tiles_of_heads",
+                                   "tiles_of_queries"))
+    q, pk, pv, row = _window_case(3 + heads, T, heads, kv_heads, Dk, Dv, bs,
+                                  nbps)
     pos = start + jnp.arange(T, dtype=jnp.int32)
-    want = paged_attention(q, _pages(k), _pages(v),
-                           jnp.broadcast_to(tables, (T, nbps)), pos,
-                           impl="dense")
-    got = paged_attention_window(q, _pages(k), _pages(v), tables[0],
-                                 jnp.int32(start), interpret=True)
-    assert got.shape == q.shape and got.dtype == q.dtype
+    want = paged_attention(q, pk, pv, jnp.broadcast_to(row, (T, nbps)), pos,
+                           value_scale=scale, impl="dense")
+    got = paged_attention_window(q, pk, pv, row, jnp.int32(start),
+                                 value_scale=scale, interpret=True)
+    assert got.shape == (T, heads, Dv) and got.dtype == q.dtype
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 atol=2e-5)
+
+
+def test_window_kernel_takes_bf16_queries_and_keys_as_they_are():
+    """bf16 pools and queries: ``q . k^T`` takes them as they are stored
+    (every product of two bf16 values is exact in float32, so these are
+    the float32 dot's products), everything behind it stays float32; the
+    result agrees with the dense recipe to bf16's rounding of the output."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention_window
+
+    heads, kv_heads, Dk, Dv, T, bs, nbps, start, scale = \
+        _WINDOW_CASES["8x2kv_k48_v32_scaled"]
+    q, pk, pv, row = _window_case(4, T, heads, kv_heads, Dk, Dv, bs, nbps,
+                                  dtype=jnp.bfloat16)
+    pos = start + jnp.arange(T, dtype=jnp.int32)
+    want = paged_attention(q, pk, pv, jnp.broadcast_to(row, (T, nbps)), pos,
+                           value_scale=scale, impl="dense")
+    got = paged_attention_window(q, pk, pv, row, jnp.int32(start),
+                                 value_scale=scale, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    onp.testing.assert_allclose(onp.asarray(got, onp.float32),
+                                onp.asarray(want, onp.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("first,second", [(0, 16), (224, 240), (240, 256)],
+                         ids=["from_0", "across_a_run", "at_a_run"])
+def test_window_position_is_the_same_bits_wherever_its_chunk_began(first,
+                                                                   second):
+    """Runs are aligned to the table's index, not to the chunk's start,
+    and a row's arithmetic reads nothing of another row: a position
+    attended by the chunk that began at ``first`` (the positions behind
+    that chunk's end not yet written: other bits lie there) is, bit for
+    bit, the position attended by the chunk that began at ``second``,
+    which holds it too.  What makes a prefix-cache hit, whose first chunk
+    begins where the hit ends, bit-identical to a cold prefill."""
+    from incubator_mxnet_tpu.ops.paged_attention import (
+        pages_per_step, paged_attention_window)
+
+    heads, kv_heads, D, bs, nbps, CH = 4, 2, 16, 16, 32, 32
+    assert pages_per_step(bs, nbps, kv_heads * D * 4) * bs == 256
+    q, pk, pv, row = _window_case(11, second + CH - first, heads, kv_heads,
+                                  D, D, bs, nbps)
+    # the earlier chunk runs before the later one's own positions exist
+    end = first + CH
+    early_k = pk.at[row[end // bs:]].set(3.0)
+    early_v = pv.at[row[end // bs:]].set(-2.0)
+    a = paged_attention_window(q[:CH], early_k, early_v, row,
+                               jnp.int32(first), interpret=True)
+    b = paged_attention_window(q[second - first:], pk, pv, row,
+                               jnp.int32(second), interpret=True)
+    shared = first + CH - second
+    assert shared == 16
+    assert onp.array_equal(onp.asarray(a[-shared:]), onp.asarray(b[:shared]))
+
+
+def test_window_kernel_never_reads_pages_past_the_chunk():
+    """Of the sequence's table row the window form fetches the pages that
+    hold a position some query of the chunk sees, and no other: a live
+    run's pages past the chunk's last position, and every later run, may
+    hold anything, NaN included (they are reserved for the request's later
+    tokens, or another sequence's by now)."""
+    from incubator_mxnet_tpu.ops.paged_attention import paged_attention_window
+
+    heads, kv_heads, D, bs, nbps, CH = 4, 2, 16, 16, 40, 32
+    q, pk, pv, row = _window_case(12, CH, heads, kv_heads, D, D, bs, nbps)
+    for start in (0, 100, 250):     # in run 0, in its middle, across its end
+        dead = row[(start + CH - 1) // bs + 1:]
+        want = paged_attention_window(q, pk, pv, row, jnp.int32(start),
+                                      interpret=True)
+        got = paged_attention_window(q, pk.at[dead].set(jnp.nan),
+                                     pv.at[dead].set(jnp.nan), row,
+                                     jnp.int32(start), interpret=True)
+        assert onp.isfinite(onp.asarray(got)).all(), start
+        assert onp.array_equal(onp.asarray(got), onp.asarray(want)), start
 
 
 def test_window_kernel_says_what_it_has_no_sizes_for():
     from incubator_mxnet_tpu.ops.paged_attention import (
         paged_attention_window, window_kernel_fits)
 
-    # 64-wide heads side by side (gpt2-medium): a KV head's lanes are no
-    # block of their own, so a chunk stays lanes of the single-query kernel
-    assert not window_kernel_fits(32, 16, 16, 64)
+    # any head width: the kernel cuts a KV head's lanes out of a run of
+    # whole pages itself (64-wide heads side by side: gpt2-medium; one KV
+    # head of 128: jamba2-3b; keys 192 and values 128 wide: mimo-v2-flash)
+    assert window_kernel_fits(32, 16, 16, 64)
     assert window_kernel_fits(256, 20, 1, 128)
-    assert not window_kernel_fits(65536, 20, 1, 128)      # VMEM
-    q, k, v, tables, _ = _paged_case(2, heads=2)
+    assert window_kernel_fits(512, 64, 4, 192, 128)
+    assert window_kernel_fits(65536, 20, 1, 128)          # in row tiles
+    # VMEM: the smallest tile holds a row of every KV head
+    assert not window_kernel_fits(32, 64, 64, 8192)
+    assert not window_kernel_fits(32, 3, 2, 64)           # no whole group
+    S = jax.ShapeDtypeStruct
+    pool = S((9, 8, 64 * 8192), jnp.float32)
     with pytest.raises(ValueError, match="window_kernel_fits"):
-        paged_attention_window(q, _pages(k), _pages(v), tables[0],
-                               jnp.int32(0), interpret=True)
+        jax.eval_shape(
+            lambda *a: paged_attention_window(*a, jnp.int32(0),
+                                              interpret=True),
+            S((32, 64, 8192), jnp.float32), pool, pool, S((8,), jnp.int32))
+    q, k, v, tables, _ = _paged_case(2, heads=2)
+    with pytest.raises(ValueError, match="KV heads"):
+        paged_attention_window(q[:, :1, :12], _pages(k), _pages(v),
+                               tables[0], jnp.int32(0), interpret=True)
 
 
 def test_grouped_heads_refuse_what_is_not_built():

@@ -814,3 +814,71 @@ def test_a_closed_engine_holds_no_device_array(net, flavour):
     assert not any(hasattr(eng, a) for a in (
         "_pool_k", "_pool_v", "_scale_k", "_scale_v", "_rec", "_dpool_k",
         "_dpool_v"))
+
+
+def _hybrid_net():
+    from incubator_mxnet_tpu.models.hybrid_ssm import HybridSSMDecoder
+
+    net = HybridSSMDecoder(
+        vocab_size=V, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=1,
+        attn_layer_period=4, attn_layer_offset=2, mamba_d_state=4,
+        mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=6,
+        max_position_embeddings=MAXLEN)
+    net.initialize()
+    return net
+
+
+def _routed_net():
+    from incubator_mxnet_tpu.models.routed_window import RoutedWindowDecoder
+
+    net = RoutedWindowDecoder(
+        vocab_size=V, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=3, num_attention_heads=8, num_key_value_heads=2,
+        swa_num_key_value_heads=4, head_dim=12, v_head_dim=8,
+        hybrid_layer_pattern=[0, 1, 0], moe_layer_freq=[0, 1, 1],
+        sliding_window=10, moe_intermediate_size=16, n_routed_experts=8,
+        n_routed_experts_published=32, num_experts_per_tok=4,
+        max_position_embeddings=MAXLEN)
+    net.initialize()
+    return net
+
+
+@pytest.mark.parametrize("decoder,kw,want", [
+    ("transformer", {"attn_impl": "pallas"}, "window"),
+    ("hybrid_ssm", {"attn_impl": "pallas"}, "window"),
+    ("routed_window", {"attn_impl": "pallas"}, "window"),
+    ("transformer", {"attn_impl": "pallas", "kv_dtype": "int8"}, "lanes"),
+    ("transformer", {"attn_impl": "dense"}, "dense"),
+    ("hybrid_ssm", {}, "dense"),                 # the CPU's default
+    ("routed_window", {"attn_impl": "dense"}, "dense"),
+], ids=["transformer_pallas", "hybrid_pallas", "routed_pallas",
+        "int8_pages", "transformer_dense", "hybrid_default", "routed_dense"])
+def test_engine_says_how_a_chunk_attends(net, decoder, kw, want):
+    """`varz_config()["chunk_attn"]`, static for an engine and a function
+    of shapes, K/V dtype and implementation alone: "window" where a
+    prefill chunk's queries walk the sequence's pages once together
+    (`paged_attention_window`: every float-paged kernel engine, whatever
+    the decoder's head widths), "lanes" where each is a lane of the
+    single-query kernel (int8 pages), "dense" where no kernel runs; the
+    `paged_attn_chunk{form=}` gauge says the same beside
+    `paged_attn_kernel{path=}`."""
+    from incubator_mxnet_tpu import telemetry
+
+    served = {"transformer": lambda: net, "hybrid_ssm": _hybrid_net,
+              "routed_window": _routed_net}[decoder]()
+    was_on = telemetry.enabled()
+    telemetry.enable()
+    try:
+        with ServingEngine(served, max_batch=2, block_size=8,
+                           poll_interval=_POLL, **kw) as eng:
+            cfg = eng.varz_config()
+            assert cfg["chunk_attn"] == want == eng._programs.chunk_attn
+            assert (cfg["paged_pages_per_step"] > 0) == (want != "dense")
+            for form in ("window", "lanes", "dense"):
+                gauge = telemetry.gauge("paged_attn_chunk",
+                                        labels={"form": form})
+                assert gauge.value == (1.0 if form == want else 0.0)
+    finally:
+        if not was_on:
+            telemetry.disable()
