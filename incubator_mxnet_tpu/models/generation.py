@@ -37,7 +37,8 @@ import jax.numpy as jnp
 
 __all__ = ["lm_generate", "lm_beam_search", "lm_score", "lm_stream",
            "nmt_translate", "bucket_length", "DecoderSpec", "SsmSpec",
-           "AttnSpec", "MoeSpec", "StackedLayers", "decoder_spec"]
+           "AttnSpec", "IndexSpec", "MoeSpec", "StackedLayers",
+           "decoder_spec"]
 
 
 class SsmSpec(NamedTuple):
@@ -48,29 +49,48 @@ class SsmSpec(NamedTuple):
     dt_rank: int
 
 
+class IndexSpec(NamedTuple):
+    """The index of a learned sparse attention layer: ``heads`` index
+    query heads of ``dim`` against one index key a position score every
+    earlier position, ``sum_j w_j relu(qi_j . ki_s)``, and a query attends
+    the ``topk`` positions that score highest (all, while there are no
+    more; ties to the lower position).  The index keys are a third pool a
+    layer beside K and V (`ops.sparse_attention`)."""
+    heads: int
+    dim: int
+    topk: int
+
+
 class AttnSpec(NamedTuple):
     """One attention layer where the layers differ: its KV heads, its
     window (0: every earlier position; W: positions ``t-W+1 .. t``, whose
     pages go back behind it), whether a learned logit a query head joins
-    the softmax's denominator (``sink``), and its rotary base."""
+    the softmax's denominator (``sink``), its rotary base, the index that
+    picks the positions a query attends (None: all the mask admits) and
+    whether an RMSNorm with a gain over the head's lanes goes over every
+    query and key head before the rotary (``qk_norm``)."""
     kv_heads: int
     window: int
     sink: bool
     rope_base: float
+    index: Optional[IndexSpec] = None
+    qk_norm: bool = False
 
 
 class MoeSpec(NamedTuple):
     """Sizes of a routed feed-forward ("routed" in ``acts``): the router
-    scores ``experts`` of them by a sigmoid and takes the ``top_k`` largest
-    of score + selection bias; weights are the selected scores over their
-    sum; of the experts this program holds ``held`` from ``first`` on,
-    each a gated SiLU MLP of ``width``.  What the others would add is left
-    out (another chip's share)."""
+    scores ``experts`` of them and takes the ``top_k`` largest: by a
+    sigmoid, of score + selection bias (``scoring`` "sigmoid"), or by a
+    softmax over all of them, with no bias ("softmax"); weights are the
+    selected scores over their sum; of the experts this program holds
+    ``held`` from ``first`` on, each a gated SiLU MLP of ``width``.  What
+    the others would add is left out (another chip's share)."""
     experts: int
     first: int
     held: int
     top_k: int
     width: int
+    scoring: str = "sigmoid"
 
 
 class DecoderSpec(NamedTuple):
@@ -95,14 +115,19 @@ class DecoderSpec(NamedTuple):
                  window, no sink, no rotary).  With it a layer's weights
                  are ``q``, ``k``, ``v`` (three matrices, keys ``head_dim``
                  and values ``v_dim`` wide) and ``sink`` ((heads,), where
-                 the layer has one) in place of ``qkv``
+                 the layer has one) in place of ``qkv``; a layer with
+                 ``qk_norm`` also ``q_norm`` and ``k_norm`` ((head_dim,)
+                 gains), one with an ``index`` ``index_q`` ((heads * dim,
+                 units)), ``index_k`` ((dim, units)) and ``index_w``
+                 ((heads, units)), matrices over the layer's normed input
     v_dim        width of a value head (0: ``head_dim``)
     rope_dim     leading lanes of every query and key head that rotate
                  with the position (halves-rotated form), 0: none
     value_scale  what an attention layer's output is multiplied by
     moe          `MoeSpec` of the "routed" feed-forwards, None without any;
                  such a layer holds ``router`` ((experts, units) matrix,
-                 (experts,) selection bias) and ``experts`` (gate, up
+                 (experts,) selection bias, None where the scoring has
+                 none) and ``experts`` (gate, up
                  (held, width, units), down (held, units, width))
 
     The weight pytree: ``embed``, ``pe`` (None without positions), ``ln``,
@@ -143,11 +168,20 @@ class DecoderSpec(NamedTuple):
         return max((a.window for a in self.attn), default=0)
 
     @property
+    def index(self) -> Optional[IndexSpec]:
+        """The index of the attention layers that have one (they share
+        it), None where no layer selects what it attends."""
+        return next((a.index for a in self.attn if a.index), None)
+
+    @property
     def carried(self) -> bool:
         """Whether a sequence carries state a block table does not name
-        (a recurrence, a window's ring) or the programs carry counts
-        (routed experts): no prefix hit, no speculation, no int8 K/V."""
-        return self.recurrent or self.window > 0 or self.moe is not None
+        (a recurrence, a window's ring), holds pages no prefix hit or
+        scale pool knows of (an index's keys) or the programs carry counts
+        (routed experts, an index): no prefix hit, no speculation, no int8
+        K/V."""
+        return self.recurrent or self.window > 0 or self.moe is not None \
+            or self.index is not None
 
 
 @jax.tree_util.register_pytree_node_class

@@ -51,7 +51,7 @@ from ..ndarray.ndarray import apply_op, wrap
 from .generation import _dense, _rms, _rope
 from .hybrid_ssm import RMSNorm
 
-__all__ = ["RoutedWindowDecoder"]
+__all__ = ["RoutedWindowDecoder", "PerLayerLeaves"]
 
 
 def _attention(x, w, H, Hkv, Dk, window, rope, base, scale):
@@ -97,13 +97,20 @@ def _gated(x, gate, up, down):
 
 
 def _routed(x, w, top_k, first):
-    """x (T, C): every held expert over every token, weighted where the
-    token chose it."""
+    """x (T, C): a sigmoid router with a selection bias, then the held
+    experts."""
     f32 = jnp.float32
     g = jax.nn.sigmoid(jnp.einsum("tc,ec->te", x, w["router"],
                                   preferred_element_type=f32))
     _, idx = jax.lax.top_k(g + w["router_bias"].astype(f32), top_k)
-    sel = jnp.take_along_axis(g, idx, 1)
+    return _held_experts(x, w, idx, jnp.take_along_axis(g, idx, 1), first)
+
+
+def _held_experts(x, w, idx, sel, first):
+    """x (T, C): every held expert over every token, weighted where the
+    token chose it (``idx``, ``sel`` (T, K): the experts each token chose
+    and their scores, renormalised here over all K)."""
+    f32 = jnp.float32
     wts = sel / jnp.sum(sel, 1, keepdims=True)
     held = w["gate_e"].shape[0]
     share = jnp.sum(jnp.where(
@@ -141,7 +148,63 @@ def _forward(static, tokens, p):
     return jax.vmap(one)(tokens)
 
 
-class RoutedWindowDecoder(HybridBlock):
+class PerLayerLeaves(HybridBlock):
+    """A decoder that holds a Parameter a layer and leaf (``q_w3``: the
+    trailing number is the layer; the module's docstring says why), and
+    what follows from that alone: the leaves' creation, a dict a layer,
+    the eager forward over them, the fingerprint.  A subclass sets
+    ``_depth``, ``_max_len`` and ``embed``, creates its leaves with
+    `_leaf`, ``head_w`` first, and gives `_forward_static` (a jitted
+    ``fn(static, tokens, p)`` and its hashable ``static``)."""
+
+    def _leaf(self, name, shape, init=None, layer=None):
+        at = name if layer is None else f"{name}{layer}"
+        dtype, grad_req = self._leaf_kw
+        setattr(self, at, self.params.get(
+            at, shape=shape, dtype=dtype, init=init, grad_req=grad_req))
+        self._leaves.append((layer, name))
+
+    def _layer_leaves(self, get):
+        """[{name: get(Parameter)}], a dict a layer."""
+        out = [{} for _ in range(self._depth)]
+        for layer, name in self._leaves:
+            if layer is not None:
+                out[layer][name] = get(getattr(self, f"{name}{layer}"))
+        return out
+
+    def forward(self, tokens):
+        tokens = wrap(tokens)
+        if tokens.shape[1] > self._max_len:
+            raise ValueError(f"sequence {tokens.shape[1]} exceeds "
+                             f"max_position_embeddings {self._max_len}")
+        fn, static = self._forward_static()
+        params = [getattr(self, name if layer is None else f"{name}{layer}")
+                  for layer, name in self._leaves]
+
+        def run(t, embed, ln, *leaves):
+            at = dict(zip(map(id, params), leaves))
+            return fn(static, t, {
+                "embed": embed, "ln": ln, "head": leaves[0],
+                "layers": self._layer_leaves(lambda p: at[id(p)])})
+
+        return apply_op(run, tokens, self.embed.weight.data(),
+                        self.ln.gamma.data(), *(p.data() for p in params))
+
+    def serve(self, **kw):
+        """This net's shared `serving.ServingEngine`, built on first use."""
+        from ..serving import default_engine
+
+        return default_engine(self, **kw)
+
+    def decoder_fingerprint(self):
+        leaves = self.__dict__.get("_decoder_leaves")
+        if leaves is None:
+            leaves = self._decoder_leaves = list(
+                self.collect_params().values())
+        return tuple(id(p.data()._data) for p in leaves)
+
+
+class RoutedWindowDecoder(PerLayerLeaves):
     """Keyword arguments are the published configuration's keys
     (``num_key_value_heads`` / ``rope_theta`` of the full layers, the
     ``swa_`` ones of the window layers; a 1 in ``hybrid_layer_pattern`` is
@@ -205,17 +268,13 @@ class RoutedWindowDecoder(HybridBlock):
              bool(moe))
             for win, moe in zip(hybrid_layer_pattern, moe_layer_freq))
         self._sink = bool(add_swa_attention_sink_bias)
+        self._depth = L
         self.embed = nn.Embedding(vocab_size, C, dtype=dtype)
         self.embed.weight.grad_req = grad_req
         F, Fe = intermediate_size, moe_intermediate_size
         self._leaves = []       # (layer or None, name without the layer)
-
-        def leaf(name, shape, init=None, layer=None):
-            at = name if layer is None else f"{name}{layer}"
-            setattr(self, at, self.params.get(
-                at, shape=shape, dtype=dtype, init=init, grad_req=grad_req))
-            self._leaves.append((layer, name))
-
+        self._leaf_kw = (dtype, grad_req)
+        leaf = self._leaf
         leaf("head_w", (vocab_size, C))
         for i, (Hkv, win, _, moe) in enumerate(self._layers):
             shapes = {"ln1_g": ((C,), "ones"), "ln2_g": ((C,), "ones"),
@@ -236,39 +295,10 @@ class RoutedWindowDecoder(HybridBlock):
                 leaf(name, *given, layer=i)
         self.ln = RMSNorm(C, layernorm_epsilon, dtype, grad_req)
 
-    def _layer_leaves(self, get):
-        """[{name: get(Parameter)}], a dict a layer."""
-        out = [{} for _ in self._layers]
-        for layer, name in self._leaves:
-            if layer is not None:
-                out[layer][name] = get(getattr(self, f"{name}{layer}"))
-        return out
-
-    def forward(self, tokens):
-        tokens = wrap(tokens)
-        if tokens.shape[1] > self._max_len:
-            raise ValueError(f"sequence {tokens.shape[1]} exceeds "
-                             f"max_position_embeddings {self._max_len}")
-        static = (self._layers, self._heads, self._dk, self._window,
-                  self._rope, self._scale, self._eps, self._top_k,
-                  self._first)
-        params = [getattr(self, name if layer is None else f"{name}{layer}")
-                  for layer, name in self._leaves]
-
-        def run(t, embed, ln, *leaves):
-            at = dict(zip(map(id, params), leaves))
-            return _forward(static, t, {
-                "embed": embed, "ln": ln, "head": leaves[0],
-                "layers": self._layer_leaves(lambda p: at[id(p)])})
-
-        return apply_op(run, tokens, self.embed.weight.data(),
-                        self.ln.gamma.data(), *(p.data() for p in params))
-
-    def serve(self, **kw):
-        """This net's shared `serving.ServingEngine`, built on first use."""
-        from ..serving import default_engine
-
-        return default_engine(self, **kw)
+    def _forward_static(self):
+        return _forward, (self._layers, self._heads, self._dk, self._window,
+                          self._rope, self._scale, self._eps, self._top_k,
+                          self._first)
 
     # -- what the serving programs read ---------------------------------- #
     def decoder_spec(self):
@@ -315,10 +345,3 @@ class RoutedWindowDecoder(HybridBlock):
         return {"embed": self.embed.weight.data()._data, "pe": None,
                 "ln": (self.ln.gamma.data()._data,),
                 "head": (self.head_w.data()._data, None), "layers": layers}
-
-    def decoder_fingerprint(self):
-        leaves = self.__dict__.get("_decoder_leaves")
-        if leaves is None:
-            leaves = self._decoder_leaves = list(
-                self.collect_params().values())
-        return tuple(id(p.data()._data) for p in leaves)

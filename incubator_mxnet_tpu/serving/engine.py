@@ -90,7 +90,7 @@ import numpy as np
 
 from .. import telemetry
 from .kv_pool import SCRATCH_BLOCK, BlockPool
-from .programs import PagedPrograms
+from .programs import PagedPrograms, wide_counts
 
 __all__ = ["ServingError", "RequestShed", "RequestTimedOut",
            "RequestCancelled", "RequestFailed", "Request", "ServingEngine",
@@ -469,8 +469,11 @@ class ServingEngine:
         self._share = (spec.moe.held, spec.moe.experts) \
             if spec.moe is not None else (0, 0)
         self._state_resets = 0          # first chunks since the last record
-        # the experts' counts the last landed step brought with its tokens
+        # the experts' counts the last landed step brought with its tokens,
+        # and behind them an index's (positions scored, positions attended)
         self._expert_counts = (0, 0, 0)
+        self._index = spec.index
+        self._index_counts = (0, 0)
         self._max_queue = int(max_queue if max_queue is not None
                               else _MAX_QUEUE)
         self._eos = int(eos_id)
@@ -490,6 +493,9 @@ class ServingEngine:
             telemetry.gauge("serving_window_pool_bytes",
                             labels={"engine": self._name}) \
                 .set(self.window_pool_bytes)
+            telemetry.gauge("serving_index_pool_bytes",
+                            labels={"engine": self._name}) \
+                .set(self.index_pool_bytes)
             telemetry.gauge("serving_experts_held",
                             labels={"engine": self._name}) \
                 .set(self._share[0])
@@ -619,7 +625,8 @@ class ServingEngine:
     @property
     def kv_pool_bytes(self) -> int:
         """Device bytes of the whole KV pool (pages + int8 scales,
-        all layers) — the denominator of the int8 capacity win.
+        all layers, and the index keys' pools of a decoder that has an
+        index) — the denominator of the int8 capacity win.
         Frozen at construction: donation swaps the pool arrays every
         step but never their shapes."""
         return self._programs.kv_pool_bytes
@@ -631,6 +638,14 @@ class ServingEngine:
         `max_seq_len`; 0 for a decoder without window layers.  Not part of
         `kv_pool_bytes`, which is the pool the block tables name."""
         return self._programs.window_pool_bytes
+
+    @property
+    def index_pool_bytes(self) -> int:
+        """Device bytes of the index keys' pools (every layer with an
+        index; a row a position, named by the block tables as K's and
+        V's): part of `kv_pool_bytes`, so a block's and a token's bytes
+        count all three arrays; 0 for a decoder without an index."""
+        return self._programs.index_pool_bytes
 
     @property
     def state_bytes(self) -> int:
@@ -648,7 +663,8 @@ class ServingEngine:
     @property
     def kv_block_bytes(self) -> int:
         """Pool bytes one block costs across all layers (K + V +
-        scales); `kv_pool_bytes == num_blocks * kv_block_bytes`."""
+        scales + index keys); `kv_pool_bytes == num_blocks *
+        kv_block_bytes`."""
         return self.kv_pool_bytes // self._num_blocks
 
     @property
@@ -870,6 +886,10 @@ class ServingEngine:
             "prefix_cache": not self._carried,
             "kv_pool_bytes": self.kv_pool_bytes,
             "window_pool_bytes": self.window_pool_bytes,
+            # of kv_pool_bytes, the index keys' pools; the positions a
+            # query attends of those its index scores (0, 0: no index)
+            "index_pool_bytes": self.index_pool_bytes,
+            "index_topk": self._index.topk if self._index else 0,
             # 0: every attention layer attends every earlier position
             "attention_window": self._window,
             "window_blocks_per_lane": self._programs.window_blocks,
@@ -1023,6 +1043,7 @@ class ServingEngine:
                 "blocks_total": self._num_blocks - 1,
                 "kv_pool_bytes": self.kv_pool_bytes,
                 "window_pool_bytes": self.window_pool_bytes,
+                "index_pool_bytes": self.index_pool_bytes,
                 "state_bytes": self.state_bytes,
                 "prefix_cache": {
                     "hits": self._stats["prefix_hits"],
@@ -1588,9 +1609,13 @@ class ServingEngine:
         with self._prof.phase("device_step"):
             nxt = np.asarray(nxt)           # sync: tokens are consumed now
             dt = time.perf_counter() - t0
+        counts = [int(c) for c in nxt[self._B:]]
         if self._moe is not None:
             # behind the lanes' tokens: pairs, tokens routed, busiest
-            self._expert_counts = tuple(int(c) for c in nxt[self._B:])
+            self._expert_counts, counts = tuple(counts[:3]), counts[3:]
+        if self._index is not None:
+            # then positions scored and attended, each in two words
+            self._index_counts = wide_counts(counts)
         now = time.monotonic()
 
         def deliver(mark):
@@ -1641,6 +1666,9 @@ class ServingEngine:
             if self._moe is not None:
                 (pool_use["expert_pairs"], pool_use["expert_tokens"],
                  pool_use["expert_busiest"]) = self._expert_counts
+            if self._index is not None:
+                (pool_use["index_positions_scored"],
+                 pool_use["sparse_positions_attended"]) = self._index_counts
         # close the ledger OUTSIDE the engine lock (it takes its own
         # leaf lock + histogram locks; never nested under self._work)
         prof.end_step(rids=[req.rid for _, req in live],

@@ -36,7 +36,9 @@ families, built only when the engine configures ``speculate_k > 0``:
 
 Both donate the pool arrays, their scale pools and the recurrent state
 (``donate_argnums=(0, 1, 2, 3, 4)``; the state is ``()`` for a decoder
-of attention layers only): the K/V pool
+of attention layers only, the carried counts for one with routed experts,
+and for one whose layers select by an index the counts and a third pool a
+layer, the index keys', beside K's and V's): the K/V pool
 is a ring the engine threads through every call, and an un-donated
 pool would copy the whole cache per token.  Donation coverage is
 CI-pinned via `.hlolint_contracts.json` (serving_* entries).  A pool
@@ -86,6 +88,8 @@ from ..ops.paged_attention import (default_impl, paged_attention,
                                    window_kernel_fits, write_rows)
 from ..ops.moe_experts import routed_experts
 from ..ops.selective_scan import selective_scan
+from ..ops.sparse_attention import (index_row, index_scores,
+                                    paged_attention_sparse, select_positions)
 
 __all__ = ["PagedPrograms"]
 
@@ -252,14 +256,20 @@ def _ring_block(lane, blk, n):
 
 def _route(moe, x, router):
     """The router of a routed feed-forward over tokens ``x`` (N, C):
-    sigmoid scores over all experts in float32, the ``top_k`` largest of
-    score + selection bias, the selected scores over their sum.  Returns
-    ``(idx (N, K) int32, weights (N, K) float32)``."""
+    scores over all experts in float32 (`generation.MoeSpec`: a sigmoid
+    each, or a softmax over all), the ``top_k`` largest (of score +
+    selection bias, where the scoring has one), the selected scores over
+    their sum.  Returns ``(idx (N, K) int32, weights (N, K) float32)``."""
     w, bias = router
-    g = jax.nn.sigmoid(jax.lax.dot_general(
+    g = jax.lax.dot_general(
         x, w.astype(x.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32))
-    _, idx = jax.lax.top_k(g + bias.astype(jnp.float32), moe.top_k)
+        preferred_element_type=jnp.float32)
+    if moe.scoring == "softmax":
+        g = jax.nn.softmax(g, axis=-1)
+        _, idx = jax.lax.top_k(g, moe.top_k)
+    else:
+        g = jax.nn.sigmoid(g)
+        _, idx = jax.lax.top_k(g + bias.astype(jnp.float32), moe.top_k)
     sel = jnp.take_along_axis(g, idx, axis=1)
     return idx.astype(jnp.int32), sel / jnp.sum(sel, axis=1, keepdims=True)
 
@@ -323,8 +333,17 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
     kernel) over the tokens that are ``ok``; ``rec`` is then ``(counts,)``,
     three int32 the programs carry: pairs computed, tokens routed (a
     layer each), the most pairs one expert of one layer took, since the
-    last decode step reported them."""
+    last decode step reported them.
+
+    A layer with an index (`generation.IndexSpec`) also projects the index
+    queries, the index key and the heads' weights from its normed input
+    (``layer<i>/indexer``) and hands them to ``attend`` with the layer's
+    index pool, which comes back written: ``rec`` is then ``(counts, index
+    pools)``, a pool an indexed layer, and the counts gain four int32
+    (`_add_wide`): positions scored and positions attended, summed over
+    the ``ok`` queries and the indexed layers."""
     new_k, new_v, new_sk, new_sv, new_st, new_cv = [], [], [], [], [], []
+    new_pi = []                         # index pools, an indexed layer
     routed = []                         # pairs by held expert, a layer
     for li, (lp, kind, act) in enumerate(zip(params["layers"], spec.kinds,
                                              spec.acts)):
@@ -342,12 +361,32 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
                 q = G._dense(x, *lp["q"]).reshape(lead + (spec.heads, -1))
                 k = G._dense(x, *lp["k"]).reshape(lead + (a.kv_heads, -1))
                 v = G._dense(x, *lp["v"]).reshape(lead + (a.kv_heads, -1))
+                if a.qk_norm:
+                    q = G._rms(q, *lp["q_norm"], eps=spec.eps)
+                    k = G._rms(k, *lp["k_norm"], eps=spec.eps)
                 if spec.rope_dim and a.rope_base:
                     with jax.named_scope("rope"):
                         q = G._rope(q, pos, spec.rope_dim, a.rope_base)
                         k = G._rope(k, pos, spec.rope_dim, a.rope_base)
-                o, pk, pv = attend(a, lp.get("sink"), q, k, v,
-                                   pool_k[len(new_k)], pool_v[len(new_k)])
+                if a.index:
+                    ix = a.index
+                    with jax.named_scope("indexer"):
+                        qi = G._dense(x, *lp["index_q"]).reshape(
+                            lead + (ix.heads, ix.dim))
+                        ki = G._dense(x, *lp["index_k"]).reshape(
+                            lead + (1, ix.dim))
+                        qi = G._rope(qi, pos, ix.dim, a.rope_base)
+                        ki = G._rope(ki, pos, ix.dim, a.rope_base)
+                        wi = _dense32(x, *lp["index_w"])
+                    o, pk, pv, pi = attend(
+                        a, None, q, k, v, pool_k[len(new_k)],
+                        pool_v[len(new_k)],
+                        (qi, ki, wi, rec[1][len(new_pi)]))
+                    new_pi.append(pi)
+                else:
+                    o, pk, pv = attend(a, lp.get("sink"), q, k, v,
+                                       pool_k[len(new_k)],
+                                       pool_v[len(new_k)])
                 h = h + G._dense(o.reshape(lead + (-1,)), *lp["proj"])
                 new_k.append(pk)
                 new_v.append(pv)
@@ -390,14 +429,88 @@ def _layers(spec, params, kv8, h, pool_k, pool_v, scale_k, scale_v, rec,
             with jax.named_scope("ffn"):
                 h = h + G._ffn_fwd(G._norm(spec, h, lp["ln2"]), lp, act)
     new_rec = (tuple(new_st), tuple(new_cv)) if new_st else ()
+    carried = []
     if routed:
         counts = jnp.stack(routed)                      # (layers, held)
-        new_rec = (jnp.stack([
+        carried = [
             rec[0][0] + jnp.sum(counts),
             rec[0][1] + len(routed) * jnp.sum(ok.astype(jnp.int32)),
-            jnp.maximum(rec[0][2], jnp.max(counts))]).astype(jnp.int32),)
+            jnp.maximum(rec[0][2], jnp.max(counts))]
+    if new_pi:
+        at = len(carried)
+        context = jnp.where(ok, pos + 1, 0)
+        carried += _add_wide(rec[0][at], rec[0][at + 1], jnp.sum(context),
+                             len(new_pi))
+        carried += _add_wide(
+            rec[0][at + 2], rec[0][at + 3],
+            jnp.sum(jnp.minimum(context, spec.index.topk)), len(new_pi))
+    if carried:
+        new_rec = (jnp.stack(carried).astype(jnp.int32),) \
+            + ((tuple(new_pi),) if new_pi else ())
     return (h, tuple(new_k), tuple(new_v), tuple(new_sk), tuple(new_sv),
             new_rec)
+
+
+# a count that can pass int32 between two decode steps (a long prompt's
+# chunks with no lane decoding) is carried as two: what lies above 2**20,
+# and the rest
+_WIDE = 20
+
+
+def _add_wide(hi, lo, x, times):
+    """``[hi, lo]`` of the count ``hi * 2**20 + lo`` after ``times`` times
+    the int32 ``x`` is added to it."""
+    lo = lo + times * (x & ((1 << _WIDE) - 1))
+    return [hi + times * (x >> _WIDE) + (lo >> _WIDE),
+            lo & ((1 << _WIDE) - 1)]
+
+
+def wide_counts(words) -> tuple:
+    """The counts that `_add_wide` carried as ``hi, lo, hi, lo, ...``."""
+    return tuple((int(hi) << _WIDE) + int(lo)
+                 for hi, lo in zip(words[0::2], words[1::2]))
+
+
+def counts_carried(spec) -> int:
+    """How many int32 the programs of this decoder carry and hand over
+    behind a decode step's tokens: three for routed experts (pairs, tokens
+    routed, the busiest expert's pairs), then four for an index (positions
+    scored and positions attended, each as `_add_wide` has it)."""
+    return 3 * (spec.moe is not None) + 4 * (spec.index is not None)
+
+
+def _sparse_attend(ix, impl, tables, last, qpos, wblk, off):
+    """`_layers`' ``attend`` of a layer with an index, for ``N`` sequences
+    of ``T`` queries (a decode step's lanes of one, a prefill chunk's one
+    of ``T``) whose arrays come ``lead``-shaped: write K/V and the index
+    key at ``(wblk, off)``, score every position at or before each query's
+    own (``qpos``) against the index pool, select the ``topk`` best, and
+    attend those alone.  ``last`` (N,): each sequence's last query's
+    position."""
+    impl = "pallas" if impl == "pallas" else "xla"
+    N, T = qpos.shape
+
+    def seqs(x):                # (lanes or chunk, ...) -> (N, T, ...)
+        return x.reshape((N, T) + x.shape[1:])
+
+    def attend(a, sink, q, k, v, pk, pv, index):
+        qi, ki, wi, pi = index
+        with jax.named_scope("kv_write"):
+            pk, pv = write_rows(pk, wblk, off, k), write_rows(pv, wblk, off, v)
+        with jax.named_scope("indexer"):
+            pi = write_rows(pi, wblk, off, jnp.pad(
+                ki, ((0, 0),) * (ki.ndim - 1)
+                + ((0, pi.shape[2] - ki.shape[-1]),)))
+            scores = index_scores(seqs(qi), seqs(wi), pi, tables, last,
+                                  impl=impl)
+        with jax.named_scope("select"):
+            seen = select_positions(scores, qpos, ix.topk)
+        with jax.named_scope("sparse_attn"):
+            o = paged_attention_sparse(seqs(q), pk, pv, tables, last, seen,
+                                       impl=impl)
+        return o.reshape(q.shape), pk, pv, pi
+
+    return attend
 
 
 def _step_attend(spec, bs, attn_impl, tables, pos, ok, wblk, off):
@@ -407,7 +520,9 @@ def _step_attend(spec, bs, attn_impl, tables, pos, ok, wblk, off):
     pages are a ring a lane (`_ring_block`): its table is reckoned here
     from the lane and its position, from the first visible block on, with
     positions counted from that block's start, which is the form
-    `paged_attention` takes a first visible position in."""
+    `paged_attention` takes a first visible position in.  A layer with an
+    index scores, selects and attends through the lanes' block tables
+    (`_sparse_attend`)."""
     W = spec.window
     if W:
         n = ring_blocks(W, bs)
@@ -418,8 +533,13 @@ def _step_attend(spec, bs, attn_impl, tables, pos, ok, wblk, off):
             lane[:, None], base[:, None] + jnp.arange(n, dtype=jnp.int32), n)
         pos_w, first_w = pos - base * bs, lo - base * bs
         wblk_w = jnp.where(ok, _ring_block(lane, pos // bs, n), jnp.int32(0))
+    if spec.index:
+        sparse = _sparse_attend(spec.index, attn_impl, tables, pos,
+                                pos[:, None], wblk, off)
 
-    def attend(a, sink, q, k, v, pk, pv):
+    def attend(a, sink, q, k, v, pk, pv, index=None):
+        if a.index:
+            return sparse(a, sink, q, k, v, pk, pv, index)
         tb, wb, p, first = (tables_w, wblk_w, pos_w, first_w) if a.window \
             else (tables, wblk, pos, None)
         with jax.named_scope("kv_write"):
@@ -570,8 +690,12 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
                        decoder without ssm layers
       prev             (B,) int32 — the step before's ``next_tokens``, the
                        device array they still are (not donated); with
-                       routed layers three counts follow the tokens, in
-                       and out (`_layers`), and ``rec`` is their carry
+                       routed layers or an index `counts_carried` counts
+                       follow the tokens, in and out (`_layers`), and
+                       ``rec`` is their carry, with an index ``(counts,
+                       index pools)``: a third pool an indexed layer,
+                       (num_blocks, bs, `index_row` (index dim)), rows
+                       as K's and V's
       tables           (B, blocks_per_seq) int32 block ids per lane
       toks             (B,) int32 — the host's token of a lane
       fresh            (B,) bool — lanes whose input token is the host's
@@ -595,7 +719,7 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
 
     def serving_step(pool_k, pool_v, scale_k, scale_v, rec, prev, tables,
                      toks, fresh, pos, active, keys, params):
-        if spec.moe is not None:
+        if counts_carried(spec):
             prev = prev[:toks.shape[0]]         # its counts follow it
         toks = jnp.where(fresh, toks, prev)
         new_k, new_v, new_sk, new_sv, new_rec, logits = _token_forward(
@@ -603,12 +727,12 @@ def _build_step(spec, block_size, blocks_per_seq, temperature, top_k,
             pool_k, pool_v, scale_k, scale_v, rec, tables, toks, pos, active)
         with jax.named_scope("pick"):
             nxt = jax.vmap(pick)(logits, pos, keys)
-        if spec.moe is not None:
-            # the experts' counts since the step before (this step's and
-            # the chunk's before it) leave with the tokens, and the carry
+        if counts_carried(spec):
+            # the counts since the step before (this step's and the
+            # chunk's before it) leave with the tokens, and the carry
             # starts again from zero
             nxt = jnp.concatenate([nxt, new_rec[0]])
-            new_rec = (jnp.zeros_like(new_rec[0]),)
+            new_rec = (jnp.zeros_like(new_rec[0]),) + new_rec[1:]
         return new_k, new_v, new_sk, new_sv, new_rec, nxt
 
     serving_step.__name__ = name
@@ -622,11 +746,15 @@ def chunk_attention(spec, chunk, kv_dtype, attn_impl) -> str:
     chunk's queries walk the pages once together), ``"lanes"`` (every
     position a lane of the single-query kernel: int8 pages, which the
     window form has no scales for, and sizes it has no tiling for) or
-    ``"dense"`` (no kernel)."""
+    ``"dense"`` (no kernel); ``"sparse"`` where every such layer has an
+    index (`ops.sparse_attention`: the pages once for a tile of queries,
+    under each query's selection)."""
     if attn_impl != "pallas":
         return "dense"
-    full = {a.kv_heads for a in spec.attn if not a.window} if spec.attn \
-        else {spec.kv_heads}
+    full = {a.kv_heads for a in spec.attn if not a.window and not a.index} \
+        if spec.attn else {spec.kv_heads}
+    if spec.index and not full:
+        return "sparse"
     fits = kv_dtype != "int8" and all(
         window_kernel_fits(chunk, spec.heads, kv_heads, spec.head_dim,
                            spec.v_dim or None) for kv_heads in full)
@@ -644,7 +772,10 @@ def _chunk_attend(spec, bs, attn_impl, window, tables, table_row, posc, ok,
     chunk before left it; then it writes into the ring only what a later
     query can still see, the ``window - 1`` positions before the chunk's
     end.  So a chunk needs no pages of its own in a window layer, however
-    long it is (docs/serving.md, "Window layers")."""
+    long it is (docs/serving.md, "Window layers").  A layer with an index
+    writes the chunk's K/V and index keys into the sequence's pages, then
+    scores, selects and attends a query at a time in one call each
+    (`_sparse_attend`)."""
     W = spec.window
     if W:
         n, W1 = ring_blocks(W, bs), W - 1
@@ -655,8 +786,17 @@ def _chunk_attend(spec, bs, attn_impl, window, tables, table_row, posc, ok,
         tail = ok & (posc >= end - W1)
         wblk_w = jnp.where(tail, _ring_block(lane, posc // bs, n),
                            jnp.int32(0))
+    if spec.index:
+        # the chunk's queries stand at start .. start+T-1, clipped or not:
+        # a query past the sequence's length is not ok, and its row unread
+        T = posc.shape[0]
+        sparse = _sparse_attend(
+            spec.index, attn_impl, table_row[None], (start + T - 1)[None],
+            (start + jnp.arange(T, dtype=jnp.int32))[None], wblk, off)
 
-    def attend(a, sink, q, k, v, pk, pv):
+    def attend(a, sink, q, k, v, pk, pv, index=None):
+        if a.index:
+            return sparse(a, sink, q, k, v, pk, pv, index)
         if not a.window:
             with jax.named_scope("kv_write"):
                 pk, pv = write_rows(pk, wblk, off, k), \
@@ -993,7 +1133,11 @@ class PagedPrograms:
     kind of per-sequence state (docs/serving.md, "Two kinds of state"),
     per ssm layer one float32 recurrent state ``(B, d_state, d_inner)``
     and one conv window ``(d_conv-1, B, d_inner)``, a row a lane, which a
-    prompt's first chunk starts from zero; when speculating, the draft's
+    prompt's first chunk starts from zero; for a decoder whose attention
+    layers select by an index, in that same slot the carried counts and an
+    index pool a layer, ``(num_blocks, bs, index_row(dim))``, named by the
+    block tables as K's and V's are (docs/serving.md, "An index over the
+    pages"); when speculating, the draft's
     K/V pools in the draft model's dtype, addressed by the SAME block
     tables and `BlockPool` ids as the target's, so one lane allocation
     covers both and eviction frees both.  This object holds the ONLY
@@ -1033,8 +1177,10 @@ class PagedPrograms:
             # a recurrence cannot be rolled back to a rejected position,
             # nor can a ring that has given a block's place away, and
             # neither state is a page a scale could sit beside; the
-            # speculative programs carry no experts' counts
+            # speculative programs carry no experts' counts, and neither
+            # they nor a prefix hit nor a scale pool know of an index pool
             what = "recurrent (ssm) layers" if self._spec.recurrent \
+                else "an index over its pages" if self._spec.index \
                 else "window layers or routed experts"
             if int(speculate_k) > 0 or draft_net is not None:
                 raise ValueError(
@@ -1083,6 +1229,7 @@ class PagedPrograms:
         self._label = self.path + ("_ssm" if self._spec.recurrent else "") \
             + ("_win" if self._spec.window else "") \
             + ("_moe" if self._spec.moe is not None else "") \
+            + ("_idx" if self._spec.index else "") \
             + sfx + ("_pallas" if self._impl_forced
                      and self._impl == "pallas" else "")
         self._params = None
@@ -1213,8 +1360,14 @@ class PagedPrograms:
             n_ssm = spec.kinds.count("ssm")
             rec = (each(n_ssm, (B, Ds, Di), jnp.float32),
                    each(n_ssm, (K - 1, B, Di), emb.dtype))
-        elif spec.moe is not None:
-            rec = (jnp.zeros((3,), jnp.int32),)     # the experts' counts
+        elif counts_carried(spec):
+            # the counts, then an index pool a layer that has an index:
+            # rows as K's and V's, named by the same block tables
+            pools_i = tuple(
+                jnp.zeros((self._num_blocks, bs, index_row(a.index.dim)), dt)
+                for a in spec.attn if a.index)
+            rec = (jnp.zeros((counts_carried(spec),), jnp.int32),) \
+                + ((pools_i,) if pools_i else ())
         n_sc = L if kv8 else 0
         # a window layer's pages: a ring of `window_blocks` a lane behind
         # the scratch block, whatever `max_seq_len` (0: no window layer)
@@ -1240,9 +1393,8 @@ class PagedPrograms:
                     each(n_sc, scales, jnp.float32, jnp.ones), rec]
         # the last step's next tokens, which the next step reads where
         # they lie (before any step: no lane takes its token from them);
-        # behind them the experts' counts of a decoder with routed layers
-        self._last = jnp.zeros((B + (3 if spec.moe is not None else 0),),
-                               jnp.int32)
+        # behind them the counts of a decoder with routed layers or an index
+        self._last = jnp.zeros((B + counts_carried(spec),), jnp.int32)
         self._draft_kv = [(), ()]
         if self._spec_k:
             dspec = self._draft_spec
@@ -1270,8 +1422,11 @@ class PagedPrograms:
         windowed = [i for i, a in enumerate(spec.attn) if a.window]
         self.window_pool_bytes = _nbytes(
             [(pools_k[i], pools_v[i]) for i in windowed])
+        # the index pools grow with a sequence's length as K's and V's do,
+        # block for block: a block's bytes count all three arrays
+        self.index_pool_bytes = _nbytes(rec[1:]) if spec.index else 0
         self.kv_pool_bytes = _nbytes(self._kv[:4] + self._draft_kv) \
-            - self.window_pool_bytes
+            - self.window_pool_bytes + self.index_pool_bytes
         self.state_bytes = _nbytes(rec) if spec.recurrent else 0
 
     # -- static description (any thread) ------------------------------- #
@@ -1343,6 +1498,12 @@ class PagedPrograms:
         """``(states, conv windows)``, a tuple entry an ssm layer; ``()``
         for a decoder without any."""
         return self._kv[4]
+
+    @property
+    def index_pools(self) -> tuple:
+        """The index keys' pools, an entry a layer with an index; ``()``
+        for a decoder without one."""
+        return self._kv[4][1] if self._spec.index else ()
 
     @property
     def draft_pools(self) -> tuple:

@@ -263,7 +263,12 @@ class Iteration:
     layers ``window_blocks_held`` is the blocks of the lanes' rings that
     hold a visible position at the commit, of ``window_blocks_total``;
     the three pool fields above then speak of the full layers' pool.  All
-    five 0 otherwise.
+    five 0 otherwise.  For a decoder whose attention layers select what
+    they attend by an index, counted on the device likewise:
+    ``index_positions_scored`` the positions the index scored, summed over
+    the iteration's queries (a decode lane, a chunk's token) and indexed
+    layers, and ``sparse_positions_attended`` the same sum of the
+    positions then attended, min(context, topk).
     """
 
     __slots__ = ("engine", "step", "t0", "t1", "causes", "occupancy",
@@ -271,14 +276,16 @@ class Iteration:
                  "block_size", "positions_written", "chunks",
                  "state_rows", "state_resets", "ahead", "expert_pairs",
                  "expert_tokens", "expert_busiest", "window_blocks_held",
-                 "window_blocks_total")
+                 "window_blocks_total", "index_positions_scored",
+                 "sparse_positions_attended")
 
     def __init__(self, engine, step, t0, t1, causes, occupancy=0,
                  queue_depth=0, blocks_reserved=0, blocks_total=0,
                  block_size=0, positions_written=0, chunks=(),
                  state_rows=0, state_resets=0, ahead=0, expert_pairs=0,
                  expert_tokens=0, expert_busiest=0, window_blocks_held=0,
-                 window_blocks_total=0):
+                 window_blocks_total=0, index_positions_scored=0,
+                 sparse_positions_attended=0):
         self.engine = engine
         self.step = step
         self.t0 = t0
@@ -299,6 +306,8 @@ class Iteration:
         self.expert_busiest = expert_busiest
         self.window_blocks_held = window_blocks_held
         self.window_blocks_total = window_blocks_total
+        self.index_positions_scored = index_positions_scored
+        self.sparse_positions_attended = sparse_positions_attended
 
     def as_dict(self) -> dict:
         d = {k: getattr(self, k) for k in self.__slots__}
@@ -506,7 +515,9 @@ class EngineProfiler:
                  state_resets: int = 0, ahead: int = 0,
                  expert_pairs: int = 0, expert_tokens: int = 0,
                  expert_busiest: int = 0, window_blocks_held: int = 0,
-                 window_blocks_total: int = 0) -> Optional[dict]:
+                 window_blocks_total: int = 0,
+                 index_positions_scored: int = 0,
+                 sparse_positions_attended: int = 0) -> Optional[dict]:
         """Close the iteration at a decode-step commit: compute the
         wall since the previous commit, carve gc + residue, push the
         record, feed histograms, judge the hiccup threshold.  Returns
@@ -539,7 +550,8 @@ class EngineProfiler:
                         block_size, positions_written, tuple(chunks),
                         state_rows, state_resets, ahead, expert_pairs,
                         expert_tokens, expert_busiest, window_blocks_held,
-                        window_blocks_total)
+                        window_blocks_total, index_positions_scored,
+                        sparse_positions_attended)
         self._ring.push(rec)
         self._totals = tuple(map(operator.add, self._totals, acc))
         self._total_wall += wall

@@ -37,6 +37,7 @@ _xent = importlib.import_module("incubator_mxnet_tpu.ops.xent_kernel")
 _dropout = importlib.import_module("incubator_mxnet_tpu.ops.dropout_kernel")
 _scan = importlib.import_module("incubator_mxnet_tpu.ops.selective_scan")
 _moe = importlib.import_module("incubator_mxnet_tpu.ops.moe_experts")
+_sparse = importlib.import_module("incubator_mxnet_tpu.ops.sparse_attention")
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -175,6 +176,35 @@ def _moe_experts(tokens):
     return lower
 
 
+# keye-vl-2-30b-a3b.serve-longdocs: 32 query heads on 4 KV heads of 128, an
+# index of 16 heads of 64 that keeps 2,048 positions, blocks of 64, 16
+# lanes of 33,792 positions, chunks of 512
+_K_B, _K_HQ, _K_HKV, _K_D, _K_HI, _K_DI, _K_TOPK = 16, 32, 4, 128, 16, 64, 2048
+_K_BS, _K_NBPS, _K_CHUNK = 64, 528, 512
+_K_NB = _K_B * _K_NBPS + 1
+_K_ROW = 128                    # index_row(64): a key, then zeros
+
+
+def _index_scores(seqs, queries):
+    """A step's lanes of one query, or a chunk's one sequence of many."""
+    def lower(S):
+        return _sparse._index_core.lower(
+            S((seqs, queries, _K_HI, _K_DI), bf16),
+            S((seqs, queries, _K_HI), f32), S((_K_NB, _K_BS, _K_ROW), bf16),
+            S((seqs, _K_NBPS), i32), S((seqs,), i32), interpret=False)
+    return lower
+
+
+def _paged_sparse(seqs, queries):
+    def lower(S):
+        pool = S((_K_NB, _K_BS, _K_HKV * _K_D), bf16)
+        return _sparse._sparse_core.lower(
+            S((seqs, queries, _K_HQ, _K_D), bf16), pool, pool,
+            S((seqs, _K_NBPS), i32), S((seqs,), i32),
+            S((seqs, queries, _K_NBPS * _K_BS), jnp.bool_), interpret=False)
+    return lower
+
+
 def _flash_fwd(T, bk):
     def lower(S):
         x = S((2, 16, T, 64), bf16)
@@ -240,6 +270,12 @@ _KERNELS = {
     "moe_experts_step_96x8_of_256": (_moe_experts(_M_B), ["moe_experts"]),
     "moe_experts_chunk_512x8_of_256": (_moe_experts(_M_CHUNK),
                                        ["moe_experts"]),
+    "index_scores_step_16_lanes": (_index_scores(_K_B, 1), ["index_scores"]),
+    "index_scores_chunk_512": (_index_scores(1, _K_CHUNK), ["index_scores"]),
+    "paged_sparse_step_32q_4kv": (_paged_sparse(_K_B, 1),
+                                  ["paged_attention_sparse"]),
+    "paged_sparse_chunk_512x32q_4kv": (_paged_sparse(1, _K_CHUNK),
+                                       ["paged_attention_sparse"]),
     "selective_scan_chunk_1x256": (_selective_scan(1, _S_CHUNK, 1),
                                    ["selective_scan"]),
     "selective_scan_step_64x1": (_selective_scan(_G_B, 1, 8),
@@ -589,6 +625,93 @@ def test_routed_program_leaves_both_kinds_of_pool_where_they_lie(
         if "S(" not in m]
 
 
+# --- the sparse cell's programs: three pools a layer ------------------------ #
+@pytest.fixture(scope="module")
+def sparse_weights():
+    """(weight shapes, spec) of the sparse cell's eight layers at their
+    published widths, as `PagedPrograms` hands them over.  One layer is
+    built (every layer is of one kind) and its shapes laid out eight
+    times."""
+    import json
+
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models import generation as G
+    from incubator_mxnet_tpu.models.routed_sparse import RoutedSparseDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "keye-vl-2-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    kw = {k: cfg[v] for k, v in cfg["program"]["kwargs"].items()}
+    kw.update(cfg["program"]["constants"], num_hidden_layers=1,
+              max_position_embeddings=_K_NBPS * _K_BS)
+    net = RoutedSparseDecoder(**kw)
+    net.initialize(mx.init.Zero())
+    one = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        G._gather_params(net, _K_NBPS * _K_BS))
+    L = cfg["num_hidden_layers"]
+    spec1 = G.decoder_spec(net)
+    spec = spec1._replace(kinds=("attn",) * L, acts=("routed",) * L,
+                          attn=spec1.attn * L)
+    return dict(one, layers=[one["layers"][0]] * L), spec
+
+
+@pytest.mark.parametrize("program", ["serving_step", "serving_prefill_chunk"])
+def test_sparse_program_leaves_all_three_pools_where_they_lie(
+        one_chip, monkeypatch, sparse_weights, program):
+    """A decoder with an index through the same two programs
+    (docs/serving.md, "An index over the pages"), at the cell's widths and
+    depth for the described v5e: the index-scores kernel and the sparse
+    attention kernel are there once a layer in both programs, the experts'
+    kernel likewise; no pool array of the three kinds is copied (8,449
+    blocks of K, V and index keys) and every one comes back in its
+    argument's buffer, the counts with them; the temporaries stay under a
+    chunk's scores and masks."""
+    from incubator_mxnet_tpu.serving import programs as SP
+
+    shapes, spec = sparse_weights
+    assert spec.index == (_K_HI, _K_DI, _K_TOPK) and spec.moe.held == 16
+    assert spec.moe.scoring == "softmax" and len(spec.attn) == 8
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools(width):
+        return tuple(S((_K_NB, _K_BS, width), bf16) for _ in spec.attn)
+
+    fn, rest = _served_program(program, None, spec, B=_K_B, bs=_K_BS,
+                               nbps=_K_NBPS, chunk=_K_CHUNK)
+    n_counts = SP.counts_carried(spec)
+    assert n_counts == 7
+    if program == "serving_step":       # the counts follow the tokens
+        rest[0] = ((_K_B + n_counts,), i32)
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), shapes)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3, 4)).lower(
+        pools(_K_HKV * _K_D), pools(_K_HKV * _K_D), (), (),
+        (S((n_counts,), i32), pools(_K_ROW)),
+        *(S(*sd) for sd in rest), params).compile()
+    hlo = compiled.as_text()
+
+    def calls(kernel):
+        return len(re.findall(
+            rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call", hlo))
+
+    assert (calls("index_scores"), calls("paged_attention_sparse"),
+            calls("moe_experts")) == (8, 8, 8)
+    assert calls("paged_attention") == calls("paged_attention_window") == 0
+    for width in (_K_HKV * _K_D, _K_ROW):
+        assert not [c for c in _copies_of(hlo, "bf16",
+                                          (_K_NB, _K_BS, width))
+                    if "S(" not in c], width
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    for i in range(25):     # 8 key pools, 8 value pools, counts, 8 index
+        assert f"{{{i}}}: ({i}, {{}}" in aliases, (i, aliases)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        64 if program == "serving_step" else 320) * 2 ** 20
+
+
 # --- the same kernels in a program traced over the 2x2 mesh ------------- #
 def _mesh_xent(x, labels):
     def loss(x):
@@ -620,6 +743,15 @@ def _mesh_paged_ring(q, pool_k, pool_v, tables, pos, sink):
 def _mesh_window(q, pool_k, pool_v, row, start):
     return _paged.paged_attention_window(q, pool_k, pool_v, row, start,
                                          value_scale=0.707)
+
+
+def _mesh_index(qi, w, pool, tables, last):
+    return _sparse.index_scores(qi, w, pool, tables, last, impl="pallas")
+
+
+def _mesh_sparse(q, pool_k, pool_v, tables, last, seen):
+    return _sparse.paged_attention_sparse(q, pool_k, pool_v, tables, last,
+                                          seen, impl="pallas")
 
 
 def _mesh_moe(x, idx, wts, gate, up, down):
@@ -662,6 +794,18 @@ _MESH_PROGRAMS = {
         ((_M_NB, _M_BS, 4 * _M_DK), bf16, P()),
         ((_M_NB, _M_BS, 4 * _M_DV), bf16, P()),
         ((_M_NBPS,), i32, P()), ((), i32, P())], 1),
+    # the sparse cell's step: the 16 lanes over `data` in both kernels, the
+    # 4 KV heads of 128 over `model` in the attention
+    "index_scores_step": (_mesh_index, [
+        ((_K_B, 1, _K_HI, _K_DI), bf16, P()), ((_K_B, 1, _K_HI), f32, P()),
+        ((_K_NB, _K_BS, _K_ROW), bf16, P()), ((_K_B, _K_NBPS), i32, P()),
+        ((_K_B,), i32, P())], 1),
+    "paged_sparse_step": (_mesh_sparse, [
+        ((_K_B, 1, _K_HQ, _K_D), bf16, P()),
+        ((_K_NB, _K_BS, _K_HKV * _K_D), bf16, P()),
+        ((_K_NB, _K_BS, _K_HKV * _K_D), bf16, P()),
+        ((_K_B, _K_NBPS), i32, P()), ((_K_B,), i32, P()),
+        ((_K_B, 1, _K_NBPS * _K_BS), jnp.bool_, P())], 1),
     # a step's experts: the 64 tiles of rows over both axes
     "moe_experts_step": (_mesh_moe, [
         ((_M_B, _M_C), bf16, P()), ((_M_B, _M_K), i32, P()),

@@ -195,17 +195,42 @@ def _xent(smoothing):
     return run
 
 
+def _index_scores(q, pool, tables, pos, labels):
+    """4 lanes' index queries (4 heads of 16) against an index pool whose
+    rows hold a key in their first 16 lanes: the lanes are what is split,
+    every shard holds the whole pool."""
+    from incubator_mxnet_tpu.ops.sparse_attention import index_scores
+
+    return index_scores(q[:, None, :, 0], q[:, None, :, 1, 0], pool, tables,
+                        pos, impl="pallas")
+
+
+def _paged_sparse(q, pool, tables, pos, labels):
+    """4 lanes of 4 query heads on 2 KV heads over 5 selected positions a
+    lane: lanes over one axis, the KV heads stay whole (a shard's head
+    must be whole 128-lane tiles of a row: heads of 16 are not)."""
+    from incubator_mxnet_tpu.ops.sparse_attention import (
+        paged_attention_sparse, select_positions)
+
+    half = pool[:, :, :pool.shape[2] // 2]
+    seen = select_positions(q[:, :1, 0, :].reshape(4, 1, 16), pos[:, None], 5)
+    return paged_attention_sparse(q[:, None, :, 0], half, half * 0.5, tables,
+                                  pos, seen, impl="pallas")
+
+
 @pytest.mark.parametrize("kernel", [_flash, _paged, _paged_int8, _xent(0.0),
                                     _xent(0.1), _paged_grouped, _paged_window,
                                     _paged_window_heads,
                                     _scan_step, _paged_options,
-                                    _paged_sink_alone, _moe_experts],
+                                    _paged_sink_alone, _moe_experts,
+                                    _index_scores, _paged_sparse],
                          ids=["flash_fwd_bwd", "paged", "paged_int8", "xent",
                               "xent_smoothed", "paged_grouped",
                               "paged_window", "paged_window_heads_split",
                               "selective_scan_step",
                               "paged_window_sink_scale", "paged_sink",
-                              "moe_experts"])
+                              "moe_experts", "index_scores",
+                              "paged_sparse"])
 def test_kernel_per_shard_matches_one_device(kernel):
     """The kernel under the 2x2 mesh's `shard_map` (interpret mode here)
     against the same kernel called as it is."""
