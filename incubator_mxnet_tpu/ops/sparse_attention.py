@@ -9,12 +9,15 @@ decode step is ``N`` lanes of one query, a prefill chunk one sequence of
 * `index_scores` — ``I(t, s) = sum_j w[t, j] * relu(qi[t, j] . ki[s])``
   against the index keys of the sequence's pages, a third pool
   ``(num_blocks, block_size, index_row(dim))`` beside K and V that the
-  same block tables name.  Float32 out, ``finfo.min`` at the positions
-  behind a query's own.
+  same block tables name.  Float32 out; what lies behind a query's own
+  position is unspecified.
 * `select_positions` — the ``k`` positions at or before a query's own with
-  the largest scores as a mask, ties to the lower position; every position
-  while there are no more than ``k``.  No sort: the ``k``-th largest score
-  is found digit by digit on the scores' order-preserving integer image.
+  the largest scores as an int32 mask, ties to the lower position; every
+  position while there are no more than ``k``.  No sort: the ``k``-th
+  largest score is found bit by bit on the scores' order-preserving
+  integer image, 32 counts a row; the kernel copies a tile of rows'
+  scores into VMEM up to the tile's highest position alone and counts
+  them there.
 * `paged_attention_sparse` — grouped-query attention of each query over
   the positions its mask names, through the block table, float32 scores
   and softmax.
@@ -23,15 +26,18 @@ decode step is ``N`` lanes of one query, a prefill chunk one sequence of
 (`_run_copies`: the pools in HBM as they lie, runs of whole pages copied by
 the kernel itself, the next live run's under this run's math; a run behind
 the sequence's last position starts nothing and computes nothing) with
-the kernels' ``name=`` ``index_scores`` and ``paged_attention_sparse``;
-``impl="xla"`` is the plain form the tests compare against and the CPU
-serves by: the pages gathered into a dense view, full-width softmax.
+the kernels' ``name=`` ``index_scores`` and ``paged_attention_sparse``,
+and the selection's kernel ``select_positions`` between them, which
+writes the mask as the attention kernel reads it; ``impl="xla"`` is the
+plain form the tests compare against and the CPU serves by: the pages
+gathered into a dense view, every column counted, full-width softmax.
 
 The attention kernel reads every page up to the sequence's last position
-and weighs by the mask, because the selection hands over a mask: gathering
-the selected rows is the cheaper read, but making a list of a mask costs
-more than it saves at the served contexts (measured: docs/serving.md, "An
-index over the pages").
+and weighs by the mask, because the selection hands over a mask: a gather
+of the selected rows is the cheaper read, but a list made from a mask
+costs more than the gather saves under a mean context of some 15k
+(measured for the step: docs/serving.md, "An index over the pages"); a
+selection that emits the list is ROADMAP's Reach A8(a).
 """
 from __future__ import annotations
 
@@ -61,11 +67,6 @@ def index_row(dim: int) -> int:
     out at that width anyway, and a page can only be cut out of the pool
     along whole tiles."""
     return -(-dim // 128) * 128
-
-
-def _positions(last, T):
-    """(N, T): the position of each query of each sequence."""
-    return last[:, None] - (T - 1) + jnp.arange(T, dtype=jnp.int32)[None, :]
 
 
 def _walk(tables_ref, last_ref, pools, bufs, sem, slot_ref, n, bs, zero,
@@ -219,49 +220,36 @@ def index_scores(qi, w, pool_i, tables, last, *, impl: Optional[str] = None,
     last query (it may lie past the table's end: no page is read there).
     Returns float32 (N, T, blocks_per_seq * block_size):
     ``sum_j w_j relu(qi_j . ki_s)`` at the positions ``s`` at or before
-    the query's own, ``finfo.min`` behind it."""
+    the query's own; what lies behind it is unspecified (the kernel writes
+    no run behind the sequence's last position): `select_positions` reads
+    a row up to its query's position alone."""
     impl = impl or ("pallas" if jax.default_backend() == "tpu" else "xla")
     if impl == "xla":
-        s = _index_xla(qi, w, pool_i, tables)
-    elif impl == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        # sequences are independent: per shard of them under a mesh
-        seqs, = mosaic.split((qi.shape[0],))
-        s = mosaic.per_shard(
-            functools.partial(_index_core, interpret=interpret),
-            (P(seqs), P(seqs), P(), P(seqs), P(seqs)), P(seqs))(
-            qi, w, pool_i, tables, last)
-    else:
+        return _index_xla(qi, w, pool_i, tables)
+    if impl != "pallas":
         raise ValueError(f"index_scores impl {impl!r} (pallas|xla)")
-    at = jnp.arange(s.shape[-1], dtype=jnp.int32)
-    seen = at <= _positions(last, qi.shape[1])[..., None]
-    return jnp.where(seen, s, _MIN)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    # sequences are independent: per shard of them under a mesh
+    seqs, = mosaic.split((qi.shape[0],))
+    return mosaic.per_shard(
+        functools.partial(_index_core, interpret=interpret),
+        (P(seqs), P(seqs), P(), P(seqs), P(seqs)), P(seqs))(
+        qi, w, pool_i, tables, last)
 
 
 # --- the selection ---------------------------------------------------------- #
-def select_positions(scores, pos, k: int):
-    """bool, ``scores``' shape: for each row of float32 ``scores`` (...,
-    W), whose query stands at ``pos`` (...), the ``k`` positions ``s <=
-    pos`` with the largest scores, ties to the lower position; all of them
-    while ``pos < k``.
+_SIGN = -(1 << 31)          # int32's sign bit, the least int32
+# VMEM a grid step of the selection kernel holds of its rows: their scores'
+# image and the mask's block (two buffers), four bytes a column each
+_SELECT_VMEM = 32 * 1024 * 1024
+# the widest tile of columns the kernel copies and counts at once
+_SELECT_COLS = 2048
 
-    The ``k``-th largest score is found without a sort: a float's bits,
-    with the sign's half of the line turned over, order as the floats do,
-    and the largest threshold that still leaves ``k`` scores at or above
-    it is built from the most significant bit down, a bit a turn of a
-    loop: a turn counts the scores at or above the threshold with the bit
-    set, and keeps the bit where ``k`` are left.  Scores that equal the
-    threshold are then taken from the lowest position up until ``k`` are
-    selected: a running count, computed only where some row has more
-    equals than places."""
+
+def _select_xla(scores, pos, k):
     u32 = jnp.uint32
-    shape = scores.shape
-    # a row a query, whatever the leading axes: a decode step's (lanes, 1,
-    # W) lays each row out alone in a tile of eight, and the counting then
-    # costs five times what it costs on (lanes, W) (PERF.md section 6, PR 38)
-    scores, pos = scores.reshape(-1, shape[-1]), pos.reshape(-1)
-    valid = jnp.arange(shape[-1], dtype=jnp.int32) <= pos[:, None]
+    valid = jnp.arange(scores.shape[-1], dtype=jnp.int32) <= pos[:, None]
     b = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), u32)
     key = jnp.where(b >> 31 == 1, ~b, b | u32(1 << 31))
     key = jnp.where(valid, key, u32(0))     # below every number's image
@@ -283,7 +271,179 @@ def select_positions(scores, pos, k: int):
         crowded,
         lambda: equal & (jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
                          <= room[:, None]),
-        lambda: equal)).reshape(shape)
+        lambda: equal)).astype(jnp.int32)
+
+
+def _select_tiles(rows, width):
+    """(rows a grid step of the selection kernel takes, columns a tile):
+    the most rows of 64, 32, 16 and 8 that divide ``rows`` and whose image
+    and mask fit `_SELECT_VMEM` (more rows, more work between two turns'
+    waits on each other); the widest tile of whole 128 lanes up to
+    `_SELECT_COLS` that divides ``width`` (the whole row where none
+    does)."""
+    tiles = [c for c in range(128, min(width, _SELECT_COLS) + 1, 128)
+             if width % c == 0]
+    fit = [r for r in (8, 16, 32, 64)
+           if rows % r == 0 and 3 * 4 * r * width <= _SELECT_VMEM]
+    return max(fit, default=8), max(tiles, default=width)
+
+
+def _select_kernel(scores, pos_ref, o_ref, img, sem, *, k, bits):
+    """One grid step = one tile of ``R`` rows (a decode step's lanes, or a
+    chunk's queries of one sequence).  Only the column tiles up to the
+    tile's highest position are copied, made the scores' order-preserving
+    int32 image in place (a position behind a row's own: the least int32,
+    below every number's image) and counted, in each of the 32 turns that
+    build a row's threshold bit by bit; the rest of the row's mask is
+    written 0.  Ties at the threshold are taken from the lowest position
+    up where a row of the tile has more of them than room: the largest
+    column bound that leaves no more than the room is built the same way,
+    ``bits`` turns."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i32, f32 = jnp.int32, jnp.float32
+    n_tiles, R, C = img.shape
+    lanes = 128 if C % 128 == 0 else C
+    r0 = pl.program_id(0) * R
+    ends = pos_ref[...] + 1                             # (R, 1)
+    live = jnp.clip(jnp.max(ends - 1) // C + 1, 1, n_tiles)
+    col = jax.lax.broadcasted_iota(i32, (R, C), 1)
+
+    def cols(c):                # tile c's columns, whole lane tiles
+        return pl.ds(pl.multiple_of(c * C, lanes), C)
+
+    def copy(c):
+        return pltpu.make_async_copy(scores.at[pl.ds(r0, R), cols(c)],
+                                     img.at[c], sem.at[c])
+
+    def key(c):
+        return jax.lax.bitcast_convert_type(img[c], i32)
+
+    def image(c, _):
+        copy(c).wait()
+        b = key(c)
+        b = jnp.where(col + c * C < ends, b ^ ((b >> 31) & ~_SIGN), _SIGN)
+        img[c] = jax.lax.bitcast_convert_type(b, f32)
+
+    jax.lax.fori_loop(0, live, lambda c, _: copy(c).start(), None)
+    jax.lax.fori_loop(0, live, image, None)
+
+    def count(pred):
+        """(R, 1): the columns of the live tiles where ``pred(key, first
+        column)`` holds, loaded a slice of ``lanes`` columns at a time and
+        added into two accumulators in turn (two chains the VPU
+        interleaves)."""
+        def tile(c, accs):
+            accs = list(accs)
+            for n, j in enumerate(range(0, C, lanes)):
+                x = jax.lax.bitcast_convert_type(img[c, :, j:j + lanes], i32)
+                accs[n % 2] = accs[n % 2] + pred(x, c * C + j).astype(i32)
+            return tuple(accs)
+        accs = jax.lax.fori_loop(0, live, tile,
+                                 (jnp.zeros((R, lanes), i32),) * 2)
+        return jnp.sum(accs[0] + accs[1], axis=1, keepdims=True)
+
+    at = jax.lax.broadcasted_iota(i32, (R, lanes), 1)
+
+    def turn(t, tau):
+        cand = tau | jax.lax.shift_right_logical(jnp.int32(_SIGN), t)
+        thr = jnp.broadcast_to(cand ^ _SIGN, (R, lanes))
+        return jnp.where(count(lambda x, _: x >= thr) >= k, cand, tau)
+
+    thr = jax.lax.fori_loop(0, 32, turn, jnp.zeros((R, 1), i32)) ^ _SIGN
+    thr_l, ends_l = (jnp.broadcast_to(a, (R, lanes)) for a in (thr, ends))
+    room = k - count(lambda x, _: x > thr_l)
+    crowded = count(lambda x, c0: (x == thr_l) & (at + c0 < ends_l)) > room
+
+    def place(t, bound):
+        cand = bound | jnp.left_shift(jnp.int32(1), bits - 1 - t)
+        lim = jnp.broadcast_to(jnp.minimum(cand, ends), (R, lanes))
+        n = count(lambda x, c0: (x == thr_l) & (at + c0 < lim))
+        return jnp.where(n <= room, cand, bound)
+
+    # a row that is not crowded builds the largest bound: all its equals
+    lim = jnp.minimum(ends, jax.lax.cond(
+        jnp.max(crowded.astype(i32)) > 0,
+        lambda: jax.lax.fori_loop(0, bits, place, jnp.zeros((R, 1), i32)),
+        lambda: ends))
+
+    def mask(c, _):
+        x = key(c)
+        o_ref[:, cols(c)] = (
+            (x > thr) | ((x == thr) & (col + c * C < lim))).astype(i32)
+
+    def behind(c, _):
+        o_ref[:, cols(c)] = jnp.zeros((R, C), i32)
+
+    jax.lax.fori_loop(0, live, mask, None)
+    jax.lax.fori_loop(live, n_tiles, behind, None)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _select_core(scores, pos, k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    M, W = scores.shape
+    pad = -M % 8                # rows of no position: nothing is seen
+    scores = jnp.pad(scores, ((0, pad), (0, 0)))
+    pos = jnp.pad(pos, (0, pad), constant_values=-1)
+    R, C = _select_tiles(M + pad, W)
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, k=k, bits=W.bit_length()),
+        grid=((M + pad) // R,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec((R, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((R, W), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((W // C, R, C), jnp.float32),
+                        pltpu.SemaphoreType.DMA((W // C,))],
+        out_shape=jax.ShapeDtypeStruct((M + pad, W), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SELECT_VMEM + 16 * 1024 * 1024),
+        interpret=interpret, name="select_positions",
+    )(scores.astype(jnp.float32), pos[:, None])
+    return out[:M]
+
+
+def select_positions(scores, pos, k: int, *, impl: Optional[str] = None,
+                     interpret: Optional[bool] = None):
+    """int32 0/1, ``scores``' shape: for each row of float32 ``scores``
+    (..., W), whose query stands at ``pos`` (...), the ``k`` positions
+    ``s <= pos`` with the largest scores, ties to the lower position; all
+    of them while ``pos < k``.  The mask is what `paged_attention_sparse`
+    reads.
+
+    The ``k``-th largest score is found without a sort: a float's bits,
+    with the sign's half of the line turned over, order as the floats do,
+    and the largest threshold that still leaves ``k`` scores at or above
+    it is built from the most significant bit down, a bit a turn of a
+    loop: a turn counts the scores at or above the threshold with the bit
+    set, and keeps the bit where ``k`` are left.  Scores that equal the
+    threshold are then taken from the lowest position up until ``k`` are
+    selected, where some row has more equals than places.
+    ``impl="pallas"`` counts a tile of rows in VMEM, over the columns up
+    to the tile's highest position alone (`_select_kernel`); ``"xla"``
+    over every column, with a running count for the ties."""
+    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    shape = scores.shape
+    # a row a query, whatever the leading axes: a decode step's (lanes, 1,
+    # W) lays each row out alone in a tile of eight, and the counting then
+    # costs five times what it costs on (lanes, W) (PERF.md section 6)
+    scores, pos = scores.reshape(-1, shape[-1]), pos.reshape(-1)
+    if impl == "xla":
+        seen = _select_xla(scores, pos, k)
+    elif impl == "pallas":
+        if interpret is None:
+            interpret = jax.default_backend() == "cpu"
+        # rows are independent: per shard of them under a mesh
+        rows, = mosaic.split((scores.shape[0],))
+        seen = mosaic.per_shard(
+            functools.partial(_select_core, k=k, interpret=interpret),
+            (P(rows), P(rows)), P(rows))(scores, pos)
+    else:
+        raise ValueError(f"select_positions impl {impl!r} (pallas|xla)")
+    return seen.reshape(shape)
 
 
 # --- attention over the selected positions --------------------------------- #
@@ -359,8 +519,11 @@ def _sparse_core(q, pool_k, pool_v, tables, last, seen, interpret):
     # (N, T, Hkv, G, D) -> (N, Hkv, G*T, D): a KV head's rows, head-major
     qh = q.reshape(N, T, Hkv, G, D).transpose(0, 2, 3, 1, 4) \
         .reshape(N, Hkv, G * T, D)
-    seen = jnp.pad(seen.astype(jnp.int32),
-                   ((0, 0), (0, 0), (0, tables.shape[1] * bs - nbps * bs)))
+    # the mask as `select_positions` writes it; a row padded to whole runs
+    # sees nothing in the padding
+    if tables.shape[1] > nbps:
+        seen = jnp.pad(seen, ((0, 0), (0, 0),
+                              (0, (tables.shape[1] - nbps) * bs)))
 
     def mine(d):
         return pl.BlockSpec((None, Hkv, rows, d),
@@ -403,7 +566,7 @@ def _sparse_xla(q, pool_k, pool_v, tables, last, seen):
     v = pool_v[tables].reshape(N, -1, Hkv, D)
     s = jnp.einsum("nthgd,nwhd->nhgtw", q.reshape(N, T, Hkv, H // Hkv, D),
                    k, preferred_element_type=jnp.float32) / math.sqrt(D)
-    s = jnp.where(seen[:, None, None], s, _MIN)
+    s = jnp.where(seen[:, None, None] != 0, s, _MIN)
     o = jnp.einsum("nhgtw,nwhd->nthgd", jax.nn.softmax(s, axis=-1), v,
                    preferred_element_type=jnp.float32)
     return o.astype(q.dtype).reshape(N, T, H, D)
@@ -414,7 +577,8 @@ def paged_attention_sparse(q, pool_k, pool_v, tables, last, seen, *,
                            interpret: Optional[bool] = None):
     """Attention of ``q`` (N, T, Hq, D) — ``T`` queries of each of ``N``
     sequences — over the positions ``seen`` (N, T, blocks_per_seq *
-    block_size; bool) names for each query, among its sequence's pages
+    block_size; `select_positions`' int32 0/1 mask) names for each
+    query, among its sequence's pages
     (``tables`` (N, blocks_per_seq)) of the pools (num_blocks, block_size,
     Hkv*D), ``Hq`` a multiple of ``Hkv``.  ``last`` (N,): the position of
     each sequence's last query; no page behind it is read.  Float32 scores

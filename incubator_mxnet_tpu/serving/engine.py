@@ -872,6 +872,8 @@ class ServingEngine:
             # how a prefill chunk attends the sequence's pages: "window"
             # (once for all its queries), "lanes" (once a query) or "dense"
             "chunk_attn": self._programs.chunk_attn,
+            # how a layer with an index selects: "kernel", "xla" or "none"
+            "index_select": self._programs.index_select,
             "max_batch": self._B,
             "block_size": self._bs,
             "max_seq_len": self._msl,

@@ -504,7 +504,7 @@ def _sparse_attend(ix, impl, tables, last, qpos, wblk, off):
             scores = index_scores(seqs(qi), seqs(wi), pi, tables, last,
                                   impl=impl)
         with jax.named_scope("select"):
-            seen = select_positions(scores, qpos, ix.topk)
+            seen = select_positions(scores, qpos, ix.topk, impl=impl)
         with jax.named_scope("sparse_attn"):
             o = paged_attention_sparse(seqs(q), pk, pv, tables, last, seen,
                                        impl=impl)
@@ -1407,6 +1407,11 @@ class PagedPrograms:
         # "lanes" or "dense" (`chunk_attention`), static for an engine
         self.chunk_attn = chunk_attention(spec, self._chunk,
                                           self._kv_dtype, self._impl)
+        # how a layer with an index selects the positions it attends:
+        # "kernel" (`select_positions`' Pallas kernel), "xla", or "none"
+        # (no index), static for an engine
+        self.index_select = "none" if spec.index is None else \
+            "kernel" if self._impl == "pallas" else "xla"
         # block-table entries a grid step of the single-query kernel
         # covers (the kernel's own rule, from these shapes); 0 on the
         # dense path, which runs no kernel

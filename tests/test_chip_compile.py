@@ -201,7 +201,17 @@ def _paged_sparse(seqs, queries):
         return _sparse._sparse_core.lower(
             S((seqs, queries, _K_HQ, _K_D), bf16), pool, pool,
             S((seqs, _K_NBPS), i32), S((seqs,), i32),
-            S((seqs, queries, _K_NBPS * _K_BS), jnp.bool_), interpret=False)
+            S((seqs, queries, _K_NBPS * _K_BS), i32), interpret=False)
+    return lower
+
+
+def _select(rows):
+    """A step's 16 lanes or a chunk's 512 queries, a row of scores of
+    every position of the table."""
+    def lower(S):
+        return _sparse._select_core.lower(
+            S((rows, _K_NBPS * _K_BS), f32), S((rows,), i32), k=_K_TOPK,
+            interpret=False)
     return lower
 
 
@@ -272,6 +282,8 @@ _KERNELS = {
                                        ["moe_experts"]),
     "index_scores_step_16_lanes": (_index_scores(_K_B, 1), ["index_scores"]),
     "index_scores_chunk_512": (_index_scores(1, _K_CHUNK), ["index_scores"]),
+    "select_positions_step_16_lanes": (_select(_K_B), ["select_positions"]),
+    "select_positions_chunk_512": (_select(_K_CHUNK), ["select_positions"]),
     "paged_sparse_step_32q_4kv": (_paged_sparse(_K_B, 1),
                                   ["paged_attention_sparse"]),
     "paged_sparse_chunk_512x32q_4kv": (_paged_sparse(1, _K_CHUNK),
@@ -662,12 +674,12 @@ def test_sparse_program_leaves_all_three_pools_where_they_lie(
         one_chip, monkeypatch, sparse_weights, program):
     """A decoder with an index through the same two programs
     (docs/serving.md, "An index over the pages"), at the cell's widths and
-    depth for the described v5e: the index-scores kernel and the sparse
-    attention kernel are there once a layer in both programs, the experts'
-    kernel likewise; no pool array of the three kinds is copied (8,449
-    blocks of K, V and index keys) and every one comes back in its
-    argument's buffer, the counts with them; the temporaries stay under a
-    chunk's scores and masks."""
+    depth for the described v5e: the index-scores kernel, the selection
+    kernel and the sparse attention kernel are there once a layer in both
+    programs, the experts' kernel likewise; no pool array of the three
+    kinds is copied (8,449 blocks of K, V and index keys) and every one
+    comes back in its argument's buffer, the counts with them; the
+    temporaries stay under a chunk's scores and masks."""
     from incubator_mxnet_tpu.serving import programs as SP
 
     shapes, spec = sparse_weights
@@ -698,8 +710,9 @@ def test_sparse_program_leaves_all_three_pools_where_they_lie(
         return len(re.findall(
             rf"%{kernel}(?:\.\d+)? = [^\n]*tpu_custom_call", hlo))
 
-    assert (calls("index_scores"), calls("paged_attention_sparse"),
-            calls("moe_experts")) == (8, 8, 8)
+    assert (calls("index_scores"), calls("select_positions"),
+            calls("paged_attention_sparse"), calls("moe_experts")) == (
+                8, 8, 8, 8)
     assert calls("paged_attention") == calls("paged_attention_window") == 0
     for width in (_K_HKV * _K_D, _K_ROW):
         assert not [c for c in _copies_of(hlo, "bf16",
@@ -747,6 +760,10 @@ def _mesh_window(q, pool_k, pool_v, row, start):
 
 def _mesh_index(qi, w, pool, tables, last):
     return _sparse.index_scores(qi, w, pool, tables, last, impl="pallas")
+
+
+def _mesh_select(scores, pos):
+    return _sparse.select_positions(scores, pos, _K_TOPK, impl="pallas")
 
 
 def _mesh_sparse(q, pool_k, pool_v, tables, last, seen):
@@ -805,7 +822,9 @@ _MESH_PROGRAMS = {
         ((_K_NB, _K_BS, _K_HKV * _K_D), bf16, P()),
         ((_K_NB, _K_BS, _K_HKV * _K_D), bf16, P()),
         ((_K_B, _K_NBPS), i32, P()), ((_K_B,), i32, P()),
-        ((_K_B, 1, _K_NBPS * _K_BS), jnp.bool_, P())], 1),
+        ((_K_B, 1, _K_NBPS * _K_BS), i32, P())], 1),
+    "select_positions_step": (_mesh_select, [
+        ((_K_B, 1, _K_NBPS * _K_BS), f32, P()), ((_K_B, 1), i32, P())], 1),
     # a step's experts: the 64 tiles of rows over both axes
     "moe_experts_step": (_mesh_moe, [
         ((_M_B, _M_C), bf16, P()), ((_M_B, _M_K), i32, P()),

@@ -194,8 +194,8 @@ def _spy_on_the_selection(monkeypatch):
     [(call's number in its program, positions, mask)]."""
     got, real, n = [], SP.select_positions, itertools.count()
 
-    def spy(scores, pos, k):
-        seen = real(scores, pos, k)
+    def spy(scores, pos, k, **kw):
+        seen = real(scores, pos, k, **kw)
         call = next(n)
         jax.debug.callback(
             lambda p, s: got.append((call, onp.asarray(p), onp.asarray(s))),
@@ -218,6 +218,9 @@ def test_engine_matches_the_reference_on_logits_and_selections(
     prompt = _prompts((45,), seed=4)[0]
     with ServingEngine(net, attn_impl=impl,
                        **dict(ENGINE, max_batch=1)) as eng:
+        # the selection's form: the kernel under pallas (interpret mode)
+        assert eng.varz_config()["index_select"] == {
+            "dense": "xla", "pallas": "kernel"}[impl]
         toks = onp.asarray(eng.submit(prompt, 18).result(timeout=900))
     seq = onp.concatenate([prompt, toks])
     assert _gap(_ref_logits(ref, w32, rcfg, seq), prompt, toks) < 1e-4
@@ -464,6 +467,7 @@ def test_an_engine_without_an_index_reports_none():
         eng.submit(onp.arange(5, dtype=onp.int32), 4).result(timeout=300)
         v = eng.varz_config()
         assert (v["index_pool_bytes"], v["index_topk"]) == (0, 0)
+        assert v["index_select"] == "none"
         assert eng.index_pool_bytes == 0 and eng._programs.index_pools == ()
         name = eng._name
     records, _ = telemetry.profiler.iterations(t0, None)

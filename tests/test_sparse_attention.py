@@ -25,6 +25,20 @@ def _pools(rng, n_seq, width, dtype=jnp.float32):
     return pool, jnp.asarray(tables, jnp.int32)
 
 
+def _positions(last, T):
+    """(N, T): the position of each query of each sequence."""
+    return onp.asarray(last)[:, None] - (T - 1) + onp.arange(T)[None, :]
+
+
+def _seen_scores(got, want, last):
+    """The index's scores of both forms at or before each query's position
+    (what lies behind it is unspecified)."""
+    got, want = onp.asarray(got), onp.asarray(want)
+    seen = onp.arange(got.shape[-1]) <= _positions(last, got.shape[1])[
+        ..., None]
+    onp.testing.assert_allclose(got[seen], want[seen], rtol=1e-5, atol=1e-5)
+
+
 def _index_args(rng, N, T, last, dtype=jnp.float32):
     pool, tables = _pools(rng, N, _sp.index_row(DI), dtype)
     pool = pool.at[:, :, DI:].set(0)        # a key, then a row's zeros
@@ -44,11 +58,9 @@ def test_index_scores_kernel_matches_the_plain_form(N, T, last):
     args = _index_args(onp.random.default_rng(0), N, T, last)
     want = _sp.index_scores(*args, impl="xla")
     got = _sp.index_scores(*args, impl="pallas", interpret=True)
-    assert want.shape == (N, T, NBPS * BS)
-    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
-                                rtol=1e-5, atol=1e-5)
-    # by hand: sum_j w_j relu(q_j . k_s) at s <= the query's position,
-    # finfo.min behind it
+    assert got.shape == want.shape == (N, T, NBPS * BS)
+    _seen_scores(got, want, last)
+    # by hand: sum_j w_j relu(q_j . k_s) at s <= the query's position
     qi, w, pool, tables, last = (onp.asarray(a) for a in args)
     for n in range(N):
         keys = pool[tables[n]].reshape(-1, pool.shape[-1])[:, :DI]
@@ -57,8 +69,6 @@ def test_index_scores_kernel_matches_the_plain_form(N, T, last):
             s = onp.maximum(qi[n, t] @ keys.T, 0.0).T @ w[n, t]
             onp.testing.assert_allclose(onp.asarray(want)[n, t, :at + 1],
                                         s[:at + 1], rtol=1e-4, atol=1e-4)
-            assert (onp.asarray(want)[n, t, at + 1:]
-                    == onp.finfo(onp.float32).min).all()
 
 
 def test_index_scores_in_bfloat16_multiply_what_is_stored():
@@ -66,8 +76,7 @@ def test_index_scores_in_bfloat16_multiply_what_is_stored():
                        jnp.bfloat16)
     want = _sp.index_scores(*args, impl="xla")
     got = _sp.index_scores(*args, impl="pallas", interpret=True)
-    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
-                                rtol=1e-5, atol=1e-5)
+    _seen_scores(got, want, [30, 66])
 
 
 def _by_sort(scores, pos, k):
@@ -130,13 +139,92 @@ def test_ties_go_to_the_lower_position():
     assert sorted(onp.flatnonzero(got[2])) == list(range(8))
 
 
+# the selection kernel's cases: rows of 4,224 columns (33 x 128) count in
+# three tiles of 1,408, so a row's context may end in any of them
+SW, SK = 4224, 256
+
+
+def _step_lanes(rng):
+    """A decode step's 16 lanes in one tile, contexts far apart: a first
+    token, under k, at k, at a tile's and a page's edges, the full row."""
+    pos = onp.array([0, 5, SK - 1, SK, 1407, 1408, 1409, 2047, 2048, 2815,
+                     3000, 4095, 4160, 4200, 4222, SW - 1], onp.int32)
+    return rng.normal(size=(16, 1, SW)), rng.permutation(pos)[:, None]
+
+
+def _chunk(rng, last=2900):
+    """A chunk's 512 queries of one sequence, its last inside a tile."""
+    return rng.normal(size=(1, 512, SW)), \
+        (last - 511 + onp.arange(512, dtype=onp.int32))[None]
+
+
+def _relu_zeros(rng):
+    """The index's ReLU: most scores exactly 0, so rows crowd at a
+    threshold of 0 and their ties go to the lower positions."""
+    s, pos = _chunk(rng, last=1600)
+    return onp.maximum(s - 1.5, 0.0), pos
+
+
+def _signed_zeros_and_min(rng):
+    """-0.0 below +0.0, and finfo.min (what the index writes behind a
+    query) a number like any other in front of it."""
+    s, pos = _step_lanes(rng)
+    s[:, :, ::3] = -0.0
+    s[:, :, 1::3] = 0.0
+    s[::2, :, 2::7] = onp.finfo(onp.float32).min
+    return s, pos
+
+
+def _under_k(rng):
+    """Every position selected: no context passes k."""
+    s, pos = _chunk(rng, last=SK - 2)
+    return s, onp.maximum(pos, -1)
+
+
+@pytest.mark.parametrize("case", [_step_lanes, _chunk, _relu_zeros,
+                                  _signed_zeros_and_min, _under_k],
+                         ids=["step_lanes", "chunk_mid_tile", "relu_ties",
+                              "signed_zeros_and_min", "under_k"])
+def test_selection_kernel_is_the_plain_form_bit_for_bit(case):
+    """The kernel (interpret mode) against `impl="xla"` at a step's
+    (16, 1, W) and a chunk's (1, 512, W): the same mask, every bit, and
+    the sort's."""
+    rng = onp.random.default_rng(6)
+    scores, pos = case(rng)
+    scores = jnp.asarray(scores, jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    want = _sp.select_positions(scores, pos, SK, impl="xla")
+    got = _sp.select_positions(scores, pos, SK, impl="pallas",
+                               interpret=True)
+    assert got.shape == want.shape == scores.shape
+    assert got.dtype == want.dtype == jnp.int32
+    onp.testing.assert_array_equal(onp.asarray(got), onp.asarray(want))
+    seen = onp.asarray(got).reshape(-1, SW)
+    p = onp.asarray(pos).reshape(-1)
+    assert (seen.sum(-1) == onp.clip(p + 1, 0, SK)).all()
+    for r in range(0, len(p), 97):      # a few rows by the sort
+        onp.testing.assert_array_equal(
+            seen[r], _by_sort(onp.asarray(scores).reshape(-1, SW)[r:r + 1],
+                              p[r:r + 1], SK)[0])
+
+
+def test_selection_tiles_at_the_cells_shapes():
+    """The kernel's tiles at the sparse cell's rows of 33,792 columns: 22
+    tiles of 1,536 (a context of 4k counts 3 of them), the step's 16 lanes
+    one grid step, the chunk's 512 queries 8 steps of 64."""
+    assert _sp._select_tiles(16, 33792) == (16, 1536)
+    assert _sp._select_tiles(512, 33792) == (64, 1536)
+    assert _sp._select_tiles(8, 80) == (8, 80)
+
+
 def _sparse_args(rng, N, T, last, H=4, Hkv=2, D=16, keep=9):
     pool_k, tables = _pools(rng, N, Hkv * D)
     pool_v = jnp.asarray(rng.normal(size=pool_k.shape), jnp.float32)
     q = jnp.asarray(rng.normal(size=(N, T, H, D)), jnp.float32)
     last = jnp.asarray(last, jnp.int32)
     scores = jnp.asarray(rng.normal(size=(N, T, NBPS * BS)), jnp.float32)
-    seen = _sp.select_positions(scores, _sp._positions(last, T), keep)
+    seen = _sp.select_positions(scores, jnp.asarray(_positions(last, T),
+                                                    jnp.int32), keep)
     return q, pool_k, pool_v, tables, last, seen
 
 
@@ -171,8 +259,8 @@ def test_a_selection_with_nothing_in_the_first_run():
     position wipes what the empty ones added."""
     rng = onp.random.default_rng(4)
     q, pk, pv, tables, last, _ = _sparse_args(rng, 1, 4, [79])
-    seen = onp.zeros((1, 4, 80), bool)
-    seen[0, :, [66, 71, 76]] = True
+    seen = onp.zeros((1, 4, 80), onp.int32)
+    seen[0, :, [66, 71, 76]] = 1
     args = (q, pk, pv, tables, last, jnp.asarray(seen))
     want = _sp.paged_attention_sparse(*args, impl="xla")
     got = _sp.paged_attention_sparse(*args, impl="pallas", interpret=True)
@@ -188,3 +276,6 @@ def test_unknown_impls_are_refused():
     with pytest.raises(ValueError, match="pallas|xla"):
         _sp.paged_attention_sparse(
             *_sparse_args(onp.random.default_rng(0), 1, 1, [3]), impl="dense")
+    with pytest.raises(ValueError, match="pallas|xla"):
+        _sp.select_positions(jnp.zeros((1, 1, 8)), jnp.zeros((1, 1), jnp.int32),
+                             2, impl="dense")
