@@ -15,6 +15,10 @@ the reference analysis this build follows.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.monotonic()    # the package's import is a phase of a start
+
 __version__ = "0.1.0"
 
 from . import base
@@ -72,3 +76,5 @@ __all__ = [
     "Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
     "num_gpus", "num_tpus", "NDArray", "MXNetError",
 ]
+
+telemetry.profiler.record_setup_span("import", _T_IMPORT, _time.monotonic())

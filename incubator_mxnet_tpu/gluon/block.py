@@ -32,6 +32,7 @@ from ..base import MXNetError
 from ..engine import LazyRef
 from ..ndarray.ndarray import NDArray, raw, wrap
 from ..ops import mosaic
+from ..telemetry.profiler import setup_phased
 from .parameter import DeferredInitializationError, Parameter, ParameterDict
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "nn_block_scope", "functionalize"]
@@ -153,10 +154,12 @@ class Block:
                 ret._params[name] = p
         return ret
 
+    @setup_phased("initialize")
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
         self.collect_params().initialize(init, ctx, verbose, force_reinit)
         return self
 
+    @setup_phased("cast")
     def cast(self, dtype):
         for p in self.collect_params().values():
             p.cast(dtype)
@@ -638,6 +641,7 @@ class HybridBlock(Block):
                             remat_backward=remat_backward, **kwargs)
         return self
 
+    @setup_phased("cast")
     def cast(self, dtype):
         """Parameter dtype changes invalidate cached programs and avals."""
         super().cast(dtype)

@@ -20,6 +20,7 @@ from .. import initializer as init_mod
 from ..base import MXNetError
 from ..context import Context, current_context
 from ..ndarray.ndarray import NDArray
+from ..telemetry.profiler import setup_phased
 
 __all__ = ["Parameter", "Constant", "ParameterDict", "DeferredInitializationError"]
 
@@ -106,6 +107,7 @@ class Parameter:
         if self._grad_req != "null":
             arr.attach_grad(self._grad_req)
 
+    @setup_phased("deferred_init")
     def _finish_deferred_init(self):
         if self._deferred_init is None:
             return
@@ -147,6 +149,7 @@ class Parameter:
         self._check_initialized()
         return [self._data_nd.context]
 
+    @setup_phased("set_data")
     def set_data(self, data):
         arr = data if isinstance(data, NDArray) else NDArray(jnp.asarray(data))
         if self._data_nd is None:

@@ -412,6 +412,7 @@ class ServingEngine:
                     close() joins the server.
     """
 
+    @telemetry.profiler.setup_phased("engine")
     def __init__(self, net, *, max_batch: int = 4, block_size: int = 16,
                  max_seq_len: Optional[int] = None,
                  num_blocks: Optional[int] = None,

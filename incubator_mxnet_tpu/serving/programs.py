@@ -1159,6 +1159,7 @@ class PagedPrograms:
     `pages_per_step`, `chunk_attn`, the labels) is frozen at
     construction."""
 
+    @telemetry.profiler.setup_phased("programs")
     def __init__(self, net, *, max_batch, block_size, temperature, top_k,
                  quantized, max_seq_len=None, num_blocks=None,
                  kv_dtype=None, attn_impl=None, prefill_chunk=32,
@@ -1234,6 +1235,7 @@ class PagedPrograms:
                      and self._impl == "pallas" else "")
         self._params = None
         self._params_key = None
+        self._called = set()                # program kinds called once
         self._step = self._program(
             ("step",) + self._key, lambda: _HostPacked(
                 _build_step(self._spec, self._bs, self._nbps,
@@ -1552,11 +1554,22 @@ class PagedPrograms:
         telemetry label, its leading outputs — the donated arrays,
         replaced — bound back into ``held`` in place.  Returns the other
         outputs (still on the device; nothing is waited for)."""
+        if kind not in self._called:
+            return self._first_call(kind, n_tokens, fn, held, *args)
         out = G._timed_decode(f"serving_{kind}_{self._label}",
                               f"serving_{self._label}", n_tokens,
                               fn, *held, *args)
         held[:] = out[:len(held)]
         return out[len(held):]
+
+    def _first_call(self, kind, *call):
+        """`_call`'s first of a kind, where its program is traced, lowered
+        and compiled (or loaded from the cache): a phase of the start,
+        ``setup.first_call.<kind>``."""
+        self._called.add(kind)
+        with telemetry.profiler.setup_phase("first_call." + kind,
+                                            label=self._label):
+            return self._call(kind, *call)
 
     def prefill_chunk(self, row, toks, start, P, key, lane, n):
         """Positions ``start .. start+n-1`` of lane ``lane``'s prompt of

@@ -68,10 +68,23 @@ it.  All streams share the CLOCK_MONOTONIC family
 on), so events interleave on one axis.  `validate_chrome_trace` is the
 conformance checker both `tests/` and the CI smoke load traces with.
 
+**Set-up record.**  A process's start by phase: `setup_phase(name)`
+spans at the layer boundaries where a start does its work (gluon's
+parameter initialisation, cast and hand-over, `ServingEngine` and
+`PagedPrograms` construction, each program family's first call) and,
+from a listener on ``jax.monitoring`` registered when this module is
+imported, JAX's own ``compile.trace`` / ``compile.lower`` /
+``compile.backend`` spans with the function's name and the persistent
+cache's hits and misses, each a child of the compiling thread's open
+phase.  All on ``time.monotonic()``, in ONE process-wide bounded record
+that outlives the engines (`setup_spans()`); a phase also stands as
+``setup.<name>`` in any running ``jax.profiler`` trace.
+
 Knobs (environment):
 
-* ``MXTPU_SERVING_PROFILER=0``   kill switch — ledger records nothing,
-  no span opens (the <5 µs/step disabled path the overhead test pins);
+* ``MXTPU_SERVING_PROFILER=0``   kill switch — ledger and set-up record
+  record nothing, no span opens (the <5 µs/step disabled path the
+  overhead test pins);
 * ``MXTPU_PROFILER_HICCUP_K=K``  hiccup threshold multiplier over the
   rolling p50 (default 3.0);
 * ``MXTPU_STALLZ_RING=N``        hiccup ring size (default 64).
@@ -89,7 +102,9 @@ through them.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
+import itertools
 import json
 import operator
 import os
@@ -98,6 +113,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax import monitoring as _jax_monitoring
 from jax.profiler import TraceAnnotation
 
 from . import registry as _registry_mod
@@ -110,8 +126,10 @@ __all__ = ["EngineProfiler", "Iteration", "StepRing", "iterations",
            "validate_chrome_trace", "install_gc_hooks",
            "uninstall_gc_hooks", "gc_hooks_installed", "gc_events",
            "gc_pause_seconds", "snapshot_lock_witness",
+           "SetupRecord", "setup_phase", "setup_phased",
+           "record_setup_span", "setup_spans", "setup_spans_dropped",
            "DEFAULT_HICCUP_K", "DEFAULT_STALL_RING", "DEFAULT_STEP_RING",
-           "CAUSES", "MAX_CAPTURE_S"]
+           "DEFAULT_SETUP_SPANS", "CAUSES", "MAX_CAPTURE_S"]
 
 DEFAULT_HICCUP_K = float(os.environ.get("MXTPU_PROFILER_HICCUP_K", "3.0")
                          or 3.0)
@@ -362,6 +380,196 @@ def iterations(since: Optional[float] = None,
     """`StepRing.window` of the process's ring: every engine's
     iterations, still there after ``engine.close()``."""
     return _steps.window(since, until, engine)
+
+
+# --------------------------------------------------------------------- #
+# the set-up record: a process's start by phase (process-wide)
+# --------------------------------------------------------------------- #
+# spans a record keeps: a start of a 3B-parameter decoder records some
+# 8,200 (every eager operation's trace is one), four times that is room
+DEFAULT_SETUP_SPANS = 32768
+# JAX's compile events -> the record's span names
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend"}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
+
+
+def _setup_on() -> bool:
+    return os.environ.get("MXTPU_SERVING_PROFILER", "1") != "0"
+
+
+class SetupRecord:
+    """The spans of a process's start, in the order they closed: the
+    first ``cap`` of them (a start is what the record is for; spans
+    beyond it are only counted, `dropped`).  A span is a dict: ``name``,
+    ``t0``, ``t1`` (``time.monotonic()``), ``tid`` and ``thread`` (its
+    name), ``id`` and ``parent`` (the id of the span open in that thread
+    when it opened; None at a thread's top), and what its site adds:
+    ``fun_name``, ``hits`` and ``misses`` on JAX's compile spans,
+    ``bytes_in_use`` and ``peak_bytes_in_use`` of the first local device
+    where a top-level phase closed with the backend up.  One per process
+    (`setup_spans()` reads it); tests make their own."""
+
+    def __init__(self, cap: int = DEFAULT_SETUP_SPANS):
+        self.cap = max(1, int(cap))
+        self.dropped = 0
+        self._spans: List[dict] = []
+        self._lock = threading.Lock()       # leaf: append and copy only
+
+    def add(self, span: dict) -> None:
+        with self._lock:
+            if len(self._spans) < self.cap:
+                self._spans.append(span)
+            else:
+                self.dropped += 1
+
+    def spans(self) -> List[dict]:
+        with self._lock:
+            return [dict(s) for s in self._spans]
+
+
+_setup = SetupRecord()
+_setup_ids = itertools.count(1)
+# per thread: `.open`, the [(id, name)] of its open phases, innermost
+# last; `.hits` / `.misses`, cache events since its last backend span
+_setup_tls = threading.local()
+
+
+def _open_phases() -> list:
+    try:
+        return _setup_tls.open
+    except AttributeError:
+        _setup_tls.open = stack = []
+        return stack
+
+
+def _device_memory() -> dict:
+    """The first local device's bytes in use and peak, where the backend
+    is up (a phase must not start it) and reports them; else nothing.
+    A host call: no device sync."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return {}
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return {k: int(stats[k]) for k in ("bytes_in_use", "peak_bytes_in_use")
+            if k in stats}
+
+
+def _close_span(name: str, t0: float, t1: float, span_id: int,
+                parent: Optional[int], attrs: dict) -> None:
+    th = threading.current_thread()
+    span = dict(attrs, name=name, t0=t0, t1=t1, id=span_id, parent=parent,
+                tid=th.ident, thread=th.name)
+    if parent is None and not name.startswith("compile."):
+        span.update(_device_memory())
+    _setup.add(span)
+
+
+class _SetupPhase:
+    """One `setup_phase` block: a span of the set-up record, and
+    ``setup.<name>`` in a running ``jax.profiler`` trace."""
+
+    __slots__ = ("_name", "_attrs", "_id", "_parent", "_t0", "_span")
+
+    def __init__(self, name: str, attrs: dict):
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self):
+        stack = _open_phases()
+        self._parent = stack[-1][0] if stack else None
+        self._id = next(_setup_ids)
+        stack.append((self._id, self._name))
+        self._span = TraceAnnotation("setup." + self._name,
+                                     **self._attrs).__enter__() \
+            if _tracing() else None
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        _open_phases().pop()
+        _close_span(self._name, self._t0, t1, self._id, self._parent,
+                    self._attrs)
+        return False
+
+
+def setup_phase(name: str, **attrs):
+    """``with setup_phase(name):`` — record the block as one span of the
+    process's set-up record (`SetupRecord`) and as ``setup.<name>`` in
+    any running ``jax.profiler`` trace.  A phase does not nest in itself:
+    inside an open phase of the same name (a block's cast casting its
+    children) the block is that phase's, and nothing opens.  A span's
+    self time is its duration less what its children (phases and JAX's
+    compile spans) cover.  Nothing when ``MXTPU_SERVING_PROFILER=0``."""
+    if not _setup_on() or any(n == name for _, n in _open_phases()):
+        return _NO_PHASE
+    return _SetupPhase(name, attrs)
+
+
+def setup_phased(name: str):
+    """Decorator: the whole call is ``setup_phase(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def timed(*args, **kwargs):
+            with setup_phase(name):
+                return fn(*args, **kwargs)
+        return timed
+    return wrap
+
+
+def record_setup_span(name: str, t0: float, t1: float) -> None:
+    """Put a phase timed without `setup_phase` (the package's own import,
+    which precedes this module) into the set-up record, at the calling
+    thread's top."""
+    if _setup_on():
+        _close_span(name, t0, t1, next(_setup_ids), None, {})
+
+
+def setup_spans() -> List[dict]:
+    """The process's set-up record (`SetupRecord`), oldest close first;
+    still there after the engines close."""
+    return _setup.spans()
+
+
+def setup_spans_dropped() -> int:
+    """Spans the process's set-up record did not keep, being full."""
+    return _setup.dropped
+
+
+def _on_compile_span(event: str, start: float, end: float, **kw) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is None or not _setup_on():
+        return
+    stack = _open_phases()
+    attrs = {"fun_name": str(kw.get("fun_name", ""))}
+    if name == "compile.backend":
+        attrs["hits"] = getattr(_setup_tls, "hits", 0)
+        attrs["misses"] = getattr(_setup_tls, "misses", 0)
+        _setup_tls.hits = _setup_tls.misses = 0
+    _close_span(name, start + _WALL_TO_MONOTONIC, end + _WALL_TO_MONOTONIC,
+                next(_setup_ids), stack[-1][0] if stack else None, attrs)
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None and _setup_on():
+        setattr(_setup_tls, key, getattr(_setup_tls, key, 0) + 1)
+
+
+# JAX stamps its compile spans with time.time(): one offset, taken here,
+# puts them on the record's clock
+_WALL_TO_MONOTONIC = time.monotonic() - time.time()
+_jax_monitoring.register_event_time_span_listener(_on_compile_span)
+_jax_monitoring.register_event_listener(_on_cache_event)
 
 
 # --------------------------------------------------------------------- #
