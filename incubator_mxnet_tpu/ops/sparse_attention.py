@@ -24,8 +24,10 @@ decode step is ``N`` lanes of one query, a prefill chunk one sequence of
 
 ``impl="pallas"`` walks the pages as `ops.paged_attention`'s kernels do
 (`_run_copies`: the pools in HBM as they lie, runs of whole pages copied by
-the kernel itself, the next live run's under this run's math; a run behind
-the sequence's last position starts nothing and computes nothing) with
+the kernel itself, the next live run's under this run's math): a grid step
+is a (sequence, tile of query rows), and a loop inside it visits the
+sequence's live runs, those up to its last position, and no other
+(`_walk`; `runs_walked` reckons them on the host), with
 the kernels' ``name=`` ``index_scores`` and ``paged_attention_sparse``,
 and the selection's kernel ``select_positions`` between them, which
 writes the mask as the attention kernel reads it; ``impl="xla"`` is the
@@ -47,6 +49,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from . import mosaic
@@ -54,7 +57,7 @@ from .paged_attention import (_WINDOW_VMEM, _run_copies, _softmax_update,
                               _window_rows, pages_per_step)
 
 __all__ = ["index_scores", "index_row", "select_positions",
-           "paged_attention_sparse"]
+           "paged_attention_sparse", "runs_walked"]
 
 _MIN = float(jnp.finfo(jnp.float32).min)
 # query rows of one head a grid step of the index kernel scores at once
@@ -70,44 +73,75 @@ def index_row(dim: int) -> int:
 
 
 def _walk(tables_ref, last_ref, pools, bufs, sem, slot_ref, n, bs, zero,
-          update):
-    """The fetching schedule of both kernels, grid ``(sequence, row tile,
-    run)``: run ``j`` of a sequence is live where it holds a position at
-    or before the sequence's last (run 0 always); a live step starts the
-    copies of the next live step (this tile's next run, the next tile's
-    run 0, the next sequence's) into the other buffer, waits for its own
-    and calls ``update(slot)``.  ``zero``: buffers that must start finite
-    (values: an unfetched page's place is weighed by exactly 0.0)."""
+          update, slabs=(), outs=()):
+    """The fetching schedule of both kernels, grid ``(sequence, row
+    tile)``: a loop over the sequence's live runs of ``n`` pages (those
+    that hold a position at or before its last; run 0 always) and no
+    other.  A turn starts the copies of the next live run (this tile's
+    next run, else run 0 of the next tile or sequence) into the other
+    buffer, waits for its own and calls ``update(slot)``; the buffer a
+    run lands in alternates from run to run across grid steps
+    (``slot_ref``).  ``zero``: buffers that must start finite (values: an
+    unfetched page's place is weighed by exactly 0.0).  ``slabs``:
+    ``(src, buf)``, ``src(seq, tile, run)`` a slice of an HBM array that
+    is copied with the run's pages into ``buf[slot]``.  ``outs``: ``(dst,
+    buf, sem)``: what ``update`` left in ``buf[slot]`` is copied to
+    ``dst(seq, tile, run)`` in HBM; a turn waits for the turn before's
+    copy (a wait needs a copy's size alone), the grid step for its
+    last."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    b, i, j = (pl.program_id(k) for k in range(3))
-    seqs, tiles, nb = (pl.num_programs(k) for k in range(3))
-    run = n * bs
-    last = last_ref[b]
+    b, i = pl.program_id(0), pl.program_id(1)
+    seqs, tiles = pl.num_programs(0), pl.num_programs(1)
+    runs = tables_ref.shape[1] // n
+    live = jax.lax.clamp(1, jax.lax.div(last_ref[b], n * bs) + 1, runs)
 
-    def copies(seq, first, slot, start):
+    def copies(seq, tile, j, slot, start):
         _run_copies(pools, bufs, sem, slot, lambda e: tables_ref[seq, e],
-                    first, last_ref[seq], n, bs, start)
+                    j * n, last_ref[seq], n, bs, start)
+        for src, buf in slabs:
+            copy = pltpu.make_async_copy(src(seq, tile, j), buf.at[slot],
+                                         sem.at[slot])
+            copy.start() if start else copy.wait()
 
-    @pl.when((b == 0) & (i == 0) & (j == 0))
+    def written(j, slot):
+        return [pltpu.make_async_copy(buf.at[slot], dst(b, i, j),
+                                      osem.at[slot])
+                for dst, buf, osem in outs]
+
+    @pl.when((b == 0) & (i == 0))
     def _first():                       # no step before it to fetch it
         slot_ref[0] = 0
         for buf in zero:
             buf[...] = jnp.zeros_like(buf)
-        copies(0, 0, 0, True)
+        copies(0, 0, 0, 0, True)
 
-    @pl.when((j == 0) | (j * run <= last))
-    def _live():
+    def turn(j, _):
         slot = slot_ref[0]
-        more = (j + 1 < nb) & ((j + 1) * run <= last)
-        nxt = jnp.where(more | (i + 1 < tiles), b, b + 1)
+        more = j + 1 < live
+        nxt = jax.lax.select(more | (i + 1 < tiles), b, b + 1)
 
         @pl.when(nxt < seqs)
         def _prefetch():
-            copies(nxt, jnp.where(more, (j + 1) * n, 0), 1 - slot, True)
+            tile = jax.lax.select(
+                more, i, jax.lax.select(i + 1 < tiles, i + 1, 0))
+            copies(nxt, tile, jax.lax.select(more, j + 1, 0), 1 - slot, True)
         slot_ref[0] = 1 - slot
-        copies(b, j * n, slot, False)
+        copies(b, i, j, slot, False)
         update(slot)
+        for copy in written(j, slot):
+            copy.start()
+        if outs:                        # the turn before's, out of the other
+            @pl.when(j > 0)
+            def _out():
+                for copy in written(0, 1 - slot):
+                    copy.wait()
+
+    jax.lax.fori_loop(0, live, turn, None)
+    if outs:                            # the last turn's
+        for copy in written(0, 1 - slot_ref[0]):
+            copy.wait()
 
 
 def _pad_runs(tables, n):
@@ -116,22 +150,48 @@ def _pad_runs(tables, n):
     return jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % n)))
 
 
-def _live_run(j, last, b, run):
-    """Index map helper: run ``j`` where it is live, else the sequence's
-    last live run (a block that is not fetched or written again)."""
-    return jnp.minimum(j, last[b] // run)
+def _index_rows(T):
+    """Query rows of a chunk's ``T`` a grid step of the index kernel
+    takes: `_INDEX_ROWS` where they divide ``T``, else all of them."""
+    return _INDEX_ROWS if T % _INDEX_ROWS == 0 else T
+
+
+def runs_walked(last, T, bs, nbps, index_bytes, kv_bytes, group):
+    """``(walked, table)`` for one call each of `index_scores` and
+    `paged_attention_sparse` over sequences of ``T`` queries whose last
+    stand at ``last`` (host ints), pages of ``bs`` positions, ``nbps`` a
+    table row, rows of ``index_bytes`` and ``kv_bytes`` (K's, V's), a KV
+    head ``group`` query heads: the runs the kernels' loops visit (`_walk`:
+    a sequence's live runs, once a row tile), and the grid steps a grid
+    with a run axis over the whole table would have taken."""
+    last = np.minimum(np.asarray(last), nbps * bs - 1)
+    walked = table = 0
+    for row_bytes, kv in ((index_bytes, False), (kv_bytes, True)):
+        n = pages_per_step(bs, nbps, row_bytes)
+        runs = -(-nbps // n)
+        tiles = group * T // _window_rows(T, group, n * bs) if kv else \
+            1 if T == 1 else T // _index_rows(T)
+        live = np.clip(last // (n * bs) + 1, 1, runs)
+        walked += tiles * int(live.sum())
+        table += tiles * runs * last.size
+    return walked, table
 
 
 # --- index scores ----------------------------------------------------------- #
-def _index_kernel(tables_ref, last_ref, q_ref, w_ref, pool, o_ref, buf, sem,
-                  slot_ref, *, bs, n, fold):
-    """One grid step = one (sequence, tile of query rows, run of ``n``
-    pages).  ``q_ref`` (P, R, dim) and ``w_ref`` (P, R, 1): the step's rows
-    against the run's keys ``(n*bs, dim)``, ``sum_p relu(q[p] . k^T) *
-    w[p]`` -> (R, n*bs).  A chunk has a head a plane and a query a row; a
-    lane of a decode step has one plane, its heads the rows, which
-    ``fold`` adds up."""
+def _index_kernel(tables_ref, last_ref, q_ref, w_ref, pool, o_hbm, buf,
+                  sem, slot_ref, o_buf, o_sem, *, bs, n, fold):
+    """One grid step = one (sequence, tile of query rows), a turn of its
+    loop one live run of ``n`` pages.  ``q_ref`` (P, R, dim) and ``w_ref``
+    (P, R, 1): the step's rows against the run's keys ``(n*bs, dim)``,
+    ``sum_p relu(q[p] . k^T) * w[p]`` -> (R, n*bs) into ``o_buf[slot]``,
+    copied from there to the run's columns of the tile's rows in
+    ``o_hbm``.  A chunk has a head a plane and a query a row; a lane of a
+    decode step has one plane, its heads the rows, which ``fold`` adds
+    up."""
+    from jax.experimental import pallas as pl
+
     run = n * bs
+    out_rows = o_buf.shape[1]
 
     def update(slot):
         k = buf[slot].reshape(run, buf.shape[-1])
@@ -148,10 +208,14 @@ def _index_kernel(tables_ref, last_ref, q_ref, w_ref, pool, o_ref, buf, sem,
             acc = s if acc is None else acc + s
         if fold:
             acc = jnp.sum(acc, axis=0, keepdims=True)
-        o_ref[...] = acc
+        o_buf[slot] = acc
+
+    def dst(seq, tile, j):
+        return o_hbm.at[seq, pl.ds(tile * out_rows, out_rows),
+                        pl.ds(pl.multiple_of(j * run, run), run)]
 
     _walk(tables_ref, last_ref, (pool,), (buf,), sem, slot_ref, n, bs, (),
-          update)
+          update, outs=((dst, o_buf, o_sem),))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -172,7 +236,7 @@ def _index_core(qi, w, pool, tables, last, interpret):
         rows, planes, q, wc = Hi, 1, qi.reshape(N, 1, Hi, Di), \
             w.reshape(N, 1, Hi, 1)
     else:                   # a head a plane, the queries its rows
-        rows = _INDEX_ROWS if T % _INDEX_ROWS == 0 else T
+        rows = _index_rows(T)
         planes, q, wc = Hi, qi.transpose(0, 2, 1, 3), \
             w.transpose(0, 2, 1)[..., None]
     out_rows = 1 if fold else rows
@@ -180,19 +244,19 @@ def _index_core(qi, w, pool, tables, last, interpret):
         functools.partial(_index_kernel, bs=bs, n=n, fold=fold),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(N, 1 if fold else T // rows, tables.shape[1] // n),
+            grid=(N, 1 if fold else T // rows),
             in_specs=[
                 pl.BlockSpec((None, planes, rows, Di),
-                             lambda b, i, j, *_: (b, 0, i, 0)),
+                             lambda b, i, *_: (b, 0, i, 0)),
                 pl.BlockSpec((None, planes, rows, 1),
-                             lambda b, i, j, *_: (b, 0, i, 0)),
+                             lambda b, i, *_: (b, 0, i, 0)),
                 pl.BlockSpec(memory_space=pltpu.HBM)],
-            out_specs=pl.BlockSpec(
-                (None, out_rows, run),
-                lambda b, i, j, t, last: (b, i, _live_run(j, last, b, run))),
+            out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
             scratch_shapes=[pltpu.VMEM((2, n) + pool.shape[1:], pool.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SMEM((1,), jnp.int32)]),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((2, out_rows, run), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((N, T, tables.shape[1] * bs),
                                        jnp.float32),
         interpret=interpret, name="index_scores",
@@ -447,33 +511,31 @@ def select_positions(scores, pos, k: int, *, impl: Optional[str] = None,
 
 
 # --- attention over the selected positions --------------------------------- #
-def _sparse_kernel(tables_ref, last_ref, q_ref, seen_ref, pool_k, pool_v,
+def _sparse_kernel(tables_ref, last_ref, q_ref, seen_hbm, pool_k, pool_v,
                    o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref,
-                   *, bs, n):
-    """One grid step = one (sequence, tile of query rows, run of ``n``
-    pages), every KV head of the run, as
+                   seen_buf, *, bs, n):
+    """One grid step = one (sequence, tile of query rows), every KV head,
+    a turn of its loop one live run of ``n`` pages, as
     `ops.paged_attention._window_kernel` with a sequence axis before it
-    and the selection in the causal mask's place: ``seen_ref`` holds the
-    tile's queries' mask over the run (a row a query; a tile of whole
-    heads repeats it a head), and a slot is weighed only where it is set.
-    A row whose run holds none of its positions adds weights that the
-    first run which does wipes out (its rescaling factor is exactly 0.0),
-    and every query has selected a position."""
+    and the selection in the causal mask's place: ``seen_buf[slot]``
+    holds the tile's queries' mask over the run, copied with its pages
+    (a row a query; a tile of whole heads repeats it a head), and a slot
+    is weighed only where it is set.  A row whose run holds none of its
+    positions adds weights that the first run which does wipes out (its
+    rescaling factor is exactly 0.0), and every query has selected a
+    position."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
     kv_heads, rows, dk = q_ref.shape
     dv = o_ref.shape[-1]
+    T, mask_rows = seen_hbm.shape[1], seen_buf.shape[1]
     run = n * bs
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _MIN)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _MIN)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
     def update(slot):
-        seen = seen_ref[...]                            # (min(rows, T), run)
+        seen = seen_buf[slot]                           # (min(rows, T), run)
         if seen.shape[0] == 1:
             seen = jnp.broadcast_to(seen, (rows, run))
         elif rows > seen.shape[0]:
@@ -493,12 +555,14 @@ def _sparse_kernel(tables_ref, last_ref, q_ref, seen_ref, pool_k, pool_v,
             _softmax_update(s, seen, head(v_buf, h, dv).astype(jnp.float32),
                             m_ref.at[h], l_ref.at[h], acc_ref.at[h])
 
-    _walk(tables_ref, last_ref, (pool_k, pool_v), (k_buf, v_buf), sem,
-          slot_ref, n, bs, (v_buf,), update)
+    def src(seq, tile, j):              # the tile's queries' mask rows
+        r0 = 0 if rows % T == 0 else jax.lax.rem(tile * rows, T)
+        return seen_hbm.at[seq, pl.ds(r0, mask_rows),
+                           pl.ds(pl.multiple_of(j * run, run), run)]
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    _walk(tables_ref, last_ref, (pool_k, pool_v), (k_buf, v_buf), sem,
+          slot_ref, n, bs, (v_buf,), update, slabs=((src, seen_buf),))
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -527,20 +591,16 @@ def _sparse_core(q, pool_k, pool_v, tables, last, seen, interpret):
 
     def mine(d):
         return pl.BlockSpec((None, Hkv, rows, d),
-                            lambda b, i, j, *_: (b, 0, i, 0))
+                            lambda b, i, *_: (b, 0, i, 0))
 
     out = pl.pallas_call(
         functools.partial(_sparse_kernel, bs=bs, n=n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(N, G * T // rows, tables.shape[1] // n),
+            grid=(N, G * T // rows),
             in_specs=[
                 mine(D),
-                pl.BlockSpec(
-                    (None, mask_rows, run),
-                    lambda b, i, j, t, last: (
-                        b, (i * rows % T) // mask_rows,
-                        _live_run(j, last, b, run))),
+                pl.BlockSpec(memory_space=pltpu.HBM),
                 pl.BlockSpec(memory_space=pltpu.HBM),
                 pl.BlockSpec(memory_space=pltpu.HBM)],
             out_specs=mine(D),
@@ -549,7 +609,8 @@ def _sparse_core(q, pool_k, pool_v, tables, last, seen, interpret):
             + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32),
                pltpu.VMEM((Hkv, rows, D), jnp.float32),
                pltpu.VMEM((Hkv, rows, 1), jnp.float32),
-               pltpu.VMEM((Hkv, rows, 1), jnp.float32)]),
+               pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+               pltpu.VMEM((2, mask_rows, run), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((N, Hkv, G * T, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WINDOW_VMEM + 16 * 1024 * 1024),

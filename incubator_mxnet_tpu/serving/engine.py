@@ -856,11 +856,16 @@ class ServingEngine:
         hiccups with their per-cause ledgers."""
         return self._prof.stallz()
 
+    def _sparse_runs(self) -> dict:
+        walked, table = self._programs.sparse_runs
+        return {"sparse_runs_walked": walked, "sparse_runs_table": table}
+
     def varz_config(self) -> dict:
         """Build/config section for `/varz` — which engine
         configuration is actually running (ops triage can't tell from
         metrics alone).  Values are frozen at construction except the
-        profiler toggle and MXTPU_* env knobs, read live."""
+        profiler toggle, MXTPU_* env knobs and the sparse kernels' run
+        counts, read live."""
         with self._lock:    # spec_ewma is written under the tick lock
             spec = self._spec_section()
         return {
@@ -875,6 +880,10 @@ class ServingEngine:
             "chunk_attn": self._programs.chunk_attn,
             # how a layer with an index selects: "kernel", "xla" or "none"
             "index_select": self._programs.index_select,
+            # runs the sparse kernels' loops visited (a sequence's live
+            # runs, a row tile each) and the grid steps a grid over every
+            # run of the table would have taken (0, 0: no kernel walks)
+            **self._sparse_runs(),
             "max_batch": self._B,
             "block_size": self._bs,
             "max_seq_len": self._msl,
@@ -1048,6 +1057,7 @@ class ServingEngine:
                 "window_pool_bytes": self.window_pool_bytes,
                 "index_pool_bytes": self.index_pool_bytes,
                 "state_bytes": self.state_bytes,
+                **self._sparse_runs(),
                 "prefix_cache": {
                     "hits": self._stats["prefix_hits"],
                     "misses": self._stats["prefix_misses"],

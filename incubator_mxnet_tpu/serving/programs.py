@@ -73,7 +73,7 @@ pools and per-lane state enter as arguments.
 """
 from __future__ import annotations
 
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -89,7 +89,8 @@ from ..ops.paged_attention import (default_impl, paged_attention,
 from ..ops.moe_experts import routed_experts
 from ..ops.selective_scan import selective_scan
 from ..ops.sparse_attention import (index_row, index_scores,
-                                    paged_attention_sparse, select_positions)
+                                    paged_attention_sparse, runs_walked,
+                                    select_positions)
 
 __all__ = ["PagedPrograms"]
 
@@ -1414,6 +1415,17 @@ class PagedPrograms:
         # (no index), static for an engine
         self.index_select = "none" if spec.index is None else \
             "kernel" if self._impl == "pallas" else "xla"
+        # what `runs_walked` needs of each indexed layer's shapes, with
+        # how many layers share them (none where no kernel walks), and the
+        # runs the two sparse kernels' loops visited, of the grid steps a
+        # grid over every run of the table would have taken: a tuple the
+        # scheduler's thread alone replaces, which any thread may read
+        isz = jnp.dtype(dt).itemsize
+        self._walks = Counter(
+            (index_row(a.index.dim) * isz, a.kv_heads * spec.head_dim * isz,
+             spec.heads // a.kv_heads)
+            for a in spec.attn if a.index and self._impl == "pallas")
+        self.sparse_runs = (0, 0)
         # block-table entries a grid step of the single-query kernel
         # covers (the kernel's own rule, from these shapes); 0 on the
         # dense path, which runs no kernel
@@ -1571,6 +1583,16 @@ class PagedPrograms:
                                             label=self._label):
             return self._call(kind, *call)
 
+    def _count_walk(self, last, T):
+        """Add a call's runs to `sparse_runs`, from the positions the
+        host hands over (no sync)."""
+        if self._walks:
+            walked, table = self.sparse_runs
+            for shapes, layers in self._walks.items():
+                w, t = runs_walked(last, T, self._bs, self._nbps, *shapes)
+                walked, table = walked + layers * w, table + layers * t
+            self.sparse_runs = (walked, table)
+
     def prefill_chunk(self, row, toks, start, P, key, lane, n):
         """Positions ``start .. start+n-1`` of lane ``lane``'s prompt of
         ``P`` tokens (``toks`` the chunk-wide window, ``row`` the lane's
@@ -1579,6 +1601,7 @@ class PagedPrograms:
         prompt.  Returns the first-token pick, meaningful on a prompt's
         final chunk."""
         start, P = np.int32(start), np.int32(P)
+        self._count_walk([start + self._chunk - 1], self._chunk)
         first, = self._call("prefill_chunk", n, self._prefill_chunk,
                             self._kv, row, toks, start, P, key,
                             np.int32(lane), self._params)
@@ -1593,6 +1616,7 @@ class PagedPrograms:
         A lane's input token is ``toks``' where ``fresh``, else what the
         step before returned for it, taken on the device: the caller need
         not have read that yet."""
+        self._count_walk(pos, 1)
         nxt, = self._call("step", n_live, self._step, self._kv, self._last,
                           tables, toks, fresh, pos, active, keys,
                           self._params)

@@ -346,6 +346,56 @@ def test_pages_a_grid_step_at_the_cells_shapes():
     assert _paged.pages_per_step(_G_BS, _G_NBPS, _G_D * 2) == 16
 
 
+def _pallas_grid(jaxpr):
+    """The grid of the one `pallas_call` in a trace, however deep."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return tuple(eqn.params["grid_mapping"].grid)
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            if inner is not None:
+                got = _pallas_grid(getattr(inner, "jaxpr", inner))
+                if got is not None:
+                    return got
+    return None
+
+
+@pytest.mark.parametrize("kernel, seqs, queries, grid", [
+    ("index_scores", _K_B, 1, (16, 1)), ("index_scores", 1, _K_CHUNK, (1, 2)),
+    ("paged_attention_sparse", _K_B, 1, (16, 1)),
+    ("paged_attention_sparse", 1, _K_CHUNK, (1, 4))],
+    ids=["index_step", "index_chunk", "sparse_step", "sparse_chunk"])
+def test_a_sparse_grid_step_is_a_sequence_and_a_row_tile(kernel, seqs,
+                                                         queries, grid):
+    """At the sparse cell's shapes the two walking kernels' grids have no
+    run axis: a grid step is a (sequence, row tile), its 33 runs of 16
+    pages a loop over the live ones (`_walk`); the step's 16 lanes one
+    tile each, the chunk's 512 queries 2 tiles of 256 in the index and 4
+    of 1,024 rows (two heads' queries) in the attention.  The host's
+    reckoning `runs_walked` takes the same tiles: at full contexts every
+    run of every tile is walked."""
+    S = jax.ShapeDtypeStruct
+    if kernel == "index_scores":
+        fn, avals = _sparse._index_core, (
+            S((seqs, queries, _K_HI, _K_DI), bf16),
+            S((seqs, queries, _K_HI), f32), S((_K_NB, _K_BS, _K_ROW), bf16),
+            S((seqs, _K_NBPS), i32), S((seqs,), i32))
+    else:
+        pool = S((_K_NB, _K_BS, _K_HKV * _K_D), bf16)
+        fn, avals = _sparse._sparse_core, (
+            S((seqs, queries, _K_HQ, _K_D), bf16), pool, pool,
+            S((seqs, _K_NBPS), i32), S((seqs,), i32),
+            S((seqs, queries, _K_NBPS * _K_BS), i32))
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, interpret=False))(*avals)
+    assert _pallas_grid(jaxpr.jaxpr) == grid
+    runs = _K_NBPS // 16
+    walked, table = _sparse.runs_walked(
+        [_K_NBPS * _K_BS - 1] * seqs, queries, _K_BS, _K_NBPS, _K_ROW * 2,
+        _K_HKV * _K_D * 2, _K_HQ // _K_HKV)
+    tiles = {1: 1 + 1, _K_CHUNK: 2 + 4}[queries]
+    assert walked == table == tiles * runs * seqs
+
+
 # --- the served programs: one layout for the KV pool -------------------- #
 # the benchmark cell's engine (gpt2-medium.serve-batch) at two layers
 _CELL_B, _CELL_CHUNK, _CELL_L = 32, 32, 2

@@ -418,6 +418,30 @@ def test_the_index_counts_ride_with_the_tokens(seeded):
     assert "index_positions_scored" in mine[0].as_dict()
 
 
+def test_the_runs_the_sparse_kernels_walk_are_counted(seeded):
+    """`sparse_runs_walked` and `sparse_runs_table` in `stats()` and
+    `/varz`: over a lane's chunks and steps, both kernels of each of the 3
+    layers visit a sequence's live runs of 16 pages (64 positions; a
+    table of 32 entries is two runs) once, where a grid over every run
+    took two steps a call."""
+    net, _, _ = seeded
+    P, out = 70, 5
+    with ServingEngine(net, attn_impl="pallas",
+                       **dict(ENGINE, max_batch=1)) as eng:
+        eng.submit(_prompts((P,), seed=9)[0], out).result(timeout=900)
+        assert eng.drain(timeout=600)
+        st, v = eng.stats(), eng.varz_config()
+    # the chunks' last positions (the program's width, 16), then the steps'
+    lasts = [s + 15 for s in range(0, P, 16)] + \
+        [P + k for k in range(st["steps"])]
+    assert st["steps"] == out - 1
+    live = sum(min(p // 64 + 1, 2) for p in lasts)
+    assert (st["sparse_runs_walked"], st["sparse_runs_table"]) == (
+        3 * 2 * live, 3 * 2 * 2 * len(lasts))
+    assert (v["sparse_runs_walked"], v["sparse_runs_table"]) == (
+        st["sparse_runs_walked"], st["sparse_runs_table"])
+
+
 def test_a_count_past_int32_is_carried_in_two_words():
     hi, lo = jnp.int32(0), jnp.int32(0)
     total = 0
@@ -468,6 +492,9 @@ def test_an_engine_without_an_index_reports_none():
         v = eng.varz_config()
         assert (v["index_pool_bytes"], v["index_topk"]) == (0, 0)
         assert v["index_select"] == "none"
+        assert (v["sparse_runs_walked"], v["sparse_runs_table"]) == (0, 0)
+        assert eng.stats()["sparse_runs_walked"] == 0
+        assert eng.stats()["sparse_runs_table"] == 0
         assert eng.index_pool_bytes == 0 and eng._programs.index_pools == ()
         name = eng._name
     records, _ = telemetry.profiler.iterations(t0, None)
